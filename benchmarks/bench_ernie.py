@@ -25,7 +25,7 @@ B, L, STEPS = 32, 128, 30
 def main():
     import jax
 
-    on_tpu = jax.devices()[0].platform != "cpu"
+    on_tpu = jax.devices()[0].platform == "tpu"
     paddle.seed(0)
     cfg = ErnieConfig.ernie3_medium() if on_tpu else ErnieConfig.tiny()
     model = ErnieForSequenceClassification(cfg, num_classes=2)

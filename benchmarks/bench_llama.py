@@ -58,8 +58,13 @@ def main() -> None:
     import paddle_tpu as paddle
     from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 
-    on_tpu = jax.devices()[0].platform != "cpu"
-    peak = 197e12 if on_tpu else 1e12
+    from paddle_tpu.observability import cost
+
+    device = paddle.device.describe()
+    on_tpu = device["platform"] == "tpu"
+    # the one peaks table (an unknown chip raises); a CPU run has no peak,
+    # carries no MFU and says cpu in its benchmark name
+    peak = cost.device_peaks(device["kind"])["peak_flops"] if on_tpu else None
     paddle.seed(0)
     cfg = LlamaConfig(vocab_size=32000, hidden_size=args.hidden,
                       intermediate_size=args.inter,
@@ -116,15 +121,17 @@ def main() -> None:
     np.asarray(loss._data)
     dt = time.perf_counter() - t0
     tok = args.batch * args.seq * steps_run / dt
-    mfu = tok * model.flops_per_token(args.seq) / peak
+    mfu = round(tok * model.flops_per_token(args.seq) / peak, 4) \
+        if peak else None
     print(json.dumps({
-        "benchmark": "llama_train", "tokens_per_sec": round(tok, 1),
-        "mfu": round(mfu, 4), "params": model.num_params(),
+        "benchmark": "llama_train" if on_tpu else "llama_train_cpu_smoke",
+        "tokens_per_sec": round(tok, 1),
+        "mfu": mfu, "params": model.num_params(),
         "hidden": args.hidden, "layers": args.layers, "batch": args.batch,
         "seq": args.seq, "scan_k": args.scan_k, "state": args.state,
         "scan_layers": args.scan_layers, "recompute": args.recompute,
         "final_loss": round(float(np.asarray(loss._data).reshape(-1)[-1]), 4),
-        "device": str(jax.devices()[0]),
+        "device": device,
     }))
 
 

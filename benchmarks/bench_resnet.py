@@ -30,7 +30,7 @@ def main() -> None:
     import paddle_tpu as paddle
     import paddle_tpu.nn as nn
 
-    on_tpu = jax.devices()[0].platform != "cpu"
+    on_tpu = jax.devices()[0].platform == "tpu"
     paddle.seed(0)
     model = paddle.vision.models.resnet50(num_classes=1000)
     opt = paddle.optimizer.Momentum(learning_rate=0.1, momentum=0.9,

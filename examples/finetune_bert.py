@@ -10,7 +10,6 @@ import numpy as np
 
 import paddle_tpu as paddle
 
-paddle.device.force_platform_from_env()
 
 
 def main():

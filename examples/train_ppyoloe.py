@@ -10,7 +10,6 @@ import numpy as np
 
 import paddle_tpu as paddle
 
-paddle.device.force_platform_from_env()
 from paddle_tpu.models.ppyoloe import PPYOLOE, PPYOLOEConfig
 
 

@@ -8,7 +8,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import paddle_tpu as paddle
 
-paddle.device.force_platform_from_env()
 import paddle_tpu.nn as nn
 import paddle_tpu.vision as vision
 

@@ -19,30 +19,57 @@ from . import flags as _flags_mod
 from .flags import get_flags, set_flags, define_flag  # noqa: F401
 
 
-def _wire_compile_cache() -> None:
-    """ROADMAP 3b / ISSUE 11 satellite: point JAX's persistent compilation
-    cache at ``PADDLE_TPU_COMPILE_CACHE_DIR`` so fleet rollouts and
-    crash-restarts warm-start — the 1.59B bench program costs ~22 s to
-    compile cold; a warm process deserializes it from disk in seconds
-    (``bench.py`` pins cold vs warm). Unset ⇒ untouched (tests wire their
-    own cache dir). Thresholds drop to zero so even small per-op/step
-    programs round-trip — the cache is content-addressed, so sharing a
-    directory across configs is safe."""
+def _compile_cache_dir(environ, platforms):
+    """The directory this package points JAX's persistent compilation cache
+    at, or ``None`` when it sets none.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: the cache was placed from outside,
+    jax reads the variable itself and no code sets a directory. Unset: a
+    fixed path inside the checkout — the path is part of the cache key, so
+    it is never a tempdir, a pid or a timestamp — except when the process
+    is pinned to the CPU backend (``JAX_PLATFORMS=cpu``, the test tier):
+    CPU executables served from a cache shared by heterogeneous processes
+    were found unsound (tests/conftest.py), and a CPU compile is cheap."""
     import os as _os
 
-    d = _os.environ.get("PADDLE_TPU_COMPILE_CACHE_DIR")
-    if not d:
-        return
+    if environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    if (platforms or "").strip().lower() == "cpu":
+        return None
+    return _os.path.join(
+        _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+        ".jax_cache")
+
+
+def compile_cache_dir():
+    """The compile-cache directory in effect for this process — the
+    environment's, else the one this package set — or ``None`` (benches
+    report warm compiles, and ``chip_smoke.py`` counts entries, only where
+    there is one)."""
+    import os as _os
+
     import jax as _jax
 
-    _os.makedirs(_os.path.expanduser(d), exist_ok=True)
-    for key, val in (("jax_compilation_cache_dir", _os.path.expanduser(d)),
-                     ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                     ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            _jax.config.update(key, val)
-        except Exception:  # older jax without the knob: best effort
-            pass
+    return _os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
+        _compile_cache_dir(_os.environ, _jax.config.jax_platforms)
+
+
+def _wire_compile_cache() -> None:
+    """The one place a compile-cache directory is set (fleet workers,
+    benches and tests inherit the environment variable instead). Where a
+    cache is in effect the size/time thresholds drop to zero so small
+    per-op programs round-trip too — the cache is content-addressed, so
+    sharing a directory across configurations is safe."""
+    import os as _os
+
+    import jax as _jax
+
+    d = _compile_cache_dir(_os.environ, _jax.config.jax_platforms)
+    if d is not None:
+        _jax.config.update("jax_compilation_cache_dir", d)
+    if compile_cache_dir():
+        _jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        _jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 
 _wire_compile_cache()

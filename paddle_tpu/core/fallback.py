@@ -58,7 +58,8 @@ from .. import device as _device
 from .. import observability as _obs
 
 __all__ = [
-    "BackendFallbackWarning", "DEFAULT_DENYLIST", "XlaRuntimeError",
+    "BackendFallbackWarning", "DEFAULT_DENYLIST", "KERNEL_OPS",
+    "XlaRuntimeError",
     "enabled", "configure", "reset", "fallback_ops", "should_fallback",
     "backend_token", "is_lowering_failure", "note_fallback", "run_cpu",
     "to_cpu", "from_cpu", "wrap_vjp",
@@ -78,6 +79,11 @@ class BackendFallbackWarning(RuntimeWarning):
 # #1): eig has no TPU lowering at all, complex sgn hits an UNIMPLEMENTED
 # elementwise lowering, hfft2's C2R path is rejected by the TPU fft rule.
 DEFAULT_DENYLIST = frozenset({"eig", "sgn", "hfft2"})
+
+# Ops whose pure fn is a Pallas kernel. A kernel the compiler refuses is a
+# defect to surface, never a reason to leave the chip: these never degrade.
+KERNEL_OPS = frozenset({"flash_attention", "flash_attention_dropout",
+                        "flash_attn_unpadded", "paged_attention_decode"})
 
 
 def _env_mode() -> str:
@@ -175,8 +181,10 @@ _MSG_MARKERS = ("unimplemented", "not implemented", "unsupported",
 _MSG_EXCLUDE = ("resource_exhausted", "out of memory")
 
 
-def is_lowering_failure(exc: BaseException) -> bool:
+def is_lowering_failure(exc: BaseException, op_name: str = "") -> bool:
     """Classify one dispatch failure: may this op degrade to CPU?"""
+    if op_name in KERNEL_OPS:
+        return False
     if isinstance(exc, NotImplementedError):
         return True
     if isinstance(exc, XlaRuntimeError):

@@ -13,6 +13,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+import numpy as np
 
 from . import tracing as _tracing
 from .tensor import Tensor, register_state_tensor, _is_tracer
@@ -20,9 +21,19 @@ from .tensor import Tensor, register_state_tensor, _is_tracer
 __all__ = ["Generator", "default_generator", "seed", "get_rng_state", "set_rng_state"]
 
 
+def _host_key(seed_val: int) -> np.ndarray:
+    """``jax.random.PRNGKey(seed_val)`` as a host array, computed without
+    jax: the default generator is built at import, and an import must not
+    initialise a backend (the chip belongs to one process — a launcher or
+    fleet-supervisor parent that imports the package has to stay off it).
+    Raw threefry2x32 key of a 32-bit seed: ``[0, seed mod 2**32]``;
+    ``tests/test_bring_up.py`` pins it against jax."""
+    return np.array([0, int(seed_val) & 0xFFFFFFFF], dtype=np.uint32)
+
+
 class Generator:
     def __init__(self, seed_val: int = 0, name: Optional[str] = None):
-        self._key = Tensor(jax.random.PRNGKey(seed_val), stop_gradient=True,
+        self._key = Tensor(_host_key(seed_val), stop_gradient=True,
                            name=name or "rng_state")
         self._key.persistable = True
         register_state_tensor(self._key)

@@ -330,8 +330,10 @@ class CapturedStep:
         except Exception as e:
             from ..jit.to_static import _is_trace_failure
             if not _is_trace_failure(e):
-                raise  # runtime failure (XLA error, device fault): surface —
-                #        the supervisor's restore-last-good owns recovery
+                raise  # lowering failure (a refused kernel arrives as
+                #        LoweringError) or runtime failure (XLA error,
+                #        device fault): surface — the supervisor's
+                #        restore-last-good owns recovery
             # the step cannot trace (tensor-dependent python control flow,
             # host read mid-step): memoize and stay eager for this signature
             # — trace-time tensor state was restored by the functionalizer,
@@ -491,6 +493,17 @@ class CapturedStep:
         return out
 
     # -- observability -------------------------------------------------------
+    def compiled_text(self) -> str:
+        """XLA-compiled HLO of the most recently used program — the
+        ``StaticFunction.compiled_text`` debug surface (set
+        ``FLAGS_to_static_capture_lowered`` before the call): what
+        ``chip_smoke.py`` reads to prove the Pallas kernels are IN the
+        step the chip ran."""
+        if not self._programs:
+            raise RuntimeError("no captured program: the step has not run, "
+                               "or every call bypassed capture")
+        return next(reversed(self._programs.values())).compiled_text()
+
     def _set_donated_bytes(self) -> None:
         if not self._donate:
             return

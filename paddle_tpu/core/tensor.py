@@ -31,10 +31,7 @@ from . import lazy as _lazy
 from . import tracing as _tracing
 from .autograd import GradNode, backward as _backward
 
-try:
-    from jax.core import Tracer as _Tracer
-except Exception:  # pragma: no cover
-    from jax._src.core import Tracer as _Tracer
+from jax.core import Tracer as _Tracer
 
 __all__ = ["Tensor", "Parameter", "to_tensor", "apply",
            "register_tensor_method", "TraceBreakError"]
@@ -144,8 +141,7 @@ class Tensor:
         if d is None or _is_tracer(self._data):
             return _device.current_place()
         dev = next(iter(self._data.devices()))
-        kind = "cpu" if dev.platform == "cpu" else "tpu"
-        return _device.Place(kind, dev.id)
+        return _device.Place(dev.platform, dev.id)
 
     @property
     def is_leaf(self) -> bool:
@@ -598,7 +594,8 @@ def _dispatch_execute(op_name: str, f: Callable, arrays, needs_grad: bool,
             outs, vjp_fn = f(*arrays), None
         _fault_point("dispatch.execute")
     except Exception as e:
-        if not (_fallback.enabled() and _fallback.is_lowering_failure(e)
+        if not (_fallback.enabled()
+                and _fallback.is_lowering_failure(e, op_name)
                 and _concrete_dispatch(ts, arrays)):
             raise
         return _fallback.run_cpu(op_name, f, arrays, needs_grad, exc=e)

@@ -69,11 +69,10 @@ def pipelined_forward(stage_fn: Callable, stacked_params, micro_inputs,
     python/paddle/distributed/fleet/meta_parallel/pipeline_parallel.py):
     device d holds model chunks {d, d+S, ...}; every tick it runs its V
     chunks and every chunk output hops one device, T = M + S*V - 1 ticks.
-    Measured caveat (benchmarks/RESULTS.md "VPP refutation"): in the
-    compiled SPMD scan this is ~1.9x SLOWER than GPipe-scan at V=2 — VPP's
-    win exists only where the bubble is idle time a runtime can fill, and
-    a compiled scan has no idle. The option exists for schedule parity and
-    for re-measurement on future hardware/runtimes."""
+    Caveat: VPP's win exists only where the bubble is idle time a runtime
+    can fill, and a compiled SPMD scan has no idle (speed against
+    GPipe-scan: not measured on today's code). The option exists for
+    schedule parity."""
     S = int(mesh.shape[axis])
     M = micro_inputs.shape[0]
     V = max(int(v_chunks), 1)

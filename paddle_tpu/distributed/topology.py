@@ -238,6 +238,14 @@ def get_hybrid_communicate_group() -> Optional[HybridCommunicateGroup]:
     return _hcg
 
 
+def multi_device_mesh() -> Optional[Mesh]:
+    """The fleet hybrid mesh when it spans more than one device, else
+    ``None`` — what the Pallas call sites ask: a Mosaic kernel cannot be
+    partitioned automatically, so under such a mesh it runs per shard
+    (flash attention) or yields to an XLA formulation (the q8 update)."""
+    return _hcg.mesh if _hcg is not None and _hcg.mesh.size > 1 else None
+
+
 def _ensure_default_topology() -> None:
     """Default 1D dp mesh over all local devices (init_parallel_env path)."""
     global _default_mesh

@@ -333,16 +333,8 @@ def _worker_loop(dataset, index_queue, result_queue, collate_fn, init_fn,
     python/paddle/io/dataloader/worker.py _worker_loop)."""
     global _worker_info
     try:
-        # keep jax (and especially any TPU plugin) OUT of worker processes:
-        # pin cpu before anything can query a backend
-        import os
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        try:
-            from .. import device as _device
-            _device.force_platform("cpu")
-        except Exception:
-            pass  # device module import raced/failed in the fresh worker:
-            #       the JAX_PLATFORMS env pin above already keeps jax on cpu
+        # the parent spawned us with JAX_PLATFORMS=cpu in the environment
+        # (_MultiProcessIter.__init__): a worker never touches the chip
         _worker_info = _WorkerInfo(worker_id, num_workers, dataset)
         if init_fn is not None:
             init_fn(worker_id)

@@ -85,11 +85,7 @@ def save(layer, path: str, input_spec: Optional[List[Any]] = None, **configs) ->
                 input_names.append(getattr(s, "name", None) or f"x{i}")
                 input_specs.append((tuple(s._data.shape), str(s._data.dtype)))
     if exported_bytes is not None:
-        try:
-            output_names = [f"out{i}" for i in range(len(exp.out_avals))]
-        except Exception:
-            pass  # exported object lacks out_avals (older jax_export):
-            #       artifact ships without output names, loaders tolerate it
+        output_names = [f"out{i}" for i in range(len(exp.out_avals))]
 
     from ..framework.artifact import write_artifact
     write_artifact(path + ".pdmodel", {

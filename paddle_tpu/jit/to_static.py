@@ -19,7 +19,7 @@ from ..core.tracing import (TraceState, pop_trace_state, push_trace_state,
                             trace_state)
 
 __all__ = ["StaticFunction", "to_static", "not_to_static", "ignore_module",
-           "register_pretrace_hook", "TraceBreakError"]
+           "register_pretrace_hook", "TraceBreakError", "LoweringError"]
 
 _ENABLED = True
 
@@ -51,18 +51,25 @@ def _lower_spec(a):
     sh = getattr(a, "sharding", None)
     if not isinstance(sh, jax.sharding.NamedSharding):
         sh = None
-    try:
-        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
-    except TypeError:
-        return jax.ShapeDtypeStruct(a.shape, a.dtype)
+    return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
+
+
+class LoweringError(RuntimeError):
+    """The program traced, and then lowering it for the device failed —
+    a Pallas kernel the compiler refused, a primitive with no lowering
+    rule. Never a graph break: demoting such a step to the eager tier
+    would re-run it op by op, where the CPU fallback could pick the op up
+    and the run would end 0 having left the chip. The compiler's own error
+    is the ``__cause__``."""
 
 
 def _is_trace_failure(e: BaseException) -> bool:
-    """Graph breaks are TRACE/LOWERING failures only (tensor-dependent Python
+    """Graph breaks are TRACE failures only (tensor-dependent Python
     control flow, tracer leaks, ops without abstract eval) — the reference
-    SOT's fallback contract. Runtime failures (XLA execution errors, device
-    OOM, asserts that only fire under jit) must NOT memoize a permanent
-    eager fallback: they re-raise so the user sees them."""
+    SOT's fallback contract. Failures after the trace completed — lowering
+    (:class:`LoweringError`), XLA execution errors, device OOM, asserts
+    that only fire under jit — must NOT memoize a permanent eager
+    fallback: they re-raise so the user sees them."""
     return isinstance(e, (jax.errors.JAXTypeError,
                           jax.errors.NonConcreteBooleanIndexError,
                           NotImplementedError, TraceBreakError))
@@ -377,7 +384,16 @@ class StaticFunction:
                     state_arrays[i] = jnp.copy(a)
                 else:
                     seen.add(id(a))
-        out_arrays, new_state, mut_vals = jitted(state_arrays, arg_arrays)
+        holder["traced"] = False
+        try:
+            out_arrays, new_state, mut_vals = jitted(state_arrays, arg_arrays)
+        except Exception as e:
+            if holder["traced"] and _is_trace_failure(e):
+                # pure_fn ran to its end, so this came out of lowering
+                raise LoweringError(
+                    f"{getattr(self._fn, '__name__', 'program')} traced but "
+                    f"did not lower ({type(e).__name__}: {e})") from e
+            raise
         for t, arr in zip(state_tensors, new_state):
             t._data = arr
         self._rebind(holder, mut_vals, leaves)
@@ -465,6 +481,7 @@ class StaticFunction:
                         spec.append((kind, ref))
                     mut_vals.append(val)
                 holder["spec"] = spec
+                holder["traced"] = True
                 return out_arrays, new_state, mut_vals
             finally:
                 pop_trace_state()
@@ -538,6 +555,7 @@ class StaticFunction:
                                              list(arg_arrays),
                                              length=self._iters)
             mut_vals = [None] * len(holder["spec"] or [])
+            holder["traced"] = True
             return outs, final_state, mut_vals
 
         donate = (0,) if self._donate else ()

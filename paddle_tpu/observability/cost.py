@@ -62,7 +62,8 @@ _log = logging.getLogger("paddle_tpu.observability.cost")
 
 __all__ = [
     "ProgramCostRecord", "mode", "installed", "install", "uninstall",
-    "clear", "records", "record_analytic", "device_model", "hbm_ledger",
+    "clear", "records", "record_analytic", "DEVICE_PEAKS", "device_peaks",
+    "device_model", "hbm_ledger",
     "utilization", "debug_doc", "flight_snapshot", "healthz_component",
     "register_kv_cache", "decode_bucket_records", "prefix_sharing_stats",
 ]
@@ -165,15 +166,29 @@ def installed() -> bool:
 # ---------------------------------------------------------------------------
 
 _GIB = 1024 ** 3
-#: per-platform defaults (the chip of record is the v5e; the CPU tier
-#: models the same chip so the bench's modeled MFU/headroom stay
-#: comparable across tiers — override any of the three via env)
-_DEVICE_DEFAULTS = {
-    "tpu": {"hbm_bytes": 16 * _GIB, "peak_flops": 197e12,
-            "hbm_bw_bytes": 819e9},
-    "cpu": {"hbm_bytes": 16 * _GIB, "peak_flops": 1e12,
-            "hbm_bw_bytes": 50e9},
+#: Published peaks of one chip, keyed by ``jax.Device.device_kind`` — the
+#: one peaks table in the repo (bench.py, the benchmarks and chip_smoke.py
+#: read it through :func:`device_peaks`). Source of the v5e row: Google
+#: Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 393 TOP/s int8,
+#: 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect.
+#: A device that is not in the table is an error, not a default: add its
+#: row with its source.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"peak_flops": 197e12, "peak_int8_ops": 393e12,
+                    "hbm_bytes": 16 * _GIB, "hbm_bw_bytes": 819e9,
+                    "ici_bits_per_s": 1600e9},
 }
+
+
+def device_peaks(device_kind: str) -> Dict[str, float]:
+    """The :data:`DEVICE_PEAKS` row for ``device_kind``; unknown raises."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise LookupError(
+            f"no published peaks for device kind {device_kind!r} in "
+            f"observability.cost.DEVICE_PEAKS (known: "
+            f"{sorted(DEVICE_PEAKS)}); add the row with its source") from None
 
 
 def _env_float(name: str) -> Optional[float]:
@@ -188,22 +203,25 @@ def _env_float(name: str) -> Optional[float]:
 
 
 def device_model() -> Dict[str, Any]:
-    """The modeled device: HBM bytes, peak flop/s, HBM bandwidth."""
-    try:
-        from .. import device as _device
-        platform = _device._accelerator_type()
-    except Exception:                                  # pragma: no cover
-        platform = "cpu"
-    base = _DEVICE_DEFAULTS.get(platform, _DEVICE_DEFAULTS["cpu"])
+    """The device the ledger and the utilization join price against: HBM
+    bytes, peak flop/s, HBM bandwidth. On a TPU, the chip's
+    :data:`DEVICE_PEAKS` row (an unknown chip raises). A CPU process has
+    no such device: every figure is ``None`` — no headroom, no MFU —
+    unless the ``PADDLE_TPU_HBM_BYTES`` / ``_PEAK_FLOPS`` /
+    ``_HBM_BW_BYTES`` overrides name one to plan against."""
+    from .. import device as _device
+    dev = _device.describe()
+    base = device_peaks(dev["kind"]) if dev["platform"] == "tpu" else {}
     hbm = _env_float("PADDLE_TPU_HBM_BYTES")
     peak = _env_float("PADDLE_TPU_PEAK_FLOPS")
     bw = _env_float("PADDLE_TPU_HBM_BW_BYTES")
     return {
-        "platform": platform,
-        "hbm_bytes": int(hbm) if hbm else base["hbm_bytes"],
-        "peak_flops": peak if peak else base["peak_flops"],
-        "hbm_bw_bytes": bw if bw else base["hbm_bw_bytes"],
-        "source": "env" if (hbm or peak or bw) else "default",
+        "platform": dev["platform"], "device_kind": dev["kind"],
+        "hbm_bytes": int(hbm) if hbm else base.get("hbm_bytes"),
+        "peak_flops": peak if peak else base.get("peak_flops"),
+        "hbm_bw_bytes": bw if bw else base.get("hbm_bw_bytes"),
+        "source": "env" if (hbm or peak or bw) else
+        ("published" if base else "none"),
     }
 
 
@@ -545,8 +563,9 @@ def hbm_ledger() -> Dict[str, Any]:
     dev = device_model()
     state_total = param + master + moment + other
     peak_hbm = state_total + kv_pool + program_temp_peak
-    headroom = dev["hbm_bytes"] - peak_hbm
-    frac = headroom / dev["hbm_bytes"] if dev["hbm_bytes"] else 0.0
+    # no device to price against (a CPU process): no headroom, no warning
+    headroom = dev["hbm_bytes"] - peak_hbm if dev["hbm_bytes"] else None
+    frac = headroom / dev["hbm_bytes"] if dev["hbm_bytes"] else None
     ledger = {
         "param_bytes": param, "master_bytes": master,
         "moment_bytes": moment, "other_state_bytes": other,
@@ -560,9 +579,10 @@ def hbm_ledger() -> Dict[str, Any]:
                  "other_state_bytes", "kv_pool_bytes",
                  "program_temp_peak_bytes", "peak_hbm_bytes",
                  "headroom_bytes"):
-        _HBM_G.set(ledger[comp], component=comp[:-len("_bytes")])
+        if ledger[comp] is not None:
+            _HBM_G.set(ledger[comp], component=comp[:-len("_bytes")])
     fire_warn = False
-    if frac < warn_fraction():
+    if frac is not None and frac < warn_fraction():
         with _LOCK:
             if not _HBM_WARN_ONCE[0]:
                 _HBM_WARN_ONCE[0] = True
@@ -612,10 +632,10 @@ def utilization() -> List[Dict[str, Any]]:
         if not secs:
             continue
         mfu = bw = None
-        if r["flops"]:
+        if r["flops"] and dev["peak_flops"]:
             mfu = r["flops"] / (secs * dev["peak_flops"])
             _MFU_G.set(mfu, site=r["site"], program=r["program"])
-        if r["bytes_accessed"]:
+        if r["bytes_accessed"] and dev["hbm_bw_bytes"]:
             bw = r["bytes_accessed"] / (secs * dev["hbm_bw_bytes"])
             _BW_G.set(bw, site=r["site"], program=r["program"])
         if mfu is None and bw is None:
@@ -685,5 +705,6 @@ def healthz_component() -> Optional[Dict[str, Any]]:
         "kv_pool_bytes": led["kv_pool_bytes"],
         "headroom_bytes": led["headroom_bytes"],
         "headroom_fraction": led["headroom_fraction"],
-        "warn": led["headroom_fraction"] < warn_fraction(),
+        "warn": (led["headroom_fraction"] is not None
+                 and led["headroom_fraction"] < warn_fraction()),
     }

@@ -35,8 +35,8 @@ __all__ = ["Counter", "Gauge", "Histogram", "LogThrottle", "Registry",
            "DEFAULT_LATENCY_BUCKETS"]
 
 # Seconds-scale latency boundaries: 10us .. 10s, roughly x3 per step —
-# wide enough to span a CPU elementwise dispatch and a relay-attached
-# compiled step in the same family.
+# wide enough to span a CPU elementwise dispatch and a whole compiled
+# train step in the same family.
 DEFAULT_LATENCY_BUCKETS = (
     1e-5, 3e-5, 1e-4, 3e-4, 1e-3, 3e-3, 1e-2, 3e-2, 0.1, 0.3, 1.0, 3.0, 10.0,
 )

@@ -377,12 +377,12 @@ _flags.define_flag(
     "scaled_dot_product_attention routes to the flash kernel above this "
     "query length (default 0 = always flash when mask/dropout-free: with the "
     "dedicated Pallas backward the flash path beats stored-probs XLA "
-    "attention at every measured length — see benchmarks/RESULTS.md)")
+    "attention; the crossover is not measured on today's code)")
 
 def _sdpa_flash_backend_ok():
     """Routing predicate only (seam for tests): the kernel picks its own
     interpret mode from the REAL backend inside _flash_dispatch."""
-    return jax.default_backend() not in ("cpu",)
+    return jax.default_backend() == "tpu"
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.0,
@@ -407,8 +407,8 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
         # interpret mode there; call F.flash_attention directly to force it)
         # mask-free attention takes the flash path: Pallas online-softmax
         # forward + dedicated dq/dkv backward kernels — O(L) activation
-        # memory and faster than stored-probs XLA attention at every
-        # measured length (flip FLAGS_sdpa_flash_min_seqlen to re-threshold)
+        # memory (flip FLAGS_sdpa_flash_min_seqlen to re-threshold; speed
+        # against stored-probs XLA attention: not measured on today's code)
         from .flash_attention import flash_attention
         return flash_attention(query, key, value, dropout=flash_dropout,
                                causal=is_causal, training=training)

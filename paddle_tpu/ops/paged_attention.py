@@ -53,10 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu only resolves on TPU-enabled jaxlib (always true here)
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["PagedDecodeCache", "mode", "decode_path", "kernel_eligible",
            "paged_attention", "paged_attention_dense",
@@ -103,28 +100,35 @@ def decode_path(override: str = "") -> str:
         return "dense"
     if m == "on":
         return "kernel"
-    return "kernel" if jax.default_backend() not in ("cpu",) else "dense"
+    return "kernel" if jax.default_backend() == "tpu" else "dense"
 
 
 def kernel_interpret() -> bool:
     """Off-TPU the kernel runs under the Pallas interpreter (tests)."""
-    return jax.default_backend() in ("cpu",)
+    return jax.default_backend() != "tpu"
 
 
-def kernel_eligible(page_size: int, head_dim: int, storage_dtype) -> bool:
-    """Mosaic tiling constraints for the compiled (non-interpret) kernel:
-    the K/V block's sublane dimension is ``page_size`` (8/16/32-multiple
-    for f32/bf16/int8) and its lane dimension is ``head_dim`` (8-aligned,
-    the flash kernel's bound). Ineligible shapes stay on the per-layer
-    dense tier — correctness is never gated on tiling."""
-    dt = jnp.dtype(storage_dtype)
-    if dt == jnp.int8:
-        sublane = 32
-    elif dt.itemsize == 2:
-        sublane = 16
-    else:
-        sublane = 8
-    return page_size % sublane == 0 and head_dim % 8 == 0
+# K and V page blocks, double-buffered by the pipeline, must fit the
+# default 16 MiB scoped-VMEM window beside the kernel's fp32 temporaries
+_VMEM_BLOCK_BUDGET = 12 * 2 ** 20
+
+
+def kernel_eligible(page_size: int, head_dim: int, storage_dtype,
+                    num_kv_heads: int = 1) -> bool:
+    """What the compiled (non-interpret) kernel needs. Mosaic on a TPU v5e
+    (libtpu 0.0.34) took every shape tried — page sizes 8, 16, 24, 32, 64
+    and 128, head dims 64, 128 and 256, fp32, bf16 and int8 pages, 8 and 32
+    KV heads, with and without GQA — so the stated bounds are the edge of
+    what was tried: ``page_size`` in whole 8-row sublane groups and
+    ``head_dim`` in whole 64-lane halves. The one hard limit is VMEM: a
+    page's K and V blocks — every KV head, double-buffered — must fit the
+    scoped-VMEM budget. Anything else stays on the per-layer dense tier —
+    correctness is never gated on tiling. ``chip_smoke.py`` re-checks the
+    serve leg's shapes on every run."""
+    block_bytes = (4 * num_kv_heads * page_size * head_dim
+                   * jnp.dtype(storage_dtype).itemsize)
+    return (page_size % 8 == 0 and head_dim % 64 == 0
+            and block_bytes <= _VMEM_BLOCK_BUDGET)
 
 
 @dataclass
@@ -176,130 +180,170 @@ class PagedDecodeCache:
 # ---------------------------------------------------------------------------
 
 def _decode_kernel(tables_ref, t_ref, layer_ref, q_ref, kn_ref, vn_ref,
-                   k_ref, v_ref, *rest, page_size: int, sm_scale: float,
-                   num_pages: int, quantized: bool):
-    """One (batch row, q head) program; grid dim 2 streams the slot's
-    page-table row. fp32 online softmax carried in VMEM scratch across
-    pages (TPU grids run sequentially, so scratch persists); the final
-    page step folds in the CURRENT token's unquantized K/V at position
-    ``t`` and writes the output block.
+                   k_ref, v_ref, *rest, page_size: int, num_pages: int,
+                   num_kv_heads: int, rep: int, quantized: bool):
+    """One batch row per program; grid dim 1 streams the slot's page-table
+    row, and a ``fori_loop`` walks the KV heads of the streamed page. fp32
+    online softmax carried in VMEM scratch across pages (TPU grids run
+    sequentially, so scratch persists); the final page step folds in the
+    CURRENT token's unquantized K/V at position ``t`` and writes the
+    output block.
 
-    Refs: q/kn/vn ``(1, 1, D)``; k/v ``(1, 1, 1, 1, ps, D)`` — the page
-    the index map resolved via the prefetched table; int8 adds two
-    ``(1, 1, 1, 1)`` scale refs. Scratch: m/l ``(1, 1)``, acc ``(1, D)``.
+    Written for what Mosaic compiles: every per-head access indexes a
+    MAJOR dimension (q/kn/vn/out carry a unit second-minor dim for that),
+    every value is a 2-D ``(rows, lanes)`` tile, logits are VPU
+    multiply + lane reductions (exact fp32 — a one-row MXU dot would run
+    bf16 passes), and the running max/denominator live in ``(1, 1)``
+    vector tiles, never scalars.
+
+    Refs: q ``(1, H, 1, D)`` fp32, pre-scaled by ``1/sqrt(D)``; kn/vn
+    ``(1, H_kv, 1, D)`` fp32; k/v ``(1, 1, 1, H_kv, ps, D)`` — the page
+    the index map resolved via the prefetched table; int8 adds one
+    ``(1, 1, 2, H_kv)`` scale ref (K row, V row; heads on lanes). Out
+    ``(1, H, 1, D)`` fp32. Scratch: m/l ``(H, 1, 1)``, acc ``(H, 1, D)``.
     """
     rest = list(rest)
-    ks_ref = rest.pop(0) if quantized else None
-    vs_ref = rest.pop(0) if quantized else None
+    sc_ref = rest.pop(0) if quantized else None
     o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
-    s = pl.program_id(2)
+    s = pl.program_id(1)
     ps = page_size
 
     @pl.when(s == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
     t = t_ref[b]
     page_start = s * ps
-    q = q_ref[0, 0].astype(jnp.float32) * sm_scale        # (D,)
 
     @pl.when(page_start < t)                 # live page: stream it
     def _stream():
-        k_blk = k_ref[0, 0, 0, 0].astype(jnp.float32)     # (ps, D)
-        v_blk = v_ref[0, 0, 0, 0].astype(jnp.float32)
-        if quantized:
-            k_blk = k_blk * ks_ref[0, 0, 0, 0]
-            v_blk = v_blk * vs_ref[0, 0, 0, 0]
-        logits = jnp.dot(k_blk, q, preferred_element_type=jnp.float32)
-        pos = page_start + jax.lax.broadcasted_iota(jnp.int32, (ps,), 0)
-        logits = jnp.where(pos < t, logits, _NEG_INF)
-        m_prev = m_ref[0, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(logits))
-        p = jnp.exp(logits - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[0, 0] = alpha * l_ref[0, 0] + jnp.sum(p)
-        acc_ref[0, :] = alpha * acc_ref[0, :] + jnp.dot(
-            p, v_blk, preferred_element_type=jnp.float32)
-        m_ref[0, 0] = m_new
+        live = page_start + jax.lax.broadcasted_iota(
+            jnp.int32, (ps, 1), 0) < t
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, num_kv_heads), 1)
+
+        def head(h, carry):
+            k_blk = k_ref[0, 0, 0, h].astype(jnp.float32)     # (ps, D)
+            v_blk = v_ref[0, 0, 0, h].astype(jnp.float32)
+            if quantized:
+                # head h's absmax scales sit on lane h: select + lane-sum
+                # lifts them into (1, 1) tiles. Applied to the logits and
+                # the weighted-V row, not to the page (same product, a
+                # D-th of the multiplies).
+                pick = lane == h
+                k_sc = jnp.sum(jnp.where(pick, sc_ref[0, 0, 0:1, :], 0.0),
+                               axis=1, keepdims=True)
+                v_sc = jnp.sum(jnp.where(pick, sc_ref[0, 0, 1:2, :], 0.0),
+                               axis=1, keepdims=True)
+            for r in range(rep):             # GQA: q heads sharing head h
+                hq = h * rep + r
+                logits = jnp.sum(k_blk * q_ref[0, hq], axis=1,
+                                 keepdims=True)               # (ps, 1)
+                if quantized:
+                    logits = logits * k_sc
+                logits = jnp.where(live, logits, _NEG_INF)
+                m_prev = m_ref[hq]                            # (1, 1)
+                m_new = jnp.maximum(
+                    m_prev, jnp.max(logits, axis=0, keepdims=True))
+                p = jnp.exp(logits - m_new)
+                alpha = jnp.exp(m_prev - m_new)
+                l_ref[hq] = alpha * l_ref[hq] + jnp.sum(
+                    p, axis=0, keepdims=True)
+                pv = jnp.sum(p * v_blk, axis=0, keepdims=True)  # (1, D)
+                if quantized:
+                    pv = pv * v_sc
+                acc_ref[hq] = alpha * acc_ref[hq] + pv
+                m_ref[hq] = m_new
+            return carry
+
+        jax.lax.fori_loop(0, num_kv_heads, head, 0)
 
     @pl.when(s == num_pages - 1)             # fold in position t, emit
     def _finish():
-        kn = kn_ref[0, 0].astype(jnp.float32)
-        vn = vn_ref[0, 0].astype(jnp.float32)
-        logit_t = jnp.dot(q, kn, preferred_element_type=jnp.float32)
-        m_prev = m_ref[0, 0]
-        m_new = jnp.maximum(m_prev, logit_t)
-        p_t = jnp.exp(logit_t - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_fin = alpha * l_ref[0, 0] + p_t
-        acc = alpha * acc_ref[0, :] + p_t * vn
-        o_ref[0, 0] = (acc / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
+        def head(h, carry):
+            kn = kn_ref[0, h]                                 # (1, D)
+            vn = vn_ref[0, h]
+            for r in range(rep):
+                hq = h * rep + r
+                logit_t = jnp.sum(q_ref[0, hq] * kn, axis=1, keepdims=True)
+                m_prev = m_ref[hq]
+                m_new = jnp.maximum(m_prev, logit_t)
+                p_t = jnp.exp(logit_t - m_new)
+                alpha = jnp.exp(m_prev - m_new)
+                l_fin = alpha * l_ref[hq] + p_t
+                acc = alpha * acc_ref[hq] + p_t * vn
+                o_ref[0, hq] = acc / jnp.maximum(l_fin, 1e-30)
+            return carry
+
+        jax.lax.fori_loop(0, num_kv_heads, head, 0)
 
 
 def _kernel_call(q, k_new, v_new, pool, scales, tables, t, layer,
                  page_size: int, interpret: bool):
     """q ``(B, H, D)``, k/v_new ``(B, H_kv, D)``, pool
     ``(P, L, 2, H_kv, ps, D)`` → out ``(B, H, D)`` in q.dtype. GQA via
-    ``rep = H // H_kv`` folded into the index maps (no repeat buffer)."""
+    ``rep = H // H_kv`` inside the head loop (no repeat buffer); the
+    page/layer/K-or-V selection stays in the index maps."""
     b, h, d = q.shape
     h_kv = pool.shape[3]
     rep = h // h_kv
     s = tables.shape[1]
     ps = page_size
     quantized = scales is not None
-    sm_scale = 1.0 / float(d) ** 0.5
-    kern = functools.partial(_decode_kernel, page_size=ps,
-                             sm_scale=sm_scale, num_pages=s,
+    kern = functools.partial(_decode_kernel, page_size=ps, num_pages=s,
+                             num_kv_heads=h_kv, rep=rep,
                              quantized=quantized)
 
-    def q_map(bi, hi, si, tabs, tt, lr):
-        return (bi, hi, 0)
-
-    def kvn_map(bi, hi, si, tabs, tt, lr):
-        return (bi, hi // rep, 0)
+    def row_map(bi, si, tabs, tt, lr):
+        return (bi, 0, 0, 0)
 
     def page_map(kv):
-        def f(bi, hi, si, tabs, tt, lr):
-            return (tabs[bi, si], lr[0], kv, hi // rep, 0, 0)
+        def f(bi, si, tabs, tt, lr):
+            return (tabs[bi, si], lr[0], kv, 0, 0, 0)
         return f
 
-    def scale_map(kv):
-        def f(bi, hi, si, tabs, tt, lr):
-            return (tabs[bi, si], lr[0], kv, hi // rep)
-        return f
+    def scale_map(bi, si, tabs, tt, lr):
+        return (tabs[bi, si], lr[0], 0, 0)
 
+    # every block's trailing two dims are the array's own, which is what
+    # the Pallas TPU lowering accepts below the (8, 128) tile
     in_specs = [
-        pl.BlockSpec((1, 1, d), q_map),
-        pl.BlockSpec((1, 1, d), kvn_map),
-        pl.BlockSpec((1, 1, d), kvn_map),
-        pl.BlockSpec((1, 1, 1, 1, ps, d), page_map(0)),
-        pl.BlockSpec((1, 1, 1, 1, ps, d), page_map(1)),
+        pl.BlockSpec((1, h, 1, d), row_map),
+        pl.BlockSpec((1, h_kv, 1, d), row_map),
+        pl.BlockSpec((1, h_kv, 1, d), row_map),
+        pl.BlockSpec((1, 1, 1, h_kv, ps, d), page_map(0)),
+        pl.BlockSpec((1, 1, 1, h_kv, ps, d), page_map(1)),
     ]
-    inputs = [q, k_new, v_new, pool, pool]
+    f32 = jnp.float32
+    inputs = [(q.astype(f32) * (1.0 / float(d) ** 0.5)).reshape(b, h, 1, d),
+              k_new.astype(f32).reshape(b, h_kv, 1, d),
+              v_new.astype(f32).reshape(b, h_kv, 1, d), pool, pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1, 1, 1), scale_map(0)),
-                     pl.BlockSpec((1, 1, 1, 1), scale_map(1))]
-        inputs += [scales, scales]
+        in_specs.append(pl.BlockSpec((1, 1, 2, h_kv), scale_map))
+        inputs.append(scales)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, h, s),
+        grid=(b, s),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, d), q_map),
+        out_specs=pl.BlockSpec((1, h, 1, d), row_map),
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),   # running max
-            pltpu.VMEM((1, 1), jnp.float32),   # running denominator
-            pltpu.VMEM((1, d), jnp.float32),   # weighted-V accumulator
+            pltpu.VMEM((h, 1, 1), f32),    # running max
+            pltpu.VMEM((h, 1, 1), f32),    # running denominator
+            pltpu.VMEM((h, 1, d), f32),    # weighted-V accumulator
         ],
     )
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, 1, d), f32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_attention_decode",
     )(tables.astype(jnp.int32), t.astype(jnp.int32), layer_arr, *inputs)
+    return out.reshape(b, h, d).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -348,7 +392,8 @@ def paged_attention(q, k_new, v_new, pool, scales, tables, t, layer, *,
     additionally requires :func:`kernel_eligible` tiling (interpret mode
     has no tiling constraints)."""
     if impl == "kernel" and (interpret or kernel_eligible(
-            page_size, int(pool.shape[-1]), pool.dtype)):
+            page_size, int(pool.shape[-1]), pool.dtype,
+            int(pool.shape[3]))):
         return _kernel_call(q, k_new, v_new, pool, scales, tables, t,
                             layer, page_size, interpret)
     return paged_attention_dense(q, k_new, v_new, pool, scales, tables, t,

@@ -36,7 +36,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _BLOCK = 2048      # quantization block (elements) — fixed by the q8 layout
-_TILE_BLOCKS = 256  # blocks per grid step: ~0.5M elems, ~16MB fp32 in VMEM
+# blocks per grid step. 256 (0.5M elements) put the non-stochastic-rounding
+# kernel at 16.70M of scoped VMEM against the 16.00M default on a TPU v5e
+# (libtpu 0.0.34: "exceeded scoped vmem limit by 720.0K"); 128 halves it
+_TILE_BLOCKS = 128
 
 
 def _kernel(sc_ref, seed_ref, mq_ref, ms_ref, vq_ref, vs_ref, base_ref,
@@ -126,5 +129,5 @@ def q8_adam_update(m_q, m_s, v_q, v_s, base, grad, scalars, seed, *,
             jax.ShapeDtypeStruct(base.shape, out_dtype),
         ],
         input_output_aliases={2: 0, 3: 1, 4: 2, 5: 3, 6: 4},
-        interpret=interpret,
+        interpret=interpret, name="q8_adam_update",
     )(scalars, seed, m_q, m_s, v_q, v_s, base, grad)

@@ -34,6 +34,13 @@ lr = lr_mod
 _Q8_BLOCK = 2048  # block size for int8 moment quantization
 
 
+def _on_one_device() -> bool:
+    """No multi-device hybrid mesh is active (``fleet.init``): the only
+    state in which a parameter can be handed to a Mosaic kernel whole."""
+    from ..distributed.topology import multi_device_mesh
+    return multi_device_mesh() is None
+
+
 def _q8_quantize(x32, block: int = _Q8_BLOCK):
     """Per-block absmax int8 quantization of an fp32 array: returns
     (q int8 (nb, block), scale fp32 (nb,)). The bitsandbytes-style 8-bit
@@ -1183,13 +1190,16 @@ class Adam(Optimizer):
         c2 = 1.0 - b2 ** t
         if (n % _Q8_BLOCK == 0 and n >= _Q8_BLOCK
                 and _flags.flag("q8_pallas_update")
-                and jax.default_backend() == "tpu"):
+                and jax.default_backend() == "tpu"
+                and _on_one_device()):
             # TPU: the whole update is ONE Pallas kernel (pipelined DMA
             # over (G, 2048) tiles, fp32 intermediates in VMEM, in-place
             # via aliasing). No cross-param ordering barrier needed — the
             # HBM fp32 transients that forced serialization don't exist
             # on this path. Ragged params fall through to the chunked
-            # XLA loop below (they are small; their cost is noise).
+            # XLA loop below (they are small; their cost is noise), and
+            # so does everything under a multi-device mesh: Mosaic
+            # kernels cannot be partitioned automatically, XLA's loop can.
             return self._adam_q8_update_pallas(
                 p, g, lr_eff, decoupled_wd, m, ms, v, vs, n, nb, c1, c2)
         gb = max(1, min(nb, int(self._Q8_CHUNK_ELEMS) // _Q8_BLOCK))

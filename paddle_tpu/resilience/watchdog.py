@@ -5,8 +5,7 @@ Born as the serving engine's step watchdog (PR 8) and generalized here
 compiled train step. A driver loop that issues one compiled call and one
 host sync per step has exactly one failure mode an in-process observer
 can still see: the call never comes back (a wedged transfer, a runaway
-collective, a relay link gone quiet). The watchdog is the observer that
-cannot be wedged:
+collective). The watchdog is the observer that cannot be wedged:
 
 * the driving thread ``arm()``s the watchdog immediately before the
   compiled call and ``disarm()``s after — two lock-guarded scalar

@@ -434,7 +434,7 @@ class Engine:
         self._paged_path = _pa.decode_path(self.config.paged_attention)
         paged_interpret = _pa.kernel_interpret()
         if self._paged_path == "kernel" and not paged_interpret and \
-                not _pa.kernel_eligible(ps, D, cfg.storage_dtype):
+                not _pa.kernel_eligible(ps, D, cfg.storage_dtype, H):
             # Mosaic tiling can't serve this shape: demote the WHOLE
             # engine to the dense tier rather than silently running the
             # per-layer fallback under a path=kernel label — the metric
@@ -442,9 +442,9 @@ class Engine:
             # the truth about which tier the measured steps ran
             _log.warning(
                 "paged-attention kernel ineligible for page_size=%d "
-                "head_dim=%d kv storage %s (tiling floors: see "
+                "head_dim=%d kv_heads=%d kv storage %s (see "
                 "ops.paged_attention.kernel_eligible) — serving on the "
-                "dense decode tier", ps, D, cfg.storage_dtype)
+                "dense decode tier", ps, D, H, cfg.storage_dtype)
             self._paged_path = "dense"
 
         def decode_fn(tok_a, tables_a, t_a, pool_a, *maybe_scales):

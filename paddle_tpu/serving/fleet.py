@@ -31,8 +31,9 @@ failover contract gets teeth:
 * :class:`FleetSupervisor` — spawns N workers, monitors them (waitpid
   + heartbeat), respawns crashed workers under the jittered
   ``fleet.respawn`` backoff policy capped by
-  ``$PADDLE_TPU_FLEET_MAX_RESPAWNS``, warm-starts them from
-  ``$PADDLE_TPU_COMPILE_CACHE_DIR``, latches a replica out of rotation
+  ``$PADDLE_TPU_FLEET_MAX_RESPAWNS``, warm-starts them from the compile
+  cache they inherit (``$JAX_COMPILATION_CACHE_DIR``), latches a replica
+  out of rotation
   BEFORE any drain-for-restart (PR 15 ``drain_replica`` ordering), and
   exposes ``fleet.replicas{state}`` / ``fleet.respawns_total`` /
   ``fleet.worker_deaths_total{reason}`` plus the ``serving.fleet``
@@ -49,8 +50,7 @@ prefix_summary) — seeded :class:`FaultSchedule` storms compose with real
 Env knobs: ``PADDLE_TPU_FLEET_MAX_RESPAWNS`` (default 3),
 ``PADDLE_TPU_FLEET_SPAWN_S`` (worker-ready budget, default 180),
 ``PADDLE_TPU_FLEET_STALE_S`` (heartbeat staleness latch, default 10),
-``PADDLE_TPU_FLEET_DRAIN_S`` (worker-side SIGTERM drain budget),
-``PADDLE_TPU_COMPILE_CACHE_DIR`` (warm respawn), plus the
+``PADDLE_TPU_FLEET_DRAIN_S`` (worker-side SIGTERM drain budget), plus the
 ``PADDLE_TPU_RETRY_FLEET_RESPAWN_*`` / ``_FLEET_DIAL_*`` policy knobs.
 """
 

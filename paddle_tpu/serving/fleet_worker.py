@@ -37,12 +37,10 @@ queued-never-admitted work resolves with the never-admitted
 process exits 0. ``SIGKILL`` is the no-cooperation case the supervisor's
 waitpid+heartbeat monitor exists for.
 
-Warm respawn: when ``$PADDLE_TPU_COMPILE_CACHE_DIR`` is set, the worker
-points jax's persistent compilation cache there BEFORE building the
-engine, so a respawned worker re-serves without paying cold compiles.
-(CPU-tier caveat: the repo's CI runs cold — the ISSUE 13 post-mortem
-found cross-process executable caches unsound on this jaxlib's CPU
-backend; the knob is for the on-chip tier.)
+Warm respawn: the worker inherits ``$JAX_COMPILATION_CACHE_DIR`` (or the
+package's in-checkout default — ``paddle_tpu/__init__.py``), so a
+respawned worker re-serves without paying cold compiles. Workers pinned
+to the CPU backend run cold (tests/conftest.py says why).
 """
 
 from __future__ import annotations
@@ -68,7 +66,6 @@ from ..distributed.rpc import recv_msg as _recv_msg, send_msg as _send_msg
 SPEC_ENV = "PADDLE_TPU_FLEET_SPEC"
 SECRET_ENV = "PADDLE_TPU_FLEET_SECRET"
 DRAIN_ENV = "PADDLE_TPU_FLEET_DRAIN_S"
-CACHE_ENV = "PADDLE_TPU_COMPILE_CACHE_DIR"
 
 # per-wait bound on the streaming handler's token-queue poll; the loop is
 # re-armed until the request's Future resolves (the engine's no-stranded-
@@ -142,7 +139,6 @@ def _srv_beat(worker: "_Worker", payload: Dict[str, Any]) -> Dict[str, Any]:
         "draining": eng.draining,
         "outstanding_pages": eng.kv.outstanding_pages,
         "active_requests": eng.active_requests,
-        "compile_cache_dir": os.environ.get(CACHE_ENV, ""),
     }
 
 
@@ -273,14 +269,6 @@ def main(argv=None) -> int:
     for extra in reversed(spec.get("pythonpath", []) or []):
         if extra not in sys.path:
             sys.path.insert(0, extra)
-
-    # warm respawn: point jax's persistent compile cache at the shared
-    # directory BEFORE the first trace/compile happens
-    cache_dir = os.environ.get(CACHE_ENV, "").strip()
-    if cache_dir:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
     factory = _load_factory(spec)
     engine = factory(**(spec.get("config") or {}))

@@ -32,6 +32,12 @@ def cost_on(metrics, monkeypatch):
     """Metrics enabled (via ``metrics``) + the cost hooks installed for
     one test; the suite-wide PADDLE_TPU_COST=off is overridden here."""
     monkeypatch.setenv("PADDLE_TPU_COST", "on")
+    # a CPU process has no device to price against: name the chip these
+    # tests plan for (the v5e row of cost.DEVICE_PEAKS)
+    v5e = cost_mod.device_peaks("TPU v5 lite")
+    monkeypatch.setenv("PADDLE_TPU_HBM_BYTES", str(v5e["hbm_bytes"]))
+    monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", str(v5e["peak_flops"]))
+    monkeypatch.setenv("PADDLE_TPU_HBM_BW_BYTES", str(v5e["hbm_bw_bytes"]))
     cost_mod.install()
     cost_mod.clear()
     cost_mod._HBM_WARN_ONCE[0] = False
@@ -282,8 +288,15 @@ def test_hbm_low_headroom_warns_once(cost_on, monkeypatch, caplog):
 
 
 def test_device_model_env_overrides(cost_on, monkeypatch):
+    for k in ("HBM_BYTES", "PEAK_FLOPS", "HBM_BW_BYTES"):
+        monkeypatch.delenv(f"PADDLE_TPU_{k}")
     dev = cost_on.device_model()
-    assert dev["platform"] in ("cpu", "tpu") and dev["source"] == "default"
+    # CPU: nothing to price against, and no borrowed row
+    assert dev["platform"] == "cpu" and dev["source"] == "none"
+    assert dev["hbm_bytes"] is None and dev["peak_flops"] is None
+    assert cost_on.hbm_ledger()["headroom_bytes"] is None
+    with pytest.raises(LookupError, match="no published peaks"):
+        cost_on.device_peaks("TPU v99")
     monkeypatch.setenv("PADDLE_TPU_HBM_BYTES", "1000")
     monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "2e12")
     dev = cost_on.device_model()

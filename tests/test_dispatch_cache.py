@@ -382,10 +382,19 @@ def test_numerics_identical_cache_on_vs_off():
     dcache.configure(enabled=False)
     ref = _model_loss_and_grads(x, w)
     dcache.configure(enabled=True)
-    for _ in range(3):  # cold, compiling, hot
-        got = _model_loss_and_grads(x, w)
-        for r, g in zip(ref, got):
-            np.testing.assert_array_equal(r, g)
+    cold, compiling, hot = (_model_loss_and_grads(x, w) for _ in range(3))
+    # within a tier, bitwise: the cold call still runs op by op like the
+    # reference, and the two compiled calls run the same executables
+    for r, g in zip(ref, cold):
+        np.testing.assert_array_equal(r, g)
+    for a, b in zip(compiling, hot):
+        np.testing.assert_array_equal(a, b)
+    # across tiers, ulp scale: a jitted op may contract a*b+c to an FMA
+    # that per-op dispatch cannot (jaxlib 0.9.0's CPU codegen does; seen:
+    # 1e-9 absolute on gradients of 1e-3). rtol is ~84 fp32 ulp, atol
+    # covers elements that cancel to near zero.
+    for r, g in zip(ref, hot):
+        np.testing.assert_allclose(g, r, rtol=1e-5, atol=1e-8)
     assert dcache.cache_info()["hits"] > 0
 
 

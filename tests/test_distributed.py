@@ -27,7 +27,6 @@ def _vals(g, shape=(3,)):
     return [np.full(shape, float(i + 1), np.float32) for i in range(g)]
 
 
-@pytest.mark.requires_shard_map
 def test_all_reduce_sum():
     t = dist.shard_stack([paddle.to_tensor(v) for v in _vals(8)])
     dist.all_reduce(t)
@@ -35,7 +34,6 @@ def test_all_reduce_sum():
     np.testing.assert_allclose(t.numpy(), np.full((8, 3), expected))
 
 
-@pytest.mark.requires_shard_map
 def test_all_reduce_max_min():
     t = dist.shard_stack([paddle.to_tensor(v) for v in _vals(8)])
     dist.all_reduce(t, op=dist.ReduceOp.MAX)
@@ -45,7 +43,6 @@ def test_all_reduce_max_min():
     np.testing.assert_allclose(t2.numpy(), np.full((8, 3), 1.0))
 
 
-@pytest.mark.requires_shard_map
 def test_all_gather():
     t = dist.shard_stack([paddle.to_tensor(v) for v in _vals(8)])
     out = []
@@ -55,7 +52,6 @@ def test_all_gather():
         np.testing.assert_allclose(o.numpy(), np.full((3,), i + 1.0))
 
 
-@pytest.mark.requires_shard_map
 def test_reduce_scatter():
     # each rank contributes (8*2,) -> each rank gets its 2-chunk of the sum
     vals = [np.arange(16, dtype=np.float32) + 100 * i for i in range(8)]
@@ -66,14 +62,12 @@ def test_reduce_scatter():
     np.testing.assert_allclose(out.numpy(), total.reshape(8, 2))
 
 
-@pytest.mark.requires_shard_map
 def test_broadcast_and_scatter():
     t = dist.shard_stack([paddle.to_tensor(v) for v in _vals(8)])
     dist.broadcast(t, src=3)
     np.testing.assert_allclose(t.numpy(), np.full((8, 3), 4.0))
 
 
-@pytest.mark.requires_shard_map
 def test_alltoall_single():
     # rank i sends chunk j (value i*10+j) to rank j
     vals = [np.array([i * 10 + j for j in range(8)], np.float32)
@@ -86,7 +80,6 @@ def test_alltoall_single():
         np.testing.assert_allclose(o[i], [j * 10 + i for j in range(8)])
 
 
-@pytest.mark.requires_shard_map
 def test_ppermute_shift():
     t = dist.shard_stack([paddle.to_tensor(v) for v in _vals(8)])
     out = dist.ppermute_shift(t, offset=1)

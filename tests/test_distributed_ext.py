@@ -316,7 +316,6 @@ class TestReviewFixes5:
 # round-3 tail: gather / get_group / split (upstream paddle.distributed)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.requires_shard_map
 def test_gather_and_get_group():
     import paddle_tpu.distributed as dist
 

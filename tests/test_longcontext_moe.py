@@ -151,7 +151,6 @@ def test_flash_attn_unpadded_packed_sequences(causal):
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.requires_shard_map
 def test_ring_attention_matches_serial(causal):
     from paddle_tpu.distributed.fleet.context_parallel import ring_flash_attention
     _sep_mesh(8)

@@ -16,7 +16,6 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 @pytest.mark.slow
 def test_two_process_dp_parity(tmp_path):
     env = dict(os.environ)
-    env.pop("PADDLE_PLATFORM", None)
     out = subprocess.run(
         [sys.executable, "-m", "paddle_tpu.distributed.launch",
          "--nproc_per_node", "2", "--log_dir", str(tmp_path),
@@ -63,7 +62,6 @@ def test_four_process_hybrid_dp2mp4_and_checkpoint(tmp_path):
     process counts). (VERDICT r2 missing item 5 / SURVEY §4 TestDistBase.)"""
     ckpt = str(tmp_path / "ckpt")
     env = dict(os.environ)
-    env.pop("PADDLE_PLATFORM", None)
     out = subprocess.run(
         [sys.executable, "-m", "paddle_tpu.distributed.launch",
          "--nproc_per_node", "4", "--log_dir", str(tmp_path / "logs"),

@@ -284,24 +284,29 @@ class TestModeResolution:
                                   page_size=16, paged_attention="bogus")
 
     def test_kernel_eligibility_tiling_table(self):
-        # sublane floors per storage dtype: f32 8, bf16 16, int8 32
-        assert pa.kernel_eligible(8, 8, jnp.float32)
-        assert not pa.kernel_eligible(8, 8, jnp.bfloat16)
-        assert pa.kernel_eligible(16, 8, jnp.bfloat16)
-        assert not pa.kernel_eligible(16, 8, jnp.int8)
-        assert pa.kernel_eligible(32, 8, jnp.int8)
-        assert not pa.kernel_eligible(32, 9, jnp.float32)   # lane 8-align
+        # the edge of what Mosaic was given on the chip (and took): page
+        # rows in 8s, head dims in 64s, any storage dtype, blocks in VMEM
+        for ps in (8, 16, 24, 32, 64, 128):
+            for dt in (jnp.float32, jnp.bfloat16, jnp.int8):
+                assert pa.kernel_eligible(ps, 128, dt, 32)
+        assert pa.kernel_eligible(64, 64, jnp.bfloat16)
+        assert pa.kernel_eligible(64, 256, jnp.bfloat16, 8)
+        assert not pa.kernel_eligible(12, 128, jnp.bfloat16)
+        assert not pa.kernel_eligible(64, 8, jnp.float32)   # the toy shapes
+        assert not pa.kernel_eligible(64, 96, jnp.float32)
+        # 4 x 64 heads x 256 rows x 128 x 2 B = 16 MiB of page blocks
+        assert not pa.kernel_eligible(256, 128, jnp.bfloat16, 64)
 
     def test_ineligible_shapes_fall_back_to_dense_math(self):
         # compiled-kernel path demotes to the dense tier instead of
         # tripping Mosaic — correctness is never gated on tiling
         rng = np.random.default_rng(8)
-        pool, _ = _make_pool("bf16", rng)        # PS=16 bf16 needs 16: ok
+        pool, _ = _make_pool("bf16", rng)        # D=8: no whole lane tile
         q, kn, vn = _qkv(rng)
         t = jnp.asarray([5, 7, 9], jnp.int32)
         got = pa.paged_attention(q, kn, vn, pool, None, TABLES, t,
                                  jnp.asarray(0), page_size=PS,
-                                 impl="dense", interpret=False)
+                                 impl="kernel", interpret=False)
         want = pa.paged_attention_dense(q, kn, vn, pool, None, TABLES, t,
                                         jnp.asarray(0), page_size=PS)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -315,15 +320,15 @@ class TestModeResolution:
         tier ran."""
         import paddle_tpu.ops.paged_attention as pamod
         monkeypatch.setattr(pamod, "kernel_interpret", lambda: False)
-        cfg = serving.ServingConfig(       # int8 needs page_size % 32
+        cfg = serving.ServingConfig(       # head_dim 8: no whole lane tile
             num_layers=1, num_heads=1, head_dim=8, max_len=32,
             max_batch=1, buckets=(1,), page_size=16, kv_dtype="int8",
             paged_attention="on")
         eng = serving.Engine(lambda *a: None, lambda *a: None, cfg)
         assert eng._paged_path == "dense"
-        cfg_ok = serving.ServingConfig(    # f32 at page_size 16: eligible
-            num_layers=1, num_heads=1, head_dim=8, max_len=32,
-            max_batch=1, buckets=(1,), page_size=16,
+        cfg_ok = serving.ServingConfig(    # head_dim 128 at page_size 16
+            num_layers=1, num_heads=1, head_dim=128, max_len=32,
+            max_batch=1, buckets=(1,), page_size=16, kv_dtype="int8",
             paged_attention="on")
         eng_ok = serving.Engine(lambda *a: None, lambda *a: None, cfg_ok)
         assert eng_ok._paged_path == "kernel"

@@ -122,7 +122,6 @@ def test_hlo_shard0_to_shard1_is_all_to_all():
         "relayout must not gather through a replicated intermediate"
 
 
-@pytest.mark.requires_shard_map
 def test_hlo_partial_consumption_reduce_scatter():
     """Partial inside a program: psum_scatter consumes partial values with
     ONE reduce-scatter (not all-reduce + slice)."""
@@ -140,7 +139,6 @@ def test_hlo_partial_consumption_reduce_scatter():
     assert "reduce-scatter" in txt and "all-reduce" not in txt
 
 
-@pytest.mark.requires_shard_map
 def test_hlo_partial_to_replicate_all_reduce():
     mesh = _jmesh()
 

@@ -212,12 +212,16 @@ class TestParityEagerVsCaptured:
     def test_trajectory_tracks_eager_at_ulp_scale(self, q8):
         ref = eager_losses(q8=q8)
         got = captured_losses(q8=q8)
-        # step 1's loss is pre-update forward over identical params:
-        # bitwise (whole-program fwd == per-op fwd; measured). The full
-        # trajectory is NOT bitwise — XLA fuses the optimizer update's
-        # a*x+b*y chains to FMA inside the whole-step kernel, which
-        # per-op dispatch cannot — so the pin is ulp-scale closeness.
-        assert got[0] == ref[0]
+        # captured vs eager is a CROSS-tier comparison, so the pin is
+        # ulp-scale closeness, never bitwise (within a tier it is bitwise
+        # — the kill/resume tests). Step 1's loss is the pre-update
+        # forward over identical params: the whole-program forward and the
+        # per-op forward differ by FMA contraction only (seen on jaxlib
+        # 0.9.0's CPU codegen: 1.4 fp32 ulp), so 4 ulp. Over the
+        # trajectory the optimizer update's a*x+b*y chains fuse too and
+        # the differences compound: rtol 1e-5.
+        np.testing.assert_allclose(got[0], ref[0],
+                                   rtol=4 * np.finfo(np.float32).eps)
         np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-6)
 
     def test_scheduler_stepped_outside_rides_carried_state(self):

@@ -2,9 +2,6 @@
 
 import pytest
 
-# the whole module drives the shard_map pipeline engine
-pytestmark = pytest.mark.requires_shard_map
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -407,8 +404,7 @@ def test_hetero_pipeline_stage_placement_physical():
 @pytest.mark.slow
 def test_interleaved_vpp_parity_vs_serial():
     """virtual_pp_degree=2 (interleaved placement, upstream VPP parity):
-    same numerics as serial; the option exists for schedule parity even
-    though RESULTS.md documents the compiled-scan slowdown."""
+    same numerics as serial; the option exists for schedule parity."""
     rng = np.random.default_rng(31)
     data_np = rng.normal(0, 1, (8, D)).astype(np.float32)
     label_np = rng.normal(0, 1, (8, 4)).astype(np.float32)

@@ -228,7 +228,7 @@ def _smoke_math_ext():
 def _smoke_math_ext2():
     import paddle_tpu as paddle
     a, b = _rand(2, 2), _rand(2, 2)
-    out = paddle.block_diag(_t(a), _t(b)).numpy()
+    out = paddle.block_diag([_t(a), _t(b)]).numpy()
     assert out.shape == (4, 4) and np.allclose(out[:2, :2], a)
 
 
@@ -295,10 +295,12 @@ def _smoke_segment():
 
 
 def _smoke_serving_decode():
-    # the serving engine's compiled paged-decode program (gather pages ->
-    # step -> scatter written page) on the real chip: 2 requests batched
+    # the serving engine's compiled dense-tier decode program (gather pages
+    # -> step -> scatter written page) on the real chip: 2 requests batched
     # continuously must decode the exact tokens of the dense bs=1 loop
-    # over the SAME toy callables
+    # over the SAME toy callables. paged_attention="off" names the tier:
+    # the toys consume the dense stacked cache, and under auto a TPU
+    # engine hands the step a PagedDecodeCache view instead
     import jax
     import jax.numpy as jnp
     from paddle_tpu import serving
@@ -352,7 +354,7 @@ def _smoke_serving_decode():
 
     cfg = serving.ServingConfig(num_layers=L, num_heads=H, head_dim=D,
                                 max_len=M, max_batch=2, buckets=(1, 2),
-                                page_size=8)
+                                page_size=8, paged_attention="off")
     eng = serving.Engine(prefill, step, cfg)
     futs = [eng.submit(serving.GenerationRequest(p, max_new_tokens=4))
             for p in prompts]
@@ -388,7 +390,8 @@ def _smoke_serving_drain():
 
     cfg = serving.ServingConfig(num_layers=L, num_heads=H, head_dim=D,
                                 max_len=M, max_batch=2, buckets=(1, 2),
-                                page_size=8, max_queue=8)
+                                page_size=8, max_queue=8,
+                                paged_attention="off")
     eng = serving.Engine(prefill, step, cfg).warmup()
     prompts = [np.arange(6, dtype=np.int32) % V,
                (np.arange(6, dtype=np.int32) * 5) % V]

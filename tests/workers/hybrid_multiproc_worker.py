@@ -16,9 +16,11 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
 
-import paddle_tpu as paddle
+# this worker IS a slice of the virtual CPU mesh: 2 local cpu devices
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 
-paddle.device.force_platform("cpu", 2)
+import paddle_tpu as paddle
 
 import jax
 import jax.numpy as jnp

@@ -4,12 +4,12 @@ to the device module and the backend-fallback module only.
 PR 6 added backend-fallback dispatch (``paddle_tpu/core/fallback.py``):
 per-op placement decisions — which device an op actually executes on —
 now have exactly two sanctioned owners: ``paddle_tpu/device.py`` (the
-Place taxonomy, ``set_device``, the memoized device-list probes that
-``force_platform`` knows how to invalidate) and the fallback module (the
-CPU degrade path). An ad-hoc ``jax.devices()``/``jax.device_put`` call
-anywhere else bypasses both: it pins placement the fallback registry
-can't see, and it can latch a stale device list across a
-``force_platform`` switch. Route through ``device.Place``/
+Place taxonomy, ``set_device``, the memoized device-list probes) and the
+fallback module (the CPU degrade path). An ad-hoc
+``jax.devices()``/``jax.device_put`` call anywhere else bypasses both: it
+pins placement the fallback registry can't see, and it initialises a
+backend from a module that a chip-less parent process may import. Route
+through ``device.Place``/
 ``default_jax_device`` or the fallback helpers instead; load-bearing
 survivors (the distributed mesh-sharding layer predates this rule) are
 grandfathered in the baseline with reasons, per the PR-3 convention.
