@@ -15,6 +15,7 @@ from ..core.tensor import (Tensor, TraceBreakError, _state_registry,
                            _is_tracer)
 from .. import flags as _flags
 from .. import observability as _obs
+from ..observability import trace as _trace
 from ..core.tracing import (TraceState, pop_trace_state, push_trace_state,
                             trace_state)
 
@@ -204,7 +205,12 @@ class StaticFunction:
         # other's tracers into the registry. Reentrant, so a rebuild
         # recursion or a nested eager fallback on the SAME thread is fine;
         # uncontended for every single-threaded caller.
-        with _INVOKE_LOCK:
+        # jit.call (ISSUE 25, mode "on" only): the whole compiled call.
+        # Its child jit.dispatch is the jitted function alone, so its self
+        # time is what this layer adds around every program: pre-trace
+        # hooks, the registry walk, the cache key, the rebind of every
+        # donated state tensor.
+        with _INVOKE_LOCK, _trace.phase("jit.call"):
             return self._call_locked(*args, **kwargs)
 
     def _call_locked(self, *args, **kwargs):
@@ -386,7 +392,9 @@ class StaticFunction:
                     seen.add(id(a))
         holder["traced"] = False
         try:
-            out_arrays, new_state, mut_vals = jitted(state_arrays, arg_arrays)
+            with _trace.phase("jit.dispatch"):
+                out_arrays, new_state, mut_vals = jitted(state_arrays,
+                                                         arg_arrays)
         except Exception as e:
             if holder["traced"] and _is_trace_failure(e):
                 # pure_fn ran to its end, so this came out of lowering

@@ -20,6 +20,22 @@ span events additionally carry ``trace``/``span``/``parent`` ids):
   in an in-process buffer; :func:`export_chrome` converts it to a Chrome
   trace-event JSON that loads in ``chrome://tracing`` / Perfetto (one
   track per trace, spans nested by time containment).
+* **The profiler mirror** (ISSUE 25) — in mode ``on`` every span is also
+  entered and left as a ``jax.profiler.TraceAnnotation`` of the same name
+  (attributes that are numbers or short strings ride along as the event's
+  stats). While a ``jax.profiler`` session runs, the program's spans
+  therefore land in the host plane of the same ``.xplane.pb`` as the
+  device's ops (plane ``/host:CPU``, one line per OS thread, the event
+  named by the span), in the profiler's own timebase: a device idle gap
+  can be set against what the host thread was doing in it. With no
+  session running an annotation is a no-op in native code.
+  ``jax.profiler`` is imported when the first span opens in mode ``on``,
+  never at import (observability is a foundation layer).
+* **Phase spans** — :func:`phase` / :func:`phase_instant` are the
+  detail tier: recorded in mode ``on`` only and never into the flight
+  ring (as the per-op events are not), so the per-step phases of the
+  serving loop and the compiled call cannot churn the post-mortem's tail
+  and mode ``flight`` keeps what it kept before them.
 * **The flight recorder** — an ALWAYS-ON lock-free ring of the last N
   events (``PADDLE_TPU_FLIGHT_EVENTS``, default 512): lifecycle instants,
   injected/real fault events, watchdog trips, NaN skips, restores. On an
@@ -35,6 +51,36 @@ only explicit ``instant``/``record`` calls (request/step-rate lifecycle
 sites, never per-op) pay one dict build + one ring slot write for the
 always-on recorder. ``bench.py`` pins the captured-step p50 delta of
 ``off`` vs ``flight`` vs ``on`` in its ``trace_overhead`` block.
+
+The spans the program opens (ISSUE 25 fixed the names; ``*`` = phase
+span, mode ``on`` only)::
+
+    serving.submit            Engine.submit (caller thread; request track)
+    serving.prefill           _admit_one: page claim done -> first token read
+    serving.decode            one batched decode step (engine track)
+      serving.decode.build  * numpy batch + the three host->device puts
+      serving.decode.launch * the decode program's call until it returns
+      serving.decode.wait   * pool swap + the one host read of the tokens
+      serving.decode.emit   * per-slot bookkeeping, stream callbacks, finish
+      serving.decode.release * the step's device arrays freed (inputs, tokens)
+    serving.cancel          * Engine.step: evict cancelled slots
+    serving.admit           * Engine.step: scheduler pop, page reservation,
+                              every prefill of this boundary, the fault gate
+    serving.publish         * Engine.step: gauges
+    serving.idle            * Engine.start's loop: waiting for work
+    serving.http.token      * (instant) front door: one per streamed token,
+                              ``lag_ms`` = engine hand-over -> frame flushed
+    jit.call                * StaticFunction: one whole compiled call
+      jit.dispatch          * the jitted function alone; ``jit.call``'s self
+                              time is hooks + registry walk + key + rebind
+    train.step / train.captured_step   the supervisor's / CapturedStep's
+
+Clocks: an event's ``ts`` is ``time.perf_counter()``. The benchmark cuts
+its window with ``time.monotonic()`` and compares the two directly; they
+are the same clock (``CLOCK_MONOTONIC``) on Linux by CPython's
+implementation only, not by the language's contract. The profiler's
+timebase is its own: compare a span with a device op through the
+mirrored annotation, never through ``ts``.
 
 Health beacons ride along (``heartbeat(name)`` from the engine/supervisor
 step loops and the watchdog poll threads); ``observability.http`` serves
@@ -54,7 +100,8 @@ from typing import Any, Dict, List, NamedTuple, Optional
 
 __all__ = [
     "SpanContext", "FlightRecorder",
-    "span", "instant", "record", "new_trace", "current",
+    "span", "phase", "instant", "phase_instant", "record", "new_trace",
+    "current",
     "mode", "enabled", "set_mode", "tracing",
     "events", "clear", "dropped", "make_event", "span_problems",
     "export_chrome", "trace_dir",
@@ -121,6 +168,24 @@ class _TraceState:
 
 
 _STATE = _TraceState()
+
+# attribute values that ride along into the profiler's trace: numbers, and
+# strings no longer than this (a prompt or a traceback is not a stat)
+_MIRROR_STR_LIMIT = 64
+_ANNOTATION = None     # jax.profiler.TraceAnnotation, bound on first use
+
+
+def _annotation(name: str, attrs: Dict[str, Any]):
+    """The span's twin in the profiler's trace (mode ``on`` only). The
+    deferred import keeps ``import paddle_tpu`` off ``jax.profiler``."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION(name, **{
+        k: v for k, v in attrs.items()
+        if isinstance(v, (int, float))
+        or (isinstance(v, str) and len(v) <= _MIRROR_STR_LIMIT)})
 
 
 def mode() -> str:
@@ -235,13 +300,15 @@ class _Span:
     context manager — begin/end pairing is structural, which is what lets
     the chaos suites assert every trace is balanced."""
 
-    __slots__ = ("_name", "_attrs", "_parent", "ctx")
+    __slots__ = ("_name", "_attrs", "_parent", "_ring", "_mirror", "ctx")
 
     def __init__(self, name: str, parent: Optional[SpanContext],
-                 attrs: Dict[str, Any]):
+                 attrs: Dict[str, Any], ring: bool = True):
         self._name = name
         self._attrs = attrs
         self._parent = parent
+        self._ring = ring
+        self._mirror = None
         self.ctx: Optional[SpanContext] = None
 
     def __enter__(self) -> "_Span":
@@ -259,10 +326,17 @@ class _Span:
         stack.append(self.ctx)
         _emit({"ts": time.perf_counter(), "kind": "B", "name": self._name,
                "attrs": self._attrs, "trace": tr, "span": sid,
-               "parent": par, "thread": threading.get_ident()})
+               "parent": par, "thread": threading.get_ident()}, self._ring)
+        if _MODE == "on":
+            # the same span on the profiler's clock (entered last, left
+            # first: the annotation lies inside the B/E pair)
+            self._mirror = _annotation(self._name, self._attrs)
+            self._mirror.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._mirror is not None:
+            self._mirror.__exit__(exc_type, exc, tb)
         stack = _stack()
         if stack and stack[-1] == self.ctx:
             stack.pop()
@@ -271,7 +345,7 @@ class _Span:
         attrs = {"error": exc_type.__name__} if exc_type is not None else {}
         _emit({"ts": time.perf_counter(), "kind": "E", "name": self._name,
                "attrs": attrs, "trace": self.ctx.trace,
-               "span": self.ctx.span})
+               "span": self.ctx.span}, self._ring)
         return False
 
 
@@ -283,6 +357,18 @@ def span(name: str, parent: Optional[SpanContext] = None, **attrs):
     if _MODE == "off":
         return _NOOP
     return _Span(name, parent, attrs)
+
+
+def phase(name: str, parent: Optional[SpanContext] = None, **attrs):
+    """A span of the detail tier (context manager, like :func:`span`): one
+    phase of a step that runs many times a second — the serving loop's
+    build/launch/wait/emit, a compiled call's dispatch. Recorded in mode
+    ``on`` only, into the buffer and the profiler's trace, never into the
+    flight ring: in ``off`` AND ``flight`` it is one global read returning
+    the shared no-op, so the ring's 512 slots keep the lifecycle tail."""
+    if _MODE != "on":
+        return _NOOP
+    return _Span(name, parent, attrs, ring=False)
 
 
 def new_trace(label: str, **attrs) -> Optional[SpanContext]:
@@ -298,18 +384,31 @@ def new_trace(label: str, **attrs) -> Optional[SpanContext]:
     return SpanContext(tid, 0)
 
 
+def _instant_event(name: str, parent: Optional[SpanContext],
+                   attrs: Dict[str, Any]) -> Dict[str, Any]:
+    if parent is not None:
+        tr, par = parent.trace, parent.span
+    else:
+        cur = current()
+        tr, par = (cur.trace, cur.span) if cur is not None else (0, 0)
+    return make_event("i", name, attrs=attrs, trace=tr, parent=par)
+
+
 def instant(name: str, parent: Optional[SpanContext] = None,
             **attrs) -> None:
     """A point event. Attached to ``parent`` (or the current span) in the
     trace tree when tracing is on; ALWAYS appended to the flight ring —
     instants are the coarse lifecycle/fault record the post-mortem needs,
     and they fire at request/step rate, never per op."""
-    if parent is not None:
-        tr, par = parent.trace, parent.span
-    else:
-        cur = current()
-        tr, par = (cur.trace, cur.span) if cur is not None else (0, 0)
-    _emit(make_event("i", name, attrs=attrs, trace=tr, parent=par))
+    _emit(_instant_event(name, parent, attrs))
+
+
+def phase_instant(name: str, parent: Optional[SpanContext] = None,
+                  **attrs) -> None:
+    """A point event of the detail tier (see :func:`phase`): per-token
+    rate, so mode ``on`` only and buffer only — never the flight ring."""
+    if _MODE == "on":
+        _emit(_instant_event(name, parent, attrs), ring=False)
 
 
 def record(name: str, **attrs) -> None:

@@ -83,8 +83,12 @@ tier ran — ISSUE 13), ``serving.prefills_total``,
 
 Tracing (ISSUE 12): each request carries a trace root from ``submit()``
 (``observability.trace`` — spans for submit/prefill, instants for
-queue/decode-cadence/fault/replay/completion, all linked across the
-caller and step threads); unrecoverable batched steps dump the flight
+queue/fault/replay/completion, all linked across the caller and step
+threads); the step loop's own phases (ISSUE 25, mode ``on`` only:
+``serving.cancel``/``admit``/``publish``/``idle`` and
+``serving.decode.build``/``launch``/``wait``/``emit``/``release``, the
+table in ``observability/trace.py``) ride the engine's track;
+unrecoverable batched steps dump the flight
 recorder (``serving_recover``); the step loop heartbeats ``/healthz``;
 ``PADDLE_TPU_OBS_HTTP_PORT`` opts into the scrape endpoint.
 """
@@ -147,10 +151,6 @@ _obs.histogram("serving.ttft_seconds",
                buckets=TTFT_BUCKETS)
 _obs.histogram("serving.tpot_seconds",
                "inter-token time after the first", buckets=TPOT_BUCKETS)
-
-# every Nth decode step drops an instant on the request's trace: enough to
-# see a request's cadence in Perfetto without an event per token
-_DECODE_TRACE_EVERY = 8
 
 # engine step-loop liveness beacon ttl (/healthz goes 503 past this)
 _HEARTBEAT_TTL_S = 60.0
@@ -704,23 +704,29 @@ class Engine:
         ONE batched decode step. Returns False when there was nothing to
         do (the idle step — no program runs, no device touch)."""
         _trace.heartbeat(self._beacon, ttl_s=_HEARTBEAT_TTL_S)
-        progressed = self._process_cancellations()
-        # draining latches out NEW admissions only: slots evicted by
-        # crash-recovery mid-drain still re-admit, or the drain would
-        # misreport an in-flight (recoverable) request as never-admitted
-        progressed |= self._admit(
-            replay_only=self._draining.is_set())
-        if not self._slots:
-            self._publish_gauges(0, 0)
-            return progressed
-
-        included = self._fault_gate()
+        if self._engine_trace is None and _trace.enabled():
+            self._engine_trace = _trace.new_trace("serving-engine")
+        # the phases of one boundary, on the engine's track (ISSUE 25):
+        # with the profiler running they name what the host was doing in
+        # each device idle gap
+        track = self._engine_trace
+        with _trace.phase("serving.cancel", parent=track):
+            progressed = self._process_cancellations()
+        with _trace.phase("serving.admit", parent=track):
+            # draining latches out NEW admissions only: slots evicted by
+            # crash-recovery mid-drain still re-admit, or the drain would
+            # misreport an in-flight (recoverable) request as
+            # never-admitted
+            progressed |= self._admit(
+                replay_only=self._draining.is_set())
+            included = self._fault_gate()       # no slots: nothing gated
         if included:
             self._decode_step(included)
             progressed = True
-        self._publish_gauges(len(included),
-                             self._bucket_for(len(included))
-                             if included else 0)
+        with _trace.phase("serving.publish", parent=track):
+            self._publish_gauges(len(included),
+                                 self._bucket_for(len(included))
+                                 if included else 0)
         return progressed
 
     def run(self) -> None:
@@ -745,7 +751,9 @@ class Engine:
         def loop():
             while not self._stop.is_set():
                 if not self.step():
-                    self._wake.wait(0.01)
+                    with _trace.phase("serving.idle",
+                                      parent=self._engine_trace):
+                        self._wake.wait(0.01)
                     self._wake.clear()
 
         self._thread = threading.Thread(
@@ -1083,23 +1091,16 @@ class Engine:
                         _T(jnp.asarray(row)),
                         _T(jnp.asarray(prompt.size, jnp.int32)),
                         _T(self.kv.pool), *self._scales_args())
-        except Exception as exc:
-            self.kv.free(pages)                 # refcount-aware: shared
-            # pages are decremented, private ones actually released
-            _obs.inc("serving.requests_total", status="failed")
-            _trace.instant("serving.fault", parent=pending.trace_ctx,
-                           rid=req.request_id, site="serving.admit",
-                           error=type(exc).__name__)
-            pending.future.set_exception(exc)
-            return "failed"
-        try:
-            # ISSUE 18: the pool swap, first-token host read and prefix
-            # publish belong to the guarded region too — the host sync
-            # raising here (wedged device, watchdog replay) used to leak
-            # the slot's pages AND strand the future; now it is just
-            # another "failed" admission
-            self._set_pool(outs[1], outs[2] if self._quantized else None)
-            first_tok = int(np.asarray(outs[0]._data)[0, 0])
+                # ISSUE 18: the pool swap, first-token host read and
+                # prefix publish belong to the guarded region too — the
+                # host sync raising here (wedged device, watchdog replay)
+                # used to leak the slot's pages AND strand the future; now
+                # it is just another "failed" admission. Inside the span
+                # (ISSUE 25): it ends when the first token exists, so its
+                # duration is a prefill, not an enqueue
+                self._set_pool(outs[1],
+                               outs[2] if self._quantized else None)
+                first_tok = int(np.asarray(outs[0]._data)[0, 0])
             now = time.monotonic()
             _obs.inc("serving.prefills_total")
             _obs.inc("serving.prefill_tokens_requested_total",
@@ -1114,7 +1115,8 @@ class Engine:
                 # shareable prompt.
                 self.kv.publish(req.prompt, pages)
         except Exception as exc:
-            self.kv.free(pages)
+            self.kv.free(pages)                 # refcount-aware: shared
+            # pages are decremented, private ones actually released
             _obs.inc("serving.requests_total", status="failed")
             _trace.instant("serving.fault", parent=pending.trace_ctx,
                            rid=req.request_id, site="serving.admit",
@@ -1184,25 +1186,24 @@ class Engine:
         raise AssertionError(f"no bucket for batch {n}")  # __post_init__
 
     def _decode_step(self, included: List[_Slot]) -> None:
-        if _trace.enabled() and self._engine_trace is None:
-            self._engine_trace = _trace.new_trace("serving-engine")
         with _trace.span("serving.decode", parent=self._engine_trace,
                          batch=len(included)):
             self._decode_step_traced(included)
 
     def _decode_step_traced(self, included: List[_Slot]) -> None:
         from ..core.tensor import Tensor as _T
-        bucket = self._bucket_for(len(included))
-        S = self.kv.config.pages_per_slot
-        tok = np.zeros((bucket, 1), np.int32)
-        t = np.zeros((bucket,), np.int32)
-        tables = np.zeros((bucket, S), np.int32)   # padded rows -> scratch
-        for i, slot in enumerate(included):
-            tok[i, 0] = slot.last_tok
-            t[i] = slot.t
-            tables[i] = slot.table_row
-        args = (_T(jnp.asarray(tok)), _T(jnp.asarray(tables)),
-                _T(jnp.asarray(t)))
+        with _trace.phase("serving.decode.build"):
+            bucket = self._bucket_for(len(included))
+            S = self.kv.config.pages_per_slot
+            tok = np.zeros((bucket, 1), np.int32)
+            t = np.zeros((bucket,), np.int32)
+            tables = np.zeros((bucket, S), np.int32)  # padded rows -> scratch
+            for i, slot in enumerate(included):
+                tok[i, 0] = slot.last_tok
+                t[i] = slot.t
+                tables[i] = slot.table_row
+            args = (_T(jnp.asarray(tok)), _T(jnp.asarray(tables)),
+                    _T(jnp.asarray(t)))
         outs = None
         with self._deadline_ctx([s.pending for s in included]):
             for attempt in (0, 1):
@@ -1210,9 +1211,10 @@ class Engine:
                 try:
                     # the device-step seam: delay = hung step (trips the
                     # watchdog), error = whole-batch device fault
-                    _faults.fault_point("serving.watchdog")
-                    outs = self._decode_program(*args, _T(self.kv.pool),
-                                                *self._scales_args())
+                    with _trace.phase("serving.decode.launch"):
+                        _faults.fault_point("serving.watchdog")
+                        outs = self._decode_program(
+                            *args, _T(self.kv.pool), *self._scales_args())
                 except Exception as exc:
                     if gen is not None:
                         self._watchdog.disarm(gen)
@@ -1244,25 +1246,24 @@ class Engine:
             # state, nothing was committed, no late tokens reach settled
             # futures or a restarted loop's pool
             return
-        self._set_pool(outs[1], outs[2] if self._quantized else None)
-        next_np = np.asarray(outs[0]._data)        # the ONE host sync
+        with _trace.phase("serving.decode.wait"):
+            self._set_pool(outs[1], outs[2] if self._quantized else None)
+            next_np = np.asarray(outs[0]._data)    # the ONE host sync
         now = time.monotonic()
         _obs.inc("serving.steps_total")
         # which decode tier actually ran (ISSUE 13): the bench's
         # all-dense-on-TPU suspect rule reads this split
         _obs.inc("serving.paged_attention_steps_total",
                  path=self._paged_path)
-        traced = _trace.enabled()
-        for i, slot in enumerate(included):
-            slot.t += 1
-            if traced and len(slot.tokens) % _DECODE_TRACE_EVERY == 0:
-                # every Nth token: a point on the REQUEST's track, linked
-                # across threads via its carried context
-                _trace.instant("serving.decode_step",
-                               parent=slot.pending.trace_ctx,
-                               rid=slot.request.request_id, t=slot.t,
-                               tokens=len(slot.tokens))
-            self._emit_token(slot, int(next_np[i, 0]), now)
+        with _trace.phase("serving.decode.emit"):
+            for i, slot in enumerate(included):
+                slot.t += 1
+                self._emit_token(slot, int(next_np[i, 0]), now)
+        with _trace.phase("serving.decode.release"):
+            # the step's device arrays (three inputs, the token output)
+            # die here, not at the frame's exit: on the chip freeing them
+            # takes about a millisecond, and it should carry a name
+            del args, outs
 
     def _emit_token(self, slot: _Slot, token: int, now: float,
                     first: bool = False) -> None:

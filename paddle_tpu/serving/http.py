@@ -285,7 +285,13 @@ class _Handler(QuietJSONHandler):
             return
         front = self._front
         events: "queue.Queue" = queue.Queue()
-        if stream:
+        if stream and _trace.mode() == "on":
+            # ISSUE 25: stamp each token at hand-over (the engine's step
+            # thread calls this), so the handler can say how long the
+            # token took from there to a flushed frame
+            req.stream = lambda rid, tok: events.put(
+                ("token", tok, time.perf_counter()))
+        elif stream:
             req.stream = lambda rid, tok: events.put(("token", tok))
         try:
             fut = front.backend.submit(req)
@@ -357,7 +363,7 @@ class _Handler(QuietJSONHandler):
         index = 0
         while True:
             try:
-                kind, val = events.get(
+                kind, val, *stamp = events.get(
                     timeout=max(0.0, deadline - time.monotonic()))
             except queue.Empty:
                 # the terminal-resolution grace expired: typed terminal
@@ -377,6 +383,11 @@ class _Handler(QuietJSONHandler):
                     b"data: " + json.dumps(
                         {"token": int(val), "index": index}
                     ).encode("utf-8") + b"\n\n")
+                if stamp:               # tracing was on at submit
+                    _trace.phase_instant(
+                        "serving.http.token", rid=req.request_id,
+                        index=index,
+                        lag_ms=(time.perf_counter() - stamp[0]) * 1e3)
                 index += 1
                 if not ok:
                     # client gone (real or double-injected): cancel so the
@@ -415,8 +426,7 @@ class _Handler(QuietJSONHandler):
         callback never blocks, without writing to the dead socket."""
         try:
             while True:
-                kind, _val = events.get(timeout=_TERMINAL_GRACE_S)
-                if kind == "end":
+                if events.get(timeout=_TERMINAL_GRACE_S)[0] == "end":
                     return
         except queue.Empty:
             return   # cancel raced a terminal already consumed: nothing owed

@@ -469,6 +469,29 @@ def test_span_discipline_flags_span_outside_with(tmp_path):
     assert len(found) == 1 and "outside a `with`" in found[0].message
 
 
+def test_span_discipline_holds_phase_spans_to_the_same_rules(tmp_path):
+    """ISSUE 25: ``phase(...)`` is a span constructor too — only as a
+    ``with`` item, and guarded in the hot modules like ``phase_instant``."""
+    found = _lint_snippet(tmp_path, """\
+        from paddle_tpu.observability import trace as _trace
+
+        def f():
+            p = _trace.phase("serving.decode.build")
+            with _trace.phase("serving.decode.wait"):
+                _trace.phase_instant("serving.http.token", rid=1)
+        """, "span-discipline")
+    assert len(found) == 1 and "`phase(...)` used outside" in found[0].message
+    hot = _lint_snippet(tmp_path, """\
+        from paddle_tpu.observability import trace as _trace
+
+        def dispatch():
+            with _trace.phase("jit.call"):
+                _trace.phase_instant("tick")
+        """, "span-discipline", filename="hot.py",
+        config={"span_hot_modules": ["hot.py"]})
+    assert len(hot) == 2 and all("enabled() guard" in f.message for f in hot)
+
+
 def test_span_discipline_with_statement_is_clean(tmp_path):
     found = _lint_snippet(tmp_path, """\
         from paddle_tpu.observability import trace as _trace

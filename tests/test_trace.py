@@ -20,6 +20,7 @@ The acceptance surface for ``paddle_tpu.observability.trace`` / ``http``:
 import json
 import os
 import threading
+import time
 import urllib.request
 from types import SimpleNamespace
 
@@ -353,7 +354,7 @@ class TestServingTrace:
             mine = _req_events(evs, r.request_id)
             names = {e["name"] for e in mine}
             assert {"serving.submit", "serving.queued", "serving.prefill",
-                    "serving.decode_step", "serving.complete"} <= names
+                    "serving.complete"} <= names
             # CONNECTED: every event of this request shares one trace id,
             # and every span parents to the request root or a sibling span
             trace_ids = {e["trace"] for e in mine}
@@ -433,6 +434,309 @@ class TestServingTrace:
         eng.stop(drain=True, timeout=5)
         assert fut.result(timeout=0).tokens == dense_reference(PROMPTS[0], 4)
         assert len(trace.events()) == buf_before
+
+
+# ---------------------------------------------------------------------------
+# phase spans + the profiler mirror (ISSUE 25)
+# ---------------------------------------------------------------------------
+
+_DECODE_PHASES = ["serving.decode.build", "serving.decode.launch",
+                  "serving.decode.wait", "serving.decode.emit",
+                  "serving.decode.release"]
+
+
+def _serve(n_requests=2, new_tokens=4, stream=None):
+    """A tiny engine through ``run()``: admission, a few batched decode
+    steps, drain. Returns the engine (stopped)."""
+    eng = make_engine(max_batch=4)
+    futs = [eng.submit(serving.GenerationRequest(
+        p, max_new_tokens=new_tokens, stream=stream))
+        for p in PROMPTS[:n_requests]]
+    eng.run()
+    eng.stop(drain=True, timeout=10)
+    for f, p in zip(futs, PROMPTS):
+        assert f.result(timeout=0).tokens == dense_reference(p, new_tokens)
+    return eng
+
+
+def _span_table(evs):
+    begins = {e["span"]: e for e in evs if e["kind"] == "B"}
+    ends = {e["span"]: e for e in evs if e["kind"] == "E"}
+    kids = {}
+    for b in begins.values():
+        kids.setdefault(b["parent"], []).append(b)
+    return begins, ends, kids
+
+
+class TestPhaseSpans:
+    def test_decode_step_has_its_phases(self, tracing, metrics):
+        _serve()
+        evs = trace.events()
+        assert trace.span_problems(evs) == []
+        begins, ends, kids = _span_table(evs)
+        decodes = [b for b in begins.values()
+                   if b["name"] == "serving.decode"]
+        assert len(decodes) >= 3
+        for d in decodes:
+            mine = kids.get(d["span"], [])
+            assert [k["name"] for k in mine] == _DECODE_PHASES
+            # in order, inside the step, not overlapping
+            t = d["ts"]
+            for k in mine:
+                assert t <= k["ts"] <= ends[k["span"]]["ts"]
+                t = ends[k["span"]]["ts"]
+            assert t <= ends[d["span"]]["ts"]
+
+    def test_jit_dispatch_inside_jit_call_inside_launch(self, tracing,
+                                                         metrics):
+        _serve()
+        begins, ends, kids = _span_table(trace.events())
+        launches = [b for b in begins.values()
+                    if b["name"] == "serving.decode.launch"]
+        assert launches
+        for la in launches:
+            calls = kids.get(la["span"], [])
+            assert [c["name"] for c in calls] == ["jit.call"]
+            inner = kids.get(calls[0]["span"], [])
+            assert [c["name"] for c in inner] == ["jit.dispatch"]
+            assert (la["ts"] <= calls[0]["ts"] <= inner[0]["ts"]
+                    <= ends[inner[0]["span"]]["ts"]
+                    <= ends[calls[0]["span"]]["ts"]
+                    <= ends[la["span"]]["ts"])
+        # every dispatch has a jit.call around it, wherever it ran
+        for b in begins.values():
+            if b["name"] == "jit.dispatch":
+                assert begins[b["parent"]]["name"] == "jit.call"
+
+    def test_step_phases_ride_the_engine_track(self, tracing, metrics):
+        eng = make_engine(max_batch=4)
+        fut = eng.submit(serving.GenerationRequest(PROMPTS[0],
+                                                   max_new_tokens=3))
+        eng.start()              # the loop thread: idle waits get a span
+        try:
+            fut.result(timeout=60)
+            time.sleep(0.05)     # let the loop go idle at least once
+        finally:
+            eng.stop(drain=True, timeout=10)
+        evs = trace.events()
+        assert trace.span_problems(evs) == []
+        track = eng._engine_trace.trace
+        names = {e["name"] for e in evs
+                 if e["kind"] == "B" and e["trace"] == track}
+        assert {"serving.cancel", "serving.admit", "serving.decode",
+                "serving.publish", "serving.idle"} <= names
+        # the request's own track keeps its prefill; the engine's admit
+        # phase covers it in time
+        begins, ends, _ = _span_table(evs)
+        pre = next(b for b in begins.values()
+                   if b["name"] == "serving.prefill")
+        assert pre["trace"] != track
+        assert any(b["name"] == "serving.admit"
+                   and b["ts"] <= pre["ts"]
+                   and ends[pre["span"]]["ts"] <= ends[b["span"]]["ts"]
+                   for b in begins.values())
+
+    def test_prefill_span_closes_after_the_first_token_exists(
+            self, tracing, metrics, monkeypatch):
+        """The pool swap and the first-token host read happen INSIDE
+        ``serving.prefill``; the token reaches the stream after it."""
+        seen = []
+        real = serving.Engine._set_pool
+
+        def spy(self, pool_t, scales_t):
+            seen.append(trace.current())
+            return real(self, pool_t, scales_t)
+
+        monkeypatch.setattr(serving.Engine, "_set_pool", spy)
+        first_token_at = {}
+
+        def stream(rid, tok):
+            first_token_at.setdefault(rid, trace.make_event("x", "x")["ts"])
+
+        _serve(n_requests=1, stream=stream)
+        begins, ends, kids = _span_table(trace.events())
+        pre = next(b for b in begins.values()
+                   if b["name"] == "serving.prefill")
+        assert seen[0] is not None and seen[0].span == pre["span"]
+        assert set(pre["attrs"]) == {"rid", "prompt", "shared_pages",
+                                     "replay"}
+        # the program call is a complete child, the read-back follows it
+        call = next(k for k in kids[pre["span"]] if k["name"] == "jit.call")
+        assert ends[call["span"]]["ts"] <= ends[pre["span"]]["ts"]
+        assert ends[pre["span"]]["ts"] <= first_token_at[
+            pre["attrs"]["rid"]]
+
+    def test_compiled_call_nests_under_the_open_span(self, tracing):
+        """What ``train.captured_step`` relies on: a compiled call opened
+        under any span becomes its child, with the dispatch inside."""
+        @paddle.jit.to_static
+        def double(x):
+            return x * 2
+
+        with trace.span("train.captured_step", label="t"):
+            out = double(paddle.to_tensor([1.0, 2.0]))
+        assert out.numpy().tolist() == [2.0, 4.0]
+        evs = trace.events()
+        assert trace.span_problems(evs) == []
+        begins, ends, kids = _span_table(evs)
+        step = next(b for b in begins.values()
+                    if b["name"] == "train.captured_step")
+        call, = kids[step["span"]]
+        assert call["name"] == "jit.call"
+        assert [k["name"] for k in kids[call["span"]]] == ["jit.dispatch"]
+
+    def test_off_mode_buffers_nothing_and_builds_no_annotation(
+            self, metrics, monkeypatch):
+        assert trace.mode() == "off"
+        built = []
+        monkeypatch.setattr(trace, "_annotation",
+                            lambda *a: built.append(a))
+        assert trace.phase("serving.decode.build") is trace.span("x")
+        trace.phase_instant("serving.http.token", rid=0)
+        before = len(trace.events())
+        _serve()
+        assert len(trace.events()) == before
+        assert built == []
+
+    def test_flight_mode_keeps_phases_out_of_the_ring(self, metrics,
+                                                       monkeypatch):
+        built = []
+        monkeypatch.setattr(trace, "_annotation",
+                            lambda *a: built.append(a))
+        trace.flight_recorder().clear()
+        with trace.tracing("flight"):
+            assert trace.phase("jit.call") is trace._NOOP
+            _serve()
+        names = {e["name"] for e in trace.flight_recorder().snapshot()}
+        trace.flight_recorder().clear()
+        assert "serving.decode" in names and "serving.prefill" in names
+        assert not {n for n in names
+                    if n.startswith(("serving.decode.", "jit."))
+                    or n in ("serving.cancel", "serving.admit",
+                             "serving.publish", "serving.idle",
+                             "serving.http.token")}
+        assert built == [] and trace.events() == []
+
+    def test_on_mode_phases_skip_the_ring(self, tracing):
+        with trace.span("serving.decode", batch=1):
+            with trace.phase("serving.decode.build"):
+                trace.phase_instant("serving.http.token", rid=1, lag_ms=0.0)
+        ring = {e["name"] for e in trace.flight_recorder().snapshot()}
+        assert ring == {"serving.decode"}
+        assert {e["name"] for e in trace.events()} == {
+            "serving.decode", "serving.decode.build", "serving.http.token"}
+
+    def test_mirror_carries_numbers_and_short_strings(self, tracing,
+                                                      monkeypatch):
+        made = []
+
+        class Fake:
+            def __init__(self, name, **kw):
+                made.append((name, kw))
+
+            def __enter__(self):
+                made.append("enter")
+
+            def __exit__(self, *exc):
+                made.append("exit")
+
+        monkeypatch.setattr(trace, "_ANNOTATION", Fake)
+        with trace.span("serving.decode", batch=3, label="r0", ratio=0.5,
+                        blob="x" * 200, obj=object()):
+            pass
+        assert made == [("serving.decode",
+                         {"batch": 3, "label": "r0", "ratio": 0.5}),
+                        "enter", "exit"]
+
+    def test_real_profiler_slice_holds_the_phase_names(self, tracing,
+                                                       metrics, tmp_path):
+        """One real ``jax.profiler`` slice on the CPU, as the benchmark's
+        ``ProfilerSlice`` takes it: the program's spans land in the host
+        plane of the ``.xplane.pb``, named as in the buffer, nested on the
+        step thread's line. Bounded by its own time limit."""
+        found, errors = {}, []
+
+        def body():
+            try:
+                import glob
+
+                import jax.profiler
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level = 0
+                opts.host_tracer_level = 1
+                jax.profiler.start_trace(str(tmp_path),
+                                         profiler_options=opts)
+                try:
+                    _serve()
+                finally:
+                    jax.profiler.stop_trace()
+                path, = glob.glob(str(
+                    tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+                data = jax.profiler.ProfileData.from_file(path)
+                for plane in data.planes:
+                    if not plane.name.startswith("/host:"):
+                        continue
+                    for line in plane.lines:
+                        evs = [(e.name, e.start_ns, e.duration_ns,
+                                dict(e.stats)) for e in line.events
+                               if e.name.startswith(("serving.", "jit."))]
+                        if evs:
+                            found[line.name] = evs
+            except Exception as exc:        # reported by the assert below
+                errors.append(exc)
+
+        t = threading.Thread(target=body, daemon=True)
+        t.start()
+        t.join(timeout=120)
+        assert not t.is_alive(), "profiler slice did not finish in 120 s"
+        assert errors == []
+        evs, = found.values()               # run(): one thread did it all
+        names = {e[0] for e in evs}
+        assert set(_DECODE_PHASES) | {
+            "serving.decode", "serving.prefill", "serving.admit",
+            "serving.cancel", "serving.publish", "jit.call",
+            "jit.dispatch"} <= names
+        # attributes arrive as stats, not in the name
+        assert any(e[0] == "serving.decode" and e[3].get("batch") == 2
+                   for e in evs)
+        # one clock: a launch lies inside its decode step on that line
+        steps = [e for e in evs if e[0] == "serving.decode"]
+        for la in (e for e in evs if e[0] == "serving.decode.launch"):
+            assert any(s[1] <= la[1] and la[1] + la[2] <= s[1] + s[2]
+                       for s in steps)
+
+    def test_front_door_stamps_every_streamed_token(self, tracing, metrics):
+        from test_serving_http import stream_generate
+        eng = make_engine(max_batch=4).start()
+        fd = serving.FrontDoor(eng)
+        try:
+            tokens, terminals = stream_generate(fd, PROMPTS[0],
+                                                max_new_tokens=6)
+        finally:
+            eng.stop(drain=True, timeout=10)
+            fd.close()
+        assert tokens == dense_reference(PROMPTS[0], 6)
+        assert [t[0] for t in terminals] == ["done"]
+        stamps = [e for e in trace.events()
+                  if e["name"] == "serving.http.token"]
+        assert [e["attrs"]["index"] for e in stamps] == list(range(6))
+        assert len({e["attrs"]["rid"] for e in stamps}) == 1
+        assert all(e["kind"] == "i" and e["attrs"]["lag_ms"] >= 0
+                   for e in stamps)
+
+    def test_front_door_untraced_stream_is_unstamped(self, metrics):
+        from test_serving_http import stream_generate
+        assert trace.mode() == "off"
+        before = len(trace.events())
+        eng = make_engine(max_batch=4).start()
+        fd = serving.FrontDoor(eng)
+        try:
+            tokens, _ = stream_generate(fd, PROMPTS[0], max_new_tokens=4)
+        finally:
+            eng.stop(drain=True, timeout=10)
+            fd.close()
+        assert tokens == dense_reference(PROMPTS[0], 4)
+        assert len(trace.events()) == before
 
 
 # ---------------------------------------------------------------------------

@@ -9,8 +9,9 @@ Two invariants of the ISSUE 12 tracing layer:
   pairs. The rule flags any call to a manual pairing API
   (``begin_span``/``end_span`` — deliberately not exported by ``trace``,
   so a finding means someone re-grew one) and any ``trace.span(...)`` /
-  ``span(...)`` call that is not the context expression of a ``with``
-  item (assigning the manager and entering it by hand re-opens the
+  ``span(...)`` call (or ``phase(...)``, its mode-``on``-only detail
+  tier) that is not the context expression of a ``with`` item (assigning
+  the manager and entering it by hand re-opens the
   unbalanced-on-exception hole).
 
 * **The dispatch fast path pays nothing for disabled tracing.** Inside
@@ -38,7 +39,11 @@ from ..engine import FileContext, Rule, register_rule
 _MANUAL_NAMES = {"begin_span", "end_span"}
 
 #: trace-layer constructors that must be guarded in hot modules
-_GUARDED_NAMES = {"span", "instant", "new_trace", "record"}
+_GUARDED_NAMES = {"span", "phase", "instant", "phase_instant", "new_trace",
+                  "record"}
+
+#: the context-manager constructors: legal only as a ``with`` item
+_SPAN_NAMES = {"span", "phase"}
 
 
 def _trace_aliases(tree: ast.Module):
@@ -134,12 +139,12 @@ class SpanDisciplineRule(Rule):
                         f"only as `with trace.span(...):` context managers "
                         f"— balanced begin/end on every exit path is the "
                         f"flight recorder's structural guarantee"))
-                elif kind == "span" and id(node) not in with_items:
+                elif kind in _SPAN_NAMES and id(node) not in with_items:
                     findings.append(ctx.finding(
                         node, self.name,
-                        "`span(...)` used outside a `with` item: entering "
-                        "the manager by hand re-opens the unbalanced-on-"
-                        "exception hole — write `with trace.span(...):`"))
+                        f"`{kind}(...)` used outside a `with` item: entering "
+                        f"the manager by hand re-opens the unbalanced-on-"
+                        f"exception hole — write `with trace.{kind}(...):`"))
                 elif kind is not None and hot and not guarded:
                     findings.append(ctx.finding(
                         node, self.name,
