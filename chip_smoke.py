@@ -107,17 +107,19 @@ def train_bytes(depth: int, batch: int = TRAIN_BATCH,
 def serve_bytes(depth: int) -> int:
     """Modeled peak HBM of the serve leg: bf16 weights, the prefill's dense
     single-slot cache, workspace, and the page pool, sized for every slot's
-    full ``max_len`` — four times. The decode and prefill programs take
-    the pool as a plain argument and return a new one (functional state:
-    a faulted step commits nothing), and the copy needs a pool-sized
-    temporary of its own. Measured on a TPU v5e at depth 6: peak =
-    weights + 4 pools to within 10 MB (2.96 + 4 x 3.23 = 15.87 GB)."""
+    full ``max_len`` — once: every serving program takes the pool donated
+    and writes it in place (ISSUE 26; before it the pool was a plain
+    argument that came back as a new array, and the peak held four).
+    Measured on a TPU v5e at depth 5: 5.31 GB in use after warm-up against
+    2.55 of weights + 2.69 of pool; the peak, 5.36, was the fp32 build.
+    At depth 13, what this model picks on 16 GB: peak 12.95 GB of the
+    14.73 modeled."""
     n = PARAMS_FIXED + depth * PARAMS_LAYER
     pages = SERVE_MAX_BATCH * (SERVE_MAX_LEN // SERVE_PAGE) + 1
     pool = pages * depth * 2 * HEADS * SERVE_PAGE * HEAD_DIM * 2
     dense_slot = depth * 2 * HEADS * SERVE_MAX_LEN * HEAD_DIM * 2
     build = 4 * n                              # fp32 init before the cast
-    run = 2 * n + 4 * pool + 2 * dense_slot + (1 << 30)
+    run = 2 * n + pool + 2 * dense_slot + (1 << 30)
     return max(build, run)
 
 
@@ -391,6 +393,19 @@ def _paged_kernel_check(*, on_chip=True) -> dict:
     return errs
 
 
+def pool_copies(text: str, pool_shape) -> int:
+    """How many ``copy`` operations of a compiled program's text produce
+    an array of the page pool's shape, in whatever layout. The decode
+    program takes the pool donated and writes it in place: it should hold
+    none (the program before ISSUE 26 held 1 / 8 / 8 in buckets 1 / 4 /
+    16: the argument was not donated, and each layer's scatter ran in a
+    layout of its own, copied back for the next layer's kernel)."""
+    import re
+    dims = ",".join(str(int(d)) for d in pool_shape)
+    return len(re.findall(
+        r"= \w+\[" + re.escape(dims) + r"\]\S* copy\(", text))
+
+
 def leg_serve(depth=None, *, config=None, on_chip=True) -> dict:
     info = open_leg(require_tpu=on_chip)
     import concurrent.futures as cf
@@ -449,6 +464,21 @@ def leg_serve(depth=None, *, config=None, on_chip=True) -> dict:
     assert engine._paged_path == want_path, engine._paged_path
     engine.warmup(prompt_lens=SERVE_PROMPT_LENS)
     warmup_s = time.perf_counter() - t0
+    # what only the chip's compiler can say: no decode bucket copies the
+    # page pool (each compiled_text() compiles once more, before the
+    # no-compile window opens)
+    paddle.set_flags({"FLAGS_to_static_capture_lowered": True})
+    copies = {}
+    for b in SERVE_BUCKETS:
+        engine._warm_decode(b)
+        copies[b] = pool_copies(engine._decode_program.compiled_text(),
+                                engine.kv.pool.shape)
+    paddle.set_flags({"FLAGS_to_static_capture_lowered": False})
+    log(f"serve: pool-shaped copies in the compiled decode programs, by "
+        f"bucket: {copies}")
+    if on_chip:
+        assert not any(copies.values()), \
+            f"a decode program copies the page pool: {copies}"
     warm_compiles, warm_compile_s = compiles.count, compiles.seconds
     log(f"serve: warmup {warmup_s:.1f}s ({warm_compiles} backend compiles, "
         f"{warm_compile_s:.1f}s); {jax.devices()[0].memory_stats()}")
@@ -528,6 +558,7 @@ def leg_serve(depth=None, *, config=None, on_chip=True) -> dict:
         "compile_s": round(warm_compile_s, 1),
         "compiles_in_warmup": warm_compiles,
         "compiles_after_warmup": after_warmup,
+        "pool_copies_by_bucket": {str(b): n for b, n in copies.items()},
         "kernel_vs_dense_max_err": kernel_errs,
         "transcript_match_vs_off": round(match, 3),
         "prefix_shared_prefill": [comp1 - comp0, req1 - req0],

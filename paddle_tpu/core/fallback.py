@@ -82,8 +82,11 @@ DEFAULT_DENYLIST = frozenset({"eig", "sgn", "hfft2"})
 
 # Ops whose pure fn is a Pallas kernel. A kernel the compiler refuses is a
 # defect to surface, never a reason to leave the chip: these never degrade.
+# paged_commit_tokens is the decode kernel's companion, the in-place write
+# into the page pool: degraded, it would carry the whole pool to the host.
 KERNEL_OPS = frozenset({"flash_attention", "flash_attention_dropout",
-                        "flash_attn_unpadded", "paged_attention_decode"})
+                        "flash_attn_unpadded", "paged_attention_decode",
+                        "paged_commit_tokens"})
 
 
 def _env_mode() -> str:

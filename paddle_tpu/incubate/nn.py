@@ -513,13 +513,17 @@ class FusedMultiTransformer(nn.Layer):
 
     def _paged_scan_decode(self, src, view, steps, attn_mask):
         """Whole-stack single-token decode over the PAGED pool: one
-        lax.scan over layers whose carry is ``(x, pool[, scales])`` — the
-        dense ``(L, 2, B, H, max_len, D)`` cache never exists in this
-        program (ISSUE 13). Each layer's attention streams its live pages
-        through the paged-attention kernel and writes position ``t``'s
-        K/V into the containing page; the layer index rides the scan xs
-        so one compiled body serves every layer."""
+        lax.scan over layers whose carry is ``x`` alone — the dense
+        ``(L, 2, B, H, max_len, D)`` cache never exists in this program
+        (ISSUE 13), and the pool is only read in it (ISSUE 26). Each
+        layer's attention streams its live pages through the
+        paged-attention kernel; its position-``t`` K/V leaves the scan
+        stacked over layers and rides the returned handle as pending, for
+        the one pool write of the step (``ops.paged_attention.
+        commit_pending``, made by the handle's owner). The layer index
+        rides the scan xs so one compiled body serves every layer."""
         import jax
+        from dataclasses import replace as _replace
 
         from ..core.tensor import Tensor as _T, apply as _apply
         from ..core.tracing import no_grad
@@ -531,42 +535,29 @@ class FusedMultiTransformer(nn.Layer):
                 "decode contract; use the dense tier for additive masks)")
         stacked = self._decode_stack()
         quantized = view.scales is not None
-        make_view = view.at_layer                 # rebind per layer below
 
         def fn(x, pool, st, tables, *rest):
-            rest = list(rest)
-            sc = rest.pop(0) if quantized else None
+            view_c = _replace(view, pool=_T(pool), tables=_T(tables),
+                              t=_T(st), pending=(),
+                              scales=_T(rest[0]) if quantized else None)
 
-            def body(carry, sl):
-                x_c, pool_c = carry[0], carry[1]
-                sc_c = carry[2] if quantized else None
+            def body(x_c, sl):
                 w = tuple(_T(a) for a in sl[:-1])
-                li = sl[-1]
-                from dataclasses import replace as _replace
-                view_l = _replace(make_view(_T(li)), pool=_T(pool_c),
-                                  tables=_T(tables), t=_T(st),
-                                  scales=_T(sc_c) if quantized else None)
                 with no_grad():
-                    xo, view_o = self._decode_layer(_T(x_c), _T(st), None,
-                                                    w, view_l)
-                out = (xo._data, view_o.pool._data)
-                if quantized:
-                    out += (view_o.scales._data,)
-                return out, None
+                    xo, view_o = self._decode_layer(
+                        _T(x_c), _T(st), None, w, view_c.at_layer(_T(sl[-1])))
+                (k_new, v_new), = view_o.pending
+                return xo._data, (k_new._data, v_new._data)
 
             layer_ids = jnp.arange(self.num_layers, dtype=jnp.int32)
-            init = (x, pool) + ((sc,) if quantized else ())
             xs = tuple(w._data for w in stacked) + (layer_ids,)
-            final, _ = jax.lax.scan(body, init, xs)
-            return final
+            x_out, (ks, vs) = jax.lax.scan(body, x, xs)
+            return x_out, ks, vs
 
         args = [src, view.pool, steps, view.tables] + \
             ([view.scales] if quantized else [])
-        outs = _apply("fmt_paged_scan_decode", fn, *args, amp=False)
-        from dataclasses import replace as _replace
-        new_view = _replace(view, pool=outs[1],
-                            scales=outs[2] if quantized else None)
-        return outs[0], new_view
+        x_out, ks, vs = _apply("fmt_paged_scan_decode", fn, *args, amp=False)
+        return x_out, _replace(view, pending=view.pending + ((ks, vs),))
 
     def forward(self, src, attn_mask=None, caches=None, pre_caches=None,
                 rotary_embs=None, rotary_emb_dims=0, seq_lens=None,

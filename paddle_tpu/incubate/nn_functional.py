@@ -473,9 +473,9 @@ def _paged_mmha(x, cache):
 
     ``x`` is the (B, 3*H*D) fused qkv of ONE new token (fused layout ⇒
     q heads == kv heads). Splits q/k/v, runs the paged kernel for the
-    view's layer, writes position ``t``'s K/V into its containing page,
-    and returns ``(out (B, H*D), cache')`` — the same contract the dense
-    branch serves from the stacked cache."""
+    view's layer and returns ``(out (B, H*D), cache')``, position ``t``'s
+    K/V pending on ``cache'`` for the step's one pool write
+    (``ops.paged_attention.commit_pending``)."""
     from ..ops.manipulation import reshape
     from ..ops.paged_attention import paged_decode_attention
     nh, hd = cache.num_kv_heads, cache.head_dim
@@ -522,7 +522,7 @@ def masked_multihead_attention(x, bias=None, src_mask=None,
         # paged-attention decode tier (ISSUE 13): the cache is a page-pool
         # view, not the dense (2, B, H, max_len, D) buffer — attention
         # streams the slot's live pages through the Pallas kernel and the
-        # token writes back into its containing page. ``sequence_lengths``
+        # token's K/V rides the returned view as pending. ``sequence_lengths``
         # already rides inside the view (``t``); an additive src_mask has
         # no kernel leg (the span mask is the decode contract).
         if src_mask is not None:

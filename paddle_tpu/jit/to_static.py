@@ -117,6 +117,10 @@ class StaticFunction:
       call; nothing is left pointing at a deleted donated array);
     * additional mutated locations discovered while tracing (``.grad`` slots,
       non-registered tensors) ride along as extra outputs via the holder spec.
+    * plain arguments are read-only inputs, except those named by
+      ``donate_argnums``: their arrays are donated like the state (XLA may
+      alias them to outputs and write in place) and are deleted by the
+      call — the caller adopts what the program returns in their place.
     * cache entries hold only WEAK references to state tensors; the cache key
       is the tuple of registry ids, so a discarded model's entry can never be
       hit again and its parameter arrays are free to be collected.
@@ -124,10 +128,22 @@ class StaticFunction:
 
     def __init__(self, fn: Callable, input_spec=None, build_strategy=None,
                  backend=None, full_graph=True, donate_states: bool = True,
-                 iters_per_call: int = 1):
+                 iters_per_call: int = 1, donate_argnums=()):
         functools.update_wrapper(self, fn)
         self._fn = fn
         self._donate = donate_states
+        # positional arguments the CALLER gives up beside the state (the
+        # serving engine's page pool): every array under them is donated,
+        # so the program may write it in place, and is deleted by the call.
+        # The caller takes the buffer back from the outputs. Asked for per
+        # argument; without it a plain argument is never consumed.
+        self._donate_argnums = tuple(sorted({int(i) for i in donate_argnums}))
+        if self._donate_argnums and (int(iters_per_call) > 1
+                                     or not full_graph):
+            # a scanned argument is sliced per step; an eager re-run after
+            # a graph break would read the deleted array
+            raise ValueError("donate_argnums needs full_graph=True and "
+                             "iters_per_call=1")
         # full_graph=False is the reference SOT contract: a trace failure
         # (tensor-dependent Python control flow) switches the signature to
         # PARTIAL-GRAPH capture — the lazy segment executor (core/lazy.py)
@@ -268,7 +284,8 @@ class StaticFunction:
         if fresh_build:
             _obs.inc("jit.cache_misses_total")
             entry = self._build(treedef, proto, statics,
-                                [t for _, t in state_items])
+                                [t for _, t in state_items],
+                                self._given_up(args, proto))
             self._cache[key] = entry
         jitted, state_refs, holder = entry
 
@@ -379,17 +396,18 @@ class StaticFunction:
             self._last_lowered = (jitted,
                                   [_lower_spec(a) for a in state_arrays],
                                   [_lower_spec(a) for a in arg_arrays])
-        if self._donate:
+        given = getattr(jitted, "given", ())
+        if self._donate or given:
             # donated buffers must be unique: two state tensors aliasing one
             # jax.Array (or a state array that is also a plain argument) make
             # XLA reject the executable call on TPU. Copy the duplicates so
-            # every donated slot owns its buffer.
-            seen = {id(a) for a in arg_arrays}
-            for i, a in enumerate(state_arrays):
-                if id(a) in seen:
-                    state_arrays[i] = jnp.copy(a)
-                else:
-                    seen.add(id(a))
+            # every donated slot owns its buffer — the given-up plain
+            # arguments first, then the state.
+            seen = {id(a) for i, a in enumerate(arg_arrays)
+                    if i not in given}
+            _own_buffers(arg_arrays, given, seen)
+            if self._donate:
+                _own_buffers(state_arrays, range(len(state_arrays)), seen)
         holder["traced"] = False
         try:
             with _trace.phase("jit.dispatch"):
@@ -437,7 +455,22 @@ class StaticFunction:
         return jax.tree_util.tree_map(stack_leaf, *outs, is_leaf=_is_tensor)
 
     # -------------------------------------------------------------------------
-    def _build(self, treedef, proto, statics, state_tensors):
+    def _given_up(self, args, proto) -> Tuple[int, ...]:
+        """Where the arrays under the ``donate_argnums`` arguments sit among
+        the call's flat array arguments (leaves in flattening order, the
+        static ones skipped). Fixed per cache entry, like ``proto``."""
+        if not self._donate_argnums:
+            return ()
+        bounds = [0]
+        for a in args:
+            bounds.append(bounds[-1] + len(jax.tree_util.tree_flatten(
+                a, is_leaf=_is_tensor)[0]))
+        leaves = {j for i in self._donate_argnums if i < len(args)
+                  for j in range(bounds[i], bounds[i + 1])}
+        arrays = [j for j, p in enumerate(proto) if p is not _STATIC]
+        return tuple(k for k, j in enumerate(arrays) if j in leaves)
+
+    def _build(self, treedef, proto, statics, state_tensors, given=()):
         _obs.inc("jit.traces_total")
         if self._iters > 1:
             return self._build_scan(treedef, proto, statics, state_tensors)
@@ -498,7 +531,10 @@ class StaticFunction:
                     t._data = arr
 
         donate = (0,) if self._donate else ()
-        jitted = jax.jit(pure_fn, donate_argnums=donate)
+        if given:
+            jitted = _GivenUp(pure_fn, given, donate)
+        else:
+            jitted = jax.jit(pure_fn, donate_argnums=donate)
         return jitted, state_refs, holder
 
     def _build_scan(self, treedef, proto, statics, state_tensors):
@@ -593,6 +629,45 @@ class StaticFunction:
                     tt._grad._data = val
 
 
+def _own_buffers(arrays: List[Any], positions, seen: set) -> None:
+    """Replace, in place, every array at ``positions`` whose buffer was
+    already ``seen`` by a copy, and note the others as seen."""
+    for i in positions:
+        if id(arrays[i]) in seen:
+            arrays[i] = jnp.copy(arrays[i])
+        else:
+            seen.add(id(arrays[i]))
+
+
+class _GivenUp:
+    """The jitted program of an entry whose flat array arguments at
+    ``given`` the caller gives up. jax donates whole top-level arguments,
+    so those arrays travel as a third one, donated beside the state; this
+    keeps the plain entry's ``(state_arrays, arg_arrays)`` surface for the
+    call, ``compiled_text()`` and the cost hook's ``lower``."""
+
+    def __init__(self, fn, given: Tuple[int, ...], donate: Tuple[int, ...]):
+        self.given = given
+
+        def pure_fn(state_arrays, kept, gone):
+            arrays = list(kept)
+            for i, a in zip(given, gone):    # ascending: final positions
+                arrays.insert(i, a)
+            return fn(state_arrays, arrays)
+
+        self._jitted = jax.jit(pure_fn, donate_argnums=donate + (2,))
+
+    def _split(self, arrays):
+        return ([a for i, a in enumerate(arrays) if i not in self.given],
+                [arrays[i] for i in self.given])
+
+    def __call__(self, state_arrays, arg_arrays):
+        return self._jitted(state_arrays, *self._split(arg_arrays))
+
+    def lower(self, state_specs, arg_specs):
+        return self._jitted.lower(state_specs, *self._split(arg_specs))
+
+
 class _StaticMarker:
     __slots__ = ()
 
@@ -632,7 +707,8 @@ def to_static(function=None, input_spec=None, build_strategy=None, backend=None,
     """``paddle.jit.to_static`` parity decorator."""
 
     sf_kwargs = {k: kwargs[k]
-                 for k in ("iters_per_call", "donate_states", "full_graph")
+                 for k in ("iters_per_call", "donate_states", "full_graph",
+                           "donate_argnums")
                  if k in kwargs}
 
     def decorate(fn):

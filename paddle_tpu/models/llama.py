@@ -330,8 +330,9 @@ class LlamaForCausalLM(nn.Layer):
           tier: write K/V at ``t``, span-masked attention) OR a
           ``PagedDecodeCache`` view — then every layer's attention
           streams its live pages through the paged-attention Pallas
-          kernel and writes position ``t`` into its containing page
-          (``PADDLE_TPU_PAGED_ATTENTION``; ISSUE 13). GQA stays a
+          kernel and leaves position ``t``'s K/V pending on the returned
+          view, which the engine commits to the pool in one write
+          (``PADDLE_TPU_PAGED_ATTENTION``; ISSUE 13, 26). GQA stays a
           kv-head broadcast on both tiers; RoPE gathers per-row rows at
           each slot's own position.
 

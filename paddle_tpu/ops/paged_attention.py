@@ -26,12 +26,18 @@ decode step's KV traffic O(live pages) reads + O(1) page writes:
   unquantized and joins the softmax in fp32 — matching the dense path,
   where the step writes the fresh token into the gathered cache *before*
   attention and quantization happens only at write-back.
-* **In-place token write** (:func:`scatter_token_inplace`): K/V for
-  position ``t`` lands in the containing pool page by scatter — O(1)
-  pages per slot, no dense round-trip. The int8 leg re-quantizes the one
-  containing page under the kv_cache requantization contract (positions
-  ``> t`` masked to zero; same math as ``scatter_token_page``, sourced
-  from the pool instead of the dense cache).
+* **One in-place token write a step** (:func:`commit_pending` over
+  :func:`scatter_token_inplace`): no layer's kernel reads another
+  layer's position-``t`` write, so the layers only collect their
+  ``(k_new, v_new)`` on the handle and the pool is written once, after
+  the last layer, for all layers together — one row-sized update per
+  batch row into the buffer the program was given (the engine donates
+  it), O(1) pages per slot, no dense round-trip. The kernels read a pool
+  that nothing in the program writes before them. The int8 leg
+  re-quantizes the containing page of every layer under the kv_cache
+  requantization contract (positions ``> t`` masked to zero; same math
+  as ``scatter_token_page``, sourced from the pool instead of the dense
+  cache).
 
 Tiering (the flash-SDPA / step-capture contract): the kernel is the TPU
 tier; off-TPU it runs under the Pallas interpreter when forced (tests)
@@ -57,7 +63,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["PagedDecodeCache", "mode", "decode_path", "kernel_eligible",
            "paged_attention", "paged_attention_dense",
-           "scatter_token_inplace", "paged_decode_attention"]
+           "scatter_token_inplace", "paged_decode_attention",
+           "commit_pending"]
 
 _NEG_INF = -1e30  # matches ops/flash_attention.py's mask fill
 
@@ -139,8 +146,10 @@ class PagedDecodeCache:
     The serving engine builds one per compiled decode call and passes it
     as the ``step_fn``'s cache argument; models that understand it
     (``FusedMultiTransformer``, ``LlamaForCausalLM.serving_callables``)
-    run their cached attention over the kernel and return an updated
-    handle. Fields are Tensors (traced inside the decode program):
+    run their cached attention over the kernel and return the handle
+    with every layer's new K/V pending; the engine commits them to the
+    pool (:func:`commit_pending`). Fields are Tensors (traced inside the
+    decode program):
 
     * ``pool``    — ``(num_pages, L, 2, H_kv, page_size, D)`` storage dtype
     * ``scales``  — ``(num_pages, L, 2, H_kv)`` fp32 (int8 leg only)
@@ -152,6 +161,10 @@ class PagedDecodeCache:
       handle
     * ``impl``    — ``"kernel"`` | ``"dense"`` (the per-layer debug tier)
     * ``interpret`` — run the kernel under the Pallas interpreter (CPU)
+    * ``pending`` — the position-``t`` ``(k_new, v_new)`` pairs of the
+      layers decoded so far, in layer order and not yet in the pool: each
+      ``(B, H_kv, D)`` for one layer or ``(n, B, H_kv, D)`` for ``n``
+      stacked ones (a scan over layers)
     """
 
     pool: object
@@ -162,6 +175,7 @@ class PagedDecodeCache:
     layer: Optional[object] = None
     impl: str = "kernel"
     interpret: bool = False
+    pending: tuple = ()
 
     def at_layer(self, layer) -> "PagedDecodeCache":
         return replace(self, layer=layer)
@@ -173,6 +187,11 @@ class PagedDecodeCache:
     @property
     def head_dim(self) -> int:
         return int(self.pool.shape[5])
+
+    @property
+    def pending_layers(self) -> int:
+        return sum(1 if k.ndim == 3 else int(k.shape[0])
+                   for k, _ in self.pending)
 
 
 # ---------------------------------------------------------------------------
@@ -404,42 +423,56 @@ def paged_attention(q, k_new, v_new, pool, scales, tables, t, layer, *,
 # the in-place token write
 # ---------------------------------------------------------------------------
 
-def scatter_token_inplace(pool, scales, tables, t, layer, k_new, v_new,
+def scatter_token_inplace(pool, scales, tables, t, k_new, v_new,
                           page_size: int):
-    """Write position ``t``'s K/V into the containing pool page for one
-    layer — no dense round-trip. Returns ``(pool', scales')``.
+    """Write position ``t``'s K/V of EVERY layer into the containing pool
+    page — no dense round-trip. ``k_new``/``v_new`` are ``(L, B, H_kv, D)``.
+    Returns ``(pool', scales')``.
 
-    bf16/native: a single-position scatter (O(1) rows per slot). int8:
-    the kv_cache requantization contract — the containing page is
-    gathered, dequantized under its old scale, the token inserted,
-    positions ``> t`` zeroed, and the page re-quantized — the exact math
-    of ``scatter_token_page``, sourced from the pool."""
+    One ``dynamic_update_slice`` per batch row, all layers in it (the
+    layer dimension is whole in the update): XLA writes each in place in
+    the pool's own layout. A scatter, per layer or one for all, is not
+    used: on the TPU it wants the pool in a layout of its own (page rows
+    above the heads), which the Pallas kernel cannot read, so the compiler
+    copied the whole pool into that layout and back around every one.
+
+    bf16/native: a single row per (layer, K/V, head). int8: the kv_cache
+    requantization contract — the containing pages are gathered,
+    dequantized under their old scales, the token inserted, positions
+    ``> t`` zeroed, and the pages re-quantized — the exact math of
+    ``scatter_token_page``, sourced from the pool. Rows that share a page
+    (padded rows, all on the scratch page) are written in row order."""
     ps = page_size
     t32 = t.astype(jnp.int32)
-    l32 = jnp.asarray(layer, jnp.int32)
     pids = jnp.take_along_axis(tables.astype(jnp.int32),
                                (t32 // ps)[:, None], axis=1)[:, 0]  # (B,)
     off = t32 % ps
-    kv_new = jnp.stack([k_new, v_new], axis=1)          # (B, 2, H_kv, D)
+    # (B, L, 2, H_kv, D): a row's update is one block of the pool
+    kv_new = jnp.swapaxes(jnp.stack([k_new, v_new], axis=2), 0, 1)
     if scales is None:
-        return pool.at[pids, l32, :, :, off, :].set(
-            kv_new.astype(pool.dtype)), None
+        rows = kv_new.astype(pool.dtype)[:, None, :, :, :, None, :]
+        for b in range(rows.shape[0]):
+            pool = jax.lax.dynamic_update_slice(
+                pool, rows[b], (pids[b], 0, 0, 0, off[b], 0))
+        return pool, None
     from ..serving.kv_cache import quantize_pages
-    p_, l_ = pool.shape[0], pool.shape[1]
-    flat_idx = pids * l_ + l32
-    page = jnp.take(pool.reshape((p_ * l_,) + pool.shape[2:]), flat_idx,
-                    axis=0).astype(jnp.float32)          # (B, 2, H, ps, D)
-    old_sc = jnp.take(scales.reshape(p_ * l_, *scales.shape[2:]), flat_idx,
-                      axis=0)                            # (B, 2, H)
+    page = jnp.take(pool, pids, axis=0).astype(jnp.float32)  # (B,L,2,H,ps,D)
+    old_sc = jnp.take(scales, pids, axis=0)                  # (B, L, 2, H)
     page = page * old_sc[..., None, None]
-    sel = jax.nn.one_hot(off, ps, dtype=jnp.bool_)[:, None, None, :, None]
+    sel = jax.nn.one_hot(off, ps, dtype=jnp.bool_)[:, None, None, None, :,
+                                                   None]
     page = jnp.where(sel, kv_new.astype(jnp.float32)[..., None, :], page)
     pos = (t32 // ps * ps)[:, None] + jnp.arange(ps, dtype=jnp.int32)[None]
-    valid = pos <= t32[:, None]                          # (B, ps)
-    page = jnp.where(valid[:, None, None, :, None], page, 0.0)
-    q8, sc = quantize_pages(page)                        # (B,2,H,ps,D)/(B,2,H)
-    return (pool.at[pids, l32].set(q8.astype(pool.dtype)),
-            scales.at[pids, l32].set(sc))
+    valid = pos <= t32[:, None]                              # (B, ps)
+    page = jnp.where(valid[:, None, None, None, :, None], page, 0.0)
+    q8, sc = quantize_pages(page)                # (B,L,2,H,ps,D)/(B,L,2,H)
+    q8 = q8.astype(pool.dtype)
+    for b in range(q8.shape[0]):
+        pool = jax.lax.dynamic_update_slice(
+            pool, q8[b][None], (pids[b], 0, 0, 0, 0, 0))
+        scales = jax.lax.dynamic_update_slice(
+            scales, sc[b][None], (pids[b], 0, 0, 0))
+    return pool, scales
 
 
 # ---------------------------------------------------------------------------
@@ -451,15 +484,20 @@ def paged_decode_attention(q, k_new, v_new, cache: PagedDecodeCache):
 
     ``q`` ``(B, H, D)``, ``k_new``/``v_new`` ``(B, H_kv, D)`` Tensors (the
     CURRENT token's projections, attended unquantized at position ``t``);
-    ``cache`` must carry a ``layer``. Returns ``(out (B, H, D) Tensor,
-    cache')`` with the token written into the pool — the decode-step
-    sequence the dense path got from gather → step → scatter, now
-    page-local."""
+    ``cache`` must carry a ``layer``, and the layers of a step come in
+    order. Returns ``(out (B, H, D) Tensor, cache')``: the pool is read,
+    not written — ``cache'`` holds this layer's ``(k_new, v_new)`` pending
+    until :func:`commit_pending` writes every layer's at once."""
     from ..core.tensor import apply
     from ._helpers import ensure_tensor
     if cache.layer is None:
         raise ValueError("paged_decode_attention: cache.layer is unset — "
                          "derive a per-layer view with cache.at_layer(i)")
+    if isinstance(cache.layer, int) and cache.layer != cache.pending_layers:
+        raise ValueError(
+            f"paged_decode_attention: layer {cache.layer} after "
+            f"{cache.pending_layers} pending — a step decodes every layer "
+            "once, in order")
     q, k_new, v_new = (ensure_tensor(x) for x in (q, k_new, v_new))
     layer_t = ensure_tensor(cache.layer).astype("int32")
     quantized = cache.scales is not None
@@ -467,16 +505,44 @@ def paged_decode_attention(q, k_new, v_new, cache: PagedDecodeCache):
 
     def f(qa, kna, vna, pool, tables, t, layer, *maybe_scales):
         sc = maybe_scales[0] if quantized else None
-        out = paged_attention(qa, kna, vna, pool, sc, tables, t, layer,
-                              page_size=ps, impl=impl, interpret=interpret)
-        pool2, sc2 = scatter_token_inplace(pool, sc, tables, t, layer,
-                                           kna, vna, page_size=ps)
-        return (out, pool2) + ((sc2,) if quantized else ())
+        return paged_attention(qa, kna, vna, pool, sc, tables, t, layer,
+                               page_size=ps, impl=impl, interpret=interpret)
 
     args = [q, k_new, v_new, cache.pool, cache.tables, cache.t,
             layer_t] + ([cache.scales] if quantized else [])
-    outs = apply("paged_attention_decode", f, *args, differentiable=False,
+    out = apply("paged_attention_decode", f, *args, differentiable=False,
+                amp=False)
+    return out, replace(cache, pending=cache.pending + ((k_new, v_new),))
+
+
+def commit_pending(cache: PagedDecodeCache) -> PagedDecodeCache:
+    """The decode step's one pool write: position ``t``'s K/V of every
+    layer, collected on the handle by :func:`paged_decode_attention`, into
+    the containing pages (:func:`scatter_token_inplace`). Called where the
+    handle's owner takes the pool back (the serving engine, after
+    ``step_fn``), so no model has to remember it."""
+    from ..core.tensor import apply
+    n = cache.pending_layers
+    if n != int(cache.pool.shape[1]):
+        raise ValueError(
+            f"commit_pending: {n} layers pending for a pool of "
+            f"{int(cache.pool.shape[1])} — a step decodes every layer once")
+    quantized = cache.scales is not None
+    ps, pairs = cache.page_size, len(cache.pending)
+
+    def f(pool, tables, t, *rest):
+        stacked = [a if a.ndim == 4 else a[None] for a in rest[:2 * pairs]]
+        ks, vs = stacked[0::2], stacked[1::2]
+        sc = rest[2 * pairs] if quantized else None
+        pool2, sc2 = scatter_token_inplace(
+            pool, sc, tables, t, jnp.concatenate(ks), jnp.concatenate(vs),
+            page_size=ps)
+        return (pool2, sc2) if quantized else pool2
+
+    args = [cache.pool, cache.tables, cache.t] + \
+        [x for pair in cache.pending for x in pair] + \
+        ([cache.scales] if quantized else [])
+    outs = apply("paged_commit_tokens", f, *args, differentiable=False,
                  amp=False)
-    new_cache = replace(cache, pool=outs[1],
-                        scales=outs[2] if quantized else None)
-    return outs[0], new_cache
+    pool2, sc2 = outs if quantized else (outs, None)
+    return replace(cache, pool=pool2, scales=sc2, pending=())

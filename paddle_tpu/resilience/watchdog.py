@@ -27,8 +27,9 @@ collective). The watchdog is the observer that cannot be wedged:
 
 * ``disarm()`` returns the window's classification (or None). What the
   caller does with a tripped-but-returned step is its own recovery
-  contract: the serving engine abandons the step's outputs and replays
-  the affected slots (functional pool state — nothing was committed);
+  contract: the serving engine abandons the step's tokens, keeps the
+  page pool it returned, and replays the affected slots (their
+  re-prefill rewrites what the step wrote);
   the training supervisor treats the step as unrecoverable and restores
   the last verified :class:`~paddle_tpu.resilience.trainer.TrainState`.
 

@@ -39,8 +39,18 @@ this repo's compiled-decode machinery):
   per distinct prompt LENGTH (prompt padding would change the model's
   attention; serve bucketed prompt lengths if that matters).
 
-Failure semantics (``resilience`` seams — all functional state, so a
-faulted step never half-writes the pool):
+The page pool is ONE buffer (ISSUE 26): every serving program takes it
+donated, writes it in place and gives it back, and the engine adopts what
+comes back. **The pool a program returns is always adopted; only its
+tokens may be abandoned.** That is sound because a decode step writes
+position ``t`` of the included slots' own pages (never a shared prefix
+page; padded rows write the scratch page), ``slot.t`` does not advance
+when tokens are abandoned, a retried step rewrites the same position with
+the same values, and a replayed slot's pages are rewritten by its
+re-prefill. A call that consumed the pool and raised leaves nothing to
+adopt: fresh pool, empty prefix index, every running slot replayed.
+
+Failure semantics (``resilience`` seams):
 
 * ``serving.admit`` fires once per admission attempt, before prefill.
   One retry; a second fault fails THAT request (future gets the error),
@@ -53,9 +63,10 @@ faulted step never half-writes the pool):
 * ``serving.watchdog`` fires once per batched-decode ATTEMPT, inside the
   armed watchdog window: a ``delay`` fault there simulates a hung device
   step, an ``error`` a whole-batch device fault. A device fault is
-  retried once (functional state: nothing was written); a second fault —
-  or a watchdog trip (``PADDLE_TPU_SERVING_WATCHDOG_S``) — abandons the
-  step's outputs and recovers the included slots through **bounded
+  retried once (the seam raises before the call, so the pool is as it
+  was); a second fault — or a watchdog trip
+  (``PADDLE_TPU_SERVING_WATCHDOG_S``) — abandons the step's tokens (its
+  pool stays adopted) and recovers the included slots through **bounded
   prefill replay**: each slot's prompt + tokens-so-far are requeued at
   the queue head and re-prefilled into a fresh slot (at most
   ``max_replays`` times, then the request fails), so one bad step no
@@ -74,7 +85,8 @@ Metrics: ``serving.requests_total{status}``, ``serving.tokens_total``,
 ``serving.steps_total``,
 ``serving.paged_attention_steps_total{path=kernel|dense}`` (which decode
 tier ran — ISSUE 13), ``serving.prefills_total``,
-``serving.step_retries_total``, ``serving.rejected_total{reason}``,
+``serving.step_retries_total``, ``serving.pool_resets_total``,
+``serving.rejected_total{reason}``,
 ``serving.watchdog_trips_total{kind}``, ``serving.replays_total``,
 ``serving.queue_depth``, ``serving.active_slots``,
 ``serving.batch_utilization``, and ``serving.ttft_seconds`` /
@@ -95,6 +107,7 @@ recorder (``serving_recover``); the step loop heartbeats ``/healthz``;
 
 from __future__ import annotations
 
+import functools
 import logging
 import os
 import threading
@@ -460,10 +473,12 @@ class Engine:
 
         def paged_decode_fn(tok_a, tables_a, t_a, pool_a, *maybe_scales):
             # same program signature as decode_fn (one compiled call per
-            # bucket; pool/scales thread through as functional state), but
-            # the cache argument is the page-pool VIEW: the step's
+            # bucket; the pool/scales come in donated and go back out),
+            # but the cache argument is the page-pool VIEW: every layer's
             # attention streams live pages through the Pallas kernel and
-            # writes position t's K/V into its containing page in place
+            # leaves position t's K/V pending on the view; the commit
+            # below is the program's one pool write, in place — made here
+            # so that no model forgets it
             sc = maybe_scales[0] if quantized else None
             view = _pa.PagedDecodeCache(
                 pool=_T(pool_a), tables=_T(tables_a), t=_T(t_a),
@@ -471,6 +486,7 @@ class Engine:
                 impl="kernel", interpret=paged_interpret)
             with no_grad():
                 nxt, view2 = step_fn(_T(tok_a), view, _T(t_a))
+                view2 = _pa.commit_pending(view2)
             out = (nxt._data.astype(jnp.int32), view2.pool._data)
             return out + ((view2.scales._data,) if quantized else ())
 
@@ -497,8 +513,15 @@ class Engine:
                           true_len, pool, *scales, differentiable=False,
                           amp=False)
 
-        self._decode_program = to_static(decode_program)
-        self._prefill_program = to_static(prefill_program)
+        # every serving program CONSUMES the pool (and the int8 scales):
+        # arguments 3 and 4 are donated, so XLA aliases them to the
+        # outputs and every write lands in place — the caller's array is
+        # deleted by the call and the returned one adopted (_adopt)
+        pool_args = (3, 4)
+        self._decode_program = to_static(decode_program,
+                                         donate_argnums=pool_args)
+        self._prefill_program = to_static(prefill_program,
+                                          donate_argnums=pool_args)
         # ISSUE 16: the cost registry files one record per warmed batch
         # bucket under serving.decode (bucket inferred from the compiled
         # tok spec) and one per prefill length under serving.prefill
@@ -533,7 +556,7 @@ class Engine:
                               true_len, pool, *scales,
                               differentiable=False, amp=False)
 
-            prog = to_static(tail_program)
+            prog = to_static(tail_program, donate_argnums=pool_args)
             prog.cost_site = "serving.prefill"
             prog.cost_label = f"{name}.prefill_tail{start}"
             return prog
@@ -545,13 +568,19 @@ class Engine:
     def _tail_program(self, start: int) -> Callable:
         """The compiled tail-prefill program for a static ``start`` offset
         (built on first use; admission normally runs on the single step
-        thread, but the lock keeps a warmup-from-caller race harmless)."""
+        thread, but the lock keeps a warmup-from-caller race harmless).
+        The callable adopts the pool the program returns (:meth:`_adopt`):
+        whoever calls it, ``kv.pool`` is a live array afterwards."""
         with self._program_lock:
             prog = self._tail_programs.get(start)
             if prog is None:
                 prog = self._build_tail_program(start)
                 self._tail_programs[start] = prog
-        return prog
+        return functools.partial(self._adopt, prog)
+
+    def _pool_args(self):
+        from ..core.tensor import Tensor as _T
+        return (_T(self.kv.pool),) + self._scales_args()
 
     def _scales_args(self):
         from ..core.tensor import Tensor as _T
@@ -562,6 +591,47 @@ class Engine:
         if scales_t is not None:
             self.kv.scales = scales_t._data
 
+    def _adopt(self, prog, *args):
+        """Call a serving program and adopt the pool it returns. The call
+        consumed the pool it was given (donated), so the returned one is
+        adopted whatever becomes of the call's tokens: only they may be
+        abandoned."""
+        outs = prog(*args)
+        self._set_pool(outs[1], outs[2] if self._quantized else None)
+        return outs
+
+    def _restore_lost_pool(self, exc: BaseException) -> bool:
+        """After a serving program raised: if the call had already
+        consumed the pool (the donated array is deleted and nothing came
+        back), every resident page is gone with it. Start from a fresh
+        pool and an empty prefix index, and send EVERY running slot
+        through bounded replay — their re-prefill rewrites their pages.
+        A call that raised before it ran (a trace failure, an injected
+        fault) leaves the pool alone, and so does this (returns False)."""
+        lost = self.kv.pool.is_deleted() or (
+            self._quantized and self.kv.scales.is_deleted())
+        if not lost:
+            return False
+        _log.warning("serving: a program call consumed the page pool and "
+                     "raised (%s) — fresh pool, prefix index dropped, %d "
+                     "running slot(s) replayed", type(exc).__name__,
+                     len(self._slots))
+        _obs.inc("serving.pool_resets_total")
+        self.kv.reset_pool()
+        self._recover_slots(list(self._slots), exc)
+        return True
+
+    def _warm_decode(self, bucket: int) -> None:
+        """One decode call of ``bucket`` all-padded rows: they read and
+        write the scratch page only."""
+        from ..core.tensor import Tensor as _T
+        S = self.kv.config.pages_per_slot
+        self._adopt(
+            self._decode_program,
+            _T(jnp.zeros((bucket, 1), jnp.int32)),
+            _T(jnp.zeros((bucket, S), jnp.int32)),
+            _T(jnp.zeros((bucket,), jnp.int32)), *self._pool_args())
+
     def warmup(self, prompt_lens: Sequence[int] = ()) -> "Engine":
         """Compile every batch bucket (and optional prefill lengths) up
         front, against the scratch page only — admission then never
@@ -569,20 +639,13 @@ class Engine:
         from ..core.tensor import Tensor as _T
         S = self.kv.config.pages_per_slot
         for b in self.config.buckets:
-            outs = self._decode_program(
-                _T(jnp.zeros((b, 1), jnp.int32)),
-                _T(jnp.zeros((b, S), jnp.int32)),
-                _T(jnp.zeros((b,), jnp.int32)),
-                _T(self.kv.pool), *self._scales_args())
-            # scratch-page writes from the all-padded batch are garbage by
-            # design but harmless — still, keep the pre-warmup pool bytes
-            del outs
+            self._warm_decode(b)
         for lp in prompt_lens:
-            self._prefill_program(
+            self._adopt(
+                self._prefill_program,
                 _T(jnp.zeros((1, int(lp)), jnp.int32)),
                 _T(jnp.zeros((S,), jnp.int32)),
-                _T(jnp.zeros((), jnp.int32)),
-                _T(self.kv.pool), *self._scales_args())
+                _T(jnp.zeros((), jnp.int32)), *self._pool_args())
         return self
 
     # ------------------------------------------------------------------
@@ -1079,18 +1142,15 @@ class Engine:
                                        site="serving.admit", retried=True,
                                        error=type(exc).__name__)
                 row = self.kv.table_row(pages)
-                if start:
-                    outs = self._tail_program(start)(
-                        _T(jnp.asarray(prompt[None, start:], jnp.int32)),
-                        _T(jnp.asarray(row)),
-                        _T(jnp.asarray(prompt.size, jnp.int32)),
-                        _T(self.kv.pool), *self._scales_args())
-                else:
-                    outs = self._prefill_program(
-                        _T(jnp.asarray(prompt[None, :], jnp.int32)),
-                        _T(jnp.asarray(row)),
-                        _T(jnp.asarray(prompt.size, jnp.int32)),
-                        _T(self.kv.pool), *self._scales_args())
+                # a tail program for a mapped prefix, else the full one;
+                # either consumes the pool and _adopt takes it back
+                prog = self._tail_program(start) if start else \
+                    functools.partial(self._adopt, self._prefill_program)
+                outs = prog(
+                    _T(jnp.asarray(prompt[None, start:], jnp.int32)),
+                    _T(jnp.asarray(row)),
+                    _T(jnp.asarray(prompt.size, jnp.int32)),
+                    *self._pool_args())
                 # ISSUE 18: the pool swap, first-token host read and
                 # prefix publish belong to the guarded region too — the
                 # host sync raising here (wedged device, watchdog replay)
@@ -1098,8 +1158,6 @@ class Engine:
                 # it is just another "failed" admission. Inside the span
                 # (ISSUE 25): it ends when the first token exists, so its
                 # duration is a prefill, not an enqueue
-                self._set_pool(outs[1],
-                               outs[2] if self._quantized else None)
                 first_tok = int(np.asarray(outs[0]._data)[0, 0])
             now = time.monotonic()
             _obs.inc("serving.prefills_total")
@@ -1122,6 +1180,10 @@ class Engine:
                            rid=req.request_id, site="serving.admit",
                            error=type(exc).__name__)
             pending.future.set_exception(exc)
+            # the pages this prefill wrote are free again, so a pool it
+            # returned stays adopted as it is; one it consumed and never
+            # returned takes every running slot's pages with it
+            self._restore_lost_pool(exc)
             return "failed"
         slot = _Slot(pending=pending, page_ids=pages, table_row=row,
                      t=int(prompt.size), last_tok=first_tok,
@@ -1213,14 +1275,20 @@ class Engine:
                     # watchdog), error = whole-batch device fault
                     with _trace.phase("serving.decode.launch"):
                         _faults.fault_point("serving.watchdog")
-                        outs = self._decode_program(
-                            *args, _T(self.kv.pool), *self._scales_args())
+                        outs = self._adopt(self._decode_program, *args,
+                                           *self._pool_args())
                 except Exception as exc:
                     if gen is not None:
                         self._watchdog.disarm(gen)
-                    # a whole-batch device fault: functional state means
-                    # nothing was written — retry the identical step once,
-                    # then recover the slots through bounded replay
+                    # a whole-batch device fault. A call that raised
+                    # before it ran (an injected fault, a trace failure)
+                    # left the pool as it was: retry the identical step
+                    # once, then recover the slots through bounded replay.
+                    # One that consumed the pool and raised took every
+                    # resident page with it: fresh pool, every running
+                    # slot replayed — there is nothing to retry against
+                    if self._restore_lost_pool(exc):
+                        return
                     if attempt:
                         self._recover_slots(included, exc)
                         return
@@ -1229,8 +1297,13 @@ class Engine:
                 verdict = self._watchdog.disarm(gen) if gen is not None \
                     else None
                 if verdict is not None:
-                    # tripped step: abandon its outputs (nothing was
-                    # committed — functional pool state) and replay
+                    # tripped step: its pool is already adopted (the call
+                    # consumed the old one), only its tokens are
+                    # abandoned. Sound because the step wrote position t
+                    # of the included slots' own pages and nothing else
+                    # (never a shared prefix page; padded rows write the
+                    # scratch page), slot.t did not advance, and the
+                    # replay's re-prefill rewrites those pages anyway
                     self._recover_slots(included, WatchdogTimeout(
                         f"decode step classified {verdict} by the "
                         f"watchdog (budget "
@@ -1241,13 +1314,13 @@ class Engine:
             abandoned = any(s not in self._slots for s in included)
         if abandoned:
             # a budgeted stop() resolved these slots while the call was in
-            # flight (wedged step, watchdog disabled): the outputs are
-            # abandoned exactly like a tripped step's — functional pool
-            # state, nothing was committed, no late tokens reach settled
-            # futures or a restarted loop's pool
+            # flight (wedged step, watchdog disabled): the tokens are
+            # abandoned exactly like a tripped step's, so none reaches a
+            # settled future. The pool the call returned stays adopted —
+            # what it wrote is position t of pages a requeued slot's
+            # re-prefill rewrites and a failed slot's successor overwrites
             return
         with _trace.phase("serving.decode.wait"):
-            self._set_pool(outs[1], outs[2] if self._quantized else None)
             next_np = np.asarray(outs[0]._data)    # the ONE host sync
         now = time.monotonic()
         _obs.inc("serving.steps_total")

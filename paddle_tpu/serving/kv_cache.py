@@ -255,10 +255,10 @@ class PagedKVCache:
     """The preallocated page pool plus refcounted accounting and the
     prompt-prefix hash index.
 
-    Holds the pool/scales as raw jnp arrays (the engine threads them
-    through its compiled programs as explicit inputs/outputs — functional
-    state, so a faulted step that is retried or abandoned cannot leave the
-    pool half-written). Thread-safe: every accounting surface (free list,
+    Holds the pool/scales as raw jnp arrays. The engine hands them to
+    each compiled program as donated inputs and rebinds what comes back:
+    one buffer, written in place; a call that consumed it and raised is
+    answered with :meth:`reset_pool`. Thread-safe: every accounting surface (free list,
     refcount table ``_ref``, prefix index ``_index``, idle LRU) is guarded
     by the single instance lock ``_lock``.
 
@@ -287,13 +287,8 @@ class PagedKVCache:
         if config.num_pages < 2:
             raise ValueError("need at least 2 pages (page 0 is scratch)")
         self.config = config
-        shape = (config.num_pages,) + config.page_shape()
-        self.pool = jnp.zeros(shape, config.storage_dtype)
         self.scales: Optional[jnp.ndarray] = None
-        if config.quantized:
-            self.scales = jnp.ones(
-                (config.num_pages, config.num_layers, 2, config.num_heads),
-                jnp.float32)
+        self._alloc_pool()
         self._lock = threading.Lock()
         # page 0 is scratch: never allocated, target of padded rows.
         # _free_set mirrors _free for O(1) double-free detection — free()
@@ -313,6 +308,31 @@ class PagedKVCache:
         self._prefix_queries = 0
         self._prefix_query_hits = 0
         self._prefix_pages_shared_total = 0
+
+    def _alloc_pool(self) -> None:
+        config = self.config
+        shape = (config.num_pages,) + config.page_shape()
+        self.pool = jnp.zeros(shape, config.storage_dtype)
+        if config.quantized:
+            self.scales = jnp.ones(
+                (config.num_pages, config.num_layers, 2, config.num_heads),
+                jnp.float32)
+
+    def reset_pool(self) -> None:
+        """A fresh zeroed pool and an empty prefix index, for the engine
+        whose program call consumed the pool and raised. No resident page
+        holds content any more, so nothing stays advertised: idle cached
+        pages return to the free list, and claimed ones follow as their
+        holders release them (the engine replays every running slot)."""
+        self._alloc_pool()
+        with self._lock:
+            self._index.clear()
+            self._page_hash.clear()
+            for pid in self._idle:
+                self._free.append(pid)
+                self._free_set.add(pid)
+            self._idle.clear()
+            _obs.set_gauge("serving.kv.prefix_index_pages", 0.0)
 
     # -- accounting ---------------------------------------------------------
     @property

@@ -297,10 +297,30 @@ def test_chip_smoke_serve_leg_runs_tiny(metrics):
     assert not [b for b in trace.health()["components"]
                 if b.startswith("serving.")], "a beacon outlived the leg"
     assert res["compiles_after_warmup"] == 0
+    assert set(res["pool_copies_by_bucket"]) == {"1", "4", "16"}
     assert res["decode_tier"] == "dense"   # auto on a CPU
     shared, asked = res["prefix_shared_prefill"]
     assert shared < asked
     json.dumps(res)
+
+
+@pytest.mark.parametrize("line,want", [
+    # the program before ISSUE 26, compiled for a v5e: the argument copied
+    # into the scatter's layout, and a layer's scatter copied back
+    ("  %copy.1350 = bf16[1025,7,2,8,64,128]{5,3,4,2,1,0:T(8,128)(2,1)} "
+     "copy(%arg_arrays_3_.1), sharding={replicated}", 1),
+    ("  %copy.1352 = bf16[1025,7,2,8,64,128]{5,4,3,2,1,0:T(8,128)(2,1)} "
+     "copy(%fusion.4), metadata={op_name=\"jit(pure_fn)/scatter\"}", 1),
+    ("  %copy.9 = s8[1025,7,2,8,64,128]{5,4,3,2,1,0} copy(%p.1)", 1),
+    # another shape, another op, the pool as an operand: not counted
+    ("  %copy.3 = bf16[16,7,2,8,64,128]{5,4,3,2,1,0} copy(%gather.2)", 0),
+    ("  %fusion.4 = bf16[1025,7,2,8,64,128]{5,4,3,2,1,0} fusion(%p.1)", 0),
+    ("  %copy.5 = bf16[8,128]{1,0} copy(bf16[1025,7,2,8,64,128] %p.1)", 0),
+])
+def test_chip_smoke_counts_pool_shaped_copies(line, want):
+    assert chip_smoke.pool_copies(
+        "HloModule jit_pure_fn\n" + line + "\n",
+        (1025, 7, 2, 8, 64, 128)) == want
 
 
 def _reset_fleet():

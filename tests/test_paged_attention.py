@@ -8,9 +8,10 @@ Covers the acceptance surface:
   dequant grid) across page-boundary-straddling lengths
   ``t = page_size-1, page_size, page_size+1``, plus GQA and the t=0
   edge, all under the CPU Pallas interpreter;
-* the in-place token write: single-position scatter on the float legs,
-  and BIT-IDENTICAL pool bytes + scales versus the legacy dense
-  ``scatter_token_page`` round-trip on the int8 leg;
+* the in-place token write, one a step for all layers (ISSUE 26): a
+  single-position write on the float legs, BIT-IDENTICAL pool bytes +
+  scales versus the legacy dense ``scatter_token_page`` round-trip on
+  the int8 leg, and the handle that collects the layers for it;
 * the structural no-materialize proof: ``compiled_text()`` of the
   engine's kernel-tier bucketed decode program contains NO dense
   ``(L, 2, B, H, max_len, D)`` stacked-cache buffer (and the dense-tier
@@ -178,21 +179,80 @@ class TestKernelParity:
 
 class TestScatterInplace:
     def test_float_leg_single_position_write(self):
+        # one write for every layer (ISSUE 26): position t of each row's
+        # containing page, in all L layers, and not one byte besides
         rng = np.random.default_rng(5)
         pool, _ = _make_pool("native", rng)
-        _, kn, vn = _qkv(rng)
+        kn = jnp.asarray(rng.standard_normal((L, B, H, D)), jnp.float32)
+        vn = jnp.asarray(rng.standard_normal((L, B, H, D)), jnp.float32)
         t = jnp.asarray([17, 15, 32], jnp.int32)
-        p2, sc2 = pa.scatter_token_inplace(pool, None, TABLES, t,
-                                           jnp.asarray(1), kn, vn,
+        p2, sc2 = pa.scatter_token_inplace(pool, None, TABLES, t, kn, vn,
                                            page_size=PS)
         assert sc2 is None
         ref = np.array(pool)
         for b in range(B):
             tb = int(t[b])
             pid = int(TABLES[b, tb // PS])
-            ref[pid, 1, 0, :, tb % PS, :] = np.asarray(kn)[b]
-            ref[pid, 1, 1, :, tb % PS, :] = np.asarray(vn)[b]
+            ref[pid, :, 0, :, tb % PS, :] = np.asarray(kn)[:, b]
+            ref[pid, :, 1, :, tb % PS, :] = np.asarray(vn)[:, b]
         np.testing.assert_array_equal(np.asarray(p2), ref)
+
+    @pytest.mark.parametrize("kv_dtype", ["native", "int8"])
+    def test_handle_collects_layers_and_commits_once(self, kv_dtype):
+        """``paged_decode_attention`` reads the pool and leaves it alone;
+        ``commit_pending`` is the step's one write, equal to the write of
+        every layer's token — whether the layers came one by one (the
+        llama loop) or stacked (the FusedMultiTransformer scan)."""
+        from paddle_tpu.core.tensor import Tensor as T
+        rng = np.random.default_rng(9)
+        pool, scales = _make_pool(kv_dtype, rng)
+        t = jnp.asarray([PS - 1, PS, PS + 1], jnp.int32)
+        qs = [_qkv(rng) for _ in range(L)]
+        view = pa.PagedDecodeCache(
+            pool=T(pool), tables=T(TABLES), t=T(t), page_size=PS,
+            scales=T(scales) if scales is not None else None,
+            impl="dense")
+        for layer, (q, kn, vn) in enumerate(qs):
+            out, view = pa.paged_decode_attention(
+                T(q), T(kn), T(vn), view.at_layer(layer))
+            want = pa.paged_attention_dense(q, kn, vn, pool, scales, TABLES,
+                                            t, layer, page_size=PS)
+            np.testing.assert_array_equal(np.asarray(out._data),
+                                          np.asarray(want))
+        assert view.pool._data is pool and view.pending_layers == L
+        done = pa.commit_pending(view)
+        k_all = jnp.stack([kn for _, kn, _ in qs])
+        v_all = jnp.stack([vn for _, _, vn in qs])
+        p_ref, sc_ref = pa.scatter_token_inplace(pool, scales, TABLES, t,
+                                                 k_all, v_all, page_size=PS)
+        np.testing.assert_array_equal(np.asarray(done.pool._data),
+                                      np.asarray(p_ref))
+        if scales is not None:
+            np.testing.assert_array_equal(np.asarray(done.scales._data),
+                                          np.asarray(sc_ref))
+        assert done.pending == ()
+        # the stacked form of the same layers commits the same bytes
+        stacked = pa.commit_pending(pa.PagedDecodeCache(
+            pool=T(pool), tables=T(TABLES), t=T(t), page_size=PS,
+            scales=T(scales) if scales is not None else None,
+            pending=((T(k_all), T(v_all)),)))
+        np.testing.assert_array_equal(np.asarray(stacked.pool._data),
+                                      np.asarray(p_ref))
+
+    def test_handle_refuses_a_layer_out_of_order_or_missing(self):
+        from paddle_tpu.core.tensor import Tensor as T
+        rng = np.random.default_rng(10)
+        pool, _ = _make_pool("native", rng)
+        q, kn, vn = _qkv(rng)
+        view = pa.PagedDecodeCache(
+            pool=T(pool), tables=T(TABLES), t=T(jnp.zeros((B,), jnp.int32)),
+            page_size=PS, impl="dense")
+        with pytest.raises(ValueError, match="in order"):
+            pa.paged_decode_attention(T(q), T(kn), T(vn), view.at_layer(1))
+        _, view = pa.paged_decode_attention(T(q), T(kn), T(vn),
+                                            view.at_layer(0))
+        with pytest.raises(ValueError, match="every layer once"):
+            pa.commit_pending(view)              # one of L=2 pending
 
     def test_int8_leg_matches_dense_scatter_bitwise(self):
         """The requantization contract: writing through the pool directly
@@ -211,12 +271,9 @@ class TestScatterInplace:
             dense = dense.at[:, 1, b, :, int(t[b]), :].set(v_new[:, b])
         pool_a, scales_a = kvc.scatter_token_page(dense, pool, scales,
                                                   TABLES, t, PS)
-        # paged path: per-layer in-place writes
-        pool_b, scales_b = pool, scales
-        for layer in range(L):
-            pool_b, scales_b = pa.scatter_token_inplace(
-                pool_b, scales_b, TABLES, t, jnp.asarray(layer),
-                k_new[layer], v_new[layer], page_size=PS)
+        # paged path: the one in-place write of all layers
+        pool_b, scales_b = pa.scatter_token_inplace(
+            pool, scales, TABLES, t, k_new, v_new, page_size=PS)
         np.testing.assert_array_equal(np.asarray(pool_a),
                                       np.asarray(pool_b))
         np.testing.assert_array_equal(np.asarray(scales_a),
@@ -555,6 +612,114 @@ class TestLlamaServing:
 
 
 # ---------------------------------------------------------------------------
+# ISSUE 26: the pool is one buffer that every program consumes and gives back
+# ---------------------------------------------------------------------------
+
+def _same_buffer_kind(new, old):
+    return new.shape == old.shape and new.dtype == old.dtype \
+        and not new.is_deleted()
+
+
+class TestDonatedPool:
+    @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+    @pytest.mark.parametrize("bucket", [1, 4])
+    @pytest.mark.parametrize("path", ["on", "off"])
+    def test_prefill_and_step_consume_the_pool(self, fmt_stack, path,
+                                               bucket, kv_dtype):
+        """Both decode tiers, both buckets, both storage legs: the array
+        that was ``kv.pool`` before a prefill or a decode step is deleted
+        by it (donated, written in place), the one adopted has its shape
+        and dtype — and sixteen steps of that still decode the dense
+        tier's tokens."""
+        prompts = (FMT_PROMPTS + [FMT_PROMPTS[0][:4]])[:bucket]
+        n_new = 17                                # 1 prefill + 16 steps
+        eng = _fmt_engine(fmt_stack, path, kv_dtype)
+        futs = [eng.submit(serving.GenerationRequest(p, max_new_tokens=n_new))
+                for p in prompts]
+        quantized = kv_dtype == "int8"
+        pool0, scales0 = eng.kv.pool, eng.kv.scales
+        assert eng._admit()                       # the prefills
+        assert pool0.is_deleted() and _same_buffer_kind(eng.kv.pool, pool0)
+        if quantized:
+            assert scales0.is_deleted() and \
+                _same_buffer_kind(eng.kv.scales, scales0)
+        pool1, scales1 = eng.kv.pool, eng.kv.scales
+        assert eng.step()                         # one decode step
+        assert eng._bucket_for(len(prompts)) == bucket
+        assert pool1.is_deleted() and _same_buffer_kind(eng.kv.pool, pool1)
+        if quantized:
+            assert scales1.is_deleted() and \
+                _same_buffer_kind(eng.kv.scales, scales1)
+        eng.run()
+        tokens = [f.result(timeout=10).tokens for f in futs]
+        assert all(len(t) == n_new for t in tokens)
+        other = "off" if path == "on" else "on"
+        assert tokens == _drain(_fmt_engine(fmt_stack, other, kv_dtype),
+                                prompts, n_new=n_new)
+        assert eng.kv.free_pages == eng.kv.config.num_pages - 1
+
+    def test_warmup_rebinds_what_its_calls_return(self, fmt_stack):
+        eng = _fmt_engine(fmt_stack, "on", "int8")
+        pool0, scales0 = eng.kv.pool, eng.kv.scales
+        eng.warmup(prompt_lens=[8])
+        assert pool0.is_deleted() and scales0.is_deleted()
+        assert _same_buffer_kind(eng.kv.pool, pool0)
+        assert _same_buffer_kind(eng.kv.scales, scales0)
+        # every page but the scratch one is as it was: zeros, unit scales
+        np.testing.assert_array_equal(np.asarray(eng.kv.pool)[1:], 0)
+        np.testing.assert_array_equal(np.asarray(eng.kv.scales)[1:], 1.0)
+
+    @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+    def test_tail_program_adopts_the_pool_when_its_result_is_dropped(
+            self, kv_dtype):
+        """What ``perfbench/runners/serve_open_loop.py::_warm_tails``
+        does: call the tail program with ``Tensor(engine.kv.pool)`` and
+        drop the result. The callable adopts the pool itself."""
+        from paddle_tpu.core.tensor import Tensor
+        from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+        paddle.seed(12)
+        model = LlamaForCausalLM(LlamaConfig.tiny(
+            vocab=64, hidden=32, layers=2, heads=4, kv_heads=2, inter=48,
+            max_pos=64))
+        model.eval()
+        cfg = model.config
+        prefill_fn, step_fn = model.serving_callables(64)
+        eng = serving.Engine(prefill_fn, step_fn, serving.ServingConfig(
+            num_layers=cfg.num_hidden_layers,
+            num_heads=cfg.num_key_value_heads,
+            head_dim=cfg.hidden_size // cfg.num_attention_heads,
+            max_len=64, max_batch=2, buckets=(1, 2), page_size=16,
+            kv_dtype=kv_dtype, paged_attention="on"))
+        slots = eng.kv.config.pages_per_slot
+        for _ in range(2):                        # built, then cached
+            pool0 = eng.kv.pool
+            eng._tail_program(16)(
+                Tensor(jnp.zeros((1, 5), jnp.int32)),
+                Tensor(jnp.zeros((slots,), jnp.int32)),
+                Tensor(jnp.asarray(21, jnp.int32)),
+                Tensor(eng.kv.pool), *eng._scales_args())
+            assert pool0.is_deleted()
+            assert _same_buffer_kind(eng.kv.pool, pool0)
+            if kv_dtype == "int8":
+                assert not eng.kv.scales.is_deleted()
+        # and the engine still serves, through the adopted pool, what the
+        # dense tier serves
+        prompt = np.random.default_rng(3).integers(0, 64, (6,),
+                                                   dtype=np.int32)
+        off = serving.Engine(prefill_fn, step_fn, serving.ServingConfig(
+            num_layers=cfg.num_hidden_layers,
+            num_heads=cfg.num_key_value_heads,
+            head_dim=cfg.hidden_size // cfg.num_attention_heads,
+            max_len=64, max_batch=2, buckets=(1, 2), page_size=16,
+            kv_dtype=kv_dtype, paged_attention="off"))
+        assert _drain(eng, [prompt], n_new=4) == \
+            _drain(off, [prompt], n_new=4)
+        del model, prefill_fn, step_fn, eng, off
+        import gc
+        gc.collect()
+
+
+# ---------------------------------------------------------------------------
 # serving under fire with the kernel path enabled
 # ---------------------------------------------------------------------------
 
@@ -563,8 +728,9 @@ class TestFaultsWithKernel:
                                                  fmt_stack):
         """A double-faulted batched step with the kernel tier enabled
         recovers through bounded prefill replay and completes the exact
-        dense-tier transcripts — functional pool state holds for the
-        paged program too."""
+        dense-tier transcripts — the injected fault raises before the
+        call, so the donated pool is as it was for the retry and the
+        replay."""
         ref = _drain(_fmt_engine(fmt_stack, "off"), FMT_PROMPTS[:2],
                      n_new=4)
         sched = faults.FaultSchedule().error("serving.watchdog", on=(2, 3))

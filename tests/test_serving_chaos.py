@@ -18,7 +18,9 @@ fire" trustworthy:
   are monotone across the sweep;
 * requests that DO complete under fire decode exactly the no-fault
   reference sequence (faults may delay or kill a request, never corrupt
-  one — functional pool state).
+  one — a step writes only its own slots' pages, and a replay's
+  re-prefill rewrites them; ISSUE 26's tests at the end pin the donated
+  pool's side of it).
 
 The per-seed schedules are deterministic (``FaultSchedule``'s own seeded
 RNG); wall-clock timing (the watchdog thread) decides only WHEN a hung
@@ -244,3 +246,160 @@ def test_soak_continuous_load_with_faults(metrics):
     resolved = sum(snap["serving.requests_total"].get(f"status={s}", 0)
                    for s in TERMINAL_STATUSES)
     assert resolved == len(futs)
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 26: the fault contract under a donated pool — the pool a program
+# returns is always adopted, only its tokens may be abandoned; a call that
+# consumed the pool and raised ends in a fresh pool and everyone replayed
+# ---------------------------------------------------------------------------
+
+def _spy_recoveries(eng, monkeypatch):
+    """Record, at every ``_recover_slots``, whether ``kv.pool`` is live."""
+    seen = []
+    real = eng._recover_slots
+
+    def spy(included, exc):
+        seen.append((type(exc).__name__, eng.kv.pool.is_deleted(),
+                     len(included)))
+        return real(included, exc)
+
+    monkeypatch.setattr(eng, "_recover_slots", spy)
+    return seen
+
+
+def test_tripped_step_adopts_its_pool_and_replays_bit_identically(
+        metrics, monkeypatch):
+    sched = faults.FaultSchedule().delay("serving.watchdog", on=(2,),
+                                         seconds=1.0)
+    eng = make_engine(watchdog_s=0.25, max_replays=1).warmup()
+    seen = _spy_recoveries(eng, monkeypatch)
+    adopted = []
+    real_set = eng._set_pool
+    monkeypatch.setattr(eng, "_set_pool", lambda p, s: (
+        adopted.append(p._data), real_set(p, s))[1])
+    with faults.installed(sched):
+        futs = [eng.submit(serving.GenerationRequest(
+            p, max_new_tokens=4)) for p in PROMPTS[:2]]
+        eng.run()
+    eng.stop()
+    # the tripped step's pool was adopted before its tokens were dropped:
+    # at recovery the engine held a live pool, the very one the call gave
+    assert seen == [("WatchdogTimeout", False, 2)]
+    assert all(a.is_deleted() for a in adopted[:-1])     # each consumed
+    assert adopted[-1] is eng.kv.pool and not eng.kv.pool.is_deleted()
+    for p, f in zip(PROMPTS, futs):
+        assert f.result(timeout=5).tokens == dense_reference(p, 4)
+    assert eng.kv.outstanding_pages == 0
+    snap = obs.snapshot()
+    assert snap["serving.replays_total"] == 2
+    assert "serving.pool_resets_total" not in snap
+
+
+def test_step_abandoned_by_a_budgeted_stop_adopts_its_pool(metrics):
+    """A step wedged past a budgeted ``stop()``: the stragglers are
+    requeued from the caller's thread, the step returns late, its tokens
+    reach nobody — and the pool it returns is the engine's pool, so the
+    restarted loop re-prefills and continues bit-identically."""
+    import time as _t
+    sched = faults.FaultSchedule().delay("serving.watchdog", on=(3,),
+                                         seconds=1.8)
+    eng = make_engine(max_batch=1).warmup()
+    with faults.installed(sched):
+        eng.start()
+        fut = eng.submit(serving.GenerationRequest(PROMPTS[0],
+                                                   max_new_tokens=8))
+        while eng.active_requests == 0:
+            _t.sleep(0.005)
+        t0 = _t.monotonic()
+        eng.stop(drain=True, timeout=0.05, on_timeout="requeue")
+        assert _t.monotonic() - t0 < 1.7          # gave up on the wedge
+        assert not fut.done() and eng.queue_depth == 1
+        held = eng.kv.pool                        # what the wedged call has
+        _t.sleep(1.0)                             # the wedged step returns
+    assert held.is_deleted()                      # consumed by that call
+    assert not eng.kv.pool.is_deleted()           # and its return adopted
+    assert eng.kv.outstanding_pages == 0
+    eng.run()                                     # resumes the requeued work
+    assert fut.result(timeout=5).tokens == dense_reference(PROMPTS[0], 8)
+    assert eng.kv.free_pages == eng.kv.config.num_pages - 1
+
+
+def _consume_and_raise_on(eng, attr, nth):
+    """Make the ``nth`` call of program ``attr`` behave like a device
+    fault AFTER donation: the pool it was given is deleted, nothing comes
+    back."""
+    real, calls = getattr(eng, attr), []
+
+    def program(*args):
+        calls.append(args)
+        if len(calls) == nth:
+            args[3]._data.delete()
+            raise RuntimeError("device fault after the pool was consumed")
+        return real(*args)
+
+    setattr(eng, attr, program)
+    return calls
+
+
+def test_decode_call_that_consumed_the_pool_and_raised(metrics):
+    from test_prefix_sharing import SHARED_PROMPTS, make_engine3
+    ref_eng = make_engine3("off", max_batch=4)
+    futs = [ref_eng.submit(serving.GenerationRequest(p, max_new_tokens=5))
+            for p in SHARED_PROMPTS]
+    ref_eng.run()
+    ref = [f.result(timeout=30).tokens for f in futs]
+
+    eng = make_engine3(max_batch=4)
+    _consume_and_raise_on(eng, "_decode_program", nth=2)
+    # the third slot sits the second step out (its serving.step seam
+    # faults): running, not included — and replayed all the same
+    sched = faults.FaultSchedule().error("serving.step", on=(6,))
+    futs = [eng.submit(serving.GenerationRequest(p, max_new_tokens=5))
+            for p in SHARED_PROMPTS]
+    with faults.installed(sched):
+        assert eng.step()                         # 3 prefills + 1 decode
+        assert eng.active_requests == 3 and eng.kv.prefix_summary()
+        assert eng.step()                         # the consuming fault
+        pool = eng.kv.pool
+        assert not pool.is_deleted()
+        assert pool.shape == ref_eng.kv.pool.shape \
+            and pool.dtype == ref_eng.kv.pool.dtype
+        assert not np.asarray(pool).any()         # fresh
+        assert eng.kv.prefix_summary() == frozenset()
+        assert eng.active_requests == 0 and eng.queue_depth == 3
+        assert eng.kv.outstanding_pages == 0
+        snap = obs.snapshot()
+        assert snap["serving.replays_total"] == 3     # every running slot
+        assert snap["serving.pool_resets_total"] == 1
+        eng.run()
+    assert [f.result(timeout=30).tokens for f in futs] == ref
+    assert eng.kv.free_pages == eng.kv.config.num_pages - 1
+    assert sched.trace == [("serving.step", 6, "error")]
+
+
+def test_prefill_call_that_consumed_the_pool_and_raised(metrics):
+    from test_prefix_sharing import SHARED_PROMPTS, make_engine3
+    ref_eng = make_engine3("off", max_batch=4)
+    f0 = ref_eng.submit(serving.GenerationRequest(SHARED_PROMPTS[0],
+                                                  max_new_tokens=6))
+    ref_eng.run()
+    ref = f0.result(timeout=30).tokens
+
+    eng = make_engine3("off", max_batch=4)
+    _consume_and_raise_on(eng, "_prefill_program", nth=2)
+    fut = eng.submit(serving.GenerationRequest(SHARED_PROMPTS[0],
+                                               max_new_tokens=6))
+    assert eng.step() and eng.active_requests == 1
+    late = eng.submit(serving.GenerationRequest(SHARED_PROMPTS[1],
+                                                max_new_tokens=3))
+    eng.step()                                    # its prefill faults
+    # the admission that raised fails alone; the running slot lost its
+    # pages with the pool and goes through replay
+    with pytest.raises(RuntimeError, match="pool was consumed"):
+        late.result(timeout=1)
+    assert not eng.kv.pool.is_deleted()
+    assert obs.snapshot()["serving.replays_total"] == 1
+    eng.run()
+    assert fut.result(timeout=30).tokens == ref
+    assert eng.kv.free_pages == eng.kv.config.num_pages - 1

@@ -248,6 +248,94 @@ def test_to_static_dedupes_aliased_state_donation():
     assert np.isfinite(out)
 
 
+class TestDonatedArguments:
+    """ISSUE 26: ``donate_argnums`` names the plain arguments a caller
+    gives up beside the state (the serving engine's page pool)."""
+
+    @staticmethod
+    def _program(**kw):
+        from paddle_tpu.core.tensor import Tensor
+
+        def step(scale, pool, bias):
+            return scale * 2.0, Tensor(pool._data.at[0].set(bias._data[0]))
+        return paddle.jit.to_static(step, **kw)
+
+    @staticmethod
+    def _args():
+        import jax.numpy as jnp
+        return (jnp.full((3,), 2.0), jnp.zeros((4, 3)), jnp.ones((3,)))
+
+    def test_named_argument_is_consumed_and_comes_back_aliased(self):
+        from paddle_tpu.core.tensor import Tensor
+        scale, pool, bias = self._args()
+        f = self._program(donate_argnums=(1,))
+        paddle.set_flags({"FLAGS_to_static_capture_lowered": True})
+        try:
+            doubled, pool2 = f(Tensor(scale), Tensor(pool), Tensor(bias))
+            text = f.compiled_text()
+        finally:
+            paddle.set_flags({"FLAGS_to_static_capture_lowered": False})
+        assert pool.is_deleted()
+        assert not scale.is_deleted() and not bias.is_deleted()
+        assert pool2._data.shape == (4, 3) and pool2._data.dtype == pool.dtype
+        np.testing.assert_array_equal(np.asarray(pool2._data)[0], 1.0)
+        np.testing.assert_array_equal(np.asarray(pool2._data)[1:], 0.0)
+        np.testing.assert_array_equal(doubled.numpy(), 4.0)
+        # the given-up input is the output's buffer: nothing to copy into
+        assert "input_output_alias" in text.splitlines()[0]
+        # the next call consumes what the last one returned
+        _, pool3 = f(Tensor(scale), pool2, Tensor(bias))
+        assert pool2._data.is_deleted() and not pool3._data.is_deleted()
+
+    def test_argument_not_named_is_not_consumed(self):
+        from paddle_tpu.core.tensor import Tensor
+        scale, pool, bias = self._args()
+        f = self._program()                      # the default: nothing given
+        _, pool2 = f(Tensor(scale), Tensor(pool), Tensor(bias))
+        assert not pool.is_deleted()
+        np.testing.assert_array_equal(np.asarray(pool), 0.0)
+        np.testing.assert_array_equal(np.asarray(pool2._data)[0], 1.0)
+
+    def test_array_given_as_state_and_as_argument_is_copied_once(
+            self, monkeypatch):
+        import jax.numpy as jnp
+        from paddle_tpu.core.tensor import Tensor
+        lin = paddle.nn.Linear(3, 3)
+
+        @paddle.jit.to_static(donate_argnums=(0,))
+        def f(pool):
+            return pool + lin.weight
+
+        copies = []
+        real = jnp.copy
+        monkeypatch.setattr(jnp, "copy",         # to_static's own jnp
+                            lambda a: copies.append(a) or real(a))
+        shared = lin.weight._data                # state AND given-up arg
+        want = 2.0 * np.asarray(shared)
+        out = f(Tensor(shared))
+        np.testing.assert_allclose(out.numpy(), want)
+        assert len(copies) == 1 and copies[0] is shared
+        assert shared.is_deleted()
+        # the state tensor was rebound to a live buffer, as always
+        np.testing.assert_allclose(np.asarray(lin.weight._data), want / 2.0)
+
+    def test_same_array_given_up_and_kept_is_copied(self):
+        from paddle_tpu.core.tensor import Tensor
+        scale, _, bias = self._args()
+        f = self._program(donate_argnums=(1,))
+        both = scale * 1.0                       # (3,) as scale AND as pool
+        doubled, pool2 = f(Tensor(both), Tensor(both), Tensor(bias))
+        assert not both.is_deleted()             # the copy was given up
+        np.testing.assert_array_equal(doubled.numpy(), 4.0)
+        np.testing.assert_array_equal(np.asarray(pool2._data), [1.0, 2.0, 2.0])
+
+    def test_refused_where_an_eager_rerun_or_a_scan_would_read_it(self):
+        with pytest.raises(ValueError, match="donate_argnums"):
+            self._program(donate_argnums=(1,), full_graph=False)
+        with pytest.raises(ValueError, match="donate_argnums"):
+            self._program(donate_argnums=(1,), iters_per_call=2)
+
+
 def test_full_graph_false_falls_back_to_eager():
     """SOT parity (upstream python/paddle/jit/sot/): tensor-data-dependent
     Python control flow breaks the graph; full_graph=False falls back to

@@ -1,0 +1,153 @@
+"""What only the chip's compiler can say, asked without the chip: the
+serving engine's decode programs, compiled for a DESCRIBED TPU v5e (the
+TPU compiler is installed here; nothing runs), hold no copy of the page
+pool (ISSUE 26).
+
+The program before ISSUE 26 held 1 / 8 / 8 pool-shaped copies in buckets
+1 / 4 / 16 at these widths: the pool argument was not donated, and each
+layer's batched scatter ran in a layout of its own (page rows above the
+heads) that the Pallas kernel cannot read, so the compiler copied the pool
+into it and back around every layer. Interpret mode and the CPU backend
+see none of that.
+
+The topology is described inside a fixture, never at import (one process
+at a time may load libtpu; every xdist worker imports this file), and all
+such compiles live in this one file.
+"""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+import paddle_tpu as paddle
+from paddle_tpu import serving
+from paddle_tpu.core.tensor import Tensor as T
+from paddle_tpu.ops import paged_attention as pa
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from chip_smoke import pool_copies  # noqa: E402  (the count the chip run makes)
+
+# Mistral-7B's KV geometry (8 KV heads x 128, pages of 64) on a small body
+KV_HEADS, HEAD_DIM, PAGE, MAX_LEN, SLOTS, LAYERS = 8, 128, 64, 1024, 8, 2
+BUCKETS = (1, 4, 8)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def llama():
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    paddle.seed(3)
+    model = LlamaForCausalLM(LlamaConfig.tiny(
+        vocab=256, hidden=KV_HEADS * HEAD_DIM, layers=LAYERS, heads=KV_HEADS,
+        kv_heads=KV_HEADS, inter=256, max_pos=MAX_LEN))
+    model.to(dtype="bfloat16")
+    model.eval()
+    yield model
+    import gc
+    del model
+    gc.collect()
+
+
+def _compiled_for_chip(prog, one_chip):
+    """The program's last call, lowered again for the described chip."""
+    jitted, state_specs, arg_specs = prog._last_lowered
+
+    def on_chip(specs):
+        return [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+                for s in specs]
+
+    return jitted.lower(on_chip(state_specs), on_chip(arg_specs)).compile()
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_decode_programs_hold_no_copy_of_the_pool(
+        llama, one_chip, kv_dtype, monkeypatch):
+    cfg = llama.config
+    # the compiled kernel, not the interpreter: the trace is what the chip
+    # would get; its CPU call fails at lowering, after the trace is kept
+    monkeypatch.setattr(pa, "kernel_interpret", lambda: False)
+    prefill_fn, step_fn = llama.serving_callables(MAX_LEN)
+    eng = serving.Engine(prefill_fn, step_fn, serving.ServingConfig(
+        num_layers=cfg.num_hidden_layers, num_heads=KV_HEADS,
+        head_dim=HEAD_DIM, max_len=MAX_LEN, max_batch=SLOTS,
+        buckets=BUCKETS, page_size=PAGE, compute_dtype="bfloat16",
+        kv_dtype=kv_dtype, paged_attention="on"))
+    assert eng._paged_path == "kernel"
+    shape = eng.kv.pool.shape
+    paddle.set_flags({"FLAGS_to_static_capture_lowered": True})
+    try:
+        for bucket in BUCKETS:
+            with pytest.raises(Exception, match="interpret mode"):
+                eng._warm_decode(bucket)
+            assert not eng.kv.pool.is_deleted()    # it never ran
+            compiled = _compiled_for_chip(eng._decode_program, one_chip)
+            text = compiled.as_text()
+            assert "paged_attention_decode" in text, \
+                "the decode kernel is not in the program"
+            assert pool_copies(text, shape) == 0, \
+                f"bucket {bucket}: the decode program copies the pool"
+            # the pool goes in and comes out as one buffer: no second one
+            # among the temporaries
+            pool_bytes = int(np.prod(shape)) * eng.kv.pool.dtype.itemsize
+            mem = compiled.memory_analysis()
+            assert mem.temp_size_in_bytes < pool_bytes // 2, \
+                (bucket, mem.temp_size_in_bytes, pool_bytes)
+    finally:
+        paddle.set_flags({"FLAGS_to_static_capture_lowered": False})
+
+
+def test_prefill_program_holds_no_copy_of_the_pool(llama, one_chip):
+    cfg = llama.config
+    prefill_fn, step_fn = llama.serving_callables(MAX_LEN)
+    eng = serving.Engine(prefill_fn, step_fn, serving.ServingConfig(
+        num_layers=cfg.num_hidden_layers, num_heads=KV_HEADS,
+        head_dim=HEAD_DIM, max_len=MAX_LEN, max_batch=SLOTS,
+        buckets=BUCKETS, page_size=PAGE, compute_dtype="bfloat16",
+        kv_dtype="bf16", paged_attention="off"))
+    paddle.set_flags({"FLAGS_to_static_capture_lowered": True})
+    try:
+        eng.warmup(prompt_lens=[96])               # runs on the CPU, dense
+        compiled = _compiled_for_chip(eng._prefill_program, one_chip)
+    finally:
+        paddle.set_flags({"FLAGS_to_static_capture_lowered": False})
+    assert pool_copies(compiled.as_text(), eng.kv.pool.shape) == 0
+
+
+def test_a_batched_scatter_would_copy_the_pool(one_chip):
+    """The positive control, and the cause on record: the write the decode
+    program used to make, one scatter over the batch rows, is compiled
+    with the pool copied into the scatter's layout and back."""
+    pool = jax.ShapeDtypeStruct((129, LAYERS, 2, KV_HEADS, PAGE, HEAD_DIM),
+                                jnp.bfloat16, sharding=one_chip)
+    rows = jax.ShapeDtypeStruct((4, LAYERS, 2, KV_HEADS, HEAD_DIM),
+                                jnp.bfloat16, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=one_chip)
+    q = jax.ShapeDtypeStruct((4, KV_HEADS, HEAD_DIM), jnp.bfloat16,
+                             sharding=one_chip)
+    tables = jax.ShapeDtypeStruct((4, MAX_LEN // PAGE), jnp.int32,
+                                  sharding=one_chip)
+
+    def step(pool, rows, pids, off, q, tables):
+        out = pa.paged_attention(q, q, q, pool, None, tables, off,
+                                 jnp.asarray(0), page_size=PAGE)
+        return out, pool.at[pids, :, :, :, off, :].set(rows)
+
+    text = jax.jit(step, donate_argnums=(0,)).lower(
+        pool, rows, ids, ids, q, tables).compile().as_text()
+    assert pool_copies(text, pool.shape) >= 1

@@ -754,7 +754,16 @@ def _build_run(seed=7, n=16, batch_size=8):
     ds = paddle.io.TensorDataset(
         [paddle.to_tensor(rng.normal(size=(n, 8)).astype(np.float32)),
          paddle.to_tensor(rng.normal(size=(n, 4)).astype(np.float32))])
-    loader = paddle.io.DataLoader(ds, batch_size=batch_size, shuffle=True)
+    # no buffered reader (ROADMAP C0): these runs abort or are killed
+    # mid-epoch, which abandons the loader's iterator inside the
+    # exception's traceback cycle. With the native prefetch queue in that
+    # cycle, the next test's gc pass may finalize the queue before the
+    # suspended generator, whose ``finally`` then closes a freed handle
+    # and takes the worker process down — in an order that only shows
+    # beside other workers. The traces pinned here do not need a prefetch
+    # thread; the queue's own fault is ROADMAP C0's.
+    loader = paddle.io.DataLoader(ds, batch_size=batch_size, shuffle=True,
+                                  use_buffer_reader=False)
     loss_fn = paddle.nn.MSELoss()
 
     def step_fn(batch):
