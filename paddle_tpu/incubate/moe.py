@@ -6,6 +6,11 @@ number_count/assign_pos/limit_by_capacity — upstream
 python/paddle/incubate/distributed/models/moe/ + paddle/fluid/operators moe
 ops).
 
+:class:`DroplessMoE` (ISSUE 27) is the serving-side expert layer: sigmoid
+scores, top-k renormalised, no capacity and no dropped token — (token,
+expert) pairs sorted by expert and one grouped matmul
+(``jax.lax.ragged_dot``) over the experts this chip holds.
+
 TPU-native design (SURVEY.md §2.5 item 10): token dispatch is the dense
 GShard einsum formulation — (tokens, experts, capacity) one-hot dispatch and
 combine tensors; no scatter kernels, XLA fuses the einsums onto the MXU. With
@@ -15,6 +20,7 @@ Global_Scatter/Gather brpc+NCCL ops collapse into GSPMD)."""
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import List, Optional, Sequence
 
@@ -28,7 +34,8 @@ from ..nn.container import LayerList
 from ..nn.layer import Layer
 from ..distributed.topology import get_hybrid_communicate_group
 
-__all__ = ["MoELayer", "NaiveGate", "GShardGate", "SwitchGate"]
+__all__ = ["MoELayer", "NaiveGate", "GShardGate", "SwitchGate",
+           "DroplessMoE", "dropless_moe"]
 
 
 class NaiveGate(Layer):
@@ -170,3 +177,143 @@ class MoELayer(Layer):
                     combine, expert_out)
         new_shape = orig_shape[:-1] + [out.shape[-1]]
         return reshape(out, new_shape)
+
+
+# ---------------------------------------------------------------------------
+# the dropless expert layer (ISSUE 27)
+# ---------------------------------------------------------------------------
+
+# tokens a grouped matmul sorts at once: the sorted (token, expert) pairs of a
+# 12k-token prompt would not fit beside a chip's share of the weights
+_CHUNK_TOKENS = 1024
+
+
+def _dropless_chunk(h, valid, wr, wg, wu, wd, *, top_k: int, first: int):
+    """One chunk of tokens through the routed experts held here.
+
+    ``h`` (T, M); ``valid`` (T,) bool — a padding row routes nowhere;
+    ``wr`` (M, E) the router over ALL experts; ``wg``/``wu`` (n, M, F) and
+    ``wd`` (n, F, M) the n experts held here, experts ``first .. first+n``.
+    Returns ``(routed (T, M) float32, rows per held expert (n,) int32)``.
+
+    Scores, top-k and the weights are float32 on the input as given (a
+    near-tie between the k-th and the next score must not turn on a bf16
+    rounding of the score itself). Every (token, expert) pair is sorted by
+    expert, pairs whose expert is not held here last: the grouped matmul
+    walks the held experts' rows only, and a token's weights are
+    renormalised over all k chosen, wherever they live. (Tried on the
+    chip, PR 27: a decode batch's held experts as one batched matmul over
+    every row — XLA streams it no better, 3.1 ms a layer against 1.95.)"""
+    t, n = h.shape[0], wg.shape[0]
+    f32 = jnp.float32
+    with jax.named_scope("moe_route"):
+        scores = jax.nn.sigmoid(jnp.dot(
+            h.astype(f32), wr.astype(f32),
+            precision=jax.lax.Precision.HIGHEST))
+        top_v, top_i = jax.lax.top_k(scores, top_k)
+        weight = top_v / jnp.sum(top_v, axis=-1, keepdims=True)
+        local = top_i - first
+        held = (local >= 0) & (local < n) & valid[:, None]
+        key = jnp.where(held, local, n).reshape(-1)       # absent: last
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.bincount(key, length=n + 1)[:n].astype(jnp.int32)
+        token = order // top_k
+    with jax.named_scope("moe_experts"):
+        x = jnp.take(h, token, axis=0)
+        gate = jax.lax.ragged_dot(x, wg, sizes, preferred_element_type=f32)
+        up = jax.lax.ragged_dot(x, wu, sizes, preferred_element_type=f32)
+        act = (jax.nn.silu(gate) * up).astype(h.dtype)
+        y = jax.lax.ragged_dot(act, wd, sizes, preferred_element_type=f32)
+        # rows past the held experts' belong to no group: whatever the
+        # grouped matmul left there is not a result
+        y = jnp.where(jnp.take(held.reshape(-1), order)[:, None],
+                      y * jnp.take(weight.reshape(-1), order)[:, None], 0.0)
+        routed = jnp.take(y, jnp.argsort(order), axis=0).reshape(
+            t, top_k, -1).sum(axis=1)
+    return routed, sizes
+
+
+def dropless_moe(h, valid, wr, wg, wu, wd, *shared, top_k: int, first: int,
+                 chunk: int):
+    """The whole layer on arrays: routed experts held here (chunked over
+    tokens: the sorted pairs of a 12k-token prompt would not fit beside
+    the weights) plus the ``shared`` experts, if any — one ``[M, s*F]`` /
+    ``[M, s*F]`` / ``[s*F, M]`` triple scaled by ``1/s``, the same sum as
+    s experts averaged. Returns ``(out (T, M) in h's dtype, rows per held
+    expert (n,) int32)``."""
+    t = h.shape[0]
+    part = functools.partial(_dropless_chunk, wr=wr, wg=wg, wu=wu, wd=wd,
+                             top_k=top_k, first=first)
+    if t <= chunk:
+        routed, sizes = part(h, valid)
+    else:
+        pad = -t % chunk
+        hp = jnp.pad(h, ((0, pad), (0, 0))).reshape(-1, chunk, h.shape[1])
+        vp = jnp.pad(valid, (0, pad)).reshape(-1, chunk)
+        routed, sizes = jax.lax.map(lambda a: part(a[0], a[1]), (hp, vp))
+        routed = routed.reshape(-1, h.shape[1])[:t]
+        sizes = sizes.sum(axis=0)
+    if shared:
+        sg, su, sd = shared
+        num_shared = sg.shape[1] // wg.shape[2]
+        with jax.named_scope("moe_shared"):
+            f32 = jnp.float32
+            mid = jax.nn.silu(jnp.dot(h, sg, preferred_element_type=f32)) \
+                * jnp.dot(h, su, preferred_element_type=f32)
+            routed = routed + jnp.dot(
+                mid.astype(h.dtype), sd,
+                preferred_element_type=f32) * (1.0 / num_shared)
+    return routed.astype(h.dtype), sizes
+
+
+class DroplessMoE(Layer):
+    """Sigmoid-routed top-k mixture of SwiGLU experts without capacity:
+    every (token, expert) pair is computed, none dropped.
+
+    The layer is told which experts it holds (``experts_held = (first,
+    count)`` of ``num_experts``): it routes over all of them, renormalises
+    each token's weights over all ``top_k`` chosen, and adds only what its
+    own experts give — one chip's part of an expert-parallel layer, which
+    on one chip runs without its exchange. ``num_shared`` shared experts
+    run for every token and are averaged.
+
+    ``forward(x (T, M), valid=None) -> (out (T, M), rows (count,) int32)``:
+    ``rows`` counts the pairs each held expert computed (the serving
+    engine's ``serving.moe.*`` counters); ``valid`` (T,) bool marks rows
+    that are tokens, not batch padding."""
+
+    def __init__(self, d_model: int, d_ff: int, num_experts: int,
+                 top_k: int, experts_held=None, num_shared: int = 0,
+                 dtype=None, chunk_tokens: int = _CHUNK_TOKENS):
+        super().__init__(dtype=dtype)
+        from ..nn.initializer import Normal
+        first, count = experts_held or (0, num_experts)
+        if first < 0 or count < 1 or first + count > num_experts:
+            raise ValueError(f"experts_held {(first, count)} lies outside "
+                             f"the {num_experts} experts")
+        self.top_k, self.first, self.num_shared = top_k, first, num_shared
+        self.chunk_tokens = chunk_tokens
+
+        def w(*shape):
+            return self.create_parameter(shape, dtype=dtype,
+                                         default_initializer=Normal(std=0.02))
+
+        self.router = w(d_model, num_experts)
+        self.w_gate, self.w_up = w(count, d_model, d_ff), w(count, d_model, d_ff)
+        self.w_down = w(count, d_ff, d_model)
+        self.shared_gate = self.shared_up = self.shared_down = None
+        if num_shared:
+            self.shared_gate = w(d_model, num_shared * d_ff)
+            self.shared_up = w(d_model, num_shared * d_ff)
+            self.shared_down = w(num_shared * d_ff, d_model)
+
+    def forward(self, x: Tensor, valid: Optional[Tensor] = None):
+        fn = functools.partial(dropless_moe, top_k=self.top_k,
+                               first=self.first, chunk=self.chunk_tokens)
+        if valid is None:
+            valid = Tensor(jnp.ones((x.shape[0],), bool))
+        shared = (self.shared_gate, self.shared_up, self.shared_down) \
+            if self.num_shared else ()
+        return apply("dropless_moe", fn, x, valid, self.router, self.w_gate,
+                     self.w_up, self.w_down, *shared, differentiable=False,
+                     amp=False)

@@ -11,3 +11,4 @@ from . import gpt  # noqa: F401
 from . import deepfm  # noqa: F401
 from . import ernie  # noqa: F401
 from . import ppyoloe  # noqa: F401
+from . import cohere2_moe  # noqa: F401

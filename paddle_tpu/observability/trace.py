@@ -70,10 +70,31 @@ span, mode ``on`` only)::
     serving.idle            * Engine.start's loop: waiting for work
     serving.http.token      * (instant) front door: one per streamed token,
                               ``lag_ms`` = engine hand-over -> frame flushed
+    serving.moe.decode      * (instant) after a decode step's read-back, for
+                              a model with an expert layer (ISSUE 27):
+                              ``rows`` = (token, expert) pairs the experts
+                              held here computed, all layers;
+                              ``experts_touched`` = held experts with a row,
+                              summed over layers; ``batch`` = live rows
+    serving.moe.prefill     * (instant) the same after a prefill's read-back
     jit.call                * StaticFunction: one whole compiled call
       jit.dispatch          * the jitted function alone; ``jit.call``'s self
                               time is hooks + registry walk + key + rebind
     train.step / train.captured_step   the supervisor's / CapturedStep's
+
+Beside them the expert layer and the pages by layer kind count (ISSUE 27;
+``observability`` registry, not events): counters ``serving.moe.rows_total``,
+``serving.moe.experts_touched_total``,
+``serving.moe.rows_by_expert_total{layer,expert}`` (``expert`` numbered
+within the held ones; the three are fed from the engine's running sum at
+most four times a second and when the last slot goes, not every step) and ``serving.kv.window_pages_released_total``; gauges
+``serving.kv.pages_in_use_by_kind{kind}`` and
+``serving.kv.window_pages_per_slot_high_water`` (the most window-kind pages
+one slot has held at once: window / page + 2 when pages leave with the
+window). The model's ``jax.named_scope``s ``attn_window``, ``attn_full``,
+``moe_route``, ``moe_experts`` and ``moe_shared`` name its ops in the HLO's
+metadata and in the profiler's own viewer; an ``.xplane.pb`` read through
+``jax.profiler.ProfileData`` carries an op's instruction name only.
 
 Clocks: an event's ``ts`` is ``time.perf_counter()``. The benchmark cuts
 its window with ``time.monotonic()`` and compares the two directly; they

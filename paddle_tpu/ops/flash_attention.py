@@ -73,7 +73,8 @@ def _keep_tile(seed, bh, q0, k0, bq, bk, keep_prob):
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int, causal: bool,
                       sm_scale: float, kv_len: int, q_len: int,
-                      with_segs: bool = False, dropout_p: float = 0.0):
+                      with_segs: bool = False, dropout_p: float = 0.0,
+                      window=None):
     """One (batch*head, q-block) program: stream K/V blocks, online softmax.
 
     Refs: q (1, Bq, D), k/v (1, Lk, D) in VMEM; o (1, Bq, D). With
@@ -92,6 +93,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int, causal: bool,
     Causal masking is bottom-right aligned (row i attends keys
     ``k <= i + kv_len - q_len``), matching ``_xla_attention`` and the
     KV-cache decode convention — lq != lk must agree with the backward path.
+
+    ``window`` (causal only) keeps the band ``0 <= row - key < window`` of
+    a sliding-window layer: K blocks wholly below a Q block's band are
+    skipped like those above the diagonal. ``None`` traces what it always
+    has.
     """
     rest = list(rest)
     qs = None
@@ -123,7 +129,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int, causal: bool,
                 jnp.int32, (bq, block_k), 0)
             k_ids = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, block_k), 1)
-            s = jnp.where(q_ids >= k_ids, s, _NEG_INF)
+            keep = q_ids >= k_ids
+            if window is not None:
+                keep = jnp.logical_and(keep, q_ids - k_ids < window)
+            s = jnp.where(keep, s, _NEG_INF)
         if with_segs:
             ks = kseg_ref[0, 0, pl.dslice(kb * block_k, block_k)].astype(
                 jnp.int32)
@@ -148,7 +157,12 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int, causal: bool,
             (q_offset + bq + causal_shift + block_k - 1) // block_k, 0, num_kb)
     else:
         last_kb = num_kb
-    m, l, acc = jax.lax.fori_loop(0, last_kb, body, (m0, l0, acc0))
+    first_kb = 0
+    if window is not None:
+        # ... and those wholly below the band of its first row
+        first_kb = jnp.clip(
+            (q_offset + causal_shift - (window - 1)) // block_k, 0, num_kb)
+    m, l, acc = jax.lax.fori_loop(first_kb, last_kb, body, (m0, l0, acc0))
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
@@ -465,7 +479,7 @@ def _run_kernel(local, lead, lead_kinds, out_kinds, q_segs, kv_segs,
 def _pallas_flash(q, k, v, causal: bool, sm_scale: float, block_q: int,
                   block_k: int, interpret: bool, with_lse: bool = False,
                   q_segs=None, kv_segs=None, dropout_p: float = 0.0,
-                  seed=None):
+                  seed=None, window=None):
     """q/k/v: (B, H, L, D) -> (B, H, L, D) [, lse (B, H, L) fp32].
 
     ``q_segs``/``kv_segs``: optional (B, L) int32 segment ids (see the
@@ -475,16 +489,21 @@ def _pallas_flash(q, k, v, causal: bool, sm_scale: float, block_q: int,
     def local(q, k, v, qs, ks, sd):
         return _pallas_flash_local(q, k, v, causal, sm_scale, block_q,
                                    block_k, interpret, with_lse, qs, ks,
-                                   dropout_p, sd)
+                                   dropout_p, sd, window)
 
     return _run_kernel(local, (q, k, v), ("bhld",) * 3,
                        ("bhld", "bhl") if with_lse else ("bhld",),
                        q_segs, kv_segs, dropout_p, seed)
 
 
+# K and V stay whole in VMEM, double-buffered: past this many bytes the
+# forward kernel asks for more than Mosaic's default 16 MiB scoped window
+_VMEM_DEFAULT_FIT = 8 * 2 ** 20
+
+
 def _pallas_flash_local(q, k, v, causal, sm_scale, block_q, block_k,
                         interpret, with_lse, q_segs, kv_segs, dropout_p,
-                        seed):
+                        seed, window=None):
     b, h, lq, d = q.shape
     lk = k.shape[2]
     block_q = min(block_q, lq)
@@ -517,12 +536,19 @@ def _pallas_flash_local(q, k, v, causal, sm_scale, block_q, block_k,
         kernel = functools.partial(_flash_fwd_kernel, block_k=block_k,
                                    causal=causal, sm_scale=sm_scale,
                                    kv_len=lk, q_len=lq, with_segs=with_segs,
-                                   dropout_p=dropout_p)
+                                   dropout_p=dropout_p, window=window)
+        more = {}
+        kv_bytes = 4 * lk * d * k.dtype.itemsize     # K, V, two buffers each
+        if kv_bytes > _VMEM_DEFAULT_FIT:
+            # a long prompt's prefill (12k tokens: 13 MB of K and V): the
+            # programs that fit the default window are compiled as before
+            more["compiler_params"] = pltpu.CompilerParams(
+                vmem_limit_bytes=kv_bytes + 24 * 2 ** 20)
         out = pl.pallas_call(
             kernel, grid=grid, in_specs=in_specs,
             out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
             out_shape=jax.ShapeDtypeStruct((b * h, lq, d), q.dtype),
-            interpret=interpret, name="flash_fwd",
+            interpret=interpret, name="flash_fwd", **more,
         )(*inputs)
         return out.reshape(b, h, lq, d)
     kernel = functools.partial(_flash_fwd_kernel_lse, block_k=block_k,
@@ -664,7 +690,7 @@ def _dropout_seed(fixed_seed_offset):
     return Tensor((kd[0] ^ kd[-1]).astype(jnp.int32).reshape(1))
 
 
-def _xla_probs(q, k, causal, sm_scale, q_segs, kv_segs):
+def _xla_probs(q, k, causal, sm_scale, q_segs, kv_segs, window=None):
     """Shared probability computation for the XLA fallbacks: logits,
     bottom-right-aligned causal tril, segment mask, softmax with the
     flash fully-masked-rows-emit-0 convention."""
@@ -673,6 +699,9 @@ def _xla_probs(q, k, causal, sm_scale, q_segs, kv_segs):
     mask = None
     if causal:
         mask = jnp.tril(jnp.ones((ql, kl), bool), k=kl - ql)
+        if window is not None:
+            mask = jnp.logical_and(mask, jnp.logical_not(jnp.tril(
+                jnp.ones((ql, kl), bool), k=kl - ql - window)))
     if q_segs is not None:
         seg = (q_segs[:, None, :, None] == kv_segs[:, None, None, :])
         mask = seg if mask is None else jnp.logical_and(mask, seg)
@@ -684,14 +713,40 @@ def _xla_probs(q, k, causal, sm_scale, q_segs, kv_segs):
 
 
 def _xla_attention(q, k, v, causal: bool, sm_scale: float,
-                   q_segs=None, kv_segs=None):
-    p = _xla_probs(q, k, causal, sm_scale, q_segs, kv_segs).astype(q.dtype)
+                   q_segs=None, kv_segs=None, window=None):
+    p = _xla_probs(q, k, causal, sm_scale, q_segs, kv_segs,
+                   window).astype(q.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _flash_core(q, k, v, causal: bool, sm_scale: float):
     return _flash_dispatch(q, k, v, causal, sm_scale)
+
+
+def _window_bwd_missing(*_):
+    raise NotImplementedError(
+        "flash_attention(window=...) has a forward only: the band's "
+        "backward kernels are not written (serving prefill does not "
+        "differentiate; train a sliding-window model on the XLA path)")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash_core_window(q, k, v, sm_scale: float, window: int):
+    """Causal attention over the band ``0 <= row - key < window``, forward
+    only: the same dispatch as :func:`_flash_core`, no ``impl="jax"``."""
+    on_tpu = jax.default_backend() == "tpu"
+    lq, lk, d = q.shape[2], k.shape[2], q.shape[3]
+    bq = _fit_block(lq, int(_flags.flag("flash_block_q")))
+    bk = _fit_block(lk, int(_flags.flag("flash_block_k")))
+    if _flags.flag("flash_impl") == "xla" or bq is None or bk is None \
+            or d % 8 != 0 or _shard_axes(q.shape[0], q.shape[1]) is False:
+        return _xla_attention(q, k, v, True, sm_scale, window=window)
+    return _pallas_flash(q, k, v, True, sm_scale, bq, bk, not on_tpu,
+                         window=window)
+
+
+_flash_core_window.defvjp(_window_bwd_missing, _window_bwd_missing)
 
 
 def _flash_dispatch(q, k, v, causal, sm_scale):
@@ -930,8 +985,16 @@ _flash_core_drop.defvjp(_flash_fwd_drop, _flash_bwd_drop)
 def flash_attention(query, key, value, dropout: float = 0.0, causal: bool = False,
                     return_softmax: bool = False, fixed_seed_offset=None,
                     rng_name: str = "", training: bool = True,
-                    q_segment_ids=None, kv_segment_ids=None, name=None):
+                    q_segment_ids=None, kv_segment_ids=None, name=None,
+                    window=None):
     """paddle.nn.functional.flash_attention parity. Inputs (B, L, H, D).
+
+    ``window`` (an int, with ``causal=True`` and neither dropout nor
+    segment ids) keeps the sliding-window band: row ``i`` attends keys
+    ``j`` with ``0 <= i - j < window`` (rows bottom-right aligned, as for
+    ``causal``). It is the forward only — differentiating through it
+    raises ``NotImplementedError``. ``window=None`` is the call it always
+    was.
 
     TPU-native extension beyond the upstream signature (trailing kwargs, so
     upstream positional calls are unaffected): ``q_segment_ids`` /
@@ -946,6 +1009,10 @@ def flash_attention(query, key, value, dropout: float = 0.0, causal: bool = Fals
     if (q_segment_ids is None) != (kv_segment_ids is None):
         raise ValueError("pass both q_segment_ids and kv_segment_ids, or "
                          "neither")
+    if window is not None and (not causal or q_segment_ids is not None
+                               or (dropout > 0.0 and training)):
+        raise ValueError("flash_attention(window=...) needs causal=True and "
+                         "takes neither segment ids nor dropout")
     if dropout > 0.0 and training:
         # round 5: attention-prob dropout stays IN the streaming kernel
         # (_flash_core_drop) — the keep mask is a stateless hash of
@@ -998,6 +1065,8 @@ def flash_attention(query, key, value, dropout: float = 0.0, causal: bool = Fals
         if segs:
             out = _flash_core_seg(qh, kh, vh, segs[0].astype(jnp.int32),
                                   segs[1].astype(jnp.int32), causal, sm_scale)
+        elif window is not None:
+            out = _flash_core_window(qh, kh, vh, sm_scale, int(window))
         else:
             out = _flash_core(qh, kh, vh, causal, sm_scale)
         return jnp.swapaxes(out, 1, 2)

@@ -39,6 +39,26 @@ decode step's KV traffic O(live pages) reads + O(1) page writes:
   as ``scatter_token_page``, sourced from the pool instead of the dense
   cache).
 
+* **A window bound** (ISSUE 27): a sliding-window layer's call takes
+  ``window`` and the COMPACT window table — ``window // page_size + 2``
+  columns, column ``j`` the logical page ``first + j`` with ``first`` the
+  page of position ``t - window + 1`` — so the grid never visits a page
+  below the window, and positions at or below ``t - window`` inside the
+  first page are masked. ``window=None`` is the kernel as it was.
+* **Many query heads to a KV head** (``rep >= 8``, ISSUE 27: 16 to 1)
+  take :func:`_decode_kernel_grouped`: the heads of one KV head are the
+  rows of one MXU matmul against eight streamed pages a grid step, where
+  the per-head kernel makes ``rep`` VPU passes over each page (on a v5e at
+  32 rows x 8000 tokens: 2.3 ms a layer against 22.8). The threshold is
+  not a crossover: at 4 heads to 1 (16 rows, 7 layers in the page, a v5e)
+  the grouped kernel is faster too — 0.37 against 0.75 ms at 300-600
+  tokens, 0.38 against 1.90 at 1500-3000, int8 0.24 / 0.33 against 0.74 /
+  2.18 — at 4-30 times the rounding error (2e-3 bf16, 8e-3 int8, still
+  under the 2e-2 held to). It stays at 8 so that a model with fewer heads
+  to a KV head runs the program it ran before ISSUE 27; taking the
+  per-head kernel out is a change to those models' numbers, to be made
+  and measured on its own.
+
 Tiering (the flash-SDPA / step-capture contract): the kernel is the TPU
 tier; off-TPU it runs under the Pallas interpreter when forced (tests)
 while ``auto`` keeps CPU on the existing dense-gather debug tier, which
@@ -61,7 +81,8 @@ from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["PagedDecodeCache", "mode", "decode_path", "kernel_eligible",
+__all__ = ["PagedDecodeCache", "PageKind", "window_first_page",
+           "window_table_pages", "mode", "decode_path", "kernel_eligible",
            "paged_attention", "paged_attention_dense",
            "scatter_token_inplace", "paged_decode_attention",
            "commit_pending"]
@@ -139,6 +160,30 @@ def kernel_eligible(page_size: int, head_dim: int, storage_dtype,
 
 
 @dataclass
+class PageKind:
+    """One pool of a :class:`PagedDecodeCache` that keeps pages by layer
+    kind: the pool, its page table rows, its int8 scales and the window of
+    its layers (``None``: full attention)."""
+
+    pool: object
+    tables: object
+    scales: Optional[object] = None
+    window: Optional[int] = None
+
+
+def window_first_page(t, window: int, page_size: int):
+    """The lowest logical page a window layer still reads at position
+    ``t`` (int or int array): the page of position ``t - window + 1``."""
+    return jnp.maximum(t - (window - 1), 0) // page_size
+
+
+def window_table_pages(window: int, page_size: int) -> int:
+    """Pages a window slot holds at most: the window's own, one more for
+    the page its low edge cuts, one for the page being written."""
+    return window // page_size + 2
+
+
+@dataclass
 class PagedDecodeCache:
     """The traced handle that threads the page pool through a decode step
     in place of the dense stacked cache.
@@ -165,6 +210,19 @@ class PagedDecodeCache:
       layers decoded so far, in layer order and not yet in the pool: each
       ``(B, H_kv, D)`` for one layer or ``(n, B, H_kv, D)`` for ``n``
       stacked ones (a scan over layers)
+    * ``window``  — ``None``, or the sliding window of the pool's layers:
+      a layer attends positions ``t - window < j <= t`` and ``tables`` is
+      the COMPACT window table ``(B, window // page_size + 2)``, entry
+      ``j`` mapping logical page ``first + j`` with ``first =
+      max(0, t - window + 1) // page_size`` (:func:`window_first_page`) —
+      pages below the window are not in the table, so they are never
+      streamed
+    * ``kinds``   — ``()`` for a model whose layers share one pool (the
+      fields above are that pool's), else one :class:`PageKind` per pool
+      (ISSUE 27: full-attention and window layers keep pages of their
+      own) and ``layer_kinds[i] = (kind, layer within the kind's pool)``;
+      :meth:`at_layer` then puts layer ``i``'s kind into ``pool`` /
+      ``tables`` / ``scales`` / ``window``
     """
 
     pool: object
@@ -176,9 +234,17 @@ class PagedDecodeCache:
     impl: str = "kernel"
     interpret: bool = False
     pending: tuple = ()
+    window: Optional[int] = None
+    kinds: tuple = ()
+    layer_kinds: tuple = ()
 
     def at_layer(self, layer) -> "PagedDecodeCache":
-        return replace(self, layer=layer)
+        if not self.kinds:
+            return replace(self, layer=layer)
+        k, local = self.layer_kinds[int(layer)]
+        kind = self.kinds[k]
+        return replace(self, layer=local, pool=kind.pool, tables=kind.tables,
+                       scales=kind.scales, window=kind.window)
 
     @property
     def num_kv_heads(self) -> int:
@@ -200,7 +266,8 @@ class PagedDecodeCache:
 
 def _decode_kernel(tables_ref, t_ref, layer_ref, q_ref, kn_ref, vn_ref,
                    k_ref, v_ref, *rest, page_size: int, num_pages: int,
-                   num_kv_heads: int, rep: int, quantized: bool):
+                   num_kv_heads: int, rep: int, quantized: bool,
+                   window: Optional[int] = None):
     """One batch row per program; grid dim 1 streams the slot's page-table
     row, and a ``fori_loop`` walks the KV heads of the streamed page. fp32
     online softmax carried in VMEM scratch across pages (TPU grids run
@@ -235,12 +302,17 @@ def _decode_kernel(tables_ref, t_ref, layer_ref, q_ref, kn_ref, vn_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     t = t_ref[b]
-    page_start = s * ps
+    if window is None:
+        page_start = s * ps
+    else:
+        page_start = (window_first_page(t, window, ps) + s) * ps
 
     @pl.when(page_start < t)                 # live page: stream it
     def _stream():
-        live = page_start + jax.lax.broadcasted_iota(
-            jnp.int32, (ps, 1), 0) < t
+        pos = page_start + jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
+        live = pos < t
+        if window is not None:
+            live = jnp.logical_and(live, pos > t - window)
         lane = jax.lax.broadcasted_iota(jnp.int32, (1, num_kv_heads), 1)
 
         def head(h, carry):
@@ -299,8 +371,120 @@ def _decode_kernel(tables_ref, t_ref, layer_ref, q_ref, kn_ref, vn_ref,
         jax.lax.fori_loop(0, num_kv_heads, head, 0)
 
 
+# with this many query heads to a KV head (a whole fp32 sublane tile of
+# them) the heads of one KV head go through the MXU together, over this
+# many pages a grid step
+_GROUPED_MIN_REP = 8
+_GROUP_PAGES = 8
+
+
+def _decode_kernel_grouped(tables_ref, t_ref, layer_ref, q_ref, kn_ref,
+                           vn_ref, *rest, page_size: int, num_steps: int,
+                           num_kv_heads: int, rep: int, quantized: bool,
+                           group: int, sm_scale: float, exact: bool,
+                           window: Optional[int] = None):
+    """:func:`_decode_kernel` for many query heads to a KV head (ISSUE 27:
+    16 to 1): the ``rep`` query heads that share KV head ``h`` are the rows
+    of ONE ``(rep, D) x (D, group * ps)`` matmul against ``group`` streamed
+    pages, and of one ``(rep, group * ps) x (group * ps, D)`` for the
+    weighted V, in place of ``rep`` VPU multiply-and-reduce passes over
+    each page. A grid step streams ``group`` consecutive table entries
+    (each its own block: pages are not contiguous in the pool), so the
+    grid is ``group`` times shorter. Same online softmax, same fold of
+    position ``t``.
+
+    Precision: a bf16 or int8 pool's values, and a bf16 model's queries,
+    are exact in bf16, so ``q . k`` takes ONE bf16 pass of the MXU and is
+    exact (``q`` comes unscaled, int8 scales and ``sm_scale`` multiply
+    the logits); ``p . v`` rounds the probabilities to bf16 as flash
+    kernels do. ``exact`` (a float32 pool) runs both at the highest
+    precision instead.
+
+    Refs: q/out ``(1, H_kv, rep, D)`` fp32 (q NOT pre-scaled); kn/vn
+    ``(1, H_kv, 1, D)``; then ``group`` K blocks, ``group`` V blocks
+    ``(1, 1, 1, H_kv, ps, D)`` and (int8) ``group`` scale blocks. Scratch:
+    m/l ``(H_kv, rep, 1)``, acc ``(H_kv, rep, D)``."""
+    rest = list(rest)
+    k_refs = [rest.pop(0) for _ in range(group)]
+    v_refs = [rest.pop(0) for _ in range(group)]
+    sc_refs = [rest.pop(0) for _ in range(group)] if quantized else None
+    o_ref, m_ref, l_ref, acc_ref = rest
+    b = pl.program_id(0)
+    s = pl.program_id(1)
+    ps = page_size
+    f32 = jnp.float32
+    precision = jax.lax.Precision.HIGHEST if exact \
+        else jax.lax.Precision.DEFAULT
+
+    @pl.when(s == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    t = t_ref[b]
+    first = 0 if window is None else window_first_page(t, window, ps)
+    start = (first + s * group) * ps         # of this step's first page
+
+    @pl.when(start < t)                      # a live page: stream the group
+    def _stream():
+        pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, group * ps), 1)
+        live = pos < t
+        if window is not None:
+            live = jnp.logical_and(live, pos > t - window)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, num_kv_heads), 1)
+
+        def tile(refs, h):
+            """Head h's rows of the group's pages, (group * ps, D) fp32."""
+            parts = [ref[0, 0, 0, h].astype(f32) for ref in refs]
+            return jnp.concatenate(parts, axis=0) if group > 1 else parts[0]
+
+        def scale_row(h, row):
+            """Head h's absmax scales of the group's pages, one a page,
+            along the logits' columns: (1, group * ps)."""
+            parts = [jnp.broadcast_to(jnp.sum(
+                jnp.where(lane == h, ref[0, 0, row:row + 1, :], 0.0),
+                axis=1, keepdims=True), (1, ps)) for ref in sc_refs]
+            return jnp.concatenate(parts, axis=1) if group > 1 else parts[0]
+
+        for h in range(num_kv_heads):        # unrolled: the heads overlap
+            logits = jax.lax.dot_general(
+                q_ref[0, h], tile(k_refs, h), (((1,), (1,)), ((), ())),
+                precision=precision,
+                preferred_element_type=f32) * sm_scale        # (rep, G*ps)
+            if quantized:
+                logits = logits * scale_row(h, 0)
+            logits = jnp.where(live, logits, _NEG_INF)
+            m_prev = m_ref[h]                                 # (rep, 1)
+            m_new = jnp.maximum(
+                m_prev, jnp.max(logits, axis=1, keepdims=True))
+            p = jnp.exp(logits - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
+            if quantized:
+                p = p * scale_row(h, 1)
+            acc_ref[h] = alpha * acc_ref[h] + jnp.dot(
+                p, tile(v_refs, h), precision=precision,
+                preferred_element_type=f32)                   # (rep, D)
+            m_ref[h] = m_new
+
+    @pl.when(s == num_steps - 1)             # fold in position t, emit
+    def _finish():
+        for h in range(num_kv_heads):
+            logit_t = jnp.sum(q_ref[0, h] * kn_ref[0, h], axis=1,
+                              keepdims=True) * sm_scale       # (rep, 1)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, logit_t)
+            p_t = jnp.exp(logit_t - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_fin = alpha * l_ref[h] + p_t
+            acc = alpha * acc_ref[h] + p_t * vn_ref[0, h]
+            o_ref[0, h] = acc / jnp.maximum(l_fin, 1e-30)
+
+
 def _kernel_call(q, k_new, v_new, pool, scales, tables, t, layer,
-                 page_size: int, interpret: bool):
+                 page_size: int, interpret: bool,
+                 window: Optional[int] = None):
     """q ``(B, H, D)``, k/v_new ``(B, H_kv, D)``, pool
     ``(P, L, 2, H_kv, ps, D)`` → out ``(B, H, D)`` in q.dtype. GQA via
     ``rep = H // H_kv`` inside the head loop (no repeat buffer); the
@@ -311,52 +495,74 @@ def _kernel_call(q, k_new, v_new, pool, scales, tables, t, layer,
     s = tables.shape[1]
     ps = page_size
     quantized = scales is not None
-    kern = functools.partial(_decode_kernel, page_size=ps, num_pages=s,
-                             num_kv_heads=h_kv, rep=rep,
-                             quantized=quantized)
+    # q and out: one block per batch row, the per-head access on a MAJOR
+    # dimension — (H, 1, D) a head, or (H_kv, rep, D) a KV head's group
+    grouped = rep >= _GROUPED_MIN_REP
+    group = _GROUP_PAGES if grouped else 1
+    if s % group:                            # whole groups: pad with the
+        tables = jnp.pad(tables, ((0, 0), (0, -s % group)))  # scratch page
+        s = tables.shape[1]
+    steps = s // group
+    qo = (h_kv, rep, d) if grouped else (h, 1, d)
+    if grouped:
+        kern = functools.partial(
+            _decode_kernel_grouped, page_size=ps, num_steps=steps,
+            num_kv_heads=h_kv, rep=rep, quantized=quantized, group=group,
+            sm_scale=1.0 / float(d) ** 0.5,
+            exact=pool.dtype == jnp.float32, window=window)
+    else:
+        kern = functools.partial(
+            _decode_kernel, page_size=ps, num_pages=s, num_kv_heads=h_kv,
+            rep=rep, quantized=quantized, window=window)
 
     def row_map(bi, si, tabs, tt, lr):
         return (bi, 0, 0, 0)
 
-    def page_map(kv):
+    def col(si, j):                          # the table column of block j
+        return si if group == 1 else si * group + j
+
+    def page_map(kv, j=0):
         def f(bi, si, tabs, tt, lr):
-            return (tabs[bi, si], lr[0], kv, 0, 0, 0)
+            return (tabs[bi, col(si, j)], lr[0], kv, 0, 0, 0)
         return f
 
-    def scale_map(bi, si, tabs, tt, lr):
-        return (tabs[bi, si], lr[0], 0, 0)
+    def scale_map(j=0):
+        def f(bi, si, tabs, tt, lr):
+            return (tabs[bi, col(si, j)], lr[0], 0, 0)
+        return f
 
     # every block's trailing two dims are the array's own, which is what
     # the Pallas TPU lowering accepts below the (8, 128) tile
     in_specs = [
-        pl.BlockSpec((1, h, 1, d), row_map),
+        pl.BlockSpec((1,) + qo, row_map),
         pl.BlockSpec((1, h_kv, 1, d), row_map),
         pl.BlockSpec((1, h_kv, 1, d), row_map),
-        pl.BlockSpec((1, 1, 1, h_kv, ps, d), page_map(0)),
-        pl.BlockSpec((1, 1, 1, h_kv, ps, d), page_map(1)),
-    ]
+    ] + [pl.BlockSpec((1, 1, 1, h_kv, ps, d), page_map(kv, j))
+         for kv in (0, 1) for j in range(group)]
     f32 = jnp.float32
-    inputs = [(q.astype(f32) * (1.0 / float(d) ** 0.5)).reshape(b, h, 1, d),
+    q32 = q.astype(f32) if grouped else q.astype(f32) * (1.0 / float(d) ** 0.5)
+    inputs = [q32.reshape((b,) + qo),
               k_new.astype(f32).reshape(b, h_kv, 1, d),
-              v_new.astype(f32).reshape(b, h_kv, 1, d), pool, pool]
+              v_new.astype(f32).reshape(b, h_kv, 1, d)] + [pool] * (2 * group)
     if quantized:
-        in_specs.append(pl.BlockSpec((1, 1, 2, h_kv), scale_map))
-        inputs.append(scales)
+        in_specs += [pl.BlockSpec((1, 1, 2, h_kv), scale_map(j))
+                     for j in range(group)]
+        inputs += [scales] * group
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, s),
+        grid=(b, steps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, 1, d), row_map),
+        out_specs=pl.BlockSpec((1,) + qo, row_map),
         scratch_shapes=[
-            pltpu.VMEM((h, 1, 1), f32),    # running max
-            pltpu.VMEM((h, 1, 1), f32),    # running denominator
-            pltpu.VMEM((h, 1, d), f32),    # weighted-V accumulator
+            pltpu.VMEM(qo[:2] + (1,), f32),    # running max
+            pltpu.VMEM(qo[:2] + (1,), f32),    # running denominator
+            pltpu.VMEM(qo, f32),               # weighted-V accumulator
         ],
     )
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     out = pl.pallas_call(
         kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, 1, d), f32),
+        out_shape=jax.ShapeDtypeStruct((b,) + qo, f32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
@@ -370,11 +576,12 @@ def _kernel_call(q, k_new, v_new, pool, scales, tables, t, layer,
 # ---------------------------------------------------------------------------
 
 def paged_attention_dense(q, k_new, v_new, pool, scales, tables, t, layer,
-                          page_size: int):
+                          page_size: int, window: Optional[int] = None):
     """Reference math for one layer: gather the slot's pages FOR THE
     DECODED LAYER ONLY (a flat ``(page, layer)`` take — the L-stacked
     dense cache still never exists), insert the current token, span-mask
-    to ``<= t``, softmax. The kernel is pinned against this."""
+    to ``<= t`` (and above ``t - window``; ``tables`` is then the compact
+    window table), softmax. The kernel is pinned against this."""
     p_, l_, _, h_kv, ps, d = pool.shape
     b, s = tables.shape
     m = s * ps
@@ -389,7 +596,11 @@ def paged_attention_dense(q, k_new, v_new, pool, scales, tables, t, layer,
     k = taken[:, :, 0].transpose(0, 2, 1, 3, 4).reshape(b, h_kv, m, d)
     v = taken[:, :, 1].transpose(0, 2, 1, 3, 4).reshape(b, h_kv, m, d)
     t32 = t.astype(jnp.int32)
-    onehot = jax.nn.one_hot(t32, m, dtype=jnp.bool_)[:, None, :, None]
+    # column c of the gathered rows is position base + c
+    base = 0 if window is None else \
+        (window_first_page(t32, window, ps) * ps)[:, None]
+    pos = base + jnp.arange(m, dtype=jnp.int32)[None, :]         # (B, M)
+    onehot = (pos == t32[:, None])[:, None, :, None]
     k = jnp.where(onehot, k_new.astype(jnp.float32)[:, :, None, :], k)
     v = jnp.where(onehot, v_new.astype(jnp.float32)[:, :, None, :], v)
     if rep > 1:
@@ -397,7 +608,9 @@ def paged_attention_dense(q, k_new, v_new, pool, scales, tables, t, layer,
         v = jnp.repeat(v, rep, axis=1)
     qf = q.astype(jnp.float32)
     logits = jnp.einsum("bhd,bhld->bhl", qf, k) / float(d) ** 0.5
-    span = jnp.arange(m, dtype=jnp.int32)[None, :] <= t32[:, None]
+    span = pos <= t32[:, None]
+    if window is not None:
+        span = jnp.logical_and(span, pos > t32[:, None] - window)
     logits = jnp.where(span[:, None, :], logits, _NEG_INF)
     p = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhl,bhld->bhd", p, v).astype(q.dtype)
@@ -405,7 +618,7 @@ def paged_attention_dense(q, k_new, v_new, pool, scales, tables, t, layer,
 
 def paged_attention(q, k_new, v_new, pool, scales, tables, t, layer, *,
                     page_size: int, impl: str = "kernel",
-                    interpret: bool = False):
+                    interpret: bool = False, window: Optional[int] = None):
     """Decode attention for one layer over the page pool. Dispatches the
     streaming kernel or the per-layer dense tier; the compiled TPU kernel
     additionally requires :func:`kernel_eligible` tiling (interpret mode
@@ -414,9 +627,9 @@ def paged_attention(q, k_new, v_new, pool, scales, tables, t, layer, *,
             page_size, int(pool.shape[-1]), pool.dtype,
             int(pool.shape[3]))):
         return _kernel_call(q, k_new, v_new, pool, scales, tables, t,
-                            layer, page_size, interpret)
+                            layer, page_size, interpret, window)
     return paged_attention_dense(q, k_new, v_new, pool, scales, tables, t,
-                                 layer, page_size)
+                                 layer, page_size, window)
 
 
 # ---------------------------------------------------------------------------
@@ -424,7 +637,7 @@ def paged_attention(q, k_new, v_new, pool, scales, tables, t, layer, *,
 # ---------------------------------------------------------------------------
 
 def scatter_token_inplace(pool, scales, tables, t, k_new, v_new,
-                          page_size: int):
+                          page_size: int, window: Optional[int] = None):
     """Write position ``t``'s K/V of EVERY layer into the containing pool
     page — no dense round-trip. ``k_new``/``v_new`` are ``(L, B, H_kv, D)``.
     Returns ``(pool', scales')``.
@@ -441,11 +654,15 @@ def scatter_token_inplace(pool, scales, tables, t, k_new, v_new,
     dequantized under their old scales, the token inserted, positions
     ``> t`` zeroed, and the pages re-quantized — the exact math of
     ``scatter_token_page``, sourced from the pool. Rows that share a page
-    (padded rows, all on the scratch page) are written in row order."""
+    (padded rows, all on the scratch page) are written in row order.
+    With a ``window``, ``tables`` is the compact window table."""
     ps = page_size
     t32 = t.astype(jnp.int32)
+    col = t32 // ps
+    if window is not None:
+        col = col - window_first_page(t32, window, ps)
     pids = jnp.take_along_axis(tables.astype(jnp.int32),
-                               (t32 // ps)[:, None], axis=1)[:, 0]  # (B,)
+                               col[:, None], axis=1)[:, 0]          # (B,)
     off = t32 % ps
     # (B, L, 2, H_kv, D): a row's update is one block of the pool
     kv_new = jnp.swapaxes(jnp.stack([k_new, v_new], axis=2), 0, 1)
@@ -493,7 +710,8 @@ def paged_decode_attention(q, k_new, v_new, cache: PagedDecodeCache):
     if cache.layer is None:
         raise ValueError("paged_decode_attention: cache.layer is unset — "
                          "derive a per-layer view with cache.at_layer(i)")
-    if isinstance(cache.layer, int) and cache.layer != cache.pending_layers:
+    if isinstance(cache.layer, int) and not cache.kinds \
+            and cache.layer != cache.pending_layers:
         raise ValueError(
             f"paged_decode_attention: layer {cache.layer} after "
             f"{cache.pending_layers} pending — a step decodes every layer "
@@ -502,11 +720,13 @@ def paged_decode_attention(q, k_new, v_new, cache: PagedDecodeCache):
     layer_t = ensure_tensor(cache.layer).astype("int32")
     quantized = cache.scales is not None
     ps, impl, interpret = cache.page_size, cache.impl, cache.interpret
+    window = cache.window
 
     def f(qa, kna, vna, pool, tables, t, layer, *maybe_scales):
         sc = maybe_scales[0] if quantized else None
         return paged_attention(qa, kna, vna, pool, sc, tables, t, layer,
-                               page_size=ps, impl=impl, interpret=interpret)
+                               page_size=ps, impl=impl, interpret=interpret,
+                               window=window)
 
     args = [q, k_new, v_new, cache.pool, cache.tables, cache.t,
             layer_t] + ([cache.scales] if quantized else [])
@@ -518,31 +738,52 @@ def paged_decode_attention(q, k_new, v_new, cache: PagedDecodeCache):
 def commit_pending(cache: PagedDecodeCache) -> PagedDecodeCache:
     """The decode step's one pool write: position ``t``'s K/V of every
     layer, collected on the handle by :func:`paged_decode_attention`, into
-    the containing pages (:func:`scatter_token_inplace`). Called where the
-    handle's owner takes the pool back (the serving engine, after
-    ``step_fn``), so no model has to remember it."""
+    the containing pages (:func:`scatter_token_inplace`) — one write per
+    pool, so a handle with pages by layer kind makes one per kind. Called
+    where the handle's owner takes the pool back (the serving engine,
+    after ``step_fn``), so no model has to remember it."""
     from ..core.tensor import apply
     n = cache.pending_layers
-    if n != int(cache.pool.shape[1]):
+    total = sum(int(k.pool.shape[1]) for k in cache.kinds) \
+        if cache.kinds else int(cache.pool.shape[1])
+    if n != total:
         raise ValueError(
-            f"commit_pending: {n} layers pending for a pool of "
-            f"{int(cache.pool.shape[1])} — a step decodes every layer once")
-    quantized = cache.scales is not None
-    ps, pairs = cache.page_size, len(cache.pending)
+            f"commit_pending: {n} layers pending for pools of "
+            f"{total} — a step decodes every layer once")
+    ps = cache.page_size
 
-    def f(pool, tables, t, *rest):
-        stacked = [a if a.ndim == 4 else a[None] for a in rest[:2 * pairs]]
-        ks, vs = stacked[0::2], stacked[1::2]
-        sc = rest[2 * pairs] if quantized else None
-        pool2, sc2 = scatter_token_inplace(
-            pool, sc, tables, t, jnp.concatenate(ks), jnp.concatenate(vs),
-            page_size=ps)
-        return (pool2, sc2) if quantized else pool2
+    def write(pool, tables, scales, window, pending):
+        quantized = scales is not None
+        pairs = len(pending)
 
-    args = [cache.pool, cache.tables, cache.t] + \
-        [x for pair in cache.pending for x in pair] + \
-        ([cache.scales] if quantized else [])
-    outs = apply("paged_commit_tokens", f, *args, differentiable=False,
-                 amp=False)
-    pool2, sc2 = outs if quantized else (outs, None)
-    return replace(cache, pool=pool2, scales=sc2, pending=())
+        def f(pool, tables, t, *rest):
+            stacked = [a if a.ndim == 4 else a[None]
+                       for a in rest[:2 * pairs]]
+            ks, vs = stacked[0::2], stacked[1::2]
+            sc = rest[2 * pairs] if quantized else None
+            pool2, sc2 = scatter_token_inplace(
+                pool, sc, tables, t, jnp.concatenate(ks),
+                jnp.concatenate(vs), page_size=ps, window=window)
+            return (pool2, sc2) if quantized else pool2
+
+        args = [pool, tables, cache.t] + [x for pair in pending for x in pair] \
+            + ([scales] if quantized else [])
+        outs = apply("paged_commit_tokens", f, *args, differentiable=False,
+                     amp=False)
+        return outs if quantized else (outs, None)
+
+    if not cache.kinds:
+        pool2, sc2 = write(cache.pool, cache.tables, cache.scales,
+                           cache.window, cache.pending)
+        return replace(cache, pool=pool2, scales=sc2, pending=())
+    if any(k.ndim != 3 for k, _ in cache.pending):
+        raise ValueError("commit_pending: pages by layer kind take one "
+                         "pending pair per layer, not stacked ones")
+    kinds = []
+    for ki, kind in enumerate(cache.kinds):
+        mine = tuple(pair for pair, (k, _) in
+                     zip(cache.pending, cache.layer_kinds) if k == ki)
+        pool2, sc2 = write(kind.pool, kind.tables, kind.scales, kind.window,
+                           mine)
+        kinds.append(replace(kind, pool=pool2, scales=sc2))
+    return replace(cache, kinds=tuple(kinds), pending=())

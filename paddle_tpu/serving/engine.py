@@ -50,6 +50,24 @@ the same values, and a replayed slot's pages are rewritten by its
 re-prefill. A call that consumed the pool and raised leaves nothing to
 adopt: fresh pool, empty prefix index, every running slot replayed.
 
+Pages by layer kind (ISSUE 27): a model that mixes full-attention and
+sliding-window layers (``ServingConfig.layer_kinds`` / ``window``) gets one
+``PagedKVCache`` per kind — the same class, its own pool ``(pages, layers
+of that kind, 2, H, page_size, D)``, free list, refcounts and prefix index
+— and every program takes and gives back one pool per kind. A
+full-attention slot holds its whole length from admission. A window slot
+holds only what its layers still read: before each decode step it claims
+the page the step writes and releases the pages below ``t - window``
+(:meth:`Engine._advance_window`), at most ``window / page_size + 2`` at
+once, and the decode kernel is handed the compact table of just those.
+Admission counts both kinds; a prefix is shared when the full pool holds
+its pages AND the window pool still holds the last window before the tail.
+A model whose layers are all of one kind gets the one pool and the programs
+it always had. A model may return a third value from ``step_fn`` /
+``prefill_fn``: an int32 array of what it counted on the device (an expert
+layer's rows per held expert), which rides behind the tokens in the step's
+one read-back (``serving.moe.*``).
+
 Failure semantics (``resilience`` seams):
 
 * ``serving.admit`` fires once per admission attempt, before prefill.
@@ -89,7 +107,11 @@ tier ran — ISSUE 13), ``serving.prefills_total``,
 ``serving.rejected_total{reason}``,
 ``serving.watchdog_trips_total{kind}``, ``serving.replays_total``,
 ``serving.queue_depth``, ``serving.active_slots``,
-``serving.batch_utilization``, and ``serving.ttft_seconds`` /
+``serving.batch_utilization``, ``serving.moe.rows_total`` /
+``experts_touched_total`` / ``rows_by_expert_total{layer,expert}``,
+``serving.kv.pages_in_use_by_kind{kind}``,
+``serving.kv.window_pages_per_slot_high_water``,
+``serving.kv.window_pages_released_total``, and ``serving.ttft_seconds`` /
 ``serving.tpot_seconds`` / ``serving.queue_wait_seconds`` histograms
 (SLO-shaped buckets — see ``TTFT_BUCKETS``/``TPOT_BUCKETS`` below).
 
@@ -113,7 +135,7 @@ import os
 import threading
 import time
 from contextlib import nullcontext
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
@@ -258,8 +280,27 @@ class ServingConfig:
     # shortest resident prefix chain worth mapping, in pages; None ->
     # $PADDLE_TPU_PREFIX_MIN_PAGES (default 1)
     min_shared_pages: Optional[int] = None
+    # pages by layer kind (ISSUE 27): a model that mixes full-attention
+    # and sliding-window layers names each layer's kind here ("full" |
+    # "window", one per layer) and its ``window``; the engine then keeps
+    # one page pool per kind — a window slot holds at most
+    # window/page_size + 2 pages, whatever its length. Empty: every layer
+    # is "full" and there is the one pool. ``num_pages_window`` sizes the
+    # window pool (default: every slot's most + the scratch page).
+    layer_kinds: Tuple[str, ...] = ()
+    window: Optional[int] = None
+    num_pages_window: Optional[int] = None
 
     def __post_init__(self):
+        self.layer_kinds = tuple(self.layer_kinds)
+        if self.layer_kinds:
+            if len(self.layer_kinds) != self.num_layers or \
+                    set(self.layer_kinds) - {"full", "window"}:
+                raise ValueError(
+                    f"layer_kinds must name \"full\" or \"window\" for each "
+                    f"of the {self.num_layers} layers, got {self.layer_kinds}")
+            if "window" in self.layer_kinds and not self.window:
+                raise ValueError("layer_kinds has window layers: set window")
         self.buckets = tuple(sorted(set(int(b) for b in self.buckets)))
         if not self.buckets or self.buckets[-1] < self.max_batch:
             raise ValueError(
@@ -308,19 +349,32 @@ class ServingConfig:
             raise ValueError(f"min_shared_pages must be >= 1, got "
                              f"{self.min_shared_pages}")
 
-    def kv_config(self) -> _kv.KVCacheConfig:
+    def kv_config(self, kind: str = "", num_layers: Optional[int] = None
+                  ) -> _kv.KVCacheConfig:
+        window = self.window if kind == "window" else None
         cfg = _kv.KVCacheConfig(
-            num_layers=self.num_layers, num_heads=self.num_heads,
+            num_layers=self.num_layers if num_layers is None else num_layers,
+            num_heads=self.num_heads,
             head_dim=self.head_dim, max_len=self.max_len,
-            page_size=self.page_size, num_pages=self.num_pages,
+            page_size=self.page_size,
+            num_pages=self.num_pages_window if window else self.num_pages,
             compute_dtype=self.compute_dtype, kv_dtype=self.kv_dtype,
-            min_shared_pages=self.min_shared_pages)
+            min_shared_pages=self.min_shared_pages, kind=kind, window=window)
         if cfg.num_pages is None:
             # every slot fully resident + the scratch page; requests with
             # short prompt+max_new claim fewer pages, freeing pool for a
             # deeper queue when num_pages is set below this default
-            cfg.num_pages = self.max_batch * cfg.pages_per_slot + 1
+            cfg.num_pages = self.max_batch * (
+                cfg.window_pages or cfg.pages_per_slot) + 1
         return cfg
+
+    def kv_configs(self) -> List[_kv.KVCacheConfig]:
+        """One pool's config per layer kind, "full" first: the one pool
+        every engine had, unless the model has window layers."""
+        if "window" not in self.layer_kinds:
+            return [self.kv_config()]
+        return [self.kv_config(kind, self.layer_kinds.count(kind))
+                for kind in ("full", "window") if kind in self.layer_kinds]
 
 
 @dataclass(eq=False)                     # identity semantics: slots hold an
@@ -330,17 +384,19 @@ class _Slot:                             # ndarray-bearing request, and
     in ``_release`` must match THIS slot, not a field-equal one."""
 
     pending: _Pending
-    page_ids: List[int]
-    table_row: np.ndarray               # (pages_per_slot,) int32
+    # per pool (one per layer kind): the page ids this slot holds one
+    # refcount on each — the leading ones may be mapped read-only from the
+    # prefix index (ISSUE 17) — and the logical page the first of them is
+    # (0 but for a window pool, which keeps no page below its window)
+    pages: List[List[int]]
+    first_page: List[int]
+    rows: List[np.ndarray]              # per pool: the decode table row
     t: int                              # next cache write position
     last_tok: int
     tokens: List[int] = field(default_factory=list)
     faults: int = 0
     first_token_time: float = 0.0
     last_token_time: float = 0.0
-    # leading pages mapped read-only from the prefix index (ISSUE 17):
-    # this slot holds one refcount on each; free() hands them back
-    shared_pages: int = 0
 
     @property
     def request(self) -> GenerationRequest:
@@ -360,10 +416,32 @@ class Engine:
         self.config = config
         self._prefill_fn = prefill_fn
         self._step_fn = step_fn
-        self.kv = _kv.PagedKVCache(config.kv_config())
-        # ISSUE 16: the HBM ledger tracks this pool's bytes (weakly — a
-        # dropped engine drops its pool from the ledger)
-        _cost.register_kv_cache(self.kv)
+        # one pool per layer kind (ISSUE 27), the full-attention one first;
+        # ``kv`` is the first: the only one unless the model has window
+        # layers. ``_layer_pool[i]`` = (pool, layer within it) of layer i.
+        self.kvs = [_kv.PagedKVCache(c) for c in config.kv_configs()]
+        self.kv = self.kvs[0]
+        names = [kv.config.kind for kv in self.kvs]
+        count = [0] * len(self.kvs)
+        self._layer_pool: List[Tuple[int, int]] = []
+        for kind in (config.layer_kinds if len(self.kvs) > 1 or names[0]
+                     else ("",) * config.num_layers):
+            k = names.index(kind)
+            self._layer_pool.append((k, count[k]))
+            count[k] += 1
+        # pages a window pool's admitted slots may come to hold at once:
+        # admission keeps it within the pool, so a decode step's page
+        # claim never fails (guarded by _slot_lock)
+        self._window_committed = [0] * len(self.kvs)
+        self._window_high_water = 0
+        # an expert layer's row counts since they were last published
+        self._expert_rows: Optional[np.ndarray] = None
+        self._expert_touches = 0
+        self._expert_rows_at = 0.0
+        # ISSUE 16: the HBM ledger tracks the pools' bytes (weakly — a
+        # dropped engine drops its pools from the ledger)
+        for kv in self.kvs:
+            _cost.register_kv_cache(kv)
         self._quantized = self.kv.config.quantized
         # ISSUE 17: prefix-cache page sharing — on only when the prefill
         # callable can start from a page-aligned offset (3-arg form)
@@ -436,7 +514,7 @@ class Engine:
         compute_dtype = jnp.dtype(cfg.compute_dtype)
         quantized = self._quantized
         step_fn, prefill_fn = self._step_fn, self._prefill_fn
-        L, H, M, D = (cfg.num_layers, cfg.num_heads, cfg.max_len,
+        L, H, M, D = (self.config.num_layers, cfg.num_heads, cfg.max_len,
                       cfg.head_dim)
         # ISSUE 13: which decode program this engine compiles — "kernel"
         # hands step_fn a PagedDecodeCache view (the dense stacked cache
@@ -460,64 +538,127 @@ class Engine:
                 "dense decode tier", ps, D, H, cfg.storage_dtype)
             self._paged_path = "dense"
 
-        def decode_fn(tok_a, tables_a, t_a, pool_a, *maybe_scales):
-            sc = maybe_scales[0] if quantized else None
-            dense = _kv.gather_pages(pool_a, sc, tables_a, compute_dtype)
-            with no_grad():
-                nxt, new_dense = step_fn(_T(tok_a), _T(dense), _T(t_a))
-            pool2, sc2 = _kv.scatter_token_page(
-                new_dense._data.astype(compute_dtype), pool_a, sc,
-                tables_a, t_a, ps)
-            out = (nxt._data.astype(jnp.int32), pool2)
-            return out + ((sc2,) if quantized else ())
+        kvs = self.kvs
+        nk = len(kvs)
+        layer_pool = self._layer_pool
+        per = 2 + int(quantized)          # a later pool's tables, pool, scales
 
-        def paged_decode_fn(tok_a, tables_a, t_a, pool_a, *maybe_scales):
+        def split(tables_a, pool_a, rest):
+            """The programs' flat arguments -> [(tables, pool, scales)] per
+            pool: the first pool's ride where the one pool's always have,
+            each later pool's after them."""
+            rest = list(rest)
+            out = [(tables_a, pool_a, rest.pop(0) if quantized else None)]
+            for _ in range(1, nk):
+                tb, pl_ = rest.pop(0), rest.pop(0)
+                out.append((tb, pl_, rest.pop(0) if quantized else None))
+            return out
+
+        def join(first, pools):
+            out = (first,)
+            for pool2, sc2 in pools:
+                out += (pool2,) + ((sc2,) if quantized else ())
+            return out
+
+        def assemble(parts):
+            """Per-pool dense caches (L_k, 2, B, H, M, D) -> the model's
+            (L, ...) in layer order; one pool's is the model's already."""
+            if nk == 1:
+                return parts[0]
+            return jnp.stack([parts[k][i] for k, i in layer_pool])
+
+        def layers_of(dense, k):
+            if nk == 1:
+                return dense
+            return dense[jnp.asarray(
+                [i for i, (kk, _) in enumerate(layer_pool) if kk == k])]
+
+        def first_out(ret):
+            """(token output, cache): a model that counts as it goes (an
+            expert layer's rows per expert) returns a third value, an int32
+            array the engine reads back WITH the tokens — one flat vector,
+            the tokens first."""
+            nxt = ret[0]._data.astype(jnp.int32)
+            if len(ret) > 2:
+                nxt = jnp.concatenate([nxt.reshape(-1), ret[2]._data.astype(
+                    jnp.int32).reshape(-1)])
+            return nxt, ret[1]
+
+        def decode_fn(tok_a, tables_a, t_a, pool_a, *rest):
+            kinds = split(tables_a, pool_a, rest)
+            dense = assemble([_kv.gather_pages(pl_, sc, tb, compute_dtype)
+                              for tb, pl_, sc in kinds])
+            with no_grad():
+                nxt, new_dense = first_out(
+                    step_fn(_T(tok_a), _T(dense), _T(t_a)))
+            new_dense = new_dense._data.astype(compute_dtype)
+            return join(nxt, [
+                _kv.scatter_token_page(layers_of(new_dense, k), pl_, sc, tb,
+                                       t_a, ps)
+                for k, (tb, pl_, sc) in enumerate(kinds)])
+
+        def paged_decode_fn(tok_a, tables_a, t_a, pool_a, *rest):
             # same program signature as decode_fn (one compiled call per
-            # bucket; the pool/scales come in donated and go back out),
+            # bucket; the pools/scales come in donated and go back out),
             # but the cache argument is the page-pool VIEW: every layer's
             # attention streams live pages through the Pallas kernel and
             # leaves position t's K/V pending on the view; the commit
-            # below is the program's one pool write, in place — made here
-            # so that no model forgets it
-            sc = maybe_scales[0] if quantized else None
+            # below is the program's one write per pool, in place — made
+            # here so that no model forgets it
+            kinds = split(tables_a, pool_a, rest)
+            tb, pl_, sc = kinds[0]
             view = _pa.PagedDecodeCache(
-                pool=_T(pool_a), tables=_T(tables_a), t=_T(t_a),
+                pool=_T(pl_), tables=_T(tb), t=_T(t_a),
                 page_size=ps, scales=_T(sc) if quantized else None,
-                impl="kernel", interpret=paged_interpret)
+                impl="kernel", interpret=paged_interpret,
+                window=kvs[0].config.window)
+            if nk > 1:
+                view = replace(view, layer_kinds=tuple(layer_pool), kinds=tuple(
+                    _pa.PageKind(pool=_T(pl_), tables=_T(tb),
+                                 scales=_T(sc) if quantized else None,
+                                 window=kv.config.window)
+                    for kv, (tb, pl_, sc) in zip(kvs, kinds)))
             with no_grad():
-                nxt, view2 = step_fn(_T(tok_a), view, _T(t_a))
+                ret = step_fn(_T(tok_a), view, _T(t_a))
+                nxt, view2 = first_out(ret)
                 view2 = _pa.commit_pending(view2)
-            out = (nxt._data.astype(jnp.int32), view2.pool._data)
-            return out + ((view2.scales._data,) if quantized else ())
+            done = view2.kinds or (view2,)
+            return join(nxt, [
+                (k.pool._data, k.scales._data if quantized else None)
+                for k in done])
 
         if self._paged_path == "kernel":
             decode_fn = paged_decode_fn
 
-        def prefill_body(ids_a, row_a, len_a, pool_a, *maybe_scales):
-            sc = maybe_scales[0] if quantized else None
+        def prefill_body(ids_a, row_a, len_a, pool_a, *rest):
+            kinds = split(row_a, pool_a, rest)
             zero = jnp.zeros((L, 2, 1, H, M, D), compute_dtype)
             with no_grad():
-                nxt, dense = prefill_fn(_T(ids_a), _T(zero))
-            pool2, sc2 = _kv.scatter_prefill_pages(
-                dense._data.astype(compute_dtype), pool_a, sc, row_a,
-                len_a, ps)
-            out = (nxt._data.astype(jnp.int32), pool2)
-            return out + ((sc2,) if quantized else ())
+                nxt, dense = first_out(prefill_fn(_T(ids_a), _T(zero)))
+            dense = dense._data.astype(compute_dtype)
+            return join(nxt, [
+                _kv.scatter_prefill_pages(layers_of(dense, k), pl_, sc, row,
+                                          len_a, ps)
+                for k, (row, pl_, sc) in enumerate(kinds)])
 
-        def decode_program(tok, tables, t, pool, *scales):
+        def decode_program(tok, tables, t, pool, *rest):
             return _apply("serving_decode_step", decode_fn, tok, tables, t,
-                          pool, *scales, differentiable=False, amp=False)
+                          pool, *rest, differentiable=False, amp=False)
 
-        def prefill_program(ids, row, true_len, pool, *scales):
+        def prefill_program(ids, row, true_len, pool, *rest):
             return _apply("serving_prefill", prefill_body, ids, row,
-                          true_len, pool, *scales, differentiable=False,
+                          true_len, pool, *rest, differentiable=False,
                           amp=False)
 
-        # every serving program CONSUMES the pool (and the int8 scales):
-        # arguments 3 and 4 are donated, so XLA aliases them to the
-        # outputs and every write lands in place — the caller's array is
-        # deleted by the call and the returned one adopted (_adopt)
-        pool_args = (3, 4)
+        # every serving program CONSUMES the pools (and the int8 scales):
+        # they are donated, so XLA aliases them to the outputs and every
+        # write lands in place — the caller's array is deleted by the call
+        # and the returned one adopted (_adopt). One pool: arguments 3
+        # and 4, as ever.
+        q = int(quantized)
+        pool_args = (3, 4) if nk == 1 else tuple(
+            at + i for k in range(nk) for i in range(1 + q)
+            for at in [3 if k == 0 else 5 + q + (k - 1) * per])
         self._decode_program = to_static(decode_program,
                                          donate_argnums=pool_args)
         self._prefill_program = to_static(prefill_program,
@@ -539,21 +680,24 @@ class Engine:
         # tail pages — the shared pages are never store targets (COW by
         # construction).
         def build_tail_program(start: int):
-            def tail_body(ids_a, row_a, len_a, pool_a, *maybe_scales):
-                sc = maybe_scales[0] if quantized else None
-                dense = _kv.gather_pages(pool_a, sc, row_a[None, :],
-                                         compute_dtype)
+            def tail_body(ids_a, row_a, len_a, pool_a, *rest):
+                kinds = split(row_a, pool_a, rest)
+                dense = assemble([
+                    _kv.gather_pages(pl_, sc, row[None, :], compute_dtype)
+                    for row, pl_, sc in kinds])
                 with no_grad():
-                    nxt, dense2 = prefill_fn(_T(ids_a), _T(dense), start)
-                pool2, sc2 = _kv.scatter_prefill_pages(
-                    dense2._data.astype(compute_dtype), pool_a, sc,
-                    row_a[start // ps:], len_a, ps, start=start)
-                out = (nxt._data.astype(jnp.int32), pool2)
-                return out + ((sc2,) if quantized else ())
+                    nxt, dense2 = first_out(
+                        prefill_fn(_T(ids_a), _T(dense), start))
+                dense2 = dense2._data.astype(compute_dtype)
+                return join(nxt, [
+                    _kv.scatter_prefill_pages(
+                        layers_of(dense2, k), pl_, sc, row[start // ps:],
+                        len_a, ps, start=start)
+                    for k, (row, pl_, sc) in enumerate(kinds)])
 
-            def tail_program(ids, row, true_len, pool, *scales):
+            def tail_program(ids, row, true_len, pool, *rest):
                 return _apply("serving_prefill", tail_body, ids, row,
-                              true_len, pool, *scales,
+                              true_len, pool, *rest,
                               differentiable=False, amp=False)
 
             prog = to_static(tail_program, donate_argnums=pool_args)
@@ -578,38 +722,51 @@ class Engine:
                 self._tail_programs[start] = prog
         return functools.partial(self._adopt, prog)
 
-    def _pool_args(self):
+    def _pool_args(self, tables=()):
+        """What every serving program takes after its first three
+        arguments: the first pool (and its int8 scales), then each later
+        pool's table, pool and scales — ``tables`` holds those later
+        tables (none for the engine with one pool)."""
         from ..core.tensor import Tensor as _T
-        return (_T(self.kv.pool),) + self._scales_args()
+        out = (_T(self.kv.pool),) + self._scales_args()
+        for kv, tb in zip(self.kvs[1:], tables):
+            out += (tb, _T(kv.pool)) + self._scales_args(kv)
+        return out
 
-    def _scales_args(self):
+    def _scales_args(self, kv=None):
         from ..core.tensor import Tensor as _T
-        return (_T(self.kv.scales),) if self._quantized else ()
+        kv = kv or self.kv
+        return (_T(kv.scales),) if self._quantized else ()
+
+    def _adopt(self, prog, *args):
+        """Call a serving program and adopt the pools it returns. The call
+        consumed the pools it was given (donated), so the returned ones are
+        adopted whatever becomes of the call's tokens: only they may be
+        abandoned."""
+        outs = prog(*args)
+        per = 1 + int(self._quantized)
+        self._set_pool(outs[1], outs[2] if self._quantized else None)
+        for k, kv in enumerate(self.kvs[1:], 1):
+            kv.pool = outs[1 + k * per]._data
+            if self._quantized:
+                kv.scales = outs[2 + k * per]._data
+        return outs
 
     def _set_pool(self, pool_t, scales_t) -> None:
         self.kv.pool = pool_t._data
         if scales_t is not None:
             self.kv.scales = scales_t._data
 
-    def _adopt(self, prog, *args):
-        """Call a serving program and adopt the pool it returns. The call
-        consumed the pool it was given (donated), so the returned one is
-        adopted whatever becomes of the call's tokens: only they may be
-        abandoned."""
-        outs = prog(*args)
-        self._set_pool(outs[1], outs[2] if self._quantized else None)
-        return outs
-
     def _restore_lost_pool(self, exc: BaseException) -> bool:
         """After a serving program raised: if the call had already
-        consumed the pool (the donated array is deleted and nothing came
-        back), every resident page is gone with it. Start from a fresh
-        pool and an empty prefix index, and send EVERY running slot
+        consumed the pools (the donated arrays are deleted and nothing came
+        back), every resident page is gone with them. Start from fresh
+        pools and an empty prefix index, and send EVERY running slot
         through bounded replay — their re-prefill rewrites their pages.
         A call that raised before it ran (a trace failure, an injected
-        fault) leaves the pool alone, and so does this (returns False)."""
-        lost = self.kv.pool.is_deleted() or (
-            self._quantized and self.kv.scales.is_deleted())
+        fault) leaves the pools alone, and so does this (returns False)."""
+        lost = any(kv.pool.is_deleted() or (
+            self._quantized and kv.scales.is_deleted()) for kv in self.kvs)
         if not lost:
             return False
         _log.warning("serving: a program call consumed the page pool and "
@@ -617,44 +774,79 @@ class Engine:
                      "running slot(s) replayed", type(exc).__name__,
                      len(self._slots))
         _obs.inc("serving.pool_resets_total")
-        self.kv.reset_pool()
+        for kv in self.kvs:
+            kv.reset_pool()
         self._recover_slots(list(self._slots), exc)
         return True
+
+    def _table_width(self, kv, decode: bool) -> int:
+        """Columns of a pool's page-table rows: every logical page, but
+        for a window pool under the decode kernel, which takes the compact
+        window table."""
+        if decode and self._paged_path == "kernel" and kv.config.window:
+            return kv.config.window_pages
+        return kv.config.pages_per_slot
+
+    def _decode_row(self, kv, ids: List[int], first: int) -> np.ndarray:
+        """A slot's row of pool ``kv``'s decode table: ``ids[0]`` is
+        logical page ``first``, which the compact window table puts in
+        column 0."""
+        width = self._table_width(kv, True)
+        compact = width != kv.config.pages_per_slot
+        return kv.table_row(ids, first=0 if compact else first, width=width)
+
+    def _zero_tables(self, lead: Tuple[int, ...], decode: bool):
+        """All-scratch tables for a warm-up call: the first pool's, then
+        the later pools' as :meth:`_pool_args` takes them."""
+        from ..core.tensor import Tensor as _T
+        tabs = [_T(jnp.zeros(lead + (self._table_width(kv, decode),),
+                             jnp.int32)) for kv in self.kvs]
+        return tabs[0], tabs[1:]
 
     def _warm_decode(self, bucket: int) -> None:
         """One decode call of ``bucket`` all-padded rows: they read and
         write the scratch page only."""
         from ..core.tensor import Tensor as _T
-        S = self.kv.config.pages_per_slot
+        first, later = self._zero_tables((bucket,), decode=True)
         self._adopt(
             self._decode_program,
-            _T(jnp.zeros((bucket, 1), jnp.int32)),
-            _T(jnp.zeros((bucket, S), jnp.int32)),
-            _T(jnp.zeros((bucket,), jnp.int32)), *self._pool_args())
+            _T(jnp.zeros((bucket, 1), jnp.int32)), first,
+            _T(jnp.zeros((bucket,), jnp.int32)), *self._pool_args(later))
 
-    def warmup(self, prompt_lens: Sequence[int] = ()) -> "Engine":
-        """Compile every batch bucket (and optional prefill lengths) up
-        front, against the scratch page only — admission then never
-        recompiles mid-flight. Idempotent; call before serving traffic."""
+    def warmup(self, prompt_lens: Sequence[int] = (),
+               tails: Sequence[Tuple[int, int]] = ()) -> "Engine":
+        """Compile every batch bucket (and optional prefill lengths, and
+        ``(shared prefix, tail)`` length pairs of prefix-shared
+        admissions) up front, against the scratch page only — admission
+        then never recompiles mid-flight. Idempotent; call before serving
+        traffic."""
         from ..core.tensor import Tensor as _T
-        S = self.kv.config.pages_per_slot
         for b in self.config.buckets:
             self._warm_decode(b)
+        first, later = self._zero_tables((), decode=False)
         for lp in prompt_lens:
             self._adopt(
                 self._prefill_program,
-                _T(jnp.zeros((1, int(lp)), jnp.int32)),
-                _T(jnp.zeros((S,), jnp.int32)),
-                _T(jnp.zeros((), jnp.int32)), *self._pool_args())
+                _T(jnp.zeros((1, int(lp)), jnp.int32)), first,
+                _T(jnp.zeros((), jnp.int32)), *self._pool_args(later))
+        for start, tail in tails:
+            self._tail_program(int(start))(
+                _T(jnp.zeros((1, int(tail)), jnp.int32)), first,
+                _T(jnp.asarray(int(start) + int(tail), jnp.int32)),
+                *self._pool_args(later))
         return self
 
     # ------------------------------------------------------------------
     # request surface
     # ------------------------------------------------------------------
-    def _pages_needed(self, request: GenerationRequest) -> int:
+    def _pages_needed(self, request: GenerationRequest, kv=None) -> int:
+        """Pages of pool ``kv`` (default: the first) the request holds at
+        most: its whole length, or a window pool's bounded share."""
+        kv = kv or self.kv
         last = min(self.config.max_len,
                    int(request.prompt.size) + request.max_new_tokens)
-        return self.kv.pages_for(last)
+        need = kv.pages_for(last)
+        return min(need, kv.config.window_pages or need)
 
     def _prefill_cost(self, request: GenerationRequest) -> int:
         """The scheduler's admission cost for a request: prompt tokens the
@@ -711,7 +903,8 @@ class Engine:
                 f"prompt ({request.prompt.size}) + max_new_tokens "
                 f"({request.max_new_tokens}) exceeds max_len "
                 f"{self.config.max_len}")
-        if self._pages_needed(request) > self.kv.config.num_pages - 1:
+        if any(self._pages_needed(request, kv) > kv.config.num_pages - 1
+               for kv in self.kvs):
             raise ValueError("request needs more pages than the pool holds")
         fut = self.scheduler.submit(request, submit_time=time.monotonic(),
                                     trace_ctx=ctx)
@@ -1023,14 +1216,22 @@ class Engine:
         # check against the same pages, over-committing the pool and then
         # letting a small request slip past a requeued large one —
         # breaking the scheduler's strict-FIFO contract
-        claimed = 0
+        claimed = [0] * len(self.kvs)
 
         def can_fit(req: GenerationRequest) -> bool:
-            nonlocal claimed
-            need = self._pages_needed(req)
-            if claimed + need > self.kv.free_pages:
-                return False
-            claimed += need
+            # both kinds of page (ISSUE 27). A full-attention pool hands a
+            # slot its whole length at admission: free pages decide. A
+            # window slot claims and releases pages as it decodes, so what
+            # decides is the most every admitted slot may come to hold:
+            # kept within the pool, a step's claim cannot fail
+            need = [self._pages_needed(req, kv) for kv in self.kvs]
+            for k, kv in enumerate(self.kvs):
+                room = kv.free_pages if not kv.config.window else \
+                    kv.config.num_pages - 1 - self._window_committed[k]
+                if claimed[k] + need[k] > room:
+                    return False
+            for k, n in enumerate(need):
+                claimed[k] += n
             return True
 
         # pop-in-progress guard: next_admissions removes replays from the
@@ -1104,28 +1305,16 @@ class Engine:
         # replayed slot re-acquires its shared prefix here too, or
         # re-prefills in full if the chain was evicted), then claim
         # private pages for the rest of the request's lifetime
-        shared: List[int] = []
-        if self._share_prefix:
-            shared = self.kv.acquire_prefix(prompt)
-        try:
-            start = len(shared) * self.config.page_size
-            pages = self.kv.alloc(self._pages_needed(req) - len(shared))
-        except BaseException:
-            # alloc REFUSING is the None return below; alloc (or the
-            # sizing arithmetic) RAISING must not strand the prefix
-            # references just acquired
-            if shared:
-                self.kv.free(shared)
-            raise
-        if pages is None:
-            if shared:
-                self.kv.free(shared)
+        claim = (self._share_prefix and self._claim_pages(req, prompt, True)) \
+            or self._claim_pages(req, prompt, False)
+        if claim is None:
             return "noroom"
-        pages = shared + pages
+        pages, first_page, shared = claim
+        start = shared * self.config.page_size
         try:
             with _trace.span("serving.prefill", parent=pending.trace_ctx,
                              rid=req.request_id, prompt=int(prompt.size),
-                             shared_pages=len(shared),
+                             shared_pages=shared,
                              replay=len(pending.replay_tokens)), \
                     self._deadline_ctx([pending]):
                 for attempt in (0, 1):
@@ -1141,16 +1330,17 @@ class Engine:
                                        rid=req.request_id,
                                        site="serving.admit", retried=True,
                                        error=type(exc).__name__)
-                row = self.kv.table_row(pages)
+                rows = [_T(jnp.asarray(kv.table_row(ids, first=lo)))
+                        for kv, ids, lo in zip(self.kvs, pages, first_page)]
                 # a tail program for a mapped prefix, else the full one;
                 # either consumes the pool and _adopt takes it back
                 prog = self._tail_program(start) if start else \
                     functools.partial(self._adopt, self._prefill_program)
                 outs = prog(
                     _T(jnp.asarray(prompt[None, start:], jnp.int32)),
-                    _T(jnp.asarray(row)),
+                    rows[0],
                     _T(jnp.asarray(prompt.size, jnp.int32)),
-                    *self._pool_args())
+                    *self._pool_args(rows[1:]))
                 # ISSUE 18: the pool swap, first-token host read and
                 # prefix publish belong to the guarded region too — the
                 # host sync raising here (wedged device, watchdog replay)
@@ -1158,8 +1348,10 @@ class Engine:
                 # it is just another "failed" admission. Inside the span
                 # (ISSUE 25): it ends when the first token exists, so its
                 # duration is a prefill, not an enqueue
-                first_tok = int(np.asarray(outs[0]._data)[0, 0])
+                flat = np.asarray(outs[0]._data).reshape(-1)
+                first_tok = int(flat[0])
             now = time.monotonic()
+            self._note_expert_rows(flat[1:], "serving.moe.prefill", 1)
             _obs.inc("serving.prefills_total")
             _obs.inc("serving.prefill_tokens_requested_total",
                      float(prompt.size))
@@ -1171,9 +1363,10 @@ class Engine:
                 # every published page). Over the ORIGINAL prompt only —
                 # a replay's appended tokens are generated content, not a
                 # shareable prompt.
-                self.kv.publish(req.prompt, pages)
+                for kv, ids, lo in zip(self.kvs, pages, first_page):
+                    kv.publish(req.prompt, ids, first=lo)
         except Exception as exc:
-            self.kv.free(pages)                 # refcount-aware: shared
+            self._free_pages(pages)             # refcount-aware: shared
             # pages are decremented, private ones actually released
             _obs.inc("serving.requests_total", status="failed")
             _trace.instant("serving.fault", parent=pending.trace_ctx,
@@ -1185,16 +1378,23 @@ class Engine:
             # returned takes every running slot's pages with it
             self._restore_lost_pool(exc)
             return "failed"
-        slot = _Slot(pending=pending, page_ids=pages, table_row=row,
+        slot = _Slot(pending=pending, pages=pages, first_page=first_page,
+                     # a full pool's row stands for the slot's whole life;
+                     # a window pool's is made before each decode step
+                     rows=[None if kv.config.window else
+                           self._decode_row(kv, ids, 0)
+                           for kv, ids in zip(self.kvs, pages)],
                      t=int(prompt.size), last_tok=first_tok,
                      tokens=list(pending.replay_tokens),
-                     first_token_time=now, last_token_time=now,
-                     shared_pages=len(shared))
+                     first_token_time=now, last_token_time=now)
         # under the eviction lock: the append must be visible as one
         # event to a concurrent budgeted stop() sweeping stragglers from
         # the caller's thread (ISSUE 14: shared-state-race)
         with self._slot_lock:
             self._slots.append(slot)
+            for k, kv in enumerate(self.kvs):
+                if kv.config.window:
+                    self._window_committed[k] += self._pages_needed(req, kv)
             self._prefill_tokens_requested += int(prompt.size)
             self._prefill_tokens_computed += int(prompt.size) - start
             late_dead = self._stop.is_set() and self._draining.is_set()
@@ -1218,6 +1418,153 @@ class Engine:
             return "ok"
         self._emit_token(slot, first_tok, now, first=True)
         return "ok"
+
+    def _claim_pages(self, req: GenerationRequest, prompt: np.ndarray,
+                     share: bool):
+        """Claim the request's pages in every pool: ``(pages, first_page,
+        shared)`` — per pool the page ids and the logical page the first
+        of them is, and how many leading pages of the prompt were mapped
+        read-only from the prefix index — or ``None`` when a pool cannot
+        cover it (nothing stays claimed).
+
+        The first pool decides how far the prefix is shared. A
+        full-attention pool maps those pages and claims the rest of the
+        request's lifetime. A window pool maps only what the tail prefill
+        reads — the pages of the ``window`` positions before the tail —
+        all of them or the prefix is not shared at all, and claims the
+        pages a later sharer of this whole prompt would read in turn and
+        up to the prompt's last; its decode steps claim and release as
+        they go (:meth:`_advance_window`). A logical page in between that
+        nobody will read has no page: its id is 0, the scratch page."""
+        ps = self.config.page_size
+        size = int(prompt.size)
+        mapped = [[] for _ in self.kvs]
+        n = 0
+        if share:
+            mapped[0] = self.kv.acquire_prefix(prompt)
+            n = len(mapped[0])
+            for k, kv in enumerate(self.kvs[1:], 1):
+                lo = kv.config.window_first_page(n * ps)
+                if n and lo < n:
+                    mapped[k] = kv.acquire_prefix(prompt, first=lo, count=n)
+                    if not mapped[k]:
+                        self._free_pages(mapped)
+                        return None
+        pages: List[List[int]] = []
+        first_page: List[int] = []
+        try:
+            for k, kv in enumerate(self.kvs):
+                if not kv.config.window:
+                    new = kv.alloc(self._pages_needed(req, kv) - n)
+                    lo, gap = 0, 0
+                else:
+                    keep = kv.config.window_first_page((size - 1) // ps * ps)
+                    lo = kv.config.window_first_page(n * ps) if mapped[k] \
+                        else max(keep, n)
+                    gap = max(0, keep - n) if mapped[k] else 0
+                    new = kv.alloc(kv.pages_for(size) - max(keep, n))
+                if new is None:
+                    raise MemoryError
+                pages.append(mapped[k] + [0] * gap + new)
+                first_page.append(lo)
+        except BaseException as exc:
+            # a pool REFUSING is the None return; a pool (or the sizing
+            # arithmetic) RAISING must not strand what was claimed so far
+            self._free_pages(mapped[len(pages):] + pages)
+            if isinstance(exc, MemoryError):
+                return None
+            raise
+        return pages, first_page, n
+
+    def _free_pages(self, pages: List[List[int]]) -> None:
+        """Release one claim on every page of a per-pool list of ids (0 is
+        a logical page that never had one)."""
+        for kv, ids in zip(self.kvs, pages):
+            kv.free([p for p in ids if p])
+
+    def _advance_window(self, slot: _Slot) -> None:
+        """Before a decode step at position ``slot.t``: in every window
+        pool, claim the page the step writes if the slot has not got it
+        yet, then release the pages that fell out of every window layer's
+        reach (ISSUE 27). Claim first: the most a slot holds is the
+        window's pages + 2, which admission kept room for."""
+        ps = self.config.page_size
+        for k, kv in enumerate(self.kvs):
+            if not kv.config.window:
+                continue
+            ids = slot.pages[k]
+            changed = grown = slot.rows[k] is None
+            while slot.first_page[k] + len(ids) <= slot.t // ps:
+                new = kv.alloc(1)
+                slot.pages[k] = ids = ids + (new or [])   # the slot's now
+                if new is None:
+                    raise RuntimeError(
+                        f"window pool {kv.config.kind!r} has no page for "
+                        f"request {slot.request.request_id} at position "
+                        f"{slot.t}: admission over-committed it")
+                changed = grown = True
+            if grown:                       # the most is just after a claim
+                held = sum(1 for p in ids if p)
+                with self._slot_lock:       # stop() may step from its thread
+                    risen = held > self._window_high_water
+                    if risen:
+                        self._window_high_water = held
+                if risen:
+                    _obs.set_gauge(
+                        "serving.kv.window_pages_per_slot_high_water",
+                        float(held))
+            drop = kv.config.window_first_page(slot.t) - slot.first_page[k]
+            if drop > 0:
+                gone = [p for p in ids[:drop] if p]
+                kv.free(gone)
+                _obs.inc("serving.kv.window_pages_released_total",
+                         float(len(gone)))
+                slot.pages[k] = ids = ids[drop:]
+                slot.first_page[k] += drop
+                changed = True
+            if changed:
+                slot.rows[k] = self._decode_row(kv, ids, slot.first_page[k])
+
+    def _note_expert_rows(self, counts: np.ndarray, event: str,
+                          batch: int) -> None:
+        """Book what an expert layer counted on the device and the step's
+        one read-back brought along (ISSUE 27): ``counts`` is flat
+        ``(layers, experts held)``, the (token, expert) pairs each held
+        expert computed. Empty for a model without one. On the step's path
+        this is one vector add; the ``serving.moe.*`` counters are fed from
+        the sum in :meth:`_publish_expert_rows`."""
+        if not counts.size:
+            return
+        with self._slot_lock:               # stop() may step from its thread
+            if self._expert_rows is None:
+                self._expert_rows = np.zeros(counts.shape, np.int64)
+            self._expert_rows += counts
+            self._expert_touches += int(np.count_nonzero(counts))
+        if _trace.mode() == "on":           # the instant exists there only
+            _trace.phase_instant(
+                event, parent=self._engine_trace, rows=int(counts.sum()),
+                experts_touched=int(np.count_nonzero(counts)), batch=batch)
+
+    def _publish_expert_rows(self, now: float, every_s: float) -> None:
+        """Feed ``serving.moe.rows_total``, ``.experts_touched_total`` and
+        ``.rows_by_expert_total{layer,expert}`` from what the steps since
+        the last call summed — at most every ``every_s`` seconds, so up to
+        64 labelled increments are not a cost of each decode step."""
+        with self._slot_lock:               # stop() may step from its thread
+            if not self._expert_touches or \
+                    now - self._expert_rows_at < every_s:
+                return
+            self._expert_rows_at = now
+            rows, touched = self._expert_rows.copy(), self._expert_touches
+            self._expert_rows[:] = 0
+            self._expert_touches = 0
+        _obs.inc("serving.moe.rows_total", float(rows.sum()))
+        _obs.inc("serving.moe.experts_touched_total", float(touched))
+        per_layer = rows.reshape(self.config.num_layers, -1)
+        for layer, expert in zip(*np.nonzero(per_layer)):
+            _obs.inc("serving.moe.rows_by_expert_total",
+                     float(per_layer[layer, expert]), layer=int(layer),
+                     expert=int(expert))
 
     def _fault_gate(self) -> List[_Slot]:
         """The per-slot ``serving.step`` seam, in admission order. A
@@ -1256,16 +1603,20 @@ class Engine:
         from ..core.tensor import Tensor as _T
         with _trace.phase("serving.decode.build"):
             bucket = self._bucket_for(len(included))
-            S = self.kv.config.pages_per_slot
             tok = np.zeros((bucket, 1), np.int32)
             t = np.zeros((bucket,), np.int32)
-            tables = np.zeros((bucket, S), np.int32)  # padded rows -> scratch
+            # one table per pool; padded rows -> scratch
+            tables = [np.zeros((bucket, self._table_width(kv, True)),
+                               np.int32) for kv in self.kvs]
             for i, slot in enumerate(included):
                 tok[i, 0] = slot.last_tok
                 t[i] = slot.t
-                tables[i] = slot.table_row
-            args = (_T(jnp.asarray(tok)), _T(jnp.asarray(tables)),
-                    _T(jnp.asarray(t)))
+                self._advance_window(slot)
+                for k, table in enumerate(tables):
+                    table[i] = slot.rows[k]
+            tables = [_T(jnp.asarray(tb)) for tb in tables]
+            args = (_T(jnp.asarray(tok)), tables[0], _T(jnp.asarray(t)))
+            later = tables[1:]
         outs = None
         with self._deadline_ctx([s.pending for s in included]):
             for attempt in (0, 1):
@@ -1276,7 +1627,7 @@ class Engine:
                     with _trace.phase("serving.decode.launch"):
                         _faults.fault_point("serving.watchdog")
                         outs = self._adopt(self._decode_program, *args,
-                                           *self._pool_args())
+                                           *self._pool_args(later))
                 except Exception as exc:
                     if gen is not None:
                         self._watchdog.disarm(gen)
@@ -1321,8 +1672,13 @@ class Engine:
             # re-prefill rewrites and a failed slot's successor overwrites
             return
         with _trace.phase("serving.decode.wait"):
-            next_np = np.asarray(outs[0]._data)    # the ONE host sync
+            # the ONE host sync: the tokens, and behind them whatever the
+            # model counted on the device (an expert layer's rows)
+            flat = np.asarray(outs[0]._data).reshape(-1)
+            next_np = flat[:bucket]
         now = time.monotonic()
+        self._note_expert_rows(flat[bucket:], "serving.moe.decode",
+                               len(included))
         _obs.inc("serving.steps_total")
         # which decode tier actually ran (ISSUE 13): the bench's
         # all-dense-on-TPU suspect rule reads this split
@@ -1331,12 +1687,12 @@ class Engine:
         with _trace.phase("serving.decode.emit"):
             for i, slot in enumerate(included):
                 slot.t += 1
-                self._emit_token(slot, int(next_np[i, 0]), now)
+                self._emit_token(slot, int(next_np[i]), now)
         with _trace.phase("serving.decode.release"):
             # the step's device arrays (three inputs, the token output)
             # die here, not at the frame's exit: on the chip freeing them
             # takes about a millisecond, and it should carry a name
-            del args, outs
+            del args, later, outs
 
     def _emit_token(self, slot: _Slot, token: int, now: float,
                     first: bool = False) -> None:
@@ -1383,7 +1739,11 @@ class Engine:
             if slot not in self._slots:
                 return False
             self._slots.remove(slot)
-        self.kv.free(slot.page_ids)
+            for k, kv in enumerate(self.kvs):
+                if kv.config.window:
+                    self._window_committed[k] -= self._pages_needed(
+                        slot.request, kv)
+        self._free_pages(slot.pages)
         return True
 
     def _finish(self, slot: _Slot, reason: str) -> None:
@@ -1462,6 +1822,9 @@ class Engine:
                 self._in_transit -= len(included)
 
     def _publish_gauges(self, active: int, bucket: int) -> None:
+        # at once when the last slot has gone: nothing may follow
+        self._publish_expert_rows(time.monotonic(),
+                                  0.25 if self._slots else 0.0)
         _obs.set_gauge("serving.active_slots", len(self._slots))
         _obs.set_gauge("serving.batch_utilization",
                        active / bucket if bucket else 0.0)

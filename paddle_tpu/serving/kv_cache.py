@@ -46,6 +46,14 @@ zero are retained on an idle LRU (still indexed, still reclaimable by
 ``alloc`` under pressure) so a later identical prompt reuses them even
 with no concurrent sharer. Admission policy (whether a request may claim
 pages at all) lives in ``serving.scheduler``.
+
+Pages by layer kind (ISSUE 27): an engine whose model mixes full-attention
+and sliding-window layers holds one :class:`PagedKVCache` per kind
+(``KVCacheConfig.kind`` / ``window``; ``num_layers`` counts that kind's
+layers). A window pool's slot keeps no page below its window, so its page
+ids start at a logical page of their own: ``table_row(ids, first=...)``,
+``publish(tokens, ids, first=...)`` and ``acquire_prefix(tokens,
+first=..., count=...)`` say which.
 """
 
 from __future__ import annotations
@@ -85,6 +93,13 @@ class KVCacheConfig:
     compute_dtype: str = "float32"    # dtype the decode step consumes
     kv_dtype: str = "native"          # "native" | "bf16" | "int8"
     min_shared_pages: int = 1         # shortest prefix chain worth sharing
+    # pages by layer kind (ISSUE 27): an engine whose model mixes
+    # full-attention and sliding-window layers keeps one PagedKVCache per
+    # kind. ``kind`` labels this pool's gauges ("" = the only pool);
+    # ``window`` is the sliding window of its layers — such a slot holds
+    # at most ``window_pages`` pages at once, whatever its length
+    kind: str = ""
+    window: Optional[int] = None
 
     def __post_init__(self):
         if self.max_len % self.page_size != 0:
@@ -97,6 +112,23 @@ class KVCacheConfig:
     @property
     def pages_per_slot(self) -> int:
         return self.max_len // self.page_size
+
+    @property
+    def window_pages(self) -> Optional[int]:
+        """Most pages one slot holds at once in a window pool (``None``
+        for full attention: a slot holds its whole length)."""
+        if self.window is None:
+            return None
+        from ..ops.paged_attention import window_table_pages
+        return min(self.pages_per_slot,
+                   window_table_pages(self.window, self.page_size))
+
+    def window_first_page(self, t: int) -> int:
+        """Lowest logical page a window layer reads at position ``t``
+        (0 for full attention)."""
+        if self.window is None:
+            return 0
+        return max(0, t - (self.window - 1)) // self.page_size
 
     @property
     def quantized(self) -> bool:
@@ -419,7 +451,8 @@ class PagedKVCache:
             self._note_usage_locked()
 
     # -- prefix sharing ------------------------------------------------------
-    def acquire_prefix(self, tokens) -> List[int]:
+    def acquire_prefix(self, tokens, first: int = 0,
+                       count: Optional[int] = None) -> List[int]:
         """Map the longest resident prefix chain of ``tokens`` read-only:
         walk the page-aligned chain digests through the index, bump each
         hit page's refcount, and return the page ids in chain order (empty
@@ -427,20 +460,31 @@ class PagedKVCache:
         pages are shareable — the unshared tail always keeps at least one
         prompt token, so the admission still has a position to prefill and
         emit the first output token from. Matches shorter than
-        ``config.min_shared_pages`` are rejected without bumping."""
+        ``config.min_shared_pages`` are rejected without bumping.
+
+        With ``count`` (a window pool following the full pool's match,
+        ISSUE 27) the answer is all or nothing: logical pages ``[first,
+        count)`` of the chain, every one resident, or ``[]`` — a window
+        layer's tail prefill reads no page below ``first``, so none is
+        asked for."""
         ps = self.config.page_size
         toks = np.asarray(tokens).reshape(-1)
         cap = max(0, (toks.size - 1) // ps)
+        if count is not None:
+            cap = min(cap, count)
         digests = prefix_chain_digests(toks, ps, limit=cap)
         with self._lock:
             self._prefix_queries += 1
             got: List[int] = []
-            for h in digests:
+            for h in digests[first:]:
                 pid = self._index.get(h)
                 if pid is None:
                     break
                 got.append(pid)
-            if len(got) < self.config.min_shared_pages:
+            if count is not None:
+                if len(got) < count - first:
+                    return []
+            elif len(got) < self.config.min_shared_pages:
                 return []
             for pid in got:
                 if pid in self._idle:
@@ -469,19 +513,22 @@ class PagedKVCache:
                 depth += 1
         return depth if depth >= self.config.min_shared_pages else 0
 
-    def publish(self, tokens, page_ids: Sequence[int]) -> int:
+    def publish(self, tokens, page_ids: Sequence[int],
+                first: int = 0) -> int:
         """Register a freshly prefilled slot's fully-prompt pages in the
         prefix index. Only pages ``k < len(tokens) // page_size`` are
         publishable (the page holding the prompt tail also receives decoded
         tokens and is NOT content-frozen). First publisher of a digest
-        wins; duplicate content on another page is left unindexed. Returns
-        the number of pages newly indexed."""
+        wins; duplicate content on another page is left unindexed.
+        ``page_ids[0]`` is logical page ``first`` (a window slot holds no
+        page below its window). Returns the number of pages newly
+        indexed."""
         ps = self.config.page_size
         toks = np.asarray(tokens).reshape(-1)
         digests = prefix_chain_digests(toks, ps)
         added = 0
         with self._lock:
-            for h, pid in zip(digests, page_ids):
+            for h, pid in zip(digests[first:], page_ids):
                 if h in self._index or pid in self._page_hash:
                     continue
                 if self._ref.get(pid, 0) <= 0:
@@ -534,6 +581,12 @@ class PagedKVCache:
         in_use = len(self._ref)
         if in_use > self._high_water:
             self._high_water = in_use
+        if self.config.kind:
+            # one of several pools: its own series, and the unlabelled
+            # gauges stay a single-pool engine's
+            _obs.set_gauge("serving.kv.pages_in_use_by_kind", float(in_use),
+                           kind=self.config.kind)
+            return
         claims = sum(self._ref.values())
         shared_extra = claims - in_use
         _obs.set_gauge("serving.kv.pages_in_use", float(in_use))
@@ -541,8 +594,14 @@ class PagedKVCache:
         _obs.set_gauge("serving.kv.pages_shared_ratio",
                        shared_extra / claims if claims else 0.0)
 
-    def table_row(self, page_ids: Sequence[int]) -> np.ndarray:
-        """A slot's page-table row: allocated ids then scratch padding."""
-        row = np.zeros(self.config.pages_per_slot, np.int32)
-        row[:len(page_ids)] = np.asarray(page_ids, np.int32)
+    def table_row(self, page_ids: Sequence[int], first: int = 0,
+                  width: Optional[int] = None) -> np.ndarray:
+        """A slot's page-table row: allocated ids then scratch padding.
+        ``page_ids[0]`` is logical page ``first`` and lands in column
+        ``first`` of a row of ``width`` columns (default: every logical
+        page); a window layer's compact row has ``first=0`` and
+        ``width=config.window_pages``."""
+        row = np.zeros(self.config.pages_per_slot if width is None
+                       else width, np.int32)
+        row[first:first + len(page_ids)] = np.asarray(page_ids, np.int32)
         return row
