@@ -57,12 +57,22 @@ span, mode ``on`` only)::
 
     serving.submit            Engine.submit (caller thread; request track)
     serving.prefill           _admit_one: page claim done -> first token read
-    serving.decode            one batched decode step (engine track)
-      serving.decode.build  * numpy batch + the three host->device puts
-      serving.decode.launch * the decode program's call until it returns
-      serving.decode.wait   * pool swap + the one host read of the tokens
-      serving.decode.emit   * per-slot bookkeeping, stream callbacks, finish
-      serving.decode.release * the step's device arrays freed (inputs, tokens)
+    serving.decode            the decode step READ at this boundary (engine
+                              track; ISSUE 28): ``batch`` = its rows,
+                              ``ahead`` = 1 if its program was launched
+                              before the step ahead of it was read. One per
+                              step read; its build/launch are the NEXT
+                              step's (absent when nobody continues), and the
+                              pipe's first build/launch ride the track bare
+      serving.decode.build  * numpy batch + the host->device puts of the
+                              step launched here
+      serving.decode.launch * that step's program call until it returns
+      serving.decode.wait   * the one host read of the tokens of the step
+                              launched a boundary EARLIER (it ran meanwhile)
+      serving.decode.emit   * its per-slot bookkeeping, stream callbacks,
+                              finish; rows of requests that ended meanwhile
+                              are discarded here
+      serving.decode.release * its device arrays freed (inputs, tokens)
     serving.cancel          * Engine.step: evict cancelled slots
     serving.admit           * Engine.step: scheduler pop, page reservation,
                               every prefill of this boundary, the fault gate
@@ -81,6 +91,12 @@ span, mode ``on`` only)::
       jit.dispatch          * the jitted function alone; ``jit.call``'s self
                               time is hooks + registry walk + key + rebind
     train.step / train.captured_step   the supervisor's / CapturedStep's
+
+Beside them the decode pipe counts (ISSUE 28; ``observability`` registry):
+``serving.decode_ahead_steps_total`` against ``serving.steps_total`` is the
+share of steps launched ahead, without a trace;
+``serving.decode_discarded_rows_total`` the rows computed for a request that
+had already ended (known only after the read, so not a span attribute).
 
 Beside them the expert layer and the pages by layer kind count (ISSUE 27;
 ``observability`` registry, not events): counters ``serving.moe.rows_total``,

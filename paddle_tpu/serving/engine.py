@@ -24,7 +24,9 @@ this repo's compiled-decode machinery):
   ``B`` tokens.
 
 * Batch rows are assigned to active slots PER STEP (per-slot state is
-  host-side: a page-table row, a position, a last token), so the batch
+  host-side: a page-table row, a position, a last token — or, for a row
+  that continues a step not yet read, where on the device that token is),
+  so the batch
   dimension is always compact. It is padded up to a BUCKET size
   (default {1, 4, 16}); padded rows point at the scratch page and are
   masked by construction, so admission/eviction changes which program
@@ -49,6 +51,33 @@ when tokens are abandoned, a retried step rewrites the same position with
 the same values, and a replayed slot's pages are rewritten by its
 re-prefill. A call that consumed the pool and raised leaves nothing to
 adopt: fresh pool, empty prefix index, every running slot replayed.
+
+One decode step is in flight ahead of the host's read (ISSUE 28). At a
+boundary the loop launches step n+1 and only then reads step n's tokens,
+so emitting, freeing, admitting and building run under a program, not
+between programs. A row that continues takes its input token from step
+n's output ON THE DEVICE (the program's ``carry`` / ``sel`` arguments);
+position and page tables are functions of host state known before the
+read (``slot.t + slot.ahead``). Who is in step n+1 is decided from what
+the host knows: a row whose step-n token is its last by
+``max_new_tokens`` or ``max_len`` is left out. **What the host cannot
+know ahead is discarded, not waited for**: a row whose request ended at
+step n by ``eos_token_id``, a raising stream callback, cancellation or a
+fault is already in step n+1, and that row of n+1 is dropped on read —
+never streamed, never counted in ``serving.tokens_total``, ``slot.t``
+never advanced (``serving.decode_discarded_rows_total``). Sound for the
+reason above: the stray write landed at the next position of pages that
+were the slot's own (or the scratch page), never a published prefix page,
+and whichever later program reuses those pages takes the pool from the
+same donation chain, so the device orders it after the stray write. The
+same holds for a window page that ``_advance_window`` releases for step
+n+1 while step n, still running, reads it. Nothing selects this: step n+1
+is launched whenever a slot passes the gate; an admission's prefill
+queues behind the step in flight and its synchronous first-token read
+drains the pipe for that boundary; when nobody is left to launch for, the
+read leaves nothing in flight, so ``run()`` returning, ``stop()`` and the
+idle loop see an engine with no program outstanding. One ``step()`` still
+emits at most one token per running row.
 
 Pages by layer kind (ISSUE 27): a model that mixes full-attention and
 sliding-window layers (``ServingConfig.layer_kinds`` / ``window``) gets one
@@ -78,14 +107,16 @@ Failure semantics (``resilience`` seams):
   slot sits out the current step; the first fault retries it at the next
   step, a second fault fails it. Its batchmates run the very same step
   unaffected: a faulted slot fails ALONE.
-* ``serving.watchdog`` fires once per batched-decode ATTEMPT, inside the
-  armed watchdog window: a ``delay`` fault there simulates a hung device
-  step, an ``error`` a whole-batch device fault. A device fault is
-  retried once (the seam raises before the call, so the pool is as it
-  was); a second fault — or a watchdog trip
-  (``PADDLE_TPU_SERVING_WATCHDOG_S``) — abandons the step's tokens (its
-  pool stays adopted) and recovers the included slots through **bounded
-  prefill replay**: each slot's prompt + tokens-so-far are requeued at
+* ``serving.watchdog`` fires once per batched-decode launch ATTEMPT,
+  inside the armed watchdog window: a ``delay`` fault there simulates a
+  hung device step, an ``error`` a whole-batch device fault. A device
+  fault is retried once (the seam raises before the call, so the pool is
+  as it was); a second fault — or a watchdog trip
+  (``PADDLE_TPU_SERVING_WATCHDOG_S``), or a lost pool — abandons the
+  tokens of EVERY step in flight, the one being launched and the one not
+  yet read (their pools stay adopted), and recovers their slots through
+  **bounded prefill replay** from ``slot.tokens``, which hold only what
+  was emitted: each slot's prompt + tokens-so-far are requeued at
   the queue head and re-prefilled into a fresh slot (at most
   ``max_replays`` times, then the request fails), so one bad step no
   longer takes every batchmate down with it.
@@ -100,7 +131,10 @@ queue time bounded; an admitted request's deadline becomes the ambient
 step it joins, so nested retry policies inherit the same budget.
 
 Metrics: ``serving.requests_total{status}``, ``serving.tokens_total``,
-``serving.steps_total``,
+``serving.steps_total`` (steps read), ``serving.decode_ahead_steps_total``
+(those launched before the step ahead of them was read) and
+``serving.decode_discarded_rows_total`` (rows computed for a request that
+had ended — ISSUE 28),
 ``serving.paged_attention_steps_total{path=kernel|dense}`` (which decode
 tier ran — ISSUE 13), ``serving.prefills_total``,
 ``serving.step_retries_total``, ``serving.pool_resets_total``,
@@ -121,7 +155,11 @@ queue/fault/replay/completion, all linked across the caller and step
 threads); the step loop's own phases (ISSUE 25, mode ``on`` only:
 ``serving.cancel``/``admit``/``publish``/``idle`` and
 ``serving.decode.build``/``launch``/``wait``/``emit``/``release``, the
-table in ``observability/trace.py``) ride the engine's track;
+table in ``observability/trace.py``) ride the engine's track — since
+ISSUE 28 ``serving.decode`` is the span of the step READ at a boundary
+(``batch`` its rows, ``ahead`` whether it was launched behind an unread
+step), its ``build``/``launch`` are the NEXT step's and its
+``wait``/``emit``/``release`` its own;
 unrecoverable batched steps dump the flight
 recorder (``serving_recover``); the step loop heartbeats ``/healthz``;
 ``PADDLE_TPU_OBS_HTTP_PORT`` opts into the scrape endpoint.
@@ -394,6 +432,10 @@ class _Slot:                             # ndarray-bearing request, and
     t: int                              # next cache write position
     last_tok: int
     tokens: List[int] = field(default_factory=list)
+    # decode steps launched for this slot whose tokens the host has not
+    # read (ISSUE 28): the next one writes position ``t + ahead``, and
+    # ``tokens``, ``t`` and ``last_tok`` know nothing of them yet
+    ahead: int = 0
     faults: int = 0
     first_token_time: float = 0.0
     last_token_time: float = 0.0
@@ -401,6 +443,21 @@ class _Slot:                             # ndarray-bearing request, and
     @property
     def request(self) -> GenerationRequest:
         return self.pending.request
+
+
+@dataclass(eq=False)
+class _Flight:
+    """One decode step the device has been given and the host has not read
+    (ISSUE 28): its rows in batch order, and the call's device arrays —
+    what was built for it, kept so that freeing them has a phase of its
+    own, and what it returned, the tokens first and the carried tokens
+    last."""
+
+    included: List[_Slot]
+    bucket: int
+    ahead: int                          # launched behind an unread step
+    args: tuple
+    outs: tuple
 
 
 class Engine:
@@ -498,6 +555,9 @@ class Engine:
         # (requests carry their own), and the opt-in scrape endpoint
         self._engine_trace = None
         self._obs_http = _obs_http.maybe_serve_from_env()
+        # the decode step launched and not yet read (ISSUE 28; written
+        # under _slot_lock: stop() may step from its own thread)
+        self._flight: Optional[_Flight] = None
         self._build_programs()
 
     # ------------------------------------------------------------------
@@ -584,18 +644,35 @@ class Engine:
                     jnp.int32).reshape(-1)])
             return nxt, ret[1]
 
+        # ISSUE 28: a decode program's last two arguments are the tokens
+        # the step before it left ON THE DEVICE (``carry``, one shape for
+        # every bucket) and, per row, which of them the row continues
+        # (``sel``; -1: the host's ``tok``). Its last output is its own
+        # tokens in that shape, for the step after it.
+        carry_rows = self.config.buckets[-1]
+
+        def pick_tok(tok_a, carry_a, sel_a):
+            return jnp.where(sel_a[:, None] >= 0,
+                             carry_a[jnp.maximum(sel_a, 0)][:, None], tok_a)
+
+        def carry_of(nxt, rows):
+            return (jnp.zeros((carry_rows,), jnp.int32)
+                    .at[:rows].set(nxt.reshape(-1)[:rows]),)
+
         def decode_fn(tok_a, tables_a, t_a, pool_a, *rest):
+            *rest, carry_a, sel_a = rest
             kinds = split(tables_a, pool_a, rest)
             dense = assemble([_kv.gather_pages(pl_, sc, tb, compute_dtype)
                               for tb, pl_, sc in kinds])
             with no_grad():
-                nxt, new_dense = first_out(
-                    step_fn(_T(tok_a), _T(dense), _T(t_a)))
+                nxt, new_dense = first_out(step_fn(
+                    _T(pick_tok(tok_a, carry_a, sel_a)), _T(dense), _T(t_a)))
             new_dense = new_dense._data.astype(compute_dtype)
             return join(nxt, [
                 _kv.scatter_token_page(layers_of(new_dense, k), pl_, sc, tb,
                                        t_a, ps)
-                for k, (tb, pl_, sc) in enumerate(kinds)])
+                for k, (tb, pl_, sc) in enumerate(kinds)]) \
+                + carry_of(nxt, tok_a.shape[0])
 
         def paged_decode_fn(tok_a, tables_a, t_a, pool_a, *rest):
             # same program signature as decode_fn (one compiled call per
@@ -605,6 +682,7 @@ class Engine:
             # leaves position t's K/V pending on the view; the commit
             # below is the program's one write per pool, in place — made
             # here so that no model forgets it
+            *rest, carry_a, sel_a = rest
             kinds = split(tables_a, pool_a, rest)
             tb, pl_, sc = kinds[0]
             view = _pa.PagedDecodeCache(
@@ -619,13 +697,14 @@ class Engine:
                                  window=kv.config.window)
                     for kv, (tb, pl_, sc) in zip(kvs, kinds)))
             with no_grad():
-                ret = step_fn(_T(tok_a), view, _T(t_a))
+                ret = step_fn(_T(pick_tok(tok_a, carry_a, sel_a)), view,
+                              _T(t_a))
                 nxt, view2 = first_out(ret)
                 view2 = _pa.commit_pending(view2)
             done = view2.kinds or (view2,)
             return join(nxt, [
                 (k.pool._data, k.scales._data if quantized else None)
-                for k in done])
+                for k in done]) + carry_of(nxt, tok_a.shape[0])
 
         if self._paged_path == "kernel":
             decode_fn = paged_decode_fn
@@ -653,10 +732,11 @@ class Engine:
         # every serving program CONSUMES the pools (and the int8 scales):
         # they are donated, so XLA aliases them to the outputs and every
         # write lands in place — the caller's array is deleted by the call
-        # and the returned one adopted (_adopt). One pool: arguments 3
-        # and 4, as ever.
+        # and the returned one adopted (_adopt). One pool: argument 3,
+        # and 4 for its scales — never what follows the pools (a decode
+        # program's carried tokens are not its to consume).
         q = int(quantized)
-        pool_args = (3, 4) if nk == 1 else tuple(
+        pool_args = tuple(
             at + i for k in range(nk) for i in range(1 + q)
             for at in [3 if k == 0 else 5 + q + (k - 1) * per])
         self._decode_program = to_static(decode_program,
@@ -708,6 +788,9 @@ class Engine:
         self._build_tail_program = build_tail_program
         self._tail_programs: Dict[int, Callable] = {}
         self._program_lock = threading.Lock()
+        # what a decode step is handed for ``carry`` when no row of it
+        # continues a step still unread (never donated: one array for good)
+        self._no_carry = _T(jnp.zeros((carry_rows,), jnp.int32))
 
     def _tail_program(self, start: int) -> Callable:
         """The compiled tail-prefill program for a static ``start`` offset
@@ -811,7 +894,8 @@ class Engine:
         self._adopt(
             self._decode_program,
             _T(jnp.zeros((bucket, 1), jnp.int32)), first,
-            _T(jnp.zeros((bucket,), jnp.int32)), *self._pool_args(later))
+            _T(jnp.zeros((bucket,), jnp.int32)), *self._pool_args(later),
+            self._no_carry, _T(jnp.full((bucket,), -1, jnp.int32)))
 
     def warmup(self, prompt_lens: Sequence[int] = (),
                tails: Sequence[Tuple[int, int]] = ()) -> "Engine":
@@ -956,9 +1040,11 @@ class Engine:
     # the step loop
     # ------------------------------------------------------------------
     def step(self) -> bool:
-        """One step boundary: evict cancellations, admit what fits, run
-        ONE batched decode step. Returns False when there was nothing to
-        do (the idle step — no program runs, no device touch)."""
+        """One step boundary: evict cancellations, admit what fits, launch
+        ONE batched decode step and read the one launched a boundary
+        earlier (ISSUE 28) — at most one token per running row. Returns
+        False when there was nothing to do (the idle step — no program
+        runs, no device touch)."""
         _trace.heartbeat(self._beacon, ttl_s=_HEARTBEAT_TTL_S)
         if self._engine_trace is None and _trace.enabled():
             self._engine_trace = _trace.new_trace("serving-engine")
@@ -976,9 +1062,7 @@ class Engine:
             progressed |= self._admit(
                 replay_only=self._draining.is_set())
             included = self._fault_gate()       # no slots: nothing gated
-        if included:
-            self._decode_step(included)
-            progressed = True
+        progressed |= self._decode(included)
         with _trace.phase("serving.publish", parent=track):
             self._publish_gauges(len(included),
                                  self._bucket_for(len(included))
@@ -986,13 +1070,15 @@ class Engine:
         return progressed
 
     def run(self) -> None:
-        """Drive step() until queue and slots drain (bench/offline mode).
-        Like :meth:`start`, clears the draining latch first, so run()
-        after ``stop(drain=True, on_timeout="requeue")`` resumes the
-        requeued work instead of refusing to admit it forever."""
+        """Drive step() until queue and slots drain and no step is left in
+        flight (bench/offline mode). Like :meth:`start`, clears the
+        draining latch first, so run() after ``stop(drain=True,
+        on_timeout="requeue")`` resumes the requeued work instead of
+        refusing to admit it forever."""
         self._stop.clear()
         self._draining.clear()
-        while self.scheduler.queue_depth or self._slots:
+        while self.scheduler.queue_depth or self._slots \
+                or self._flight is not None:
             self.step()
 
     def start(self) -> "Engine":
@@ -1011,6 +1097,7 @@ class Engine:
                                       parent=self._engine_trace):
                         self._wake.wait(0.01)
                     self._wake.clear()
+            self._decode([])        # stopped: read what is in flight
 
         self._thread = threading.Thread(
             target=loop, name="paddle-tpu-serving", daemon=True)
@@ -1078,7 +1165,8 @@ class Engine:
                 # scheduler probe stays OUTSIDE _slot_lock (it takes the
                 # scheduler's own lock — no nesting, no new lock order)
                 with self._slot_lock:
-                    busy = bool(self._slots) or self._in_transit > 0
+                    busy = bool(self._slots) or self._in_transit > 0 \
+                        or self._flight is not None
                 return busy or self.scheduler.queued_replays() > 0
             if self._thread is not None:
                 # the loop thread keeps stepping (new admissions are
@@ -1116,6 +1204,11 @@ class Engine:
                     "stragglers without it; its late return is abandoned "
                     "(slots already released; restart the process to "
                     "reclaim the thread)")
+        if t is None or not t.is_alive():
+            # nobody steps any more: read the step in flight, so that a
+            # stopped engine has no program outstanding (a wedged loop
+            # thread keeps its own: what it reads late is discarded)
+            self._decode([])
         self._thread = None
         if self._watchdog is not None:
             self._watchdog.stop()
@@ -1482,26 +1575,28 @@ class Engine:
         for kv, ids in zip(self.kvs, pages):
             kv.free([p for p in ids if p])
 
-    def _advance_window(self, slot: _Slot) -> None:
-        """Before a decode step at position ``slot.t``: in every window
-        pool, claim the page the step writes if the slot has not got it
-        yet, then release the pages that fell out of every window layer's
-        reach (ISSUE 27). Claim first: the most a slot holds is the
-        window's pages + 2, which admission kept room for."""
+    def _advance_window(self, slot: _Slot, t: int) -> None:
+        """Before a decode step of ``slot`` at position ``t``: in every
+        window pool, claim the page the step writes if the slot has not
+        got it yet, then release the pages that fell out of every window
+        layer's reach (ISSUE 27). Claim first: the most a slot holds is
+        the window's pages + 2, which admission kept room for. The step
+        before may still be running and reading a page released here: see
+        the module docstring on what is in flight."""
         ps = self.config.page_size
         for k, kv in enumerate(self.kvs):
             if not kv.config.window:
                 continue
             ids = slot.pages[k]
             changed = grown = slot.rows[k] is None
-            while slot.first_page[k] + len(ids) <= slot.t // ps:
+            while slot.first_page[k] + len(ids) <= t // ps:
                 new = kv.alloc(1)
                 slot.pages[k] = ids = ids + (new or [])   # the slot's now
                 if new is None:
                     raise RuntimeError(
                         f"window pool {kv.config.kind!r} has no page for "
                         f"request {slot.request.request_id} at position "
-                        f"{slot.t}: admission over-committed it")
+                        f"{t}: admission over-committed it")
                 changed = grown = True
             if grown:                       # the most is just after a claim
                 held = sum(1 for p in ids if p)
@@ -1513,7 +1608,7 @@ class Engine:
                     _obs.set_gauge(
                         "serving.kv.window_pages_per_slot_high_water",
                         float(held))
-            drop = kv.config.window_first_page(slot.t) - slot.first_page[k]
+            drop = kv.config.window_first_page(t) - slot.first_page[k]
             if drop > 0:
                 gone = [p for p in ids[:drop] if p]
                 kv.free(gone)
@@ -1568,9 +1663,16 @@ class Engine:
 
     def _fault_gate(self) -> List[_Slot]:
         """The per-slot ``serving.step`` seam, in admission order. A
-        faulted slot sits this step out; everyone else proceeds."""
+        faulted slot sits this step out; everyone else proceeds. A slot
+        whose token in flight is its last — the host can count
+        ``max_new_tokens`` and ``max_len`` ahead of the read — is not in
+        the next step and passes no seam, as if it had finished already."""
         included: List[_Slot] = []
         for slot in list(self._slots):
+            if len(slot.tokens) + slot.ahead >= \
+                    slot.request.max_new_tokens or \
+                    slot.t + slot.ahead >= self.config.max_len:
+                continue
             try:
                 _faults.fault_point("serving.step")
             except Exception as exc:
@@ -1594,29 +1696,69 @@ class Engine:
                 return b
         raise AssertionError(f"no bucket for batch {n}")  # __post_init__
 
-    def _decode_step(self, included: List[_Slot]) -> None:
+    def _decode(self, included: List[_Slot]) -> bool:
+        """One boundary of the decode pipe (ISSUE 28): launch the next
+        step for ``included``, THEN read the step launched a boundary
+        earlier — so the device runs the one while the host emits the
+        other, admits, and builds the one after. With nothing in flight
+        the launch stands alone (the pipe's first boundary: its phases
+        ride the engine's track); with nobody to launch for, the read
+        does, and leaves nothing in flight. ``serving.decode`` is the
+        span of the step READ here. False: there was neither."""
+        with self._slot_lock:
+            prev = self._flight
+        if prev is None:
+            if included:
+                self._launch(included, None)
+            return bool(included)
         with _trace.span("serving.decode", parent=self._engine_trace,
-                         batch=len(included)):
-            self._decode_step_traced(included)
+                         batch=len(prev.included), ahead=prev.ahead):
+            if included:
+                if not self._launch(included, prev):
+                    return True     # prev was abandoned with the launch
+            else:
+                with self._slot_lock:
+                    self._flight = None
+            self._land(prev)
+        return True
 
-    def _decode_step_traced(self, included: List[_Slot]) -> None:
+    def _launch(self, included: List[_Slot], prev: Optional[_Flight]
+                ) -> bool:
+        """Build and launch one decode step; it becomes the step in flight.
+        A row that is in ``prev``, still unread, takes its input token
+        from ``prev``'s output on the device; every other row takes the
+        host's ``last_tok``. Positions and tables are functions of host
+        state known before ``prev``'s tokens are. False: the launch failed
+        for good (a second device fault, a watchdog trip, a lost pool) and
+        every slot concerned was recovered, ``prev`` abandoned with it."""
         from ..core.tensor import Tensor as _T
-        with _trace.phase("serving.decode.build"):
+        # inside the span of the step read at this boundary, or bare on the
+        # engine's track when there is none
+        parent = self._engine_trace if prev is None else None
+        with _trace.phase("serving.decode.build", parent=parent):
             bucket = self._bucket_for(len(included))
             tok = np.zeros((bucket, 1), np.int32)
+            sel = np.full((bucket,), -1, np.int32)
             t = np.zeros((bucket,), np.int32)
             # one table per pool; padded rows -> scratch
             tables = [np.zeros((bucket, self._table_width(kv, True)),
                                np.int32) for kv in self.kvs]
+            row_of = {id(s): i for i, s in enumerate(prev.included)} \
+                if prev is not None else {}
             for i, slot in enumerate(included):
-                tok[i, 0] = slot.last_tok
-                t[i] = slot.t
-                self._advance_window(slot)
+                if slot.ahead:              # its token is on the device
+                    sel[i] = row_of[id(slot)]
+                else:
+                    tok[i, 0] = slot.last_tok
+                t[i] = slot.t + slot.ahead
+                self._advance_window(slot, int(t[i]))
                 for k, table in enumerate(tables):
                     table[i] = slot.rows[k]
             tables = [_T(jnp.asarray(tb)) for tb in tables]
             args = (_T(jnp.asarray(tok)), tables[0], _T(jnp.asarray(t)))
             later = tables[1:]
+            carried = (prev.outs[-1] if prev is not None
+                       else self._no_carry, _T(jnp.asarray(sel)))
         outs = None
         with self._deadline_ctx([s.pending for s in included]):
             for attempt in (0, 1):
@@ -1624,10 +1766,12 @@ class Engine:
                 try:
                     # the device-step seam: delay = hung step (trips the
                     # watchdog), error = whole-batch device fault
-                    with _trace.phase("serving.decode.launch"):
+                    with _trace.phase("serving.decode.launch",
+                                      parent=parent):
                         _faults.fault_point("serving.watchdog")
                         outs = self._adopt(self._decode_program, *args,
-                                           *self._pool_args(later))
+                                           *self._pool_args(later),
+                                           *carried)
                 except Exception as exc:
                     if gen is not None:
                         self._watchdog.disarm(gen)
@@ -1639,10 +1783,10 @@ class Engine:
                     # resident page with it: fresh pool, every running
                     # slot replayed — there is nothing to retry against
                     if self._restore_lost_pool(exc):
-                        return
+                        return False
                     if attempt:
                         self._recover_slots(included, exc)
-                        return
+                        return False
                     _obs.inc("serving.step_retries_total")
                     continue
                 verdict = self._watchdog.disarm(gen) if gen is not None \
@@ -1650,49 +1794,78 @@ class Engine:
                 if verdict is not None:
                     # tripped step: its pool is already adopted (the call
                     # consumed the old one), only its tokens are
-                    # abandoned. Sound because the step wrote position t
-                    # of the included slots' own pages and nothing else
-                    # (never a shared prefix page; padded rows write the
-                    # scratch page), slot.t did not advance, and the
-                    # replay's re-prefill rewrites those pages anyway
+                    # abandoned, and those of the step before it. Sound
+                    # because a step writes its own position of the
+                    # included slots' own pages and nothing else (never a
+                    # shared prefix page; padded rows write the scratch
+                    # page), slot.t did not advance, and the replay's
+                    # re-prefill rewrites those pages anyway
                     self._recover_slots(included, WatchdogTimeout(
                         f"decode step classified {verdict} by the "
                         f"watchdog (budget "
                         f"{self._watchdog.timeout_s:.3f}s)"))
-                    return
+                    return False
                 break
+        for slot in included:
+            slot.ahead += 1
         with self._slot_lock:
-            abandoned = any(s not in self._slots for s in included)
-        if abandoned:
-            # a budgeted stop() resolved these slots while the call was in
-            # flight (wedged step, watchdog disabled): the tokens are
-            # abandoned exactly like a tripped step's, so none reaches a
-            # settled future. The pool the call returned stays adopted —
-            # what it wrote is position t of pages a requeued slot's
-            # re-prefill rewrites and a failed slot's successor overwrites
-            return
+            self._flight = _Flight(included, bucket, int(prev is not None),
+                                   args + tuple(later) + carried, outs)
+        return True
+
+    def _land(self, flight: _Flight) -> None:
+        """Read a launched step's tokens and emit them. A row whose slot
+        has gone since the launch — it ended at the step before by
+        ``eos_token_id`` or a raising callback, was cancelled, or a
+        budgeted stop() resolved it while the loop was wedged — is
+        discarded: never streamed, never counted, and what its step wrote
+        is position ``t`` of pages that were the slot's own."""
         with _trace.phase("serving.decode.wait"):
             # the ONE host sync: the tokens, and behind them whatever the
             # model counted on the device (an expert layer's rows)
-            flat = np.asarray(outs[0]._data).reshape(-1)
-            next_np = flat[:bucket]
+            flat = np.asarray(flight.outs[0]._data).reshape(-1)
+            next_np = flat[:flight.bucket]
         now = time.monotonic()
-        self._note_expert_rows(flat[bucket:], "serving.moe.decode",
-                               len(included))
+        self._note_expert_rows(flat[flight.bucket:], "serving.moe.decode",
+                               len(flight.included))
         _obs.inc("serving.steps_total")
+        if flight.ahead:
+            _obs.inc("serving.decode_ahead_steps_total")
         # which decode tier actually ran (ISSUE 13): the bench's
         # all-dense-on-TPU suspect rule reads this split
         _obs.inc("serving.paged_attention_steps_total",
                  path=self._paged_path)
         with _trace.phase("serving.decode.emit"):
-            for i, slot in enumerate(included):
+            with self._slot_lock:
+                live = {id(s) for s in self._slots}
+            discarded = 0
+            for i, slot in enumerate(flight.included):
+                if id(slot) not in live:
+                    discarded += 1
+                    continue
+                slot.ahead -= 1
                 slot.t += 1
                 self._emit_token(slot, int(next_np[i]), now)
+            if discarded:
+                _obs.inc("serving.decode_discarded_rows_total",
+                         float(discarded))
         with _trace.phase("serving.decode.release"):
-            # the step's device arrays (three inputs, the token output)
-            # die here, not at the frame's exit: on the chip freeing them
-            # takes about a millisecond, and it should carry a name
-            del args, later, outs
+            # the step's device arrays (its inputs, the token output) die
+            # here, not with the object: on the chip freeing them takes
+            # about a millisecond, and it should carry a name
+            flight.args = flight.outs = ()
+
+    def _abandon_flight(self) -> List[_Slot]:
+        """Drop the step in flight unread: its tokens are abandoned (the
+        pool it returned stays adopted). Returns its slots, which the
+        caller recovers: their ``tokens`` hold only what was emitted."""
+        with self._slot_lock:
+            flight, self._flight = self._flight, None
+        if flight is None:
+            return []
+        for slot in flight.included:
+            slot.ahead = 0
+        return flight.included
 
     def _emit_token(self, slot: _Slot, token: int, now: float,
                     first: bool = False) -> None:
@@ -1784,6 +1957,12 @@ class Engine:
         batchmates no longer share one slot's fate. Past ``max_replays``
         the slot's Future gets ``exc``."""
         requeue: List[_Pending] = []
+        # the tokens of a step still in flight are abandoned with this
+        # one's (ISSUE 28), so its slots are recovered too — in admission
+        # order, as they are requeued
+        flown = self._abandon_flight()
+        included = [s for s in list(self._slots)
+                    if s in included or s in flown]
         # post-mortem first: the flight ring's tail already carries the
         # fault/trip events that got us here — snapshot it to disk before
         # recovery mutates anything (ISSUE 12: crash-recovery dump site)

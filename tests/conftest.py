@@ -121,6 +121,48 @@ def tracing(tmp_path, monkeypatch):
     trace.flight_recorder().clear()
 
 
+@pytest.fixture(scope="module")
+def tier_engine():
+    """``tier_engine(tier, **config) -> serving.Engine`` over ONE tiny
+    Llama that both decode tiers can run: ``"dense"`` (gather -> step ->
+    scatter, what the CPU serves on) and ``"kernel"`` (the page-pool view
+    through the Pallas kernel, interpreted here). Its prefill of a prompt
+    plus the tokens so far continues as its decode steps would, so a
+    replayed stream can be held to bit-identity. Shared by the serving +
+    chaos suites' ISSUE 28 tests. Module-scoped WITH teardown: the
+    parameters live in the weakref state registry (see
+    ``test_paged_attention.fmt_stack``)."""
+    import gc
+
+    import paddle_tpu as paddle
+    from paddle_tpu import serving
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    vocab, max_len = 64, 64
+    paddle.seed(12)
+    model = LlamaForCausalLM(LlamaConfig.tiny(
+        vocab=vocab, hidden=32, layers=2, heads=4, kv_heads=2, inter=48,
+        max_pos=max_len))
+    model.eval()
+    cfg = model.config
+    prefill_fn, step_fn = model.serving_callables(max_len)
+
+    def make(tier, **config):
+        config.setdefault("max_batch", 4)
+        config.setdefault("buckets", (1, 2, 4))
+        return serving.Engine(prefill_fn, step_fn, serving.ServingConfig(
+            num_layers=cfg.num_hidden_layers,
+            num_heads=cfg.num_key_value_heads,
+            head_dim=cfg.hidden_size // cfg.num_attention_heads,
+            max_len=max_len, page_size=16,
+            paged_attention={"dense": "off", "kernel": "on"}[tier],
+            **config))
+
+    make.vocab = vocab
+    yield make
+    del make, prefill_fn, step_fn, model
+    gc.collect()
+
+
 # ---------------------------------------------------------------------------
 # Test tiers. The DEFAULT tier is the core loop: autograd, to_static,
 # optimizers, distributed/pipeline/ZeRO, checkpoint, quant, IO — the

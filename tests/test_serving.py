@@ -1199,3 +1199,152 @@ class TestDrain:
             f0.result(timeout=1)
         assert eng.kv.free_pages == eng.kv.config.num_pages - 1
         assert sched.trace == [("serving.drain", 1, "error")]
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 28: one decode step in flight ahead of the host's read
+# ---------------------------------------------------------------------------
+
+TIERS = ["dense", "kernel"]
+
+
+def _request(prompt, n_new, streamed=None, **kw):
+    stream = None if streamed is None else \
+        (lambda rid, tok: streamed.setdefault(rid, []).append(tok))
+    return serving.GenerationRequest(np.asarray(prompt, np.int32),
+                                     max_new_tokens=n_new, stream=stream,
+                                     **kw)
+
+
+def one_at_a_time(eng, prompts, n_new):
+    """Each request alone through ``eng``, the next only when the one
+    before has finished: the streams a batched run must reproduce."""
+    out = []
+    for p, n in zip(prompts, n_new):
+        fut = eng.submit(_request(p, n))
+        eng.run()
+        out.append(fut.result(timeout=0).tokens)
+    return out
+
+
+def decode_programs(eng):
+    """How many decode executables the engine has compiled."""
+    return sum(entry[0]._jitted._cache_size()
+               for entry in eng._decode_program.program_cache.values())
+
+
+def tier_prompts(vocab, lens=(8, 5, 11, 7)):
+    rng = np.random.default_rng(28)
+    return [rng.integers(0, vocab, (n,), dtype=np.int32) for n in lens]
+
+
+@pytest.mark.parametrize("tier", TIERS)
+class TestDecodeAhead:
+    def test_mixed_run_streams_equal_one_at_a_time(self, tier, tier_engine,
+                                                   metrics):
+        """Admissions mid-flight, different lengths, the bucket going
+        1 -> 4 -> 2 -> 1 -> 2 -> 1: every stream is the one the request
+        decodes alone, nearly every step was launched before the step
+        ahead of it was read, and the run compiled one decode program per
+        bucket."""
+        prompts = tier_prompts(tier_engine.vocab)
+        n_new = [16, 4, 7, 5]
+        eng = tier_engine(tier)
+        streamed = {}
+        reqs = [_request(p, n, streamed) for p, n in zip(prompts, n_new)]
+        futs = [eng.submit(reqs[0])]
+        for _ in range(3):                  # A alone: bucket 1, pipe full
+            eng.step()
+        assert eng._flight is not None and eng._flight.bucket == 1
+        futs += [eng.submit(reqs[1]), eng.submit(reqs[2])]
+        eng.step()                          # B and C join: bucket 4
+        assert eng._flight.bucket == 4 and eng._flight.ahead == 1
+        while eng.active_requests > 1:      # B, then C, leave: 2, then 1
+            eng.step()
+        assert eng._flight.bucket == 1
+        futs.append(eng.submit(reqs[3]))    # D joins: up to 2 again
+        eng.run()
+        assert eng._flight is None
+        tokens = [f.result(timeout=0).tokens for f in futs]
+        assert tokens == one_at_a_time(eng, prompts, n_new)
+        assert [streamed[r.request_id] for r in reqs] == tokens
+        assert decode_programs(eng) == len(eng.config.buckets) == 3
+        snap = obs.snapshot()
+        # every batched step but the pipe's first was launched ahead (the
+        # one-at-a-time runs after it add one first boundary each)
+        assert snap["serving.decode_ahead_steps_total"] == \
+            snap["serving.steps_total"] - 1 - len(prompts)
+        assert snap.get("serving.decode_discarded_rows_total") is None
+        assert eng.kv.outstanding_pages == 0
+
+    def test_eos_with_a_step_in_flight(self, tier, tier_engine, metrics):
+        """A request that ends by ``eos_token_id`` is already in the next
+        step: that row is discarded on read — nothing past the eos is
+        streamed or counted, no page leaks, and its batchmate is
+        untouched."""
+        prompts = tier_prompts(tier_engine.vocab)[:2]
+        eng = tier_engine(tier)
+        ref = one_at_a_time(eng, prompts, [10, 10])
+        k = next(i for i in range(2, 9) if ref[0][i] not in ref[0][:i])
+        obs.reset()
+        streamed = {}
+        reqs = [_request(prompts[0], 10, streamed, eos_token_id=ref[0][k]),
+                _request(prompts[1], 10, streamed)]
+        futs = [eng.submit(r) for r in reqs]
+        eng.run()
+        res = [f.result(timeout=0) for f in futs]
+        assert res[0].finish_reason == "eos"
+        assert res[0].tokens == ref[0][:k + 1]
+        assert res[1].tokens == ref[1]
+        assert [streamed[r.request_id] for r in reqs] == \
+            [r.tokens for r in res]
+        snap = obs.snapshot()
+        assert snap["serving.decode_discarded_rows_total"] == 1
+        assert snap["serving.tokens_total"] == k + 1 + 10
+        assert eng._flight is None and eng.kv.outstanding_pages == 0
+
+    def test_nothing_outstanding_after_run_and_stop(self, tier,
+                                                    tier_engine):
+        """``run()`` returning, ``stop()`` pausing a live loop and a
+        drained ``stop()`` each leave no program unread and a live pool;
+        a paused engine resumes where it stood."""
+        prompts = tier_prompts(tier_engine.vocab)[:2]
+        eng = tier_engine(tier)
+        ref = one_at_a_time(eng, prompts, [12, 12])
+        assert eng._flight is None and not eng.kv.pool.is_deleted()
+
+        import threading
+        seen = threading.Event()
+        futs = [eng.submit(_request(prompts[0], 12)),
+                eng.submit(serving.GenerationRequest(
+                    prompts[1], max_new_tokens=12,
+                    stream=lambda rid, tok: seen.set()))]
+        eng.start()
+        assert seen.wait(timeout=60)
+        eng.stop()                          # pause: slots stay
+        assert eng._flight is None and not eng.kv.pool.is_deleted()
+        assert all(s.ahead == 0 for s in eng._slots)
+        np.asarray(eng.kv.pool)             # readable: nothing holds it
+        eng.start()
+        try:
+            assert [f.result(timeout=60).tokens for f in futs] == ref
+        finally:
+            eng.stop(drain=True, timeout=30)
+        assert eng._flight is None and not eng.kv.pool.is_deleted()
+        assert eng.kv.outstanding_pages == 0
+
+    def test_a_slot_that_sat_a_step_out_takes_its_token_from_the_host(
+            self, tier, tier_engine, metrics):
+        """A ``serving.step`` fault keeps one slot out of one step: its
+        next input token has been read by then and comes from the host
+        while its batchmate's stays on the device — same streams."""
+        prompts = tier_prompts(tier_engine.vocab)[:2]
+        eng = tier_engine(tier)
+        ref = one_at_a_time(eng, prompts, [8, 8])
+        sched = faults.FaultSchedule().error("serving.step", on=(6,))
+        futs = [eng.submit(_request(p, 8)) for p in prompts]
+        with faults.installed(sched):
+            eng.run()
+        assert [f.result(timeout=0).tokens for f in futs] == ref
+        assert sched.trace == [("serving.step", 6, "error")]
+        assert obs.snapshot()["serving.step_retries_total"] == 1

@@ -36,7 +36,8 @@ from paddle_tpu import observability as obs
 from paddle_tpu import serving
 from paddle_tpu.resilience import DeadlineExceeded, faults
 
-from test_serving import PROMPTS, dense_reference, make_engine
+from test_serving import (PROMPTS, TIERS, _request, dense_reference,
+                          make_engine, one_at_a_time, tier_prompts)
 
 EXPECTED_ERRORS = (faults.FaultInjected, serving.WatchdogTimeout,
                    DeadlineExceeded, serving.DrainTimeout,
@@ -403,3 +404,78 @@ def test_prefill_call_that_consumed_the_pool_and_raised(metrics):
     eng.run()
     assert fut.result(timeout=30).tokens == ref
     assert eng.kv.free_pages == eng.kv.config.num_pages - 1
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 28: a fault with a decode step in flight, on both decode tiers
+# ---------------------------------------------------------------------------
+
+def _run_with_step_in_flight(eng, prompts, n_new, streamed):
+    """Submit, then step until the pipe is full: a step launched and not
+    read, every request running."""
+    reqs = [_request(p, n_new, streamed) for p in prompts]
+    futs = [eng.submit(r) for r in reqs]
+    for _ in range(3):
+        eng.step()
+    assert eng._flight is not None and eng._flight.ahead == 1
+    assert all(s.ahead == 1 for s in eng._slots)
+    return reqs, futs
+
+
+@pytest.mark.parametrize("tier", TIERS)
+class TestFaultsWithAStepInFlight:
+    def test_cancel(self, tier, tier_engine, metrics):
+        """The cancelled request's row of the step in flight is discarded
+        on read; its partial transcript is a prefix of the reference, its
+        batchmate's stream is whole, every page returns."""
+        prompts = tier_prompts(tier_engine.vocab)[:2]
+        eng = tier_engine(tier)
+        ref = one_at_a_time(eng, prompts, [10, 10])
+        obs.reset()
+        streamed = {}
+        reqs, futs = _run_with_step_in_flight(eng, prompts, 10, streamed)
+        eng.cancel(reqs[0].request_id)
+        eng.run()
+        res = [f.result(timeout=0) for f in futs]
+        assert res[0].finish_reason == "cancelled"
+        assert 1 <= len(res[0].tokens) < 10
+        assert res[0].tokens == ref[0][:len(res[0].tokens)]
+        assert streamed[reqs[0].request_id] == res[0].tokens
+        assert res[1].tokens == ref[1] == streamed[reqs[1].request_id]
+        assert obs.snapshot()["serving.decode_discarded_rows_total"] == 1
+        assert eng._flight is None and eng.kv.outstanding_pages == 0
+
+    @pytest.mark.parametrize("fault", ["error", "trip"])
+    def test_whole_batch_fault_abandons_every_step_in_flight(
+            self, tier, tier_engine, fault, metrics):
+        """A second ``serving.watchdog`` error, or a watchdog trip, at the
+        launch of step n+1 with step n unread: both steps' tokens are
+        abandoned, every slot replays from what was EMITTED, and the
+        streams come out bit-identical — no token twice, none missing."""
+        prompts = tier_prompts(tier_engine.vocab)[:3]
+        watchdog_s = 1.0 if fault == "trip" else None
+        eng = tier_engine(tier, watchdog_s=watchdog_s, max_replays=1)
+        eng.warmup()            # no compile inside the watchdog's window
+        ref = one_at_a_time(eng, prompts, [9, 9, 9])
+        obs.reset()
+        sched = faults.FaultSchedule()
+        if fault == "error":
+            sched.error("serving.watchdog", on=(1, 2))
+        else:
+            sched.delay("serving.watchdog", on=(1,), seconds=2.5)
+        streamed = {}
+        reqs, futs = _run_with_step_in_flight(eng, prompts, 9, streamed)
+        emitted = [len(s.tokens) for s in eng._slots]
+        with faults.installed(sched):
+            eng.step()          # launch attempt(s) fail: replay
+            assert eng._flight is None and eng.active_requests == 0
+            assert eng.queue_depth == 3 and eng.kv.outstanding_pages == 0
+            assert [len(streamed[r.request_id]) for r in reqs] == emitted
+            eng.run()
+        assert [f.result(timeout=0).tokens for f in futs] == ref
+        assert [streamed[r.request_id] for r in reqs] == ref
+        snap = obs.snapshot()
+        assert snap["serving.replays_total"] == 3
+        assert snap["serving.tokens_total"] == 3 * 9    # none twice
+        assert eng._flight is None and eng.kv.outstanding_pages == 0
+        eng.stop()

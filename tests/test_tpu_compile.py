@@ -1,7 +1,8 @@
 """What only the chip's compiler can say, asked without the chip: the
 serving engine's decode programs, compiled for a DESCRIBED TPU v5e (the
 TPU compiler is installed here; nothing runs), hold no copy of the page
-pool (ISSUE 26).
+pool (ISSUE 26) — with the tokens carried on the device between steps
+(ISSUE 28) as without.
 
 The program before ISSUE 26 held 1 / 8 / 8 pool-shaped copies in buckets
 1 / 4 / 16 at these widths: the pool argument was not donated, and each
@@ -96,7 +97,19 @@ def test_decode_programs_hold_no_copy_of_the_pool(
             with pytest.raises(Exception, match="interpret mode"):
                 eng._warm_decode(bucket)
             assert not eng.kv.pool.is_deleted()    # it never ran
+            # ISSUE 28: the program takes the step before's tokens from the
+            # device — one shape for every bucket — and a selection per
+            # row, after the pool and its scales, and gives its own tokens
+            # back in that shape; none of that is donated
+            arg_specs = eng._decode_program._last_lowered[2]
+            assert [(a.shape, a.dtype) for a in arg_specs[-2:]] == [
+                ((BUCKETS[-1],), jnp.int32), ((bucket,), jnp.int32)]
+            assert arg_specs[3].shape == shape
+            assert not eng._no_carry._data.is_deleted()
             compiled = _compiled_for_chip(eng._decode_program, one_chip)
+            outs = jax.tree_util.tree_leaves(compiled.out_info[0])
+            assert (outs[-1].shape, outs[-1].dtype) == \
+                ((BUCKETS[-1],), jnp.int32)
             text = compiled.as_text()
             assert "paged_attention_decode" in text, \
                 "the decode kernel is not in the program"

@@ -477,9 +477,22 @@ class TestPhaseSpans:
         decodes = [b for b in begins.values()
                    if b["name"] == "serving.decode"]
         assert len(decodes) >= 3
+        # ISSUE 28: a span is the step READ at its boundary; the build and
+        # launch in it are the NEXT step's. So the last span of a pipe has
+        # none, and the pipe's first launch rides the engine's track
+        assert [k["name"] for k in kids.get(decodes[-1]["span"], [])] == \
+            _DECODE_PHASES[2:]
+        assert [b["name"] for b in kids.get(decodes[0]["parent"], [])
+                if b["ts"] < decodes[0]["ts"]
+                and b["name"].startswith("serving.decode.")] == \
+            _DECODE_PHASES[:2]
+        assert [d["attrs"]["ahead"] for d in decodes] == \
+            [0] + [1] * (len(decodes) - 1)
         for d in decodes:
             mine = kids.get(d["span"], [])
-            assert [k["name"] for k in mine] == _DECODE_PHASES
+            assert [k["name"] for k in mine] == (
+                _DECODE_PHASES if d is not decodes[-1]
+                else _DECODE_PHASES[2:])
             # in order, inside the step, not overlapping
             t = d["ts"]
             for k in mine:
@@ -699,9 +712,15 @@ class TestPhaseSpans:
         # attributes arrive as stats, not in the name
         assert any(e[0] == "serving.decode" and e[3].get("batch") == 2
                    for e in evs)
-        # one clock: a launch lies inside its decode step on that line
+        # one clock: a launch lies inside a decode step on that line (the
+        # step read while it runs) — but the pipe's first, which has none
         steps = [e for e in evs if e[0] == "serving.decode"]
-        for la in (e for e in evs if e[0] == "serving.decode.launch"):
+        launches = sorted((e for e in evs
+                           if e[0] == "serving.decode.launch"),
+                          key=lambda e: e[1])
+        assert len(launches) == len(steps) >= 3
+        assert launches[0][1] + launches[0][2] <= min(s[1] for s in steps)
+        for la in launches[1:]:
             assert any(s[1] <= la[1] and la[1] + la[2] <= s[1] + s[2]
                        for s in steps)
 
