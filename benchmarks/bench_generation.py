@@ -141,7 +141,7 @@ def _prefix_suspect_reasons(legs: dict) -> list[str]:
         reasons.append(
             "prefix_sharing: the 90% overlap leg shared ZERO pages — the "
             "run never exercised prefix reuse (check "
-            "PADDLE_TPU_PREFIX_SHARING and page alignment)")
+            "ServingConfig.prefix_sharing and page alignment)")
     for name, leg in legs.items():
         if not leg["transcripts_match"]:
             reasons.append(
@@ -195,8 +195,8 @@ def _paged_suspect_reasons(block, on_tpu: bool, formula_live=None,
                            formula_dense=None):
     """All-dense-on-TPU disqualifies the number of record: with the
     kernel available (mode != off) every measured decode step running the
-    dense tier means the run benchmarked the debug path — e.g. a test
-    env's PADDLE_TPU_PAGED_ATTENTION=off leaking in (the
+    dense tier means the run benchmarked the debug path — e.g. an
+    ineligible kernel shape demoting the engine (the
     _capture_suspect_reasons rule, for the serving tier).
 
     The formula cross-check (ISSUE 16) is one-sided: the hand formula
@@ -209,7 +209,7 @@ def _paged_suspect_reasons(block, on_tpu: bool, formula_live=None,
         reasons.append(
             "paged_attention: every decode step ran the dense gather tier "
             "on TPU — the measured tok/s is the debug path, not the "
-            "kernel (check PADDLE_TPU_PAGED_ATTENTION and kernel "
+            "kernel (check ServingConfig.paged_attention and kernel "
             "eligibility)")
     if block.get("attn_bytes_source") == "measured":
         ran_kernel = block["kernel_steps"] >= block["dense_steps"] \
@@ -575,7 +575,6 @@ def _run_serving(args, paddle, prefill_raw, prefill, lm_step, decode_one,
     live_b, dense_b = _paged_attn_bytes_per_token(
         L, H, E // H, M, page_size, sbytes, args.prompt, n_new)
     steps_by_path = snap.get("serving.paged_attention_steps_total", {}) or {}
-    from paddle_tpu.ops import paged_attention as _pa
     kernel_steps = int(steps_by_path.get("path=kernel", 0))
     dense_steps = int(steps_by_path.get("path=dense", 0))
     # ISSUE 16: the tier that ran reports the cost registry's MEASURED
@@ -594,7 +593,7 @@ def _run_serving(args, paddle, prefill_raw, prefill, lm_step, decode_one,
         else:
             dense_rep = measured_b
     paged_block = {
-        "mode": _pa.mode(),
+        "mode": cfg.paged_attention,
         "kernel_steps": kernel_steps,
         "dense_steps": dense_steps,
         "attn_bytes_per_token_live": live_rep,

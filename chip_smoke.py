@@ -459,7 +459,7 @@ def leg_serve(depth=None, *, config=None, on_chip=True) -> dict:
 
     # -- the engine under test: auto resolves the decode tier ------------
     t0 = time.perf_counter()
-    engine = build_engine("r0", "", SERVE_MAX_BATCH, SERVE_BUCKETS)
+    engine = build_engine("r0", "auto", SERVE_MAX_BATCH, SERVE_BUCKETS)
     want_path = "kernel" if on_chip else "dense"
     assert engine._paged_path == want_path, engine._paged_path
     engine.warmup(prompt_lens=SERVE_PROMPT_LENS)
@@ -470,9 +470,10 @@ def leg_serve(depth=None, *, config=None, on_chip=True) -> dict:
     paddle.set_flags({"FLAGS_to_static_capture_lowered": True})
     copies = {}
     for b in SERVE_BUCKETS:
-        engine._warm_decode(b)
-        copies[b] = pool_copies(engine._decode_program.compiled_text(),
-                                engine.kv.pool.shape)
+        engine.programs.warm(buckets=[b])
+        copies[b] = pool_copies(
+            engine.programs.decode_program.compiled_text(),
+            engine.kv.pool.shape)
     paddle.set_flags({"FLAGS_to_static_capture_lowered": False})
     log(f"serve: pool-shaped copies in the compiled decode programs, by "
         f"bucket: {copies}")

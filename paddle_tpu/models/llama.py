@@ -332,7 +332,7 @@ class LlamaForCausalLM(nn.Layer):
           streams its live pages through the paged-attention Pallas
           kernel and leaves position ``t``'s K/V pending on the returned
           view, which the engine commits to the pool in one write
-          (``PADDLE_TPU_PAGED_ATTENTION``; ISSUE 13, 26). GQA stays a
+          (``ServingConfig.paged_attention``; ISSUE 13, 26). GQA stays a
           kv-head broadcast on both tiers; RoPE gathers per-row rows at
           each slot's own position.
 

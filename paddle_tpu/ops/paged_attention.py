@@ -62,7 +62,8 @@ decode step's KV traffic O(live pages) reads + O(1) page writes:
 Tiering (the flash-SDPA / step-capture contract): the kernel is the TPU
 tier; off-TPU it runs under the Pallas interpreter when forced (tests)
 while ``auto`` keeps CPU on the existing dense-gather debug tier, which
-stays the parity reference (``PADDLE_TPU_PAGED_ATTENTION=auto|on|off``).
+stays the parity reference (``ServingConfig.paged_attention``:
+``auto|on|off``, :func:`decode_path`).
 :func:`paged_attention_dense` is that reference restricted to one layer —
 it gathers only the slot's pages for the layer being decoded, so even the
 debug tier of a paged program never rebuilds the L-stacked cache.
@@ -71,7 +72,6 @@ debug tier of a paged program never rebuilds the L-stacked cache.
 from __future__ import annotations
 
 import functools
-import os
 from dataclasses import dataclass, replace
 from typing import Optional
 
@@ -82,53 +82,25 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["PagedDecodeCache", "PageKind", "window_first_page",
-           "window_table_pages", "mode", "decode_path", "kernel_eligible",
+           "window_table_pages", "decode_path", "kernel_eligible",
            "paged_attention", "paged_attention_dense",
            "scatter_token_inplace", "paged_decode_attention",
            "commit_pending"]
 
 _NEG_INF = -1e30  # matches ops/flash_attention.py's mask fill
 
-_VALID_MODES = ("auto", "on", "off")
-
-
-def mode() -> str:
-    """Resolve ``PADDLE_TPU_PAGED_ATTENTION`` (default ``auto``).
-
-    ``auto`` — kernel on TPU, dense-gather debug tier on CPU (the same
-    device split as flash SDPA); ``on`` — kernel everywhere (Pallas
-    interpreter off-TPU: slow, for parity tests); ``off`` — dense tier
-    everywhere."""
-    m = os.environ.get("PADDLE_TPU_PAGED_ATTENTION", "auto").strip().lower()
-    if m in _VALID_MODES:
-        return m
-    if not m:                        # set-but-empty reads as unset
-        return "auto"
-    if m in ("0", "false", "no", "disable", "disabled"):
-        return "off"
-    if m in ("1", "true", "yes", "enable", "enabled", "kernel"):
-        return "on"
-    # a typo must not silently flip the decode tier (e.g. "dense" reading
-    # as auto -> kernel on TPU): fail like the config-field validation
-    raise ValueError(
-        f"PADDLE_TPU_PAGED_ATTENTION must be auto|on|off, got {m!r}")
-
-
-def decode_path(override: str = "") -> str:
-    """``"kernel"`` or ``"dense"`` for the current device + mode.
-
-    ``override`` (a ``ServingConfig.paged_attention`` value) wins over the
-    env knob when non-empty, mirroring the watchdog/queue-wait contract."""
-    m = (override or "").strip().lower() or mode()
-    if m not in _VALID_MODES:
+def decode_path(mode: str = "auto") -> str:
+    """``"kernel"`` or ``"dense"`` for a ``ServingConfig.paged_attention``
+    value on the current device: ``auto`` — the kernel on a TPU, the
+    dense-gather debug tier elsewhere (the same device split as flash
+    SDPA); ``on`` — the kernel everywhere (Pallas interpreter off-TPU:
+    slow, for parity tests); ``off`` — the dense tier everywhere."""
+    if mode not in ("auto", "on", "off"):
         raise ValueError(
-            f"paged_attention mode must be auto|on|off, got {m!r} "
-            "(env: PADDLE_TPU_PAGED_ATTENTION)")
-    if m == "off":
-        return "dense"
-    if m == "on":
-        return "kernel"
-    return "kernel" if jax.default_backend() == "tpu" else "dense"
+            f"paged_attention mode must be auto|on|off, got {mode!r}")
+    if mode == "auto":
+        mode = "on" if jax.default_backend() == "tpu" else "off"
+    return "kernel" if mode == "on" else "dense"
 
 
 def kernel_interpret() -> bool:

@@ -16,12 +16,16 @@ this repo's compiled-decode machinery):
   the stacked-cache layout (FusedMultiTransformer's serving path) plugs
   in unchanged.
 
-* Around ``step_fn`` the engine traces ONE program per batch bucket:
-  gather the active slots' pages into the dense stacked cache
-  (dequantizing on the int8 leg), run the step, scatter back only the
-  page each slot wrote (``serving/kv_cache.py``). Paging costs no extra
-  dispatches — one compiled call and one host sync per step, for
-  ``B`` tokens.
+* The compiled programs live in ``serving/programs.py``, which alone
+  knows how a call to one is laid out: ONE decode program per batch
+  bucket (kernel tier: attention reads the page pool through a
+  ``PagedDecodeCache`` view; dense tier: gather the active slots' pages,
+  run the step, scatter back the page each slot wrote), the full prefill,
+  one tail prefill per shared-prefix length. Tier and sharing follow the
+  platform and the prefill callable's arity under ``ServingConfig``'s
+  ``auto``; no environment switch selects either. The engine hands
+  ``programs.decode`` / ``prefill`` named parts and gets a ``Step`` back,
+  unread: one compiled call and one host sync per step, for ``B`` tokens.
 
 * Batch rows are assigned to active slots PER STEP (per-slot state is
   host-side: a page-table row, a position, a last token — or, for a row
@@ -42,15 +46,13 @@ this repo's compiled-decode machinery):
   attention; serve bucketed prompt lengths if that matters).
 
 The page pool is ONE buffer (ISSUE 26): every serving program takes it
-donated, writes it in place and gives it back, and the engine adopts what
-comes back. **The pool a program returns is always adopted; only its
-tokens may be abandoned.** That is sound because a decode step writes
-position ``t`` of the included slots' own pages (never a shared prefix
-page; padded rows write the scratch page), ``slot.t`` does not advance
-when tokens are abandoned, a retried step rewrites the same position with
-the same values, and a replayed slot's pages are rewritten by its
-re-prefill. A call that consumed the pool and raised leaves nothing to
-adopt: fresh pool, empty prefix index, every running slot replayed.
+donated, writes it in place and gives it back, and ``programs`` adopts
+what comes back. **The pool a program returns is always adopted; only its
+tokens may be abandoned** (why that is sound: ``serving/programs.py``);
+``slot.t`` does not advance when tokens are abandoned. A call that
+consumed the pool and raised leaves nothing to adopt
+(``programs.pools_lost()``): fresh pool, empty prefix index, every
+running slot replayed.
 
 One decode step is in flight ahead of the host's read (ISSUE 28). At a
 boundary the loop launches step n+1 and only then reads step n's tokens,
@@ -167,27 +169,28 @@ recorder (``serving_recover``); the step loop heartbeats ``/healthz``;
 
 from __future__ import annotations
 
-import functools
 import logging
 import os
 import threading
 import time
 from contextlib import nullcontext
-from dataclasses import dataclass, field, replace
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from dataclasses import dataclass, field
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
 
 from .. import observability as _obs
+from ..core.tensor import Tensor as _T
 from ..observability import cost as _cost
 from ..observability import http as _obs_http
 from ..observability import trace as _trace
 from ..resilience import deadline_scope, faults as _faults, jitter_sleep
+from ..resilience.watchdog import StepWatchdog, WatchdogTimeout
 from . import kv_cache as _kv
+from .programs import Programs, Step
 from .scheduler import (GenerationRequest, GenerationResult, Scheduler,
                         _Pending)
-from .watchdog import StepWatchdog, WatchdogTimeout
 
 __all__ = ["ServingConfig", "Engine", "EngineStopped", "DrainTimeout",
            "TTFT_BUCKETS", "TPOT_BUCKETS"]
@@ -249,24 +252,6 @@ def _env_seconds(name: str) -> Optional[float]:
     return val if val > 0 else None
 
 
-def _prefill_accepts_start(fn: Callable) -> bool:
-    """Whether a prefill callable takes the ISSUE 17 start offset —
-    ``prefill_fn(ids, cache, start)`` — and can therefore prefill only the
-    unshared tail of a prefix-shared admission. 2-arg callables (the PR 7
-    contract) keep working unchanged: sharing just stays off for them."""
-    import inspect
-    try:
-        sig = inspect.signature(fn)
-    except (TypeError, ValueError):
-        return False
-    params = list(sig.parameters.values())
-    if any(p.kind == p.VAR_POSITIONAL for p in params):
-        return True
-    pos = [p for p in params
-           if p.kind in (p.POSITIONAL_ONLY, p.POSITIONAL_OR_KEYWORD)]
-    return len(pos) >= 3
-
-
 @dataclass
 class ServingConfig:
     """Engine sizing + policy. Model-shape fields must match the cache
@@ -286,7 +271,7 @@ class ServingConfig:
     max_queue: int = 64
     page_size: int = 64
     num_pages: Optional[int] = None      # default: full coverage + scratch
-    kv_dtype: str = ""                   # "" -> $PADDLE_TPU_KV_DTYPE or native
+    kv_dtype: str = "native"             # native | bf16 | int8 pages
     compute_dtype: str = "float32"
     policy: str = "fifo"
     prefill_token_budget: Optional[int] = None
@@ -300,24 +285,18 @@ class ServingConfig:
     # hard cap on queue wait; None -> $PADDLE_TPU_SERVING_MAX_QUEUE_WAIT
     # (0/absent = unbounded). Pass 0 to force off regardless of env.
     max_queue_wait_s: Optional[float] = None
-    # paged-attention decode tier (ISSUE 13): "" -> the
-    # $PADDLE_TPU_PAGED_ATTENTION env knob (default auto). auto = Pallas
-    # kernel on TPU / dense-gather debug tier on CPU; on = kernel
-    # everywhere (Pallas interpreter off-TPU — parity tests); off = the
-    # dense tier everywhere. The config field wins when set, the
-    # watchdog/queue-wait contract.
-    paged_attention: str = ""
-    # prefix-cache page sharing (ISSUE 17): "" -> the
-    # $PADDLE_TPU_PREFIX_SHARING env knob (default auto). Sharing needs a
-    # prefill callable that accepts a start offset (``prefill_fn(ids,
-    # cache, start)`` — the 3-arg form); auto = share when the callable is
-    # tail-capable and fall back to full prefill otherwise, on = require a
-    # tail-capable callable (Engine raises at build if 2-arg), off = never
-    # share. Pure host-side bookkeeping: no hardware dependency.
-    prefix_sharing: str = ""
-    # shortest resident prefix chain worth mapping, in pages; None ->
-    # $PADDLE_TPU_PREFIX_MIN_PAGES (default 1)
-    min_shared_pages: Optional[int] = None
+    # paged-attention decode tier (ISSUE 13): auto = Pallas kernel on TPU /
+    # dense-gather debug tier on CPU; on = kernel everywhere (Pallas
+    # interpreter off-TPU — parity tests); off = the dense tier
+    # everywhere, the parity tests' reference.
+    paged_attention: str = "auto"
+    # prefix-cache page sharing (ISSUE 17) needs a prefill callable that
+    # accepts a start offset (``prefill_fn(ids, cache, start)``): auto =
+    # share when it does and prefill in full otherwise, on = require it
+    # (Engine raises at build if 2-arg), off = never share (the reference).
+    prefix_sharing: str = "auto"
+    # shortest resident prefix chain worth mapping, in pages
+    min_shared_pages: int = 1
     # pages by layer kind (ISSUE 27): a model that mixes full-attention
     # and sliding-window layers names each layer's kind here ("full" |
     # "window", one per layer) and its ``window``; the engine then keeps
@@ -344,13 +323,9 @@ class ServingConfig:
             raise ValueError(
                 f"buckets {self.buckets} must cover max_batch "
                 f"{self.max_batch}")
-        if not self.kv_dtype:
-            self.kv_dtype = os.environ.get(
-                "PADDLE_TPU_KV_DTYPE", "native").strip().lower() or "native"
         if self.kv_dtype not in ("native", "bf16", "int8"):
             raise ValueError(
-                f"kv_dtype must be native|bf16|int8, got {self.kv_dtype!r} "
-                "(env: PADDLE_TPU_KV_DTYPE)")
+                f"kv_dtype must be native|bf16|int8, got {self.kv_dtype!r}")
         if self.max_replays < 0:
             raise ValueError(f"max_replays must be >= 0, got "
                              f"{self.max_replays}")
@@ -363,26 +338,12 @@ class ServingConfig:
                 "PADDLE_TPU_SERVING_MAX_QUEUE_WAIT")
         elif self.max_queue_wait_s <= 0:
             self.max_queue_wait_s = None
-        from ..ops import paged_attention as _pa
-        if not self.paged_attention:
-            self.paged_attention = _pa.mode()
-        self.paged_attention = self.paged_attention.strip().lower()
-        if self.paged_attention not in ("auto", "on", "off"):
-            raise ValueError(
-                f"paged_attention must be auto|on|off, got "
-                f"{self.paged_attention!r} (env: PADDLE_TPU_PAGED_ATTENTION)")
-        if not self.prefix_sharing:
-            self.prefix_sharing = os.environ.get(
-                "PADDLE_TPU_PREFIX_SHARING", "auto").strip().lower() \
-                or "auto"
-        self.prefix_sharing = self.prefix_sharing.strip().lower()
-        if self.prefix_sharing not in ("auto", "on", "off"):
-            raise ValueError(
-                f"prefix_sharing must be auto|on|off, got "
-                f"{self.prefix_sharing!r} (env: PADDLE_TPU_PREFIX_SHARING)")
-        if self.min_shared_pages is None:
-            raw = os.environ.get("PADDLE_TPU_PREFIX_MIN_PAGES", "").strip()
-            self.min_shared_pages = int(raw) if raw else 1
+        for name in ("paged_attention", "prefix_sharing"):
+            value = getattr(self, name).strip().lower()
+            if value not in ("auto", "on", "off"):
+                raise ValueError(
+                    f"{name} must be auto|on|off, got {value!r}")
+            setattr(self, name, value)
         if self.min_shared_pages < 1:
             raise ValueError(f"min_shared_pages must be >= 1, got "
                              f"{self.min_shared_pages}")
@@ -449,15 +410,15 @@ class _Slot:                             # ndarray-bearing request, and
 class _Flight:
     """One decode step the device has been given and the host has not read
     (ISSUE 28): its rows in batch order, and the call's device arrays —
-    what was built for it, kept so that freeing them has a phase of its
-    own, and what it returned, the tokens first and the carried tokens
-    last."""
+    the inputs ``built`` for it, kept so that freeing them has a phase of
+    its own, and the ``step`` it returned: its tokens, unread, and the
+    tokens it carries to the step after it."""
 
     included: List[_Slot]
     bucket: int
     ahead: int                          # launched behind an unread step
-    args: tuple
-    outs: tuple
+    built: Optional[tuple]
+    step: Optional[Step]
 
 
 class Engine:
@@ -471,8 +432,6 @@ class Engine:
     def __init__(self, prefill_fn: Callable, step_fn: Callable,
                  config: ServingConfig):
         self.config = config
-        self._prefill_fn = prefill_fn
-        self._step_fn = step_fn
         # one pool per layer kind (ISSUE 27), the full-attention one first;
         # ``kv`` is the first: the only one unless the model has window
         # layers. ``_layer_pool[i]`` = (pool, layer within it) of layer i.
@@ -499,10 +458,13 @@ class Engine:
         # dropped engine drops its pools from the ledger)
         for kv in self.kvs:
             _cost.register_kv_cache(kv)
-        self._quantized = self.kv.config.quantized
+        # the compiled programs and how they are called; the tier they run
+        self.programs = Programs(prefill_fn, step_fn, config, self.kvs,
+                                 self._layer_pool)
+        self._paged_path = self.programs.path
         # ISSUE 17: prefix-cache page sharing — on only when the prefill
         # callable can start from a page-aligned offset (3-arg form)
-        capable = _prefill_accepts_start(prefill_fn)
+        capable = self.programs.tail_capable
         if config.prefix_sharing == "on" and not capable:
             raise ValueError(
                 "prefix_sharing=on requires a tail-capable prefill "
@@ -558,287 +520,30 @@ class Engine:
         # the decode step launched and not yet read (ISSUE 28; written
         # under _slot_lock: stop() may step from its own thread)
         self._flight: Optional[_Flight] = None
-        self._build_programs()
 
     # ------------------------------------------------------------------
-    # compiled programs
+    # compiled programs (serving/programs.py owns them)
     # ------------------------------------------------------------------
-    def _build_programs(self) -> None:
-        from ..core.tensor import Tensor as _T, apply as _apply
-        from ..core.tracing import no_grad
-        from ..jit import to_static
-        from ..ops import paged_attention as _pa
+    def warmup(self, prompt_lens: Sequence[int] = (),
+               tails: Sequence[Tuple[int, int]] = ()) -> "Engine":
+        """Compile every batch bucket (and optional prefill lengths, and
+        ``(shared prefix, tail)`` length pairs of prefix-shared
+        admissions) up front, against the scratch page only — admission
+        then never recompiles mid-flight. Idempotent; call before serving
+        traffic."""
+        self.programs.warm(self.config.buckets, prompt_lens, tails)
+        return self
 
-        cfg = self.kv.config
-        ps = cfg.page_size
-        compute_dtype = jnp.dtype(cfg.compute_dtype)
-        quantized = self._quantized
-        step_fn, prefill_fn = self._step_fn, self._prefill_fn
-        L, H, M, D = (self.config.num_layers, cfg.num_heads, cfg.max_len,
-                      cfg.head_dim)
-        # ISSUE 13: which decode program this engine compiles — "kernel"
-        # hands step_fn a PagedDecodeCache view (the dense stacked cache
-        # never exists in the program), "dense" keeps the PR 7
-        # gather -> step -> scatter debug tier (and stays the default on
-        # CPU under auto, where the toy/test callables consume the dense
-        # layout)
-        self._paged_path = _pa.decode_path(self.config.paged_attention)
-        paged_interpret = _pa.kernel_interpret()
-        if self._paged_path == "kernel" and not paged_interpret and \
-                not _pa.kernel_eligible(ps, D, cfg.storage_dtype, H):
-            # Mosaic tiling can't serve this shape: demote the WHOLE
-            # engine to the dense tier rather than silently running the
-            # per-layer fallback under a path=kernel label — the metric
-            # (and the bench's all-dense-on-TPU suspect rule) must tell
-            # the truth about which tier the measured steps ran
-            _log.warning(
-                "paged-attention kernel ineligible for page_size=%d "
-                "head_dim=%d kv_heads=%d kv storage %s (see "
-                "ops.paged_attention.kernel_eligible) — serving on the "
-                "dense decode tier", ps, D, H, cfg.storage_dtype)
-            self._paged_path = "dense"
-
-        kvs = self.kvs
-        nk = len(kvs)
-        layer_pool = self._layer_pool
-        per = 2 + int(quantized)          # a later pool's tables, pool, scales
-
-        def split(tables_a, pool_a, rest):
-            """The programs' flat arguments -> [(tables, pool, scales)] per
-            pool: the first pool's ride where the one pool's always have,
-            each later pool's after them."""
-            rest = list(rest)
-            out = [(tables_a, pool_a, rest.pop(0) if quantized else None)]
-            for _ in range(1, nk):
-                tb, pl_ = rest.pop(0), rest.pop(0)
-                out.append((tb, pl_, rest.pop(0) if quantized else None))
-            return out
-
-        def join(first, pools):
-            out = (first,)
-            for pool2, sc2 in pools:
-                out += (pool2,) + ((sc2,) if quantized else ())
-            return out
-
-        def assemble(parts):
-            """Per-pool dense caches (L_k, 2, B, H, M, D) -> the model's
-            (L, ...) in layer order; one pool's is the model's already."""
-            if nk == 1:
-                return parts[0]
-            return jnp.stack([parts[k][i] for k, i in layer_pool])
-
-        def layers_of(dense, k):
-            if nk == 1:
-                return dense
-            return dense[jnp.asarray(
-                [i for i, (kk, _) in enumerate(layer_pool) if kk == k])]
-
-        def first_out(ret):
-            """(token output, cache): a model that counts as it goes (an
-            expert layer's rows per expert) returns a third value, an int32
-            array the engine reads back WITH the tokens — one flat vector,
-            the tokens first."""
-            nxt = ret[0]._data.astype(jnp.int32)
-            if len(ret) > 2:
-                nxt = jnp.concatenate([nxt.reshape(-1), ret[2]._data.astype(
-                    jnp.int32).reshape(-1)])
-            return nxt, ret[1]
-
-        # ISSUE 28: a decode program's last two arguments are the tokens
-        # the step before it left ON THE DEVICE (``carry``, one shape for
-        # every bucket) and, per row, which of them the row continues
-        # (``sel``; -1: the host's ``tok``). Its last output is its own
-        # tokens in that shape, for the step after it.
-        carry_rows = self.config.buckets[-1]
-
-        def pick_tok(tok_a, carry_a, sel_a):
-            return jnp.where(sel_a[:, None] >= 0,
-                             carry_a[jnp.maximum(sel_a, 0)][:, None], tok_a)
-
-        def carry_of(nxt, rows):
-            return (jnp.zeros((carry_rows,), jnp.int32)
-                    .at[:rows].set(nxt.reshape(-1)[:rows]),)
-
-        def decode_fn(tok_a, tables_a, t_a, pool_a, *rest):
-            *rest, carry_a, sel_a = rest
-            kinds = split(tables_a, pool_a, rest)
-            dense = assemble([_kv.gather_pages(pl_, sc, tb, compute_dtype)
-                              for tb, pl_, sc in kinds])
-            with no_grad():
-                nxt, new_dense = first_out(step_fn(
-                    _T(pick_tok(tok_a, carry_a, sel_a)), _T(dense), _T(t_a)))
-            new_dense = new_dense._data.astype(compute_dtype)
-            return join(nxt, [
-                _kv.scatter_token_page(layers_of(new_dense, k), pl_, sc, tb,
-                                       t_a, ps)
-                for k, (tb, pl_, sc) in enumerate(kinds)]) \
-                + carry_of(nxt, tok_a.shape[0])
-
-        def paged_decode_fn(tok_a, tables_a, t_a, pool_a, *rest):
-            # same program signature as decode_fn (one compiled call per
-            # bucket; the pools/scales come in donated and go back out),
-            # but the cache argument is the page-pool VIEW: every layer's
-            # attention streams live pages through the Pallas kernel and
-            # leaves position t's K/V pending on the view; the commit
-            # below is the program's one write per pool, in place — made
-            # here so that no model forgets it
-            *rest, carry_a, sel_a = rest
-            kinds = split(tables_a, pool_a, rest)
-            tb, pl_, sc = kinds[0]
-            view = _pa.PagedDecodeCache(
-                pool=_T(pl_), tables=_T(tb), t=_T(t_a),
-                page_size=ps, scales=_T(sc) if quantized else None,
-                impl="kernel", interpret=paged_interpret,
-                window=kvs[0].config.window)
-            if nk > 1:
-                view = replace(view, layer_kinds=tuple(layer_pool), kinds=tuple(
-                    _pa.PageKind(pool=_T(pl_), tables=_T(tb),
-                                 scales=_T(sc) if quantized else None,
-                                 window=kv.config.window)
-                    for kv, (tb, pl_, sc) in zip(kvs, kinds)))
-            with no_grad():
-                ret = step_fn(_T(pick_tok(tok_a, carry_a, sel_a)), view,
-                              _T(t_a))
-                nxt, view2 = first_out(ret)
-                view2 = _pa.commit_pending(view2)
-            done = view2.kinds or (view2,)
-            return join(nxt, [
-                (k.pool._data, k.scales._data if quantized else None)
-                for k in done]) + carry_of(nxt, tok_a.shape[0])
-
-        if self._paged_path == "kernel":
-            decode_fn = paged_decode_fn
-
-        def prefill_body(ids_a, row_a, len_a, pool_a, *rest):
-            kinds = split(row_a, pool_a, rest)
-            zero = jnp.zeros((L, 2, 1, H, M, D), compute_dtype)
-            with no_grad():
-                nxt, dense = first_out(prefill_fn(_T(ids_a), _T(zero)))
-            dense = dense._data.astype(compute_dtype)
-            return join(nxt, [
-                _kv.scatter_prefill_pages(layers_of(dense, k), pl_, sc, row,
-                                          len_a, ps)
-                for k, (row, pl_, sc) in enumerate(kinds)])
-
-        def decode_program(tok, tables, t, pool, *rest):
-            return _apply("serving_decode_step", decode_fn, tok, tables, t,
-                          pool, *rest, differentiable=False, amp=False)
-
-        def prefill_program(ids, row, true_len, pool, *rest):
-            return _apply("serving_prefill", prefill_body, ids, row,
-                          true_len, pool, *rest, differentiable=False,
-                          amp=False)
-
-        # every serving program CONSUMES the pools (and the int8 scales):
-        # they are donated, so XLA aliases them to the outputs and every
-        # write lands in place — the caller's array is deleted by the call
-        # and the returned one adopted (_adopt). One pool: argument 3,
-        # and 4 for its scales — never what follows the pools (a decode
-        # program's carried tokens are not its to consume).
-        q = int(quantized)
-        pool_args = tuple(
-            at + i for k in range(nk) for i in range(1 + q)
-            for at in [3 if k == 0 else 5 + q + (k - 1) * per])
-        self._decode_program = to_static(decode_program,
-                                         donate_argnums=pool_args)
-        self._prefill_program = to_static(prefill_program,
-                                          donate_argnums=pool_args)
-        # ISSUE 16: the cost registry files one record per warmed batch
-        # bucket under serving.decode (bucket inferred from the compiled
-        # tok spec) and one per prefill length under serving.prefill
-        name = self.config.name or "engine"
-        self._decode_program.cost_site = "serving.decode"
-        self._decode_program.cost_label = f"{name}.decode"
-        self._prefill_program.cost_site = "serving.prefill"
-        self._prefill_program.cost_label = f"{name}.prefill"
-
-        # ISSUE 17: tail prefill — one program per static page-aligned
-        # start offset (bounded by pages_per_slot). The dense cache enters
-        # populated with the shared prefix (gathered from the mapped
-        # pages), the 3-arg prefill callable computes K/V for tail
-        # positions [start, prompt_len) only, and the scatter writes ONLY
-        # tail pages — the shared pages are never store targets (COW by
-        # construction).
-        def build_tail_program(start: int):
-            def tail_body(ids_a, row_a, len_a, pool_a, *rest):
-                kinds = split(row_a, pool_a, rest)
-                dense = assemble([
-                    _kv.gather_pages(pl_, sc, row[None, :], compute_dtype)
-                    for row, pl_, sc in kinds])
-                with no_grad():
-                    nxt, dense2 = first_out(
-                        prefill_fn(_T(ids_a), _T(dense), start))
-                dense2 = dense2._data.astype(compute_dtype)
-                return join(nxt, [
-                    _kv.scatter_prefill_pages(
-                        layers_of(dense2, k), pl_, sc, row[start // ps:],
-                        len_a, ps, start=start)
-                    for k, (row, pl_, sc) in enumerate(kinds)])
-
-            def tail_program(ids, row, true_len, pool, *rest):
-                return _apply("serving_prefill", tail_body, ids, row,
-                              true_len, pool, *rest,
-                              differentiable=False, amp=False)
-
-            prog = to_static(tail_program, donate_argnums=pool_args)
-            prog.cost_site = "serving.prefill"
-            prog.cost_label = f"{name}.prefill_tail{start}"
-            return prog
-
-        self._build_tail_program = build_tail_program
-        self._tail_programs: Dict[int, Callable] = {}
-        self._program_lock = threading.Lock()
-        # what a decode step is handed for ``carry`` when no row of it
-        # continues a step still unread (never donated: one array for good)
-        self._no_carry = _T(jnp.zeros((carry_rows,), jnp.int32))
-
+    # ROADMAP C11's debt: perfbench's ``_warm_tails`` reaches in with the
+    # one-pool call ``_tail_program(start)(ids, row, true_len, pool,
+    # *_scales_args())``. The pool it hands over is the engine's own, which
+    # the programs take themselves. Both go when it calls warmup(tails=).
     def _tail_program(self, start: int) -> Callable:
-        """The compiled tail-prefill program for a static ``start`` offset
-        (built on first use; admission normally runs on the single step
-        thread, but the lock keeps a warmup-from-caller race harmless).
-        The callable adopts the pool the program returns (:meth:`_adopt`):
-        whoever calls it, ``kv.pool`` is a live array afterwards."""
-        with self._program_lock:
-            prog = self._tail_programs.get(start)
-            if prog is None:
-                prog = self._build_tail_program(start)
-                self._tail_programs[start] = prog
-        return functools.partial(self._adopt, prog)
+        return lambda ids, row, true_len, *_pool: self.programs.prefill(
+            ids, [row], true_len, start)
 
-    def _pool_args(self, tables=()):
-        """What every serving program takes after its first three
-        arguments: the first pool (and its int8 scales), then each later
-        pool's table, pool and scales — ``tables`` holds those later
-        tables (none for the engine with one pool)."""
-        from ..core.tensor import Tensor as _T
-        out = (_T(self.kv.pool),) + self._scales_args()
-        for kv, tb in zip(self.kvs[1:], tables):
-            out += (tb, _T(kv.pool)) + self._scales_args(kv)
-        return out
-
-    def _scales_args(self, kv=None):
-        from ..core.tensor import Tensor as _T
-        kv = kv or self.kv
-        return (_T(kv.scales),) if self._quantized else ()
-
-    def _adopt(self, prog, *args):
-        """Call a serving program and adopt the pools it returns. The call
-        consumed the pools it was given (donated), so the returned ones are
-        adopted whatever becomes of the call's tokens: only they may be
-        abandoned."""
-        outs = prog(*args)
-        per = 1 + int(self._quantized)
-        self._set_pool(outs[1], outs[2] if self._quantized else None)
-        for k, kv in enumerate(self.kvs[1:], 1):
-            kv.pool = outs[1 + k * per]._data
-            if self._quantized:
-                kv.scales = outs[2 + k * per]._data
-        return outs
-
-    def _set_pool(self, pool_t, scales_t) -> None:
-        self.kv.pool = pool_t._data
-        if scales_t is not None:
-            self.kv.scales = scales_t._data
+    def _scales_args(self) -> tuple:
+        return (_T(self.kv.scales),) if self.kv.config.quantized else ()
 
     def _restore_lost_pool(self, exc: BaseException) -> bool:
         """After a serving program raised: if the call had already
@@ -848,9 +553,7 @@ class Engine:
         through bounded replay — their re-prefill rewrites their pages.
         A call that raised before it ran (a trace failure, an injected
         fault) leaves the pools alone, and so does this (returns False)."""
-        lost = any(kv.pool.is_deleted() or (
-            self._quantized and kv.scales.is_deleted()) for kv in self.kvs)
-        if not lost:
+        if not self.programs.pools_lost():
             return False
         _log.warning("serving: a program call consumed the page pool and "
                      "raised (%s) — fresh pool, prefix index dropped, %d "
@@ -861,64 +564,6 @@ class Engine:
             kv.reset_pool()
         self._recover_slots(list(self._slots), exc)
         return True
-
-    def _table_width(self, kv, decode: bool) -> int:
-        """Columns of a pool's page-table rows: every logical page, but
-        for a window pool under the decode kernel, which takes the compact
-        window table."""
-        if decode and self._paged_path == "kernel" and kv.config.window:
-            return kv.config.window_pages
-        return kv.config.pages_per_slot
-
-    def _decode_row(self, kv, ids: List[int], first: int) -> np.ndarray:
-        """A slot's row of pool ``kv``'s decode table: ``ids[0]`` is
-        logical page ``first``, which the compact window table puts in
-        column 0."""
-        width = self._table_width(kv, True)
-        compact = width != kv.config.pages_per_slot
-        return kv.table_row(ids, first=0 if compact else first, width=width)
-
-    def _zero_tables(self, lead: Tuple[int, ...], decode: bool):
-        """All-scratch tables for a warm-up call: the first pool's, then
-        the later pools' as :meth:`_pool_args` takes them."""
-        from ..core.tensor import Tensor as _T
-        tabs = [_T(jnp.zeros(lead + (self._table_width(kv, decode),),
-                             jnp.int32)) for kv in self.kvs]
-        return tabs[0], tabs[1:]
-
-    def _warm_decode(self, bucket: int) -> None:
-        """One decode call of ``bucket`` all-padded rows: they read and
-        write the scratch page only."""
-        from ..core.tensor import Tensor as _T
-        first, later = self._zero_tables((bucket,), decode=True)
-        self._adopt(
-            self._decode_program,
-            _T(jnp.zeros((bucket, 1), jnp.int32)), first,
-            _T(jnp.zeros((bucket,), jnp.int32)), *self._pool_args(later),
-            self._no_carry, _T(jnp.full((bucket,), -1, jnp.int32)))
-
-    def warmup(self, prompt_lens: Sequence[int] = (),
-               tails: Sequence[Tuple[int, int]] = ()) -> "Engine":
-        """Compile every batch bucket (and optional prefill lengths, and
-        ``(shared prefix, tail)`` length pairs of prefix-shared
-        admissions) up front, against the scratch page only — admission
-        then never recompiles mid-flight. Idempotent; call before serving
-        traffic."""
-        from ..core.tensor import Tensor as _T
-        for b in self.config.buckets:
-            self._warm_decode(b)
-        first, later = self._zero_tables((), decode=False)
-        for lp in prompt_lens:
-            self._adopt(
-                self._prefill_program,
-                _T(jnp.zeros((1, int(lp)), jnp.int32)), first,
-                _T(jnp.zeros((), jnp.int32)), *self._pool_args(later))
-        for start, tail in tails:
-            self._tail_program(int(start))(
-                _T(jnp.zeros((1, int(tail)), jnp.int32)), first,
-                _T(jnp.asarray(int(start) + int(tail), jnp.int32)),
-                *self._pool_args(later))
-        return self
 
     # ------------------------------------------------------------------
     # request surface
@@ -1388,7 +1033,6 @@ class Engine:
         request (``pending.replay_tokens``) re-prefills prompt + the
         tokens already generated, so the continuation is bit-identical to
         a never-faulted run."""
-        from ..core.tensor import Tensor as _T
         req = pending.request
         prompt = req.prompt
         if pending.replay_tokens:
@@ -1423,17 +1067,13 @@ class Engine:
                                        rid=req.request_id,
                                        site="serving.admit", retried=True,
                                        error=type(exc).__name__)
-                rows = [_T(jnp.asarray(kv.table_row(ids, first=lo)))
-                        for kv, ids, lo in zip(self.kvs, pages, first_page)]
                 # a tail program for a mapped prefix, else the full one;
-                # either consumes the pool and _adopt takes it back
-                prog = self._tail_program(start) if start else \
-                    functools.partial(self._adopt, self._prefill_program)
-                outs = prog(
+                # either consumes the pools and adopts what comes back
+                first = self.programs.prefill(
                     _T(jnp.asarray(prompt[None, start:], jnp.int32)),
-                    rows[0],
-                    _T(jnp.asarray(prompt.size, jnp.int32)),
-                    *self._pool_args(rows[1:]))
+                    [_T(jnp.asarray(kv.table_row(ids, first=lo)))
+                     for kv, ids, lo in zip(self.kvs, pages, first_page)],
+                    _T(jnp.asarray(prompt.size, jnp.int32)), start)
                 # ISSUE 18: the pool swap, first-token host read and
                 # prefix publish belong to the guarded region too — the
                 # host sync raising here (wedged device, watchdog replay)
@@ -1441,10 +1081,10 @@ class Engine:
                 # it is just another "failed" admission. Inside the span
                 # (ISSUE 25): it ends when the first token exists, so its
                 # duration is a prefill, not an enqueue
-                flat = np.asarray(outs[0]._data).reshape(-1)
-                first_tok = int(flat[0])
+                (first_tok,), counts = first.read()
+                first_tok = int(first_tok)
             now = time.monotonic()
-            self._note_expert_rows(flat[1:], "serving.moe.prefill", 1)
+            self._note_expert_rows(counts, "serving.moe.prefill", 1)
             _obs.inc("serving.prefills_total")
             _obs.inc("serving.prefill_tokens_requested_total",
                      float(prompt.size))
@@ -1475,7 +1115,7 @@ class Engine:
                      # a full pool's row stands for the slot's whole life;
                      # a window pool's is made before each decode step
                      rows=[None if kv.config.window else
-                           self._decode_row(kv, ids, 0)
+                           self.programs.decode_row(kv, ids, 0)
                            for kv, ids in zip(self.kvs, pages)],
                      t=int(prompt.size), last_tok=first_tok,
                      tokens=list(pending.replay_tokens),
@@ -1618,7 +1258,8 @@ class Engine:
                 slot.first_page[k] += drop
                 changed = True
             if changed:
-                slot.rows[k] = self._decode_row(kv, ids, slot.first_page[k])
+                slot.rows[k] = self.programs.decode_row(
+                    kv, ids, slot.first_page[k])
 
     def _note_expert_rows(self, counts: np.ndarray, event: str,
                           batch: int) -> None:
@@ -1731,7 +1372,6 @@ class Engine:
         state known before ``prev``'s tokens are. False: the launch failed
         for good (a second device fault, a watchdog trip, a lost pool) and
         every slot concerned was recovered, ``prev`` abandoned with it."""
-        from ..core.tensor import Tensor as _T
         # inside the span of the step read at this boundary, or bare on the
         # engine's track when there is none
         parent = self._engine_trace if prev is None else None
@@ -1741,7 +1381,7 @@ class Engine:
             sel = np.full((bucket,), -1, np.int32)
             t = np.zeros((bucket,), np.int32)
             # one table per pool; padded rows -> scratch
-            tables = [np.zeros((bucket, self._table_width(kv, True)),
+            tables = [np.zeros((bucket, self.programs.table_width(kv, True)),
                                np.int32) for kv in self.kvs]
             row_of = {id(s): i for i, s in enumerate(prev.included)} \
                 if prev is not None else {}
@@ -1754,12 +1394,11 @@ class Engine:
                 self._advance_window(slot, int(t[i]))
                 for k, table in enumerate(tables):
                     table[i] = slot.rows[k]
-            tables = [_T(jnp.asarray(tb)) for tb in tables]
-            args = (_T(jnp.asarray(tok)), tables[0], _T(jnp.asarray(t)))
-            later = tables[1:]
-            carried = (prev.outs[-1] if prev is not None
-                       else self._no_carry, _T(jnp.asarray(sel)))
-        outs = None
+            built = (_T(jnp.asarray(tok)),
+                     [_T(jnp.asarray(tb)) for tb in tables],
+                     _T(jnp.asarray(t)),
+                     prev.step.carry if prev is not None
+                     else self.programs.no_carry, _T(jnp.asarray(sel)))
         with self._deadline_ctx([s.pending for s in included]):
             for attempt in (0, 1):
                 gen = self._watchdog.arm() if self._watchdog else None
@@ -1769,9 +1408,7 @@ class Engine:
                     with _trace.phase("serving.decode.launch",
                                       parent=parent):
                         _faults.fault_point("serving.watchdog")
-                        outs = self._adopt(self._decode_program, *args,
-                                           *self._pool_args(later),
-                                           *carried)
+                        step = self.programs.decode(*built)
                 except Exception as exc:
                     if gen is not None:
                         self._watchdog.disarm(gen)
@@ -1810,7 +1447,7 @@ class Engine:
             slot.ahead += 1
         with self._slot_lock:
             self._flight = _Flight(included, bucket, int(prev is not None),
-                                   args + tuple(later) + carried, outs)
+                                   built, step)
         return True
 
     def _land(self, flight: _Flight) -> None:
@@ -1823,10 +1460,9 @@ class Engine:
         with _trace.phase("serving.decode.wait"):
             # the ONE host sync: the tokens, and behind them whatever the
             # model counted on the device (an expert layer's rows)
-            flat = np.asarray(flight.outs[0]._data).reshape(-1)
-            next_np = flat[:flight.bucket]
+            next_np, counts = flight.step.read()
         now = time.monotonic()
-        self._note_expert_rows(flat[flight.bucket:], "serving.moe.decode",
+        self._note_expert_rows(counts, "serving.moe.decode",
                                len(flight.included))
         _obs.inc("serving.steps_total")
         if flight.ahead:
@@ -1853,7 +1489,7 @@ class Engine:
             # the step's device arrays (its inputs, the token output) die
             # here, not with the object: on the chip freeing them takes
             # about a millisecond, and it should carry a name
-            flight.args = flight.outs = ()
+            flight.built = flight.step = None
 
     def _abandon_flight(self) -> List[_Slot]:
         """Drop the step in flight unread: its tokens are abandoned (the

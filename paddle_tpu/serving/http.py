@@ -77,13 +77,13 @@ from ..observability import trace as _trace
 from ..observability.http import QuietJSONHandler, ServerHost
 from ..resilience import DeadlineExceeded, faults as _faults
 from ..resilience.breaker import BreakerOpen
+from ..resilience.watchdog import WatchdogTimeout
 # pinned into the api import layer (tools/lint import_layers): the rpc
 # transport is a leaf shared with the fleet tier
 from ..distributed.rpc import RpcTransportError
 from .engine import EngineStopped
 from .router import NoHealthyReplica, Router
 from .scheduler import GenerationRequest, QueueFull
-from .watchdog import WatchdogTimeout
 
 __all__ = ["FrontDoor", "status_for", "retry_after_s"]
 

@@ -450,8 +450,8 @@ def test_decode_program_holds_no_copy_of_either_pool(one_chip, monkeypatch):
     paddle.set_flags({"FLAGS_to_static_capture_lowered": True})
     try:
         with pytest.raises(Exception, match="interpret mode"):
-            eng._warm_decode(4)
-        compiled = _compiled_for_chip(eng._decode_program, one_chip)
+            eng.programs.warm(buckets=[4])
+        compiled = _compiled_for_chip(eng.programs.decode_program, one_chip)
     finally:
         paddle.set_flags({"FLAGS_to_static_capture_lowered": False})
     text = compiled.as_text()
@@ -461,4 +461,4 @@ def test_decode_program_holds_no_copy_of_either_pool(one_chip, monkeypatch):
     pools = sum(int(np.prod(kv.pool.shape)) * 2 for kv in eng.kvs)
     assert compiled.memory_analysis().temp_size_in_bytes < pools // 4
     # the window pool's table is the compact one: 66 columns, not 128
-    assert eng._table_width(eng.kvs[1], True) == 66
+    assert eng.programs.table_width(eng.kvs[1], True) == 66

@@ -308,37 +308,40 @@ class TestGatherCastSatellite:
 
 class TestModeResolution:
     def test_env_knob(self, monkeypatch):
-        monkeypatch.delenv("PADDLE_TPU_PAGED_ATTENTION", raising=False)
-        assert pa.mode() == "auto"
+        """``auto`` follows the platform, ``on`` / ``off`` force a tier,
+        anything else raises — and the environment name that used to
+        mirror the field (ISSUE 29) decides nothing."""
+        monkeypatch.setenv("PADDLE_TPU_PAGED_ATTENTION", "on")
         assert pa.decode_path() == "dense"       # CPU backend in tier-1
-        monkeypatch.setenv("PADDLE_TPU_PAGED_ATTENTION", "on")
-        assert pa.mode() == "on" and pa.decode_path() == "kernel"
-        monkeypatch.setenv("PADDLE_TPU_PAGED_ATTENTION", "off")
-        assert pa.decode_path() == "dense"
-        monkeypatch.setenv("PADDLE_TPU_PAGED_ATTENTION", "0")
-        assert pa.mode() == "off"
-        # a typo must fail loudly, not silently flip the tier via "auto"
-        monkeypatch.setenv("PADDLE_TPU_PAGED_ATTENTION", "dense")
-        with pytest.raises(ValueError, match="PADDLE_TPU_PAGED_ATTENTION"):
-            pa.mode()
-        monkeypatch.setenv("PADDLE_TPU_PAGED_ATTENTION", "on")
-        # config override wins over env, the watchdog/queue-wait contract
+        assert pa.decode_path("auto") == "dense"
+        monkeypatch.setattr(pa.jax, "default_backend", lambda: "tpu")
+        assert pa.decode_path("auto") == "kernel"
+        monkeypatch.undo()
         assert pa.decode_path("on") == "kernel"
+        assert pa.decode_path("off") == "dense"
+        # a typo must fail loudly, not silently flip the tier via "auto"
+        for typo in ("dense", "", "1", "ON"):
+            with pytest.raises(ValueError, match="auto|on|off"):
+                pa.decode_path(typo)
 
     def test_serving_config_resolution(self, monkeypatch):
+        def config(**kw):
+            return serving.ServingConfig(
+                num_layers=1, num_heads=1, head_dim=8, max_len=32,
+                max_batch=1, buckets=(1,), page_size=16, **kw)
+
         monkeypatch.setenv("PADDLE_TPU_PAGED_ATTENTION", "on")
-        cfg = serving.ServingConfig(num_layers=1, num_heads=1, head_dim=8,
-                                    max_len=32, max_batch=1, buckets=(1,),
-                                    page_size=16)
-        assert cfg.paged_attention == "on"
-        cfg2 = serving.ServingConfig(num_layers=1, num_heads=1, head_dim=8,
-                                     max_len=32, max_batch=1, buckets=(1,),
-                                     page_size=16, paged_attention="off")
-        assert cfg2.paged_attention == "off"
-        with pytest.raises(ValueError, match="PADDLE_TPU_PAGED_ATTENTION"):
-            serving.ServingConfig(num_layers=1, num_heads=1, head_dim=8,
-                                  max_len=32, max_batch=1, buckets=(1,),
-                                  page_size=16, paged_attention="bogus")
+        monkeypatch.setenv("PADDLE_TPU_PREFIX_SHARING", "off")
+        monkeypatch.setenv("PADDLE_TPU_PREFIX_MIN_PAGES", "7")
+        cfg = config()
+        assert (cfg.paged_attention, cfg.prefix_sharing, cfg.kv_dtype,
+                cfg.min_shared_pages) == ("auto", "auto", "native", 1)
+        assert config(paged_attention=" Off ").paged_attention == "off"
+        for field in ("paged_attention", "prefix_sharing"):
+            for bogus in ("bogus", ""):
+                with pytest.raises(ValueError,
+                                   match=f"{field} must be auto"):
+                    config(**{field: bogus})
 
     def test_kernel_eligibility_tiling_table(self):
         # the edge of what Mosaic was given on the chip (and took): page
@@ -531,7 +534,7 @@ class TestStructuralNoMaterialize:
         paddle.set_flags({"FLAGS_to_static_capture_lowered": True})
         try:
             eng.warmup()
-            return eng._decode_program.compiled_text()
+            return eng.programs.decode_program.compiled_text()
         finally:
             paddle.set_flags({"FLAGS_to_static_capture_lowered": False})
 

@@ -379,12 +379,14 @@ class TestEngine:
             assert f.result(timeout=5).tokens == dense_reference(p, 5)
 
     def test_env_knob_selects_kv_dtype(self, monkeypatch):
+        """The field is the one way to say it (ISSUE 29): the environment
+        name that mirrored it is not read, and a bogus value raises."""
         monkeypatch.setenv("PADDLE_TPU_KV_DTYPE", "int8")
-        eng = make_engine(kv_dtype="")          # defer to env
-        assert eng.kv.config.quantized
-        monkeypatch.setenv("PADDLE_TPU_KV_DTYPE", "bogus")
-        with pytest.raises(ValueError, match="PADDLE_TPU_KV_DTYPE"):
-            make_engine(kv_dtype="")
+        assert not make_engine().kv.config.quantized
+        assert make_engine(kv_dtype="int8").kv.config.quantized
+        for bogus in ("bogus", ""):
+            with pytest.raises(ValueError, match="kv_dtype must be"):
+                make_engine(kv_dtype=bogus)
 
     def test_admission_at_full_batch(self):
         """max_batch=1: the second request waits queued, joins the moment
@@ -643,7 +645,7 @@ class TestAdmissionContainment:
         def wedged(*a, **k):
             raise RuntimeError("host sync wedged")
 
-        monkeypatch.setattr(eng, "_set_pool", wedged)
+        monkeypatch.setattr(eng.programs, "_adopt", wedged)
         fut = eng.submit(serving.GenerationRequest(PROMPTS[0],
                                                    max_new_tokens=3))
         # the pool swap / first-token host read raising is just another
@@ -1230,7 +1232,7 @@ def one_at_a_time(eng, prompts, n_new):
 def decode_programs(eng):
     """How many decode executables the engine has compiled."""
     return sum(entry[0]._jitted._cache_size()
-               for entry in eng._decode_program.program_cache.values())
+               for entry in eng.programs.decode_program.program_cache.values())
 
 
 def tier_prompts(vocab, lens=(8, 5, 11, 7)):

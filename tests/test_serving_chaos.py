@@ -276,9 +276,9 @@ def test_tripped_step_adopts_its_pool_and_replays_bit_identically(
     eng = make_engine(watchdog_s=0.25, max_replays=1).warmup()
     seen = _spy_recoveries(eng, monkeypatch)
     adopted = []
-    real_set = eng._set_pool
-    monkeypatch.setattr(eng, "_set_pool", lambda p, s: (
-        adopted.append(p._data), real_set(p, s))[1])
+    real_adopt = eng.programs._adopt
+    monkeypatch.setattr(eng.programs, "_adopt", lambda outs: (
+        real_adopt(outs), adopted.append(eng.kv.pool))[0])
     with faults.installed(sched):
         futs = [eng.submit(serving.GenerationRequest(
             p, max_new_tokens=4)) for p in PROMPTS[:2]]
@@ -330,16 +330,17 @@ def _consume_and_raise_on(eng, attr, nth):
     """Make the ``nth`` call of program ``attr`` behave like a device
     fault AFTER donation: the pool it was given is deleted, nothing comes
     back."""
-    real, calls = getattr(eng, attr), []
+    real, calls = getattr(eng.programs, attr), []
 
     def program(*args):
         calls.append(args)
         if len(calls) == nth:
-            args[3]._data.delete()
+            for i in eng.programs._donate:
+                args[i]._data.delete()
             raise RuntimeError("device fault after the pool was consumed")
         return real(*args)
 
-    setattr(eng, attr, program)
+    setattr(eng.programs, attr, program)
     return calls
 
 
@@ -352,7 +353,7 @@ def test_decode_call_that_consumed_the_pool_and_raised(metrics):
     ref = [f.result(timeout=30).tokens for f in futs]
 
     eng = make_engine3(max_batch=4)
-    _consume_and_raise_on(eng, "_decode_program", nth=2)
+    _consume_and_raise_on(eng, "decode_program", nth=2)
     # the third slot sits the second step out (its serving.step seam
     # faults): running, not included — and replayed all the same
     sched = faults.FaultSchedule().error("serving.step", on=(6,))
@@ -388,7 +389,7 @@ def test_prefill_call_that_consumed_the_pool_and_raised(metrics):
     ref = f0.result(timeout=30).tokens
 
     eng = make_engine3("off", max_batch=4)
-    _consume_and_raise_on(eng, "_prefill_program", nth=2)
+    _consume_and_raise_on(eng, "prefill_program", nth=2)
     fut = eng.submit(serving.GenerationRequest(SHARED_PROMPTS[0],
                                                max_new_tokens=6))
     assert eng.step() and eng.active_requests == 1

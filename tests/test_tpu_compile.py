@@ -95,18 +95,19 @@ def test_decode_programs_hold_no_copy_of_the_pool(
     try:
         for bucket in BUCKETS:
             with pytest.raises(Exception, match="interpret mode"):
-                eng._warm_decode(bucket)
+                eng.programs.warm(buckets=[bucket])
             assert not eng.kv.pool.is_deleted()    # it never ran
             # ISSUE 28: the program takes the step before's tokens from the
             # device — one shape for every bucket — and a selection per
             # row, after the pool and its scales, and gives its own tokens
             # back in that shape; none of that is donated
-            arg_specs = eng._decode_program._last_lowered[2]
+            arg_specs = eng.programs.decode_program._last_lowered[2]
             assert [(a.shape, a.dtype) for a in arg_specs[-2:]] == [
                 ((BUCKETS[-1],), jnp.int32), ((bucket,), jnp.int32)]
             assert arg_specs[3].shape == shape
-            assert not eng._no_carry._data.is_deleted()
-            compiled = _compiled_for_chip(eng._decode_program, one_chip)
+            assert not eng.programs.no_carry._data.is_deleted()
+            compiled = _compiled_for_chip(eng.programs.decode_program,
+                                          one_chip)
             outs = jax.tree_util.tree_leaves(compiled.out_info[0])
             assert (outs[-1].shape, outs[-1].dtype) == \
                 ((BUCKETS[-1],), jnp.int32)
@@ -136,7 +137,8 @@ def test_prefill_program_holds_no_copy_of_the_pool(llama, one_chip):
     paddle.set_flags({"FLAGS_to_static_capture_lowered": True})
     try:
         eng.warmup(prompt_lens=[96])               # runs on the CPU, dense
-        compiled = _compiled_for_chip(eng._prefill_program, one_chip)
+        compiled = _compiled_for_chip(eng.programs.prefill_program,
+                                      one_chip)
     finally:
         paddle.set_flags({"FLAGS_to_static_capture_lowered": False})
     assert pool_copies(compiled.as_text(), eng.kv.pool.shape) == 0

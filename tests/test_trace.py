@@ -554,13 +554,14 @@ class TestPhaseSpans:
         """The pool swap and the first-token host read happen INSIDE
         ``serving.prefill``; the token reaches the stream after it."""
         seen = []
-        real = serving.Engine._set_pool
+        from paddle_tpu.serving.programs import Programs
+        real = Programs._adopt
 
-        def spy(self, pool_t, scales_t):
+        def spy(self, outs):
             seen.append(trace.current())
-            return real(self, pool_t, scales_t)
+            return real(self, outs)
 
-        monkeypatch.setattr(serving.Engine, "_set_pool", spy)
+        monkeypatch.setattr(Programs, "_adopt", spy)
         first_token_at = {}
 
         def stream(rid, tok):

@@ -523,8 +523,7 @@ class TestDataLoaderResume:
 def test_watchdog_backcompat_reexport():
     from paddle_tpu import serving
     from paddle_tpu.resilience import watchdog as rwd
-    from paddle_tpu.serving import watchdog as swd
-    assert swd.StepWatchdog is rwd.StepWatchdog
+    assert serving.StepWatchdog is rwd.StepWatchdog     # still exported
     assert serving.WatchdogTimeout is rwd.WatchdogTimeout
 
 
