@@ -92,15 +92,24 @@ def train_bytes(depth: int, batch: int = TRAIN_BATCH,
     fp32 build, where ``scan_layers`` stacks the per-layer parameters while
     the originals are still alive; and the step, at 6 bytes a parameter
     (bf16 weight, bf16 gradient, two int8 moments) plus activations —
-    logits and their gradient in fp32, one recomputed layer's working set,
-    a saved bf16 carry per layer. On a TPU v5e the build is the higher
-    hump: 12.51 GB measured at depth 7 against 12.38 modeled."""
+    logits and their gradient in fp32, one layer's working set, and what
+    each scanned layer's checkpoint keeps in bf16 (ISSUE 30): its carry,
+    q, k, v, flash's output and fp32 log-sum-exp, the post-attention
+    residual, and the gate and up projections. At depth 7 the build was
+    the higher hump before ISSUE 30 (12.51 GB measured on a TPU v5e
+    against 12.38 modeled); with the kept values the step is, and the
+    model, which sums what XLA partly overlaps, runs ahead of the chip
+    (Mistral's widths, depth 7: 15.95 GB modeled, 14.71 by the compiled
+    step's own ``peak_memory_in_bytes``; PERF.md)."""
     n = PARAMS_FIXED + depth * PARAMS_LAYER
     build = 4 * PARAMS_FIXED + 2 * 4 * depth * PARAMS_LAYER
     tokens = batch * seq
+    kept = (2 * 6 * HIDDEN      # carry, q, k, v (32 heads each), out, residual
+            + 2 * 2 * FFN       # gate, up
+            + 4 * HEADS)        # log-sum-exp, fp32
     acts = (2 * 4 * tokens * VOCAB             # logits and d(logits)
             + 12 * 2 * tokens * FFN            # one layer's MLP tensors
-            + depth * 2 * tokens * HIDDEN)     # saved carries
+            + depth * tokens * kept)
     return max(build, 6 * n + acts)
 
 

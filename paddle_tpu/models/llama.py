@@ -19,10 +19,13 @@ import math
 from dataclasses import dataclass, field
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
-from ..core.tensor import Tensor, apply, to_tensor
+from ..core.tensor import Tensor, _is_tracer, apply, to_tensor
+from ..core.tracing import grad_enabled, no_grad
 from .. import nn
 from ..nn import functional as F
 from ..ops.creation import zeros
@@ -42,15 +45,27 @@ class LlamaConfig:
     rope_theta: float = 10000.0
     tie_word_embeddings: bool = False
     dtype: str = "float32"
-    # activation checkpointing per decoder layer (fleet.utils.recompute);
-    # trades ~1/3 more FLOPs for O(layers) less activation memory — the
-    # standard big-model training setting on TPU
+    # activation checkpointing, one checkpoint per decoder layer. What a
+    # layer keeps for its backward depends on the branch:
+    # * with ``scan_layers`` (jax.checkpoint on the scan body, ISSUE 30):
+    #   the layer's input and ``SCAN_SAVED_NAMES`` — q and k after rotary, v
+    #   (at their own head counts), flash's output and log-sum-exp, the
+    #   post-attention residual, and the gate_proj / up_proj outputs. In
+    #   bf16 that is 2·(4·hidden + 2·kv_width + 2·intermediate) + 4·heads
+    #   bytes a token a layer (94 KB at Mistral-7B's widths: 386 MB a layer
+    #   per 4096 tokens, where the layer's input alone is 33.5 MB). Computed
+    #   again in the backward: the two norms, rotary's inputs, GQA's repeat
+    #   of k and v, silu(gate)·up — elementwise work, no matmul and no
+    #   flash forward (closest to upstream's recompute_granularity=
+    #   "core_attn"; "full" keeps the input only, as this branch did before).
+    # * without it (fleet.utils.recompute around each layer): the layer's
+    #   input only; the whole forward runs again, ~1/3 more FLOPs.
     recompute: bool = False
     # scan-over-layers: stack identical decoder-layer params and lax.scan a
     # single layer body over them. The compiled program stops growing with
     # depth (a 32-layer model compiles as fast as a 2-layer one) and
-    # composes with ``recompute`` as jax.checkpoint on the scan body — the
-    # standard TPU big-model trainer structure. NOTE: state_dict keys use
+    # composes with ``recompute`` as above — the standard TPU big-model
+    # trainer structure. NOTE: state_dict keys use
     # the stacked layout (model.scan_*) — not interchangeable with the
     # per-layer layout; cached generation requires scan_layers=False
     scan_layers: bool = False
@@ -73,6 +88,26 @@ def _rope_cache(max_len: int, head_dim: int, theta: float):
     t = np.arange(max_len, dtype=np.float32)
     freqs = np.outer(t, inv)  # (L, D/2)
     return np.cos(freqs), np.sin(freqs)
+
+
+# What the scanned layer's checkpoint keeps for its backward, besides the
+# scan's carry (``LlamaConfig.recompute``): named where each is made —
+# ``flash_out`` / ``flash_lse`` in ``ops/flash_attention.py``'s forward rules.
+SCAN_SAVED_NAMES = ("attn_q", "attn_k", "attn_v", "flash_out", "flash_lse",
+                    "attn_residual", "mlp_gate", "mlp_up")
+
+
+def _keep(x: Tensor, name: str) -> Tensor:
+    """Name ``x`` for the scanned layer's recompute policy: under it a named
+    value is kept for the backward, not computed a second time. Only
+    ``jax.checkpoint`` reads a name, and the scan body is the one place it
+    wraps these layers — traced, with the tape off (jax differentiates the
+    whole scan). Anywhere else ``x`` passes through untouched, so every
+    other program's lowered text stays what it was."""
+    if grad_enabled() or not _is_tracer(x._data):
+        return x
+    return apply("checkpoint_name", lambda a: checkpoint_name(a, name), x,
+                 amp=False)
 
 
 def apply_rotary(x: Tensor, cos: Tensor, sin: Tensor, position_offset: int = 0):
@@ -110,8 +145,10 @@ class LlamaAttention(nn.Layer):
         k = reshape(self.k_proj(x), [b, l, -1, self.head_dim])
         v = reshape(self.v_proj(x), [b, l, -1, self.head_dim])
         offset = 0 if cache is None else cache[0].shape[1]
-        q = apply_rotary(q, cos, sin, offset)
-        k = apply_rotary(k, cos, sin, offset)
+        # the 8-head k and v: flash repeats them to 32 heads from these
+        q = _keep(apply_rotary(q, cos, sin, offset), "attn_q")
+        k = _keep(apply_rotary(k, cos, sin, offset), "attn_k")
+        v = _keep(v, "attn_v")
         if cache is not None:
             k = concat([cache[0], k], axis=1)
             v = concat([cache[1], v], axis=1)
@@ -139,7 +176,8 @@ class LlamaMLP(nn.Layer):
             config.intermediate_size, config.hidden_size, bias_attr=False)
 
     def forward(self, x):
-        return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
+        act = F.silu(_keep(self.gate_proj(x), "mlp_gate"))
+        return self.down_proj(act * _keep(self.up_proj(x), "mlp_up"))
 
 
 class LlamaDecoderLayer(nn.Layer):
@@ -156,11 +194,33 @@ class LlamaDecoderLayer(nn.Layer):
         h = self.self_attn(self.input_layernorm(x), cos, sin, attn_mask, cache)
         if cache is not None:
             h, new_cache = h
-        x = res + h
+        x = _keep(res + h, "attn_residual")
         x = x + self.mlp(self.post_attention_layernorm(x))
         if cache is not None:
             return x, new_cache
         return x
+
+
+def _scan_policy(carry):
+    """The scanned layer's recompute policy: keep ``SCAN_SAVED_NAMES`` and
+    compute everything else again. Each value the policy keeps is counted
+    as the backward is traced, into the two gauges that say it engaged:
+    how many named values a layer keeps, and their bytes with the carry's."""
+    from .. import observability as _obs
+    names = jax.checkpoint_policies.save_only_these_names(*SCAN_SAVED_NAMES)
+    carry_bytes = carry.size * carry.dtype.itemsize
+    kept = {}                                   # name -> bytes
+
+    def policy(prim, *avals, **params):
+        keep = names(prim, *avals, **params)
+        if keep:
+            kept[params["name"]] = avals[0].size * avals[0].dtype.itemsize
+            _obs.set_gauge("train.scan.saved_values", len(kept))
+            _obs.set_gauge("train.scan.saved_bytes_per_layer",
+                           carry_bytes + sum(kept.values()))
+        return keep
+
+    return policy
 
 
 class LlamaModel(nn.Layer):
@@ -205,38 +265,43 @@ class LlamaModel(nn.Layer):
             q.trainable = False
             q.stop_gradient = True
 
-    def _scan_forward(self, x):
-        import jax
-
-        from ..core.tensor import Tensor as _T, apply as _apply
-        from ..core.tracing import no_grad  # noqa: F401
-
+    def _scan_body(self, cos, sin, carry):
+        """One decoder layer as ``lax.scan``'s body over the stacked
+        parameters (raw arrays in, raw arrays out). With
+        ``config.recompute`` it is one ``jax.checkpoint`` per layer that
+        keeps ``SCAN_SAVED_NAMES`` and the carry, and computes the rest —
+        norms, rotary, SwiGLU's product — again in the backward."""
         template = self._scan_template
         names = self._scan_names
-        flat = [self._scan_params[n] for n in names]
-        recompute = self.config.recompute
+
+        def body(carry, sl):
+            with no_grad():
+                sd = template.state_dict()
+                saved = {n: sd[n]._data for n in names}
+                for n, v in zip(names, sl):
+                    sd[n]._data = v
+                try:
+                    out = template(Tensor(carry), Tensor(cos),
+                                   Tensor(sin))._data
+                finally:
+                    for n in names:
+                        sd[n]._data = saved[n]
+            return out, None
+
+        if self.config.recompute:
+            body = jax.checkpoint(body, policy=_scan_policy(carry))
+        return body
+
+    def _scan_forward(self, x):
+        flat = [self._scan_params[n] for n in self._scan_names]
 
         def fn(cos, sin, h, *stacked):
-            def body(carry, sl):
-                with no_grad():
-                    sd = template.state_dict()
-                    saved = {n: sd[n]._data for n in names}
-                    for n, v in zip(names, sl):
-                        sd[n]._data = v
-                    try:
-                        out = template(_T(carry), _T(cos), _T(sin))._data
-                    finally:
-                        for n in names:
-                            sd[n]._data = saved[n]
-                return out, None
-
-            if recompute:
-                body = jax.checkpoint(body)
-            out, _ = jax.lax.scan(body, h, list(stacked))
+            out, _ = jax.lax.scan(self._scan_body(cos, sin, h), h,
+                                  list(stacked))
             return out
 
-        return _apply("llama_scan_layers", fn, self.rope_cos, self.rope_sin,
-                      x, *flat, amp=False)
+        return apply("llama_scan_layers", fn, self.rope_cos, self.rope_sin,
+                     x, *flat, amp=False)
 
     def forward(self, input_ids, attn_mask=None, caches=None):
         x = self.embed_tokens(input_ids)

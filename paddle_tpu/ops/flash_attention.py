@@ -21,6 +21,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
@@ -779,11 +780,22 @@ def _bwd_kernel_eligible(q, k):
     return use, (not on_tpu), bq, bk
 
 
+def _keep(out, lse):
+    """Name the forward kernel's two results for a caller's recompute
+    policy. They are the custom_vjp's primal output and its residuals at
+    once, so a ``jax.checkpoint(..., policy=save_only_these_names(
+    "flash_out", "flash_lse"))`` around the call keeps them and the backward
+    runs ``flash_bwd_dq`` / ``flash_bwd_dkv`` without a second
+    ``flash_fwd_lse`` (``models/llama.py::_scan_forward``). Under no such
+    policy a name is an identity that lowers to nothing."""
+    return checkpoint_name(out, "flash_out"), checkpoint_name(lse, "flash_lse")
+
+
 def _flash_fwd(q, k, v, causal, sm_scale):
     use_kernel, interpret, bq, bk = _bwd_kernel_eligible(q, k)
     if use_kernel:
-        out, lse = _pallas_flash(q, k, v, causal, sm_scale, bq, bk,
-                                 interpret, with_lse=True)
+        out, lse = _keep(*_pallas_flash(q, k, v, causal, sm_scale, bq, bk,
+                                        interpret, with_lse=True))
         return out, (q, k, v, out, lse)
     out = _flash_dispatch(q, k, v, causal, sm_scale)
     return out, (q, k, v, None, None)
@@ -882,9 +894,9 @@ def _flash_core_seg(q, k, v, q_segs, kv_segs, causal: bool, sm_scale: float):
 def _flash_fwd_seg(q, k, v, q_segs, kv_segs, causal, sm_scale):
     use_kernel, interpret, bq, bk = _bwd_kernel_eligible(q, k)
     if use_kernel:
-        out, lse = _pallas_flash(q, k, v, causal, sm_scale, bq, bk,
-                                 interpret, with_lse=True,
-                                 q_segs=q_segs, kv_segs=kv_segs)
+        out, lse = _keep(*_pallas_flash(q, k, v, causal, sm_scale, bq, bk,
+                                        interpret, with_lse=True,
+                                        q_segs=q_segs, kv_segs=kv_segs))
         return out, (q, k, v, out, lse, q_segs, kv_segs)
     out = _xla_attention(q, k, v, causal, sm_scale, q_segs, kv_segs)
     return out, (q, k, v, None, None, q_segs, kv_segs)
@@ -951,10 +963,10 @@ def _flash_fwd_drop(q, k, v, q_segs, kv_segs, seed, causal, sm_scale,
                     dropout_p):
     use_kernel, interpret, bq, bk = _bwd_kernel_eligible(q, k)
     if use_kernel:
-        out, lse = _pallas_flash(q, k, v, causal, sm_scale, bq, bk,
-                                 interpret, with_lse=True, q_segs=q_segs,
-                                 kv_segs=kv_segs, dropout_p=dropout_p,
-                                 seed=seed)
+        out, lse = _keep(*_pallas_flash(q, k, v, causal, sm_scale, bq, bk,
+                                        interpret, with_lse=True,
+                                        q_segs=q_segs, kv_segs=kv_segs,
+                                        dropout_p=dropout_p, seed=seed))
         return out, (q, k, v, out, lse, q_segs, kv_segs, seed)
     out = _xla_attention_dropout(q, k, v, causal, sm_scale, q_segs, kv_segs,
                                  seed, dropout_p)

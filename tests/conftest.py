@@ -163,6 +163,41 @@ def tier_engine():
     gc.collect()
 
 
+@pytest.fixture(scope="session")
+def scanned_layers_fn():
+    """``scanned_layers_fn(model) -> (f, stacked)`` for a ``LlamaModel``
+    built with ``scan_layers``: ``f(h, *stacked)`` is the scan over its
+    decoder layers as a pure jax function of the hidden states and the
+    stacked parameters' arrays, so ``jax.grad`` / ``make_jaxpr`` / ``lower``
+    see the checkpointed layers alone (``model.config.recompute`` is read
+    at each trace). ISSUE 30's tests, here and in ``test_tpu_compile``."""
+    def make(model):
+        cos, sin = model.rope_cos._data, model.rope_sin._data
+
+        def f(h, *stacked):
+            return jax.lax.scan(model._scan_body(cos, sin, h), h,
+                                list(stacked))[0]
+
+        return f, [model._scan_params[n]._data for n in model._scan_names]
+
+    return make
+
+
+@pytest.fixture
+def flash_kernels_not_interpreted(monkeypatch):
+    """Differentiated flash calls trace the Pallas TPU kernels, not their
+    interpreter (which is what a CPU process picks): for tests that lower
+    or compile a program for the chip and read its kernel calls. Such a
+    trace cannot run here."""
+    from paddle_tpu.ops import flash_attention as fa
+
+    eligible = fa._bwd_kernel_eligible
+    monkeypatch.setattr(
+        fa, "_bwd_kernel_eligible",
+        lambda q, k: (lambda use, _, bq, bk: (use, False, bq, bk))(
+            *eligible(q, k)))
+
+
 # ---------------------------------------------------------------------------
 # Test tiers. The DEFAULT tier is the core loop: autograd, to_static,
 # optimizers, distributed/pipeline/ZeRO, checkpoint, quant, IO — the

@@ -2,7 +2,8 @@
 serving engine's decode programs, compiled for a DESCRIBED TPU v5e (the
 TPU compiler is installed here; nothing runs), hold no copy of the page
 pool (ISSUE 26) — with the tokens carried on the device between steps
-(ISSUE 28) as without.
+(ISSUE 28) as without. And the scanned, checkpointed train layers hold
+the flash forward kernel once, not twice (ISSUE 30).
 
 The program before ISSUE 26 held 1 / 8 / 8 pool-shaped copies in buckets
 1 / 4 / 16 at these widths: the pool argument was not donated, and each
@@ -166,3 +167,43 @@ def test_a_batched_scatter_would_copy_the_pool(one_chip):
     text = jax.jit(step, donate_argnums=(0,)).lower(
         pool, rows, ids, ids, q, tables).compile().as_text()
     assert pool_copies(text, pool.shape) >= 1
+
+
+@pytest.mark.parametrize("recompute", [True, False])
+def test_scanned_layers_run_the_flash_forward_once(
+        one_chip, recompute, monkeypatch, scanned_layers_fn,
+        flash_kernels_not_interpreted):
+    """ISSUE 30: forward and backward of the scanned decoder layers at
+    Mistral's head geometry (4:1 heads of 128), compiled for the chip. The
+    checkpointed layer keeps flash's output and log-sum-exp by name, so the
+    compiled backward loop calls the two backward kernels and no second
+    ``flash_fwd_lse`` — the same kernel calls as with no checkpoint at all,
+    in less memory (before ISSUE 30: two forward calls)."""
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.ops import nn_ops
+
+    monkeypatch.setattr(nn_ops, "_sdpa_flash_backend_ok", lambda: True)
+    cfg = LlamaConfig.tiny(vocab=256, hidden=4 * HEAD_DIM, layers=LAYERS,
+                           heads=4, kv_heads=1, inter=1024, max_pos=512)
+    cfg.scan_layers, cfg.recompute = True, recompute
+    paddle.seed(3)
+    net = LlamaForCausalLM(cfg)
+    net.to(dtype="bfloat16")
+    f, stacked = scanned_layers_fn(net.model)
+
+    def on_chip(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    grad = jax.jit(jax.grad(
+        lambda *a: f(*a).astype(jnp.float32).sum(),
+        argnums=tuple(range(1, 1 + len(stacked)))))
+    compiled = grad.lower(on_chip((1, 512, 4 * HEAD_DIM)),
+                          *[on_chip(a.shape) for a in stacked]).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+
+    def count(kernel):
+        return sum(kernel in c for c in calls)
+
+    assert (count("flash_fwd_lse"), count("flash_bwd_dq"),
+            count("flash_bwd_dkv")) == (1, 1, 1), calls
