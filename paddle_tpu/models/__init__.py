@@ -12,3 +12,4 @@ from . import deepfm  # noqa: F401
 from . import ernie  # noqa: F401
 from . import ppyoloe  # noqa: F401
 from . import cohere2_moe  # noqa: F401
+from . import minicpm_sala  # noqa: F401

@@ -87,6 +87,24 @@ span, mode ``on`` only)::
                               ``experts_touched`` = held experts with a row,
                               summed over layers; ``batch`` = live rows
     serving.moe.prefill     * (instant) the same after a prefill's read-back
+    serving.sparse.decode   * (instant) after a decode step's read-back, for
+                              a model that chooses the pages it attends
+                              (ISSUE 31): ``rows`` = live rows,
+                              ``pages_resident`` = pages those rows hold,
+                              ``pages_read`` = pages they attend (the
+                              selection's chosen pages with a position to
+                              read), both summed over rows, KV heads and
+                              sparse layers ON THE DEVICE by the decode
+                              program and read back behind its tokens
+    serving.linear.decode   * (instant) the same step's state updates:
+                              ``rows`` live rows x ``layers`` lightning
+                              layers, one state read and written each
+    serving.state.snapshot    (span) an admission files the states its
+                              prefill kept at snapshot boundaries
+                              (``states`` of them)
+    serving.state.restore     (span) an admission that mapped ``pages``
+                              prefix pages fetches the state kept at that
+                              boundary, to start its tail prefill from
     jit.call                * StaticFunction: one whole compiled call
       jit.dispatch          * the jitted function alone; ``jit.call``'s self
                               time is hooks + registry walk + key + rebind
@@ -97,6 +115,14 @@ Beside them the decode pipe counts (ISSUE 28; ``observability`` registry):
 share of steps launched ahead, without a trace;
 ``serving.decode_discarded_rows_total`` the rows computed for a request that
 had already ended (known only after the read, so not a span attribute).
+
+Beside them the state snapshots count (ISSUE 31; ``observability``
+registry): counters ``serving.state.snapshot_hits_total`` /
+``serving.state.snapshot_misses_total`` (admissions whose prompt's first
+pages were resident and that did / did not find a state snapshot to start
+from; a first ask counts as neither) and
+``serving.state.snapshot_evictions_total``; gauge
+``serving.state.snapshot_bytes``.
 
 Beside them the expert layer and the pages by layer kind count (ISSUE 27;
 ``observability`` registry, not events): counters ``serving.moe.rows_total``,

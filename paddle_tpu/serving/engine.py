@@ -99,6 +99,27 @@ it always had. A model may return a third value from ``step_fn`` /
 layer's rows per held expert), which rides behind the tokens in the step's
 one read-back (``serving.moe.*``).
 
+A fixed state per slot (ISSUE 31): a model whose layers mix sparse
+attention over pages with linear attention (``layer_kinds`` ``"sparse"`` /
+``"linear"``, ``ServingConfig.state_shape``) gets, beside the one page pool
+of its sparse layers, a ``kv_cache.StatePool`` — one float32 row of
+``(linear layers, *state_shape)`` a slot, claimed at admission, released
+with the slot — and a ``kv_cache.IndexPool`` of the compressed keys stored
+with each page; both are donated to and returned by every program like a
+pool. **A prefix is shared only up to a boundary whose state was kept**: a
+prefill leaves the state after every ``state_snapshot_tokens`` tokens in a
+``kv_cache.SnapshotStore`` (a byte budget, least recently used out first)
+under the prefix chain digest of the boundary's last page; a later prompt
+maps the pages up to the deepest such boundary and prefills the rest from
+the state kept there. Pages past it are not shared — a wrong state is not a
+slower answer. With decode-ahead nothing of a state crosses to the host;
+the third value such a model's ``step_fn`` returns is two counts — the
+pages its rows held and the pages its selections attended, summed on the
+device — which ride behind the tokens and become the
+``serving.sparse.decode`` instant (the engine knows nothing of the rule
+that chose them). The state pool has a row a slot and a slot takes exactly
+one, so admission never waits for a row.
+
 Failure semantics (``resilience`` seams):
 
 * ``serving.admit`` fires once per admission attempt, before prefill.
@@ -147,7 +168,11 @@ tier ran — ISSUE 13), ``serving.prefills_total``,
 ``experts_touched_total`` / ``rows_by_expert_total{layer,expert}``,
 ``serving.kv.pages_in_use_by_kind{kind}``,
 ``serving.kv.window_pages_per_slot_high_water``,
-``serving.kv.window_pages_released_total``, and ``serving.ttft_seconds`` /
+``serving.kv.window_pages_released_total``,
+``serving.state.snapshot_hits_total`` / ``_misses_total`` (admissions that
+found resident prefix pages and did / did not find a state to start from) /
+``_evictions_total``, ``serving.state.snapshot_bytes``, and
+``serving.ttft_seconds`` /
 ``serving.tpot_seconds`` / ``serving.queue_wait_seconds`` histograms
 (SLO-shaped buckets — see ``TTFT_BUCKETS``/``TPOT_BUCKETS`` below).
 
@@ -307,17 +332,39 @@ class ServingConfig:
     layer_kinds: Tuple[str, ...] = ()
     window: Optional[int] = None
     num_pages_window: Optional[int] = None
+    # a fixed state per slot (ISSUE 31): ``layer_kinds`` may also name
+    # "sparse" (full-attention pages whose blocks the model chooses from
+    # compressed keys stored with them, ``index_per_page`` to a page) and
+    # "linear" (no pages: one float32 state of ``state_shape`` a slot).
+    # ``state_snapshot_tokens``: a prefill keeps the state after every so
+    # many tokens (whole pages), and a prefix is shared up to such a
+    # boundary only; ``state_snapshot_bytes`` bounds what is kept.
+    state_shape: Tuple[int, ...] = ()
+    index_per_page: int = 0
+    state_snapshot_tokens: int = 4096
+    state_snapshot_bytes: int = 1 << 30
 
     def __post_init__(self):
         self.layer_kinds = tuple(self.layer_kinds)
+        self.state_shape = tuple(self.state_shape)
         if self.layer_kinds:
             if len(self.layer_kinds) != self.num_layers or \
-                    set(self.layer_kinds) - {"full", "window"}:
+                    set(self.layer_kinds) - {"full", "window", "sparse",
+                                             "linear"}:
                 raise ValueError(
-                    f"layer_kinds must name \"full\" or \"window\" for each "
+                    f"layer_kinds must name \"full\", \"window\", "
+                    f"\"sparse\" or \"linear\" for each "
                     f"of the {self.num_layers} layers, got {self.layer_kinds}")
             if "window" in self.layer_kinds and not self.window:
                 raise ValueError("layer_kinds has window layers: set window")
+        if "linear" in self.layer_kinds:
+            if not self.state_shape or set(self.layer_kinds) != {
+                    "sparse", "linear"} or not self.index_per_page:
+                raise ValueError(
+                    "linear layers need state_shape, and today they come "
+                    "with sparse layers (index_per_page) and no other kind")
+            if self.state_snapshot_tokens % self.page_size:
+                raise ValueError("state_snapshot_tokens must be whole pages")
         self.buckets = tuple(sorted(set(int(b) for b in self.buckets)))
         if not self.buckets or self.buckets[-1] < self.max_batch:
             raise ValueError(
@@ -370,10 +417,11 @@ class ServingConfig:
     def kv_configs(self) -> List[_kv.KVCacheConfig]:
         """One pool's config per layer kind, "full" first: the one pool
         every engine had, unless the model has window layers."""
-        if "window" not in self.layer_kinds:
+        if not {"window", "sparse"} & set(self.layer_kinds):
             return [self.kv_config()]
         return [self.kv_config(kind, self.layer_kinds.count(kind))
-                for kind in ("full", "window") if kind in self.layer_kinds]
+                for kind in ("full", "window", "sparse")
+                if kind in self.layer_kinds]
 
 
 @dataclass(eq=False)                     # identity semantics: slots hold an
@@ -400,6 +448,7 @@ class _Slot:                             # ndarray-bearing request, and
     faults: int = 0
     first_token_time: float = 0.0
     last_token_time: float = 0.0
+    state_row: int = 0                  # its row of the state pool (0: none)
 
     @property
     def request(self) -> GenerationRequest:
@@ -440,11 +489,23 @@ class Engine:
         names = [kv.config.kind for kv in self.kvs]
         count = [0] * len(self.kvs)
         self._layer_pool: List[Tuple[int, int]] = []
+        n_linear = config.layer_kinds.count("linear")
         for kind in (config.layer_kinds if len(self.kvs) > 1 or names[0]
                      else ("",) * config.num_layers):
+            if kind == "linear":            # no pool: a state row's layer
+                self._layer_pool.append((-1, 0))
+                continue
             k = names.index(kind)
             self._layer_pool.append((k, count[k]))
             count[k] += 1
+        # ISSUE 31: the state pool, the compressed keys beside the pages,
+        # and the states kept at prefix boundaries (None: a model of pages)
+        self.state = self.index = self.snapshots = None
+        if n_linear:
+            self.state = _kv.StatePool(config.max_batch, n_linear,
+                                       config.state_shape)
+            self.index = _kv.IndexPool(self.kv.config, config.index_per_page)
+            self.snapshots = _kv.SnapshotStore(config.state_snapshot_bytes)
         # pages a window pool's admitted slots may come to hold at once:
         # admission keeps it within the pool, so a decode step's page
         # claim never fails (guarded by _slot_lock)
@@ -459,8 +520,9 @@ class Engine:
         for kv in self.kvs:
             _cost.register_kv_cache(kv)
         # the compiled programs and how they are called; the tier they run
-        self.programs = Programs(prefill_fn, step_fn, config, self.kvs,
-                                 self._layer_pool)
+        self.programs = Programs(
+            prefill_fn, step_fn, config, self.kvs, self._layer_pool,
+            extras=[self.index, self.state] if n_linear else ())
         self._paged_path = self.programs.path
         # ISSUE 17: prefix-cache page sharing — on only when the prefill
         # callable can start from a page-aligned offset (3-arg form)
@@ -562,6 +624,12 @@ class Engine:
         _obs.inc("serving.pool_resets_total")
         for kv in self.kvs:
             kv.reset_pool()
+        if self.state is not None:          # the states went with the pools
+            self.index.reset()
+            self.state.reset()
+            self.snapshots.reset()
+            for slot in self._slots:        # reset() freed every row
+                slot.state_row = 0
         self._recover_slots(list(self._slots), exc)
         return True
 
@@ -584,9 +652,21 @@ class Engine:
         claim: the admission itself re-resolves (and refcounts) the chain
         under the kv lock."""
         full = int(request.prompt.size)
-        shared = self.kv.peek_prefix_pages(request.prompt) \
+        shared = self._shareable_pages(request.prompt)[1] \
             * self.config.page_size
         return max(1, full - shared)
+
+    def _shareable_pages(self, prompt) -> Tuple[int, int]:
+        """``(resident, shareable)`` leading pages of ``prompt``: those the
+        prefix index holds, and those an admission may map — all of them,
+        or for a model with a state per slot only up to the deepest
+        boundary whose state the snapshot store still has."""
+        resident = self.kv.peek_prefix_pages(prompt)
+        if self.snapshots is None or not resident:
+            return resident, resident
+        digests = _kv.prefix_chain_digests(
+            prompt, self.config.page_size, limit=resident)
+        return resident, self.snapshots.deepest(digests, resident)
 
     def prefix_summary(self) -> frozenset:
         """The kv pool's advertised prefix index (chain digests) — the
@@ -1048,7 +1128,11 @@ class Engine:
             return "noroom"
         pages, first_page, shared = claim
         start = shared * self.config.page_size
+        state_row, start_state = 0, None
         try:
+            if self.state is not None:
+                state_row = self.state.alloc()
+                start_state = self._start_state(prompt, shared)
             with _trace.span("serving.prefill", parent=pending.trace_ctx,
                              rid=req.request_id, prompt=int(prompt.size),
                              shared_pages=shared,
@@ -1073,7 +1157,9 @@ class Engine:
                     _T(jnp.asarray(prompt[None, start:], jnp.int32)),
                     [_T(jnp.asarray(kv.table_row(ids, first=lo)))
                      for kv, ids, lo in zip(self.kvs, pages, first_page)],
-                    _T(jnp.asarray(prompt.size, jnp.int32)), start)
+                    _T(jnp.asarray(prompt.size, jnp.int32)), start,
+                    *(() if self.state is None else (
+                        _T(jnp.asarray(state_row, jnp.int32)), start_state)))
                 # ISSUE 18: the pool swap, first-token host read and
                 # prefix publish belong to the guarded region too — the
                 # host sync raising here (wedged device, watchdog replay)
@@ -1098,7 +1184,10 @@ class Engine:
                 # shareable prompt.
                 for kv, ids, lo in zip(self.kvs, pages, first_page):
                     kv.publish(req.prompt, ids, first=lo)
+                self._keep_snapshots(req.prompt, start, first.extra)
         except Exception as exc:
+            if state_row:
+                self.state.free(state_row)
             self._free_pages(pages)             # refcount-aware: shared
             # pages are decremented, private ones actually released
             _obs.inc("serving.requests_total", status="failed")
@@ -1119,7 +1208,8 @@ class Engine:
                            for kv, ids in zip(self.kvs, pages)],
                      t=int(prompt.size), last_tok=first_tok,
                      tokens=list(pending.replay_tokens),
-                     first_token_time=now, last_token_time=now)
+                     first_token_time=now, last_token_time=now,
+                     state_row=state_row)
         # under the eviction lock: the append must be visible as one
         # event to a concurrent budgeted stop() sweeping stragglers from
         # the caller's thread (ISSUE 14: shared-state-race)
@@ -1173,7 +1263,16 @@ class Engine:
         size = int(prompt.size)
         mapped = [[] for _ in self.kvs]
         n = 0
-        if share:
+        if share and self.snapshots is not None:
+            # a state per slot (ISSUE 31): map the pages up to the deepest
+            # boundary whose state is kept, all of them or none
+            resident, n = self._shareable_pages(prompt)
+            mapped[0] = self.kv.acquire_prefix(prompt, count=n) if n else []
+            n = len(mapped[0])
+            if resident:
+                _obs.inc("serving.state.snapshot_hits_total" if n else
+                         "serving.state.snapshot_misses_total")
+        elif share:
             mapped[0] = self.kv.acquire_prefix(prompt)
             n = len(mapped[0])
             for k, kv in enumerate(self.kvs[1:], 1):
@@ -1208,6 +1307,37 @@ class Engine:
                 return None
             raise
         return pages, first_page, n
+
+    def _start_state(self, prompt: np.ndarray, shared: int):
+        """The state an admission that mapped ``shared`` prefix pages
+        starts its prefill from: the snapshot kept at that boundary
+        (``None``: from zero)."""
+        if not shared:
+            return None
+        with _trace.span("serving.state.restore", pages=shared):
+            digest = _kv.prefix_chain_digests(
+                prompt, self.config.page_size, limit=shared)[-1]
+            kept = self.snapshots.get(digest)
+        if kept is None:                    # evicted since the page claim:
+            raise RuntimeError(             # the one step thread rules it out
+                "state snapshot vanished between the page claim and the "
+                "prefill")
+        return _T(kept)
+
+    def _keep_snapshots(self, prompt: np.ndarray, start: int, kept) -> None:
+        """File the states a prefill from ``start`` kept, one every
+        ``state_snapshot_tokens``, under the chain digests of the ORIGINAL
+        prompt's pages at those boundaries (a replay's appended tokens are
+        generated content, not a shareable prompt)."""
+        if kept is None or not int(kept.shape[0]):
+            return
+        ps, every = self.config.page_size, self.config.state_snapshot_tokens
+        digests = _kv.prefix_chain_digests(prompt, ps)
+        with _trace.span("serving.state.snapshot", states=int(kept.shape[0])):
+            for i in range(int(kept.shape[0])):
+                pages = (start + (i + 1) * every) // ps
+                if pages <= len(digests):
+                    self.snapshots.put(digests[pages - 1], kept._data[i])
 
     def _free_pages(self, pages: List[List[int]]) -> None:
         """Release one claim on every page of a per-pool list of ids (0 is
@@ -1385,12 +1515,14 @@ class Engine:
                                np.int32) for kv in self.kvs]
             row_of = {id(s): i for i, s in enumerate(prev.included)} \
                 if prev is not None else {}
+            state_rows = np.zeros((bucket,), np.int32)
             for i, slot in enumerate(included):
                 if slot.ahead:              # its token is on the device
                     sel[i] = row_of[id(slot)]
                 else:
                     tok[i, 0] = slot.last_tok
                 t[i] = slot.t + slot.ahead
+                state_rows[i] = slot.state_row
                 self._advance_window(slot, int(t[i]))
                 for k, table in enumerate(tables):
                     table[i] = slot.rows[k]
@@ -1399,6 +1531,8 @@ class Engine:
                      _T(jnp.asarray(t)),
                      prev.step.carry if prev is not None
                      else self.programs.no_carry, _T(jnp.asarray(sel)))
+            if self.state is not None:
+                built += (_T(jnp.asarray(state_rows)),)
         with self._deadline_ctx([s.pending for s in included]):
             for attempt in (0, 1):
                 gen = self._watchdog.arm() if self._watchdog else None
@@ -1462,8 +1596,20 @@ class Engine:
             # model counted on the device (an expert layer's rows)
             next_np, counts = flight.step.read()
         now = time.monotonic()
-        self._note_expert_rows(counts, "serving.moe.decode",
-                               len(flight.included))
+        if self.state is None:
+            self._note_expert_rows(counts, "serving.moe.decode",
+                                   len(flight.included))
+        elif _trace.mode() == "on":
+            # what the step's selections counted on the device: pages held
+            # and pages attended, per KV head and sparse layer
+            if counts.size:
+                _trace.phase_instant(
+                    "serving.sparse.decode", parent=self._engine_trace,
+                    rows=len(flight.included),
+                    pages_resident=int(counts[0]), pages_read=int(counts[1]))
+            _trace.phase_instant(
+                "serving.linear.decode", parent=self._engine_trace,
+                rows=len(flight.included), layers=self.state.shape[1])
         _obs.inc("serving.steps_total")
         if flight.ahead:
             _obs.inc("serving.decode_ahead_steps_total")
@@ -1553,6 +1699,8 @@ class Engine:
                     self._window_committed[k] -= self._pages_needed(
                         slot.request, kv)
         self._free_pages(slot.pages)
+        if slot.state_row:
+            self.state.free(slot.state_row)
         return True
 
     def _finish(self, slot: _Slot, reason: str) -> None:

@@ -75,7 +75,8 @@ from ..ops.paged_attention import PagedDecodeCache  # noqa: F401  (re-export:
 
 __all__ = ["KVCacheConfig", "PagedKVCache", "PagedDecodeCache",
            "gather_pages", "scatter_token_page", "scatter_prefill_pages",
-           "quantize_pages", "prefix_chain_digests"]
+           "quantize_pages", "prefix_chain_digests", "StatePool",
+           "IndexPool", "SnapshotStore"]
 
 _Q8_MAX = 127.0  # symmetric absmax grid, same rule as the q8 optimizer state
 
@@ -605,3 +606,144 @@ class PagedKVCache:
                        else width, np.int32)
         row[first:first + len(page_ids)] = np.asarray(page_ids, np.int32)
         return row
+
+
+# ---------------------------------------------------------------------------
+# the third kind of cache (ISSUE 31): a fixed state per slot, the compressed
+# keys stored with the pages, and snapshots of the state at prefix boundaries
+# ---------------------------------------------------------------------------
+
+class StatePool:
+    """One float32 state ``(layers, *shape)`` per slot for the layers that
+    keep a fixed state and no pages (linear attention): ``array`` is
+    ``(rows + 1, layers, *shape)``, row 0 the scratch row padded batch rows
+    name. Donated to every program and adopted back like a page pool; the
+    host side is a free list of rows. Thread-safe."""
+
+    def __init__(self, rows: int, layers: int, shape: Sequence[int]):
+        self.shape = (rows + 1, layers) + tuple(int(n) for n in shape)
+        self.rows = rows
+        self._lock = threading.Lock()
+        self.reset()
+
+    def reset(self) -> None:
+        """Fresh zeroed states; every row free (the engine replays every
+        running slot after a call consumed the pools and raised)."""
+        self.array = jnp.zeros(self.shape, jnp.float32)
+        with self._lock:
+            self._free: List[int] = list(range(self.rows, 0, -1))
+
+    @property
+    def free_rows(self) -> int:
+        with self._lock:
+            return len(self._free)
+
+    @property
+    def row_bytes(self) -> int:
+        return 4 * int(np.prod(self.shape[1:]))
+
+    def alloc(self) -> int:
+        """A free row. There is one per slot and a slot takes exactly one,
+        so an admission that found a free slot finds a row."""
+        with self._lock:
+            if not self._free:
+                raise RuntimeError(
+                    f"no free state row of {self.rows}: more slots hold one "
+                    f"than the engine has slots")
+            return self._free.pop()
+
+    def free(self, row: int) -> None:
+        with self._lock:
+            if row <= 0 or row in self._free:
+                raise ValueError(f"double free / scratch free: state row "
+                                 f"{row}")
+            self._free.append(row)
+
+
+class IndexPool:
+    """The compressed keys of a sparse-attention model, stored WITH the
+    pages: ``array`` is ``(num_pages, layers, per_page * H, D)`` float32
+    (row ``e * H + h``: a page's ``e``-th entry of KV head ``h``; the
+    values are rounded to the pages' storage dtype, the container is
+    float32 so that on a TPU a page's rows fill whole (8, 128) tiles and
+    the decode step's gather moves no padding), indexed by the same page
+    ids and tables, so a shared prefix page shares its entries. No
+    accounting of its own."""
+
+    def __init__(self, config: KVCacheConfig, per_page: int):
+        self.per_page = per_page
+        self.shape = (config.num_pages, config.num_layers,
+                      per_page * config.num_heads, config.head_dim)
+        self.dtype = jnp.float32
+        self.reset()
+
+    def reset(self) -> None:
+        self.array = jnp.zeros(self.shape, self.dtype)
+
+
+class SnapshotStore:
+    """States kept at page-aligned prefix boundaries, keyed by the prefix
+    chain digest of the boundary's last page (``prefix_chain_digests``): a
+    later prompt that shares the pages up to a boundary starts its prefill
+    from the state kept there. A byte budget, least recently used first
+    out. ``serving.state.snapshot_evictions_total`` and the gauge
+    ``serving.state.snapshot_bytes`` are fed here; hits and misses by the
+    engine, which knows what a lookup was for. Thread-safe."""
+
+    def __init__(self, budget_bytes: int):
+        self.budget = int(budget_bytes)
+        self._lock = threading.Lock()
+        self._kept: "OrderedDict[bytes, jnp.ndarray]" = OrderedDict()
+        self._bytes = 0
+
+    def __len__(self) -> int:
+        with self._lock:
+            return len(self._kept)
+
+    @property
+    def nbytes(self) -> int:
+        with self._lock:
+            return self._bytes
+
+    def deepest(self, digests: Sequence[bytes], limit: int) -> int:
+        """The largest ``n <= limit`` with a snapshot under
+        ``digests[n - 1]`` (0: none)."""
+        with self._lock:
+            for n in range(min(limit, len(digests)), 0, -1):
+                if digests[n - 1] in self._kept:
+                    return n
+        return 0
+
+    def get(self, digest: bytes):
+        with self._lock:
+            state = self._kept.get(digest)
+            if state is not None:
+                self._kept.move_to_end(digest)
+            return state
+
+    def put(self, digest: bytes, state) -> None:
+        size = int(state.size) * state.dtype.itemsize
+        evicted = 0
+        with self._lock:
+            if digest in self._kept:
+                self._kept.move_to_end(digest)
+                return
+            if size > self.budget:
+                return
+            while self._bytes + size > self.budget:
+                _, old = self._kept.popitem(last=False)
+                self._bytes -= int(old.size) * old.dtype.itemsize
+                evicted += 1
+            self._kept[digest] = state
+            self._bytes += size
+            total = self._bytes
+        if evicted:
+            _obs.inc("serving.state.snapshot_evictions_total",
+                     float(evicted))
+        _obs.set_gauge("serving.state.snapshot_bytes", float(total))
+
+    def reset(self) -> None:
+        with self._lock:
+            self._kept.clear()
+            self._bytes = 0
+        _obs.set_gauge("serving.state.snapshot_bytes", 0.0)

@@ -24,6 +24,17 @@ tokens in ``carry``'s shape. Nobody else indexes either. ``carry`` is the
 tokens the step before left ON THE DEVICE (one shape for every bucket) and
 ``sel`` says, per row, which of them the row continues (-1: ``head``'s).
 
+**A model with a fixed state per slot** (ISSUE 31: ``config.state_shape``)
+adds two donated arrays behind the pools — the compressed keys stored with
+the pages (``kv_cache.IndexPool``) and the state pool
+(``kv_cache.StatePool``) — and their own bodies: the decode step hands the
+model a ``HybridDecodeCache`` (the page-pool view with those two on it and
+each row's state row), a prefill a ``HybridPrefill`` (the gathered prefix,
+the state at ``start``), scatters what it filled, puts the final state in
+the slot's row and returns the states it kept at snapshot boundaries
+(:attr:`Step.extra`). They ride in ``tail``: donated first, then a call's
+own small arguments.
+
 **Adoption.** Every program takes the pools (and their scales) donated,
 writes them in place and gives them back; the call deletes the arrays it
 was given. **The pool a program returns is always adopted; only its tokens
@@ -43,6 +54,7 @@ import threading
 from dataclasses import dataclass, replace
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -84,6 +96,7 @@ class Step:
     tokens: _T
     rows: int
     carry: Optional[_T] = None
+    extra: Optional[_T] = None      # a hybrid prefill's state snapshots
 
     def read(self) -> Tuple[np.ndarray, np.ndarray]:
         """The call's ONE host sync: ``(tokens (rows,), counts (flat))``."""
@@ -98,8 +111,12 @@ class Programs:
 
     def __init__(self, prefill_fn: Callable, step_fn: Callable, config,
                  kvs: Sequence[_kv.PagedKVCache],
-                 layer_pool: Sequence[Tuple[int, int]]):
+                 layer_pool: Sequence[Tuple[int, int]],
+                 extras: Sequence = ()):
         self.kvs = list(kvs)
+        # donated arrays behind the pools, each held as ``.array`` (ISSUE
+        # 31: the compressed-key pool, then the state pool); () otherwise
+        self.extras = list(extras)
         cfg = self.kvs[0].config
         self._quantized = cfg.quantized
         self.tail_capable = _prefill_accepts_start(prefill_fn)
@@ -123,7 +140,7 @@ class Programs:
         # carried tokens are not its to consume)
         marks = self._flatten("head", [
             ("tables", "pool", "pool" if self._quantized else None)
-        ] * len(self.kvs), "mid")
+        ] * len(self.kvs), "mid", ("pool",) * len(self.extras))
         self._donate = tuple(i for i, m in enumerate(marks) if m == "pool")
         self._name = config.name or "engine"
         self._carry_rows = config.buckets[-1]
@@ -180,31 +197,53 @@ class Programs:
         parts = [(tb, _T(kv.pool),
                   _T(kv.scales) if self._quantized else None)
                  for kv, tb in zip(self.kvs, tables)]
-        return self._adopt(prog(*self._flatten(head, parts, mid, tail)))
+        if not self.extras:
+            return self._adopt(prog(*self._flatten(head, parts, mid, tail)))
+        held = tuple(_T(x.array) for x in self.extras)
+        first, rest = self._adopt(prog(*self._flatten(
+            head, parts, mid, held + tuple(tail))))
+        for x, new in zip(self.extras, rest):
+            x.array = new._data
+        return first, rest[len(held):]
 
     # ------------------------------------------------------------------
     # what the engine calls
     # ------------------------------------------------------------------
-    def decode(self, tok, tables, t, carry, sel) -> Step:
+    def decode(self, tok, tables, t, carry, sel, state_rows=None) -> Step:
         """Launch one decode step of ``tok.shape[0]`` rows: ``tables`` one
         ``(B, width)`` table per pool, ``carry`` the step before's
-        :attr:`Step.carry` (or :attr:`no_carry`). Returns unread."""
+        :attr:`Step.carry` (or :attr:`no_carry`); ``state_rows`` ``(B,)``
+        each row's row of the state pool, for a model that keeps one.
+        Returns unread."""
+        tail = (carry, sel) if not self.extras else (carry, sel, state_rows)
         first, (carried,) = self._call(self.decode_program, tok, tables, t,
-                                       (carry, sel))
+                                       tail)
         return Step(first, int(tok.shape[0]), carried)
 
-    def prefill(self, ids, rows, true_len, start: int = 0) -> Step:
+    def prefill(self, ids, rows, true_len, start: int = 0, state_row=None,
+                start_state=None) -> Step:
         """Prefill one slot whose table row in each pool is ``rows``: the
         full program, or for ``start > 0`` the tail program of that offset
-        (``ids`` then holds positions ``start`` onwards only)."""
+        (``ids`` then holds positions ``start`` onwards only). A model that
+        keeps a state leaves it in ``state_row`` of the state pool and
+        starts from ``start_state`` (the snapshot at ``start``; zeros for a
+        full prefill); what it kept at the boundaries it passed is the
+        step's ``extra``."""
         prog = self._tail_program(start) if start else self.prefill_program
-        return Step(self._call(prog, ids, rows, true_len)[0], 1)
+        if not self.extras:
+            return Step(self._call(prog, ids, rows, true_len)[0], 1)
+        if start_state is None:
+            start_state = self.zero_state
+        first, (snaps,) = self._call(prog, ids, rows, true_len,
+                                     (state_row, start_state))
+        return Step(first, 1, extra=snaps)
 
     def pools_lost(self) -> bool:
         """Whether a call that raised had already consumed the pools: the
         donated arrays are deleted and nothing came back."""
         return any(kv.pool.is_deleted() or (
-            self._quantized and kv.scales.is_deleted()) for kv in self.kvs)
+            self._quantized and kv.scales.is_deleted()) for kv in self.kvs) \
+            or any(x.array.is_deleted() for x in self.extras)
 
     def table_width(self, kv, decode: bool) -> int:
         """Columns of a pool's page-table rows: every logical page, but
@@ -232,15 +271,18 @@ class Programs:
         def zeros(*shape):
             return _T(jnp.zeros(shape, jnp.int32))
 
+        hybrid = bool(self.extras)
         for b in buckets:
             self.decode(zeros(b, 1), [zeros(b, self.table_width(kv, True))
                                       for kv in self.kvs], zeros(b),
-                        self.no_carry, _T(jnp.full((b,), -1, jnp.int32)))
+                        self.no_carry, _T(jnp.full((b,), -1, jnp.int32)),
+                        zeros(b) if hybrid else None)
         for start, n in [(0, lp) for lp in prompt_lens] + list(tails):
             start, n = int(start), int(n)
             self.prefill(zeros(1, n), [zeros(self.table_width(kv, False))
                                        for kv in self.kvs],
-                         _T(jnp.asarray(start + n, jnp.int32)), start)
+                         _T(jnp.asarray(start + n, jnp.int32)), start,
+                         zeros() if hybrid else None)
 
     # ------------------------------------------------------------------
     # the programs
@@ -385,10 +427,87 @@ class Programs:
                     for k, (row, pl_, sc) in enumerate(kinds)])
             return body
 
+        if self.extras:
+            decode_body, prefill_body, tail_body = self._hybrid_bodies(
+                prefill_fn, step_fn, carry_of, pick_tok, first_out)
+        else:
+            decode_body = decode_kernel if self.path == "kernel" \
+                else decode_dense
         self._tail_body = tail_body
         self.decode_program = self._program(
-            "serving_decode_step",
-            decode_kernel if self.path == "kernel" else decode_dense,
-            "serving.decode", "decode")
+            "serving_decode_step", decode_body, "serving.decode", "decode")
         self.prefill_program = self._program(
             "serving_prefill", prefill_body, "serving.prefill", "prefill")
+
+    def _hybrid_bodies(self, prefill_fn, step_fn, carry_of, pick_tok,
+                       first_out):
+        """The decode, prefill and tail bodies of a model with sparse
+        pages, compressed keys beside them and a state per slot (ISSUE 31):
+        one page pool, ``tail = (index pool, state pool, ...)``."""
+        from ..ops import sparse_attention as _sa
+        kv = self.kvs[0]
+        cfg = kv.config
+        ps = cfg.page_size
+        compute_dtype = jnp.dtype(cfg.compute_dtype)
+        index, state = self.extras
+        per = index.per_page
+        stride = ps // per
+        self.zero_state = _T(jnp.zeros(state.shape[1:], jnp.float32))
+        unflatten, returns = self._unflatten, self._returns
+        impl = "kernel" if self.path == "kernel" else "dense"
+
+        def decode_body(*args):
+            tok_a, kinds, t_a, (index_a, state_a, carry_a, sel_a, rows_a) = \
+                unflatten(args, 5)
+            tb, pl_, _ = kinds[0]
+            view = _sa.HybridDecodeCache(
+                pool=_T(pl_), tables=_T(tb), t=_T(t_a), page_size=ps,
+                impl=impl, interpret=self._interpret,
+                index_pool=_T(index_a), state=_T(state_a),
+                state_rows=_T(rows_a))
+            with no_grad():
+                nxt, view2 = first_out(step_fn(
+                    _T(pick_tok(tok_a, carry_a, sel_a)), view, _T(t_a)))
+                # the step's writes, after its last layer: the token's K/V
+                # and the compressed keys it completed (the state rows were
+                # written by their layers, in place)
+                view2 = _sa.commit_index(_pa.commit_pending(view2))
+            return returns(nxt, [(view2.pool._data, None)],
+                           (view2.index_pool._data, view2.state._data)
+                           + carry_of(nxt, tok_a.shape[0]))
+
+        def prefill_at(start: int):
+            def body(*args):
+                ids_a, kinds, len_a, (index_a, state_a, row_a, from_a) = \
+                    unflatten(args, 4)
+                row, pl_, _ = kinds[0]
+                if start:
+                    dense = _kv.gather_pages(pl_, None, row[None, :],
+                                             compute_dtype)
+                else:
+                    dense = jnp.zeros((cfg.num_layers, 2, 1, cfg.num_heads,
+                                       cfg.max_len, cfg.head_dim),
+                                      compute_dtype)
+                with no_grad():
+                    nxt, out = first_out(prefill_fn(
+                        _T(ids_a), _sa.HybridPrefill(kv=_T(dense),
+                                                     state=_T(from_a)),
+                        start))
+                pages = row[start // ps:]
+                pool2, _ = _kv.scatter_prefill_pages(
+                    out.kv._data.astype(compute_dtype), pl_, None, pages,
+                    len_a, ps, start=start)
+                # (L, H, M / stride, D) -> the tail pages' entries
+                ent = out.entries._data[:, :, start // stride:]
+                l_, h_, _, d_ = ent.shape
+                ent = ent.reshape(l_, h_, -1, per, d_).transpose(
+                    2, 0, 3, 1, 4).reshape(-1, l_, per * h_, d_)
+                index2 = index_a.at[pages].set(ent.astype(index_a.dtype))
+                state2 = jax.lax.dynamic_update_slice(
+                    state_a, out.state._data.astype(state_a.dtype)[None],
+                    (row_a.reshape(()),) + (0,) * (state_a.ndim - 1))
+                return returns(nxt, [(pool2, None)],
+                               (index2, state2, out.snapshots._data))
+            return body
+
+        return decode_body, prefill_at(0), prefill_at

@@ -761,3 +761,65 @@ class TestFaultsWithKernel:
         assert futs[0].result(timeout=10).tokens == ref[0]
         assert futs[2].result(timeout=10).tokens == ref[2]
         assert eng.kv.free_pages == eng.kv.config.num_pages - 1
+
+
+# ---------------------------------------------------------------------------
+# a table per (row, KV head) — ISSUE 31: the pages a selection chose
+# ---------------------------------------------------------------------------
+
+def _sparse_case(rng, rep=4):
+    from paddle_tpu.ops import sparse_attention as sa
+    pool = jnp.asarray(rng.standard_normal((P, L, 2, H, PS, D)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((B, H * rep, D)), jnp.float32)
+    kn = jnp.asarray(rng.standard_normal((B, H, D)), jnp.float32)
+    vn = jnp.asarray(rng.standard_normal((B, H, D)), jnp.float32)
+    return sa, pool, q, kn, vn
+
+
+@pytest.mark.parametrize("t_last", [PS * 3 + 5, PS * 2, PS * 2 + 1])
+def test_one_table_for_every_kv_head_is_the_per_row_kernel(t_last):
+    """Every page of the row in each head's table, the last one partly:
+    what ``paged_attention`` computes from the row's one table."""
+    rng = np.random.default_rng(t_last)
+    sa, pool, q, kn, vn = _sparse_case(rng)
+    tables = jnp.asarray(rng.integers(1, P, (B, S)), jnp.int32)
+    t = jnp.asarray([t_last, PS + 3, t_last - 1], jnp.int32)
+    col = jnp.arange(S)[None, :]
+    lens = jnp.clip(t[:, None] - col * PS, 0, PS)
+    both = lambda a: jnp.broadcast_to(a[:, None, :], (B, H, S))  # noqa: E731
+    want = pa.paged_attention_dense(q, kn, vn, pool, None, tables, t, 1, PS)
+    for impl in ("dense", "kernel"):
+        got = sa.sparse_paged_attention(
+            q, kn, vn, pool, both(tables), both(lens), 1, page_size=PS,
+            impl=impl, interpret=True)
+        assert np.abs(np.asarray(got) - np.asarray(want)).max() < 2e-5
+
+
+@pytest.mark.parametrize("columns", [3, 8, 11])
+def test_a_table_per_kv_head_reads_each_heads_own_pages(columns):
+    """Each head its own pages, in its own order, some columns naming no
+    page: the kernel against the gather, and against plain attention over
+    the tokens the tables name."""
+    rng = np.random.default_rng(columns)
+    sa, pool, q, kn, vn = _sparse_case(rng)
+    tables = jnp.asarray(rng.integers(1, P, (B, H, columns)), jnp.int32)
+    lens = jnp.asarray(rng.choice([0, 5, PS], (B, H, columns)), jnp.int32)
+    lens = lens.at[0, 0].set(0)              # a head that reads t alone
+    got = {impl: np.asarray(sa.sparse_paged_attention(
+        q, kn, vn, pool, tables, lens, 0, page_size=PS, impl=impl,
+        interpret=True)) for impl in ("dense", "kernel")}
+    assert np.abs(got["kernel"] - got["dense"]).max() < 2e-5
+    rep = q.shape[1] // H
+    for b, h in np.ndindex(B, H):
+        ks = [np.asarray(pool[tables[b, h, c], 0, 0, h, :lens[b, h, c]])
+              for c in range(columns)] + [np.asarray(kn[b, h])[None]]
+        vs = [np.asarray(pool[tables[b, h, c], 0, 1, h, :lens[b, h, c]])
+              for c in range(columns)] + [np.asarray(vn[b, h])[None]]
+        kk, vv = np.concatenate(ks), np.concatenate(vs)
+        qh = np.asarray(q[b, h * rep:(h + 1) * rep])
+        s = qh @ kk.T / np.sqrt(D)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        want = (p / p.sum(-1, keepdims=True)) @ vv
+        assert np.abs(got["kernel"][b, h * rep:(h + 1) * rep] - want).max() \
+            < 2e-5
+    assert np.abs(got["kernel"][0, :rep] - np.asarray(vn[0, 0])).max() < 1e-6

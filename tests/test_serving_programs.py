@@ -250,3 +250,87 @@ def test_the_environment_names_that_mirrored_the_fields_decide_nothing(
         plain.kv.pool.dtype
     assert eng.prefix_sharing_enabled and eng.kv.config.min_shared_pages == 1
     assert isinstance(eng.programs, Programs)
+
+
+# ---------------------------------------------------------------------------
+# a state per slot (ISSUE 31): the state pool and the compressed keys ride
+# behind the pools, donated and adopted like them
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def hybrid():
+    from test_minicpm_sala import _engine
+    from paddle_tpu.models.minicpm_sala import (MiniCPMSalaConfig,
+                                                MiniCPMSalaForCausalLM)
+    paddle.seed(31)
+    model = MiniCPMSalaForCausalLM(MiniCPMSalaConfig.tiny())
+    model.eval()
+    return lambda **over: _engine(model, **over)
+
+
+def _hybrid_decode_args(eng, bucket):
+    return _decode_args(eng, bucket) + (_zeros(bucket),)
+
+
+def test_layout_donates_the_state_pool_and_the_compressed_keys(hybrid):
+    eng = hybrid()
+    p = eng.programs
+    assert [type(x).__name__ for x in p.extras] == ["IndexPool", "StatePool"]
+    parts = [("tables", "pool", None)]
+    flat = p._flatten("head", parts, "mid",
+                      ("index", "state", "carry", "sel", "rows"))
+    assert flat == ("head", "tables", "mid", "pool", "index", "state",
+                    "carry", "sel", "rows")
+    assert [flat[i] for i in p._donate] == ["pool", "index", "state"]
+    head, back, mid, tail = p._unflatten(flat, 5)
+    assert (head, back, mid) == ("head", parts, "mid")
+    assert list(tail) == ["index", "state", "carry", "sel", "rows"]
+
+
+def test_a_decode_step_adopts_the_state_pool_it_was_given_donated(hybrid):
+    eng = hybrid()
+    pool0, index0, state0 = eng.kv.pool, eng.index.array, eng.state.array
+    step = eng.programs.decode(*_hybrid_decode_args(eng, 3))
+    assert step.read()[0].shape == (3,)
+    assert pool0.is_deleted() and index0.is_deleted() \
+        and state0.is_deleted()                       # consumed by the call
+    assert not eng.state.array.is_deleted() \
+        and eng.state.array.shape == state0.shape     # what it returned
+    assert not eng.index.array.is_deleted()
+    assert not eng.programs.zero_state._data.is_deleted()   # never donated
+    assert not eng.programs.pools_lost()
+    # a prefill writes the slot's row of the state pool and keeps snapshots
+    row = eng.state.alloc()
+    before = np.asarray(eng.state.array)
+    step = eng.programs.prefill(
+        T(jnp.ones((1, 20), jnp.int32)),
+        [T(jnp.asarray(eng.kv.table_row(eng.kv.alloc(6))))],
+        T(jnp.asarray(20, jnp.int32)), 0, T(jnp.asarray(row, jnp.int32)))
+    assert step.extra.shape[0] == 20 // eng.config.state_snapshot_tokens
+    after = np.asarray(eng.state.array)
+    assert np.abs(after[row]).max() > 0
+    assert (np.delete(after, row, 0) == np.delete(before, row, 0)).all()
+
+
+def test_a_consuming_call_that_raised_loses_the_state_too(hybrid):
+    eng = hybrid()
+    doc = np.arange(40, dtype=np.int32) % 90
+    fut = eng.submit(serving.GenerationRequest(doc, max_new_tokens=2))
+    eng.run()
+    assert len(fut.result(timeout=30).tokens) == 2 and len(eng.snapshots)
+    _consume_and_raise_on(eng, "decode_program", nth=1)
+    with pytest.raises(RuntimeError, match="consumed"):
+        eng.programs.decode(*_hybrid_decode_args(eng, 1))
+    assert eng.state.array.is_deleted() and eng.index.array.is_deleted()
+    assert eng.programs.pools_lost()
+    assert eng._restore_lost_pool(RuntimeError("x"))
+    assert not eng.programs.pools_lost()
+    assert not np.asarray(eng.state.array).any()      # fresh states
+    assert len(eng.snapshots) == 0                    # nothing to start from
+    assert eng.state.free_rows == eng.config.max_batch
+    # and the engine serves on: the document is prefilled in full again
+    before = eng.prefill_token_stats()[1]
+    fut = eng.submit(serving.GenerationRequest(doc, max_new_tokens=2))
+    eng.run()
+    assert len(fut.result(timeout=30).tokens) == 2
+    assert eng.prefill_token_stats()[1] - before == 40

@@ -207,3 +207,47 @@ def test_scanned_layers_run_the_flash_forward_once(
 
     assert (count("flash_fwd_lse"), count("flash_bwd_dq"),
             count("flash_bwd_dkv")) == (1, 1, 1), calls
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 31: the two decode kernels of a model with sparse pages and a state
+# per slot, at MiniCPM-SALA's widths (32 query heads on 2 KV heads of 128,
+# pages of 64, 32 rows, 64 chosen pages a head; 32 state heads of 128 x 128)
+# ---------------------------------------------------------------------------
+
+def _spec(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def test_sparse_decode_kernel_compiles_for_the_chip(one_chip):
+    from paddle_tpu.ops import sparse_attention as sa
+    b, h, hkv, d, ps, pages = 32, 32, 2, 128, 64, 4097
+    bf, i32 = jnp.bfloat16, jnp.int32
+    text = jax.jit(lambda q, kn, vn, pool, tab, lens: sa._sparse_kernel_call(
+        q, kn, vn, pool, tab, lens, 1, ps, False)).lower(
+        _spec(one_chip, (b, h, d), bf), _spec(one_chip, (b, hkv, d), bf),
+        _spec(one_chip, (b, hkv, d), bf),
+        _spec(one_chip, (pages, 2, 2, hkv, ps, d), bf),
+        _spec(one_chip, (b, hkv, 64), i32),
+        _spec(one_chip, (b, hkv, 64), i32)).compile().as_text()
+    assert "sparse_attention_decode" in text and "tpu_custom_call" in text
+    assert not pool_copies(text, (pages, 2, 2, hkv, ps, d))
+
+
+def test_linear_state_kernel_compiles_for_the_chip_and_writes_in_place(
+        one_chip):
+    from paddle_tpu.ops import linear_attention as la
+    b, h, d, rows, layers = 32, 32, 128, 9, 6
+    bf = jnp.bfloat16
+    slopes = la.lightning_slopes(h, 10, 32)
+    compiled = jax.jit(
+        lambda q, k, v, pool, r: la._decode_kernel_call(
+            q, k, v, slopes, pool, r, 3, d ** -0.5, False),
+        donate_argnums=(3,)).lower(
+        *[_spec(one_chip, (b, h, d), bf)] * 3,
+        _spec(one_chip, (rows, layers, h, d, d), jnp.float32),
+        _spec(one_chip, (b,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "linear_state_decode" in text and "tpu_custom_call" in text
+    # the donated pool is updated where it lies: no copy of its shape
+    assert not pool_copies(text, (rows, layers, h, d, d))

@@ -44,6 +44,17 @@ def _per_device_fraction(arr):
     return per_dev / total, len(shards)
 
 
+@pytest.fixture(autouse=True)
+def _no_stale_state():
+    """A compiled step threads every live parameter through its program. A
+    model an earlier test file left in a reference cycle, laid out over
+    another mesh, is live until the collector runs — and then the step is
+    refused for "incompatible devices". Collect before building a world."""
+    import gc
+    gc.collect()
+    yield
+
+
 @pytest.fixture
 def sharded_world():
     paddle.seed(0)
