@@ -17,13 +17,31 @@ kernels" (upstream: paddle/phi/kernels/gpu/fused_adam_kernel.cu and the
 multi_tensor_adam family); the sqrt-space second moment is this repo's
 round-4 finding (linear int8 of v explodes training).
 
-Layout contract (matches `optimizer._q8_quantize`):
-  m_q, v_q : int8  (nb, 2048)   v_q stores quantized sqrt(v)
-  m_s, v_s : fp32  (nb, 1)      per-block absmax/127 scales
-  base     : param dtype (nb, 2048) flattened view of the param/master
-  grad     : any float (nb, 2048)
-The caller guarantees n % 2048 == 0 (the optimizer routes ragged params
-to the chunked XLA path — they are small, so their cost is noise).
+Layout contract (matches `optimizer._q8_quantize`; since PR 32 the operands
+keep the PARAMETER's layout instead of a flat one):
+  base     : param dtype (R, C)      a 2-D view of the param/master whose
+                                     rows hold whole quantization blocks:
+                                     C = k * 2048
+  grad     : any float   (R, C)
+  m_q, v_q : int8        (R, C)      v_q stores quantized sqrt(v)
+  m_s, v_s : fp32        (R, k)      per-block absmax/127 scales
+Block b of the flattened parameter is lanes [c*2048, (c+1)*2048) of row r
+with b = r*k + c, so (R, C) / (R, k) hold the same blocks in the same order
+as the flat (nb, 2048) / (nb,) form and a reshape between the two is exact.
+The optimizer hands in a parameter of ndim >= 2 whose last dimension is a
+multiple of 2048 as `x.reshape(prod(shape[:-1]), shape[-1])` — a merge of
+leading dimensions, free in the chip's tiled layout (tiles cover the last
+two dimensions), where `x.reshape(nb, 2048)` changes the minor dimension
+and costs a parameter-sized copy each for base in, grad in and base out.
+Everything else with n % 2048 == 0 comes as (nb, 2048) with k = 1: the
+same kernel, the block geometry read off the operand. Ragged parameters
+take the chunked XLA path (they are small, so their cost is noise).
+
+The grid walks (row group, column block): each step sees one (G, 2048)
+tile of the four big operands — the tile the flat form always had — and
+the whole (G, k) rows of the two scale arrays, which stay resident while
+the column axis advances (a (G, 1) block of an (R, k) array is not a legal
+TPU block).
 """
 
 from __future__ import annotations
@@ -36,9 +54,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _BLOCK = 2048      # quantization block (elements) — fixed by the q8 layout
-# blocks per grid step. 256 (0.5M elements) put the non-stochastic-rounding
-# kernel at 16.70M of scoped VMEM against the 16.00M default on a TPU v5e
-# (libtpu 0.0.34: "exceeded scoped vmem limit by 720.0K"); 128 halves it
+# blocks per grid step: the rows of a (G, 2048) tile. 256 (0.5M elements)
+# put the non-stochastic-rounding kernel at 16.70M of scoped VMEM against
+# the 16.00M default on a TPU v5e (libtpu 0.0.34: "exceeded scoped vmem
+# limit by 720.0K"); 128 halves it
 _TILE_BLOCKS = 128
 
 
@@ -46,9 +65,15 @@ def _kernel(sc_ref, seed_ref, mq_ref, ms_ref, vq_ref, vs_ref, base_ref,
             g_ref, mq_o, ms_o, vq_o, vs_o, base_o, *, use_sr, has_wd,
             out_dtype):
     lr, wd, c1, c2, eps, b1, b2 = (sc_ref[i] for i in range(7))
+    # this step's column of the resident (G, k) scale rows, picked and put
+    # back with a lane mask (a dynamic lane index is no Mosaic load/store)
+    j = pl.program_id(1)
+    mine = jax.lax.broadcasted_iota(jnp.int32, ms_ref.shape, 1) == j
+    column = lambda ref: jnp.sum(jnp.where(mine, ref[:], 0.0), axis=1,
+                                 keepdims=True)
     g32 = g_ref[:].astype(jnp.float32)
-    m32 = mq_ref[:].astype(jnp.float32) * ms_ref[:]
-    sv = vq_ref[:].astype(jnp.float32) * vs_ref[:]
+    m32 = mq_ref[:].astype(jnp.float32) * column(ms_ref)
+    sv = vq_ref[:].astype(jnp.float32) * column(vs_ref)
     v32 = sv * sv
     nm = b1 * m32 + (1.0 - b1) * g32
     nv = b2 * v32 + (1.0 - b2) * g32 * g32
@@ -57,12 +82,12 @@ def _kernel(sc_ref, seed_ref, mq_ref, ms_ref, vq_ref, vs_ref, base_ref,
     msc = jnp.max(jnp.abs(nm), axis=1, keepdims=True) / 127.0
     msc = jnp.where(msc == 0.0, 1.0, msc)
     mq_o[:] = jnp.clip(jnp.round(nm / msc), -127, 127).astype(jnp.int8)
-    ms_o[:] = msc
+    ms_o[:] = jnp.where(mine, msc, ms_o[:])
     sq = jnp.sqrt(nv)
     vsc = jnp.max(jnp.abs(sq), axis=1, keepdims=True) / 127.0
     vsc = jnp.where(vsc == 0.0, 1.0, vsc)
     vq_o[:] = jnp.clip(jnp.round(sq / vsc), -127, 127).astype(jnp.int8)
-    vs_o[:] = vsc
+    vs_o[:] = jnp.where(mine, vsc, vs_o[:])
 
     upd = base_ref[:].astype(jnp.float32)
     if has_wd:
@@ -72,7 +97,8 @@ def _kernel(sc_ref, seed_ref, mq_ref, ms_ref, vq_ref, vs_ref, base_ref,
         # stochastic f32->bf16 rounding, per-tile seeded (unbiased: adds
         # uniform low mantissa bits then truncates — optimizer.
         # _stochastic_round_bf16's rule with the on-core PRNG)
-        pltpu.prng_seed(seed_ref[0] + pl.program_id(0))
+        pltpu.prng_seed(seed_ref[0] + pl.program_id(0) * pl.num_programs(1)
+                        + j)
         bits = jax.lax.bitcast_convert_type(upd, jnp.uint32)
         rnd = pltpu.prng_random_bits(upd.shape).astype(jnp.uint32) \
             & jnp.uint32(0xFFFF)
@@ -93,11 +119,12 @@ def q8_adam_update(m_q, m_s, v_q, v_s, base, grad, scalars, seed, *,
     scalars: (7,) fp32 — lr_eff, weight_decay, c1 (=1-b1^t), c2 (=1-b2^t),
     epsilon, beta1, beta2. seed: (1,) int32 (ignored unless use_sr).
     Returns (m_q', m_s', v_q', v_s', base') aliased onto the inputs."""
-    nb = m_q.shape[0]
-    g = min(_TILE_BLOCKS, nb)
-    grid = (pl.cdiv(nb, g),)
-    row = lambda i: (i, 0)
-    const = lambda i: (0,)
+    rows, k = m_s.shape
+    g = min(_TILE_BLOCKS, rows)
+    grid = (pl.cdiv(rows, g), k)
+    tile = pl.BlockSpec((g, _BLOCK), lambda i, j: (i, j))
+    scale_rows = pl.BlockSpec((g, k), lambda i, j: (i, 0))
+    const = lambda i, j: (0,)
     out_dtype = base.dtype
     kern = functools.partial(_kernel, use_sr=use_sr, has_wd=has_wd,
                              out_dtype=out_dtype)
@@ -107,20 +134,9 @@ def q8_adam_update(m_q, m_s, v_q, v_s, base, grad, scalars, seed, *,
         in_specs=[
             pl.BlockSpec((7,), const, memory_space=pltpu.SMEM),
             pl.BlockSpec((1,), const, memory_space=pltpu.SMEM),
-            pl.BlockSpec((g, _BLOCK), row),
-            pl.BlockSpec((g, 1), row),
-            pl.BlockSpec((g, _BLOCK), row),
-            pl.BlockSpec((g, 1), row),
-            pl.BlockSpec((g, _BLOCK), row),
-            pl.BlockSpec((g, _BLOCK), row),
+            tile, scale_rows, tile, scale_rows, tile, tile,
         ],
-        out_specs=[
-            pl.BlockSpec((g, _BLOCK), row),
-            pl.BlockSpec((g, 1), row),
-            pl.BlockSpec((g, _BLOCK), row),
-            pl.BlockSpec((g, 1), row),
-            pl.BlockSpec((g, _BLOCK), row),
-        ],
+        out_specs=[tile, scale_rows, tile, scale_rows, tile],
         out_shape=[
             jax.ShapeDtypeStruct(m_q.shape, jnp.int8),
             jax.ShapeDtypeStruct(m_s.shape, jnp.float32),

@@ -32,6 +32,8 @@ lr = lr_mod
 
 
 _Q8_BLOCK = 2048  # block size for int8 moment quantization
+# a parameter's int8 state, by accumulator name: (moments, scales) twice
+_Q8_STATE = ("moment1", "moment1_scale", "moment2_sqrt", "moment2_sqrt_scale")
 
 
 def _on_one_device() -> bool:
@@ -63,6 +65,23 @@ def _q8_dequantize(q, scale, shape):
         n *= int(s)
     flat = (q.astype(jnp.float32) * scale[:, None]).reshape(-1)
     return flat[:n].reshape(shape)
+
+
+def _q8_shapes(shape):
+    """(moments' shape, scales' shape) of a parameter's int8 state. A
+    parameter of ndim >= 2 whose last dimension C holds whole blocks keeps
+    its own layout with the leading dimensions merged, (R, C) and
+    (R, C // 2048): the view in which the fused kernel walks it, free on
+    the chip where flat rows of one block — (nb, 2048) and (nb,), the form
+    of everything else — cost a copy of the parameter each way. Block ``b``
+    of the flattened parameter is block ``b`` of either form read row by
+    row, so a reshape between the two is exact."""
+    n = int(np.prod(shape)) if shape else 1
+    if len(shape) >= 2 and n and shape[-1] % _Q8_BLOCK == 0:
+        rows, cols = n // int(shape[-1]), int(shape[-1])
+        return (rows, cols), (rows, cols // _Q8_BLOCK)
+    nb = -(-n // _Q8_BLOCK)
+    return (nb, _Q8_BLOCK), (nb,)
 
 
 def _stochastic_round_bf16(x32, key):
@@ -780,15 +799,14 @@ class Adam(Optimizer):
 
     def _create_accumulators(self, p):
         if self._moment_q8:
-            n = int(np.prod(p._data.shape)) if p._data.shape else 1
-            nb = -(-n // _Q8_BLOCK)
+            moments, scales = _q8_shapes(p._data.shape)
             # "moment2_sqrt": the second moment is stored in SQRT space
             # (see _adam_q8_update) — the key name versions the format so
             # a legacy linear-v checkpoint cannot silently bind to it
             for name in ("moment1", "moment2_sqrt"):
-                self._acc(name, p, init=jnp.zeros((nb, _Q8_BLOCK), jnp.int8))
+                self._acc(name, p, init=jnp.zeros(moments, jnp.int8))
                 self._acc(name + "_scale", p,
-                          init=jnp.ones((nb,), jnp.float32))
+                          init=jnp.ones(scales, jnp.float32))
             return
         self._acc("moment1", p, dtype=self._moment_dtype)
         self._acc("moment2", p, dtype=self._moment_dtype)
@@ -1038,7 +1056,16 @@ class Adam(Optimizer):
 
     def _step_impl(self) -> None:
         if not self._use_multi_tensor or self._fused is None:
+            # which way this step's int8 updates went (`_adam_q8_update`),
+            # counted as the step runs or is traced: parameters the fused
+            # kernel walked where they lie, and the elements of those whose
+            # operands were reshaped into flat (nb, 2048) rows
+            self._q8_routed = {"in_layout_params": 0, "relaid_elements": 0}
             super()._step_impl()
+            if self._moment_q8:
+                from .. import observability as _obs
+                for name, value in self._q8_routed.items():
+                    _obs.set_gauge("train.q8." + name, value)
             return
         self._step_t._set_data(self._step_t._data + 1)
         self._fused_step()
@@ -1068,8 +1095,6 @@ class Adam(Optimizer):
         current format is sqrt-space under the versioned key moment2_sqrt.
         Binding the old arrays directly would square-shrink v (~1000x too
         large updates); convert linear -> sqrt per block on load instead."""
-        if not self._moment_q8:
-            return
         store = self._accumulators.pop("moment2", None)
         sstore = self._accumulators.pop("moment2_scale", None)
         if not store:
@@ -1091,10 +1116,23 @@ class Adam(Optimizer):
             self._accumulators.setdefault("moment2_sqrt_scale", {})[pid] = sc
             sc._set_data(nsc)
 
+    def _q8_state_into_view(self) -> None:
+        """int8 state as a checkpoint gave it -> the shapes this optimizer
+        keeps (`_q8_shapes`). A checkpoint from before PR 32 holds every
+        parameter's moments flat, (nb, 2048) with (nb,) scales; the blocks
+        and their order are the same, so the reshape is exact."""
+        for p in self._param_groups:
+            for name, shape in zip(_Q8_STATE, _q8_shapes(p._data.shape) * 2):
+                t = self._accumulators.get(name, {}).get(id(p))
+                if t is not None and t._data.shape != shape:
+                    t._set_data(t._data.reshape(shape))
+
     def set_state_dict(self, state):
         if self._fused is None:
             super().set_state_dict(state)
-            self._convert_legacy_q8_v()
+            if self._moment_q8:
+                self._convert_legacy_q8_v()
+                self._q8_state_into_view()
             return
         step = state.get("step", 0)
         if isinstance(step, Tensor):
@@ -1177,13 +1215,10 @@ class Adam(Optimizer):
         and collapsed throughput ~10x (measured: fwd+bwd 0.165s/step, the
         copying optimizer tail +1.5s). A ragged tail (params not a multiple
         of chunk x block) is processed as one separate static-shape chunk."""
-        m = self._acc("moment1", p)
-        ms = self._acc("moment1_scale", p)
-        v = self._acc("moment2_sqrt", p)
-        vs = self._acc("moment2_sqrt_scale", p)
+        m, ms, v, vs = (self._acc(name, p) for name in _Q8_STATE)
         shape = p._data.shape
         n = int(np.prod(shape)) if shape else 1
-        nb = int(m._data.shape[0])
+        nb = int(ms._data.size)
         b1, b2 = self._beta1, self._beta2
         t = self._step_t._data.astype(jnp.float32)
         c1 = 1.0 - b1 ** t
@@ -1201,7 +1236,8 @@ class Adam(Optimizer):
             # so does everything under a multi-device mesh: Mosaic
             # kernels cannot be partitioned automatically, XLA's loop can.
             return self._adam_q8_update_pallas(
-                p, g, lr_eff, decoupled_wd, m, ms, v, vs, n, nb, c1, c2)
+                p, g, lr_eff, decoupled_wd, m, ms, v, vs, c1, c2)
+        self._q8_routed["relaid_elements"] += n
         gb = max(1, min(nb, int(self._Q8_CHUNK_ELEMS) // _Q8_BLOCK))
         full_blocks = n // _Q8_BLOCK          # blocks with no ragged tail
         loops = full_blocks // gb             # uniform in-loop chunks
@@ -1296,7 +1332,10 @@ class Adam(Optimizer):
 
         U = max(1, int(self._Q8_UNROLL))
         loops_u, peel = divmod(loops, U)
-        carry = (m._data, ms._data, v._data, vs._data, base)
+        # the loop walks flat rows of one block whatever view the state is
+        # kept in (`_q8_shapes`: the same blocks in the same order)
+        carry = (m._data.reshape(nb, _Q8_BLOCK), ms._data.reshape(nb),
+                 v._data.reshape(nb, _Q8_BLOCK), vs._data.reshape(nb), base)
         if loops_u > 0:
             carry = jax.lax.fori_loop(0, loops_u, unrolled_body(U), carry)
         if peel:
@@ -1332,10 +1371,10 @@ class Adam(Optimizer):
             newb = jax.lax.dynamic_update_slice_in_dim(
                 newb, new_b[:tail_n], off, 0)
 
-        m._set_data(mb)
-        ms._set_data(msb)
-        v._set_data(vb)
-        vs._set_data(vsb)
+        m._set_data(mb.reshape(m._data.shape))
+        ms._set_data(msb.reshape(ms._data.shape))
+        v._set_data(vb.reshape(v._data.shape))
+        vs._set_data(vsb.reshape(vs._data.shape))
         toks.append(msb[0])  # later params' updates order after us (window)
         new_flat = newb.reshape(shape)
         if master is not None:
@@ -1346,14 +1385,26 @@ class Adam(Optimizer):
             p._set_data(new_flat)
 
     def _adam_q8_update_pallas(self, p, g, lr_eff, decoupled_wd,
-                               m, ms, v, vs, n, nb, c1, c2):
-        """Fused single-kernel int8 update (see ops/q8_adam_pallas.py)."""
+                               m, ms, v, vs, c1, c2):
+        """Fused single-kernel int8 update (see ops/q8_adam_pallas.py).
+
+        Parameter and gradient enter the kernel in the view the moments
+        are kept in (`_q8_shapes`): the parameter's own layout with the
+        leading dimensions merged where its rows hold whole blocks — no
+        copy on the chip — and flat (nb, 2048) rows otherwise, which cost
+        a relayout of the parameter each way."""
         from ..ops.q8_adam_pallas import q8_adam_update
 
+        view = m._data.shape
+        if ms._data.ndim == 2:
+            self._q8_routed["in_layout_params"] += 1
+        else:
+            self._q8_routed["relaid_elements"] += m._data.size
+        scale_view = (view[0], view[1] // _Q8_BLOCK)
         master = self._ensure_master(p)
         base = (master._data if master is not None else p._data) \
-            .reshape(nb, _Q8_BLOCK)
-        gview = g.reshape(nb, _Q8_BLOCK)
+            .reshape(view)
+        gview = g.reshape(view)
         use_sr = (master is None and p._data.dtype == jnp.bfloat16
                   and self._stochastic_rounding)
         if use_sr:
@@ -1373,13 +1424,13 @@ class Adam(Optimizer):
             c2.astype(jnp.float32), jnp.float32(self._epsilon),
             jnp.float32(self._beta1), jnp.float32(self._beta2)])
         mq, msc, vq, vsc, newb = q8_adam_update(
-            m._data, ms._data.reshape(nb, 1), v._data,
-            vs._data.reshape(nb, 1), base, gview, scalars, seed,
+            m._data, ms._data.reshape(scale_view), v._data,
+            vs._data.reshape(scale_view), base, gview, scalars, seed,
             use_sr=use_sr, has_wd=bool(wd))
         m._set_data(mq)
-        ms._set_data(msc.reshape(nb))
+        ms._set_data(msc.reshape(ms._data.shape))
         v._set_data(vq)
-        vs._set_data(vsc.reshape(nb))
+        vs._set_data(vsc.reshape(vs._data.shape))
         new_flat = newb.reshape(p._data.shape)
         if master is not None:
             master._set_data(new_flat)

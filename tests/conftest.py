@@ -184,6 +184,44 @@ def scanned_layers_fn():
 
 
 @pytest.fixture
+def q8_update_fn(monkeypatch):
+    """``q8_update_fn(shape) -> (update, arrays, opt)``: the int8 AdamW
+    update of one bf16 parameter without master weights (``train-4k``'s
+    kind), routed as a lone chip routes it, as a pure jax function
+    ``update(w, g, m, m_scale, v, v_scale)`` returning the five it writes.
+    For ``make_jaxpr`` / ``lower`` only: the kernel is the chip's, not its
+    interpreter, so the function cannot run here. ISSUE 32's tests, in
+    ``test_bring_up`` and ``test_tpu_compile``."""
+    import paddle_tpu as paddle
+    from paddle_tpu.optimizer import _Q8_STATE
+    from paddle_tpu.static import create_parameter
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def make(shape):
+        p = create_parameter(shape, "bfloat16")
+        opt = paddle.optimizer.AdamW(
+            1e-2, parameters=[p], weight_decay=0.01, moment_dtype="int8",
+            use_master_weights=False)
+        state = [opt._accumulators[name][id(p)] for name in _Q8_STATE]
+        held = [t._data for t in [p] + state]
+
+        def update(w, g, *st):
+            opt._q8_routed = {"in_layout_params": 0, "relaid_elements": 0}
+            try:
+                for t, x in zip([p] + state, (w,) + st):
+                    t._set_data(x)
+                opt._adam_q8_update(p, g, 1e-2, 0.01)
+                return [t._data for t in [p] + state]
+            finally:
+                for t, x in zip([p] + state, held):
+                    t._set_data(x)
+
+        return update, [held[0], held[0]] + held[1:], opt
+
+    return make
+
+
+@pytest.fixture
 def flash_kernels_not_interpreted(monkeypatch):
     """Differentiated flash calls trace the Pallas TPU kernels, not their
     interpreter (which is what a CPU process picks): for tests that lower

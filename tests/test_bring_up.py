@@ -150,6 +150,59 @@ def test_q8_adam_kernel_lowers_for_tpu(use_sr):
     assert _kernel_names(text) == ["q8_adam_update"]
 
 
+@pytest.mark.parametrize("use_sr", [False, True])
+def test_q8_adam_kernel_lowers_in_the_parameters_layout(use_sr):
+    """A stacked MLP weight walked where it lies (PR 32), at the widths
+    whose rows hold whole 2048-element blocks: `train-4k`'s (7, 4096,
+    14336) as (28672, 14336), seven column blocks a row, scales
+    (28672, 7). (`chip_smoke`'s 11008 is no multiple of 2048: flat rows,
+    the test above.)"""
+    from paddle_tpu.ops.q8_adam_pallas import q8_adam_update
+    rows, cols = 7 * 4096, 14336
+    q8 = SDS((rows, cols), jnp.int8)
+    sc = SDS((rows, cols // 2048), jnp.float32)
+    w = SDS((rows, cols), jnp.bfloat16)
+    text = q8_adam_update.trace(
+        q8, sc, q8, sc, w, w, SDS((7,), jnp.float32), SDS((1,), jnp.int32),
+        use_sr=use_sr, has_wd=True).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert _kernel_names(text) == ["q8_adam_update"]
+
+
+def test_q8_update_relays_no_parameter(q8_update_fn):
+    """The traced int8 update of a [2, 64, 4096] bf16 parameter, routed as
+    a lone chip routes it: parameter and gradient reach the kernel through
+    one `reshape` to [R, C] each — leading dimensions merged, the last kept
+    — the new parameter leaves it through the inverse, and no other
+    equation of the trace touches anything of the parameter's size."""
+    shape, view = (2, 64, 4096), (128, 4096)
+    update, arrays, opt = q8_update_fn(shape)
+    assert [a.shape for a in arrays[2:]] == [view, (128, 2), view, (128, 2)]
+    jaxpr = jax.make_jaxpr(update)(*arrays).jaxpr
+    assert opt._q8_routed == {"in_layout_params": 1, "relaid_elements": 0}
+
+    n = int(np.prod(shape))
+    big = [e for e in jaxpr.eqns
+           if any(getattr(v.aval, "size", 0) >= n
+                  for v in list(e.invars) + list(e.outvars))]
+    kernel, = [e for e in big if e.primitive.name in ("pjit", "jit")]
+    assert kernel.params["name"] == "q8_adam_update"
+    inner = [e.primitive.name for e in kernel.params["jaxpr"].jaxpr.eqns]
+    assert inner == ["pallas_call"], inner
+    w_in, g_in = jaxpr.invars[:2]
+    made_by = {v: e for e in jaxpr.eqns for v in e.outvars}
+    for operand, source in zip(kernel.invars[4:6], (w_in, g_in)):
+        eqn = made_by[operand]
+        assert eqn.primitive.name == "reshape" and eqn.invars[0] is source
+        assert operand.aval.shape == view
+    reshapes = [e for e in big if e is not kernel]
+    assert [e.primitive.name for e in reshapes] == ["reshape"] * 3, big
+    for e in reshapes:                   # merges or splits of leading dims
+        assert e.invars[0].aval.shape[-1] == e.outvars[0].aval.shape[-1]
+    assert reshapes[-1].invars[0] is kernel.outvars[4]
+    assert reshapes[-1].outvars[0].aval.shape == shape
+
+
 # ---------------------------------------------------------------------------
 # (b) importing the package takes no device
 # ---------------------------------------------------------------------------

@@ -362,3 +362,211 @@ def test_q8_pallas_routing_gate():
     loss = (model(x) ** 2).mean()
     loss.backward()
     opt.step()  # must not raise (would, if Pallas ran on CPU)
+
+
+# ---------------------------------------------------------------------------
+# PR 32: the int8 state keeps the parameter's layout, the kernel walks it
+# ---------------------------------------------------------------------------
+
+def _q8_optimizer(shape, wd, seed=21):
+    from paddle_tpu.static import create_parameter
+    paddle.seed(seed)
+    p = create_parameter(shape, "float32",
+                         default_initializer=nn.initializer.Normal(0.0, 0.1))
+    opt = paddle.optimizer.AdamW(1e-2, parameters=[p], weight_decay=wd,
+                                 moment_dtype="int8",
+                                 stochastic_rounding=False)
+    return opt, p
+
+
+def _q8_steps(opt, p, steps, first=0):
+    """`steps` updates of the one parameter under seeded gradients whose
+    blocks differ in scale by orders of magnitude."""
+    shape = tuple(p.shape)
+    for i in range(first, first + steps):
+        rng = np.random.default_rng(100 + i)
+        g = rng.normal(0, 1, shape) * 10.0 ** rng.integers(-4, 1, shape[:-1]
+                                                           + (1,))
+        loss = (p * paddle.to_tensor(g.astype(np.float32))).sum()
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+
+
+def _q8_outputs(opt, p):
+    """The update's five outputs, the state in its flat form."""
+    from paddle_tpu.optimizer import _Q8_STATE
+    out = [np.asarray(opt._accumulators[name][id(p)]._data)
+           for name in _Q8_STATE]
+    nb = out[1].size
+    return [out[0].reshape(nb, 2048), out[1].reshape(nb),
+            out[2].reshape(nb, 2048), out[3].reshape(nb),
+            np.asarray(p._data.astype(jnp.float32))]
+
+
+def _q8_routed(metrics):
+    """(parameters the kernel walked in layout, elements relaid) of the
+    last step."""
+    snap = metrics.snapshot()
+    return (snap["train.q8.in_layout_params"],
+            snap["train.q8.relaid_elements"])
+
+
+@pytest.fixture
+def q8_kernel_on_cpu(monkeypatch):
+    """Route the int8 update as a lone chip would, with the kernel in
+    interpret mode: the test steers the backend question, the program has
+    no option for it."""
+    import functools
+
+    from paddle_tpu.ops import q8_adam_pallas
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        q8_adam_pallas, "q8_adam_update",
+        functools.partial(q8_adam_pallas.q8_adam_update, interpret=True))
+
+
+_IN_LAYOUT_SHAPES = [(3, 64, 4096), (64, 2048), (2, 32, 6144)]
+
+
+@pytest.mark.parametrize("wd", [0.0, 0.01])
+@pytest.mark.parametrize("shape", _IN_LAYOUT_SHAPES)
+def test_q8_in_layout_kernel_matches_flat_kernel(shape, wd):
+    """The kernel walking a parameter's own (R, C) view — two, one and
+    three column blocks a row; 192 rows leave a ragged row group — against
+    the same kernel over flat (nb, 2048) rows, the only geometry it had
+    before PR 32: every block's codes and scales and the new parameter are
+    the same bits."""
+    from paddle_tpu.ops.q8_adam_pallas import q8_adam_update
+    rows, cols = int(np.prod(shape[:-1])), shape[-1]
+    k, nb = cols // 2048, rows * cols // 2048
+    rng = np.random.default_rng(3)
+    codes = lambda: jnp.asarray(rng.integers(-127, 128, (rows, cols)),
+                                jnp.int8)
+    scales = lambda: jnp.asarray(
+        10.0 ** rng.uniform(-6, -1, (rows, k)), jnp.float32)
+    state = [codes(), scales(), codes(), scales()]
+    base = jnp.asarray(rng.normal(0, 0.1, (rows, cols)), jnp.float32)
+    grad = jnp.asarray(rng.normal(0, 1, (rows, cols))
+                       * 10.0 ** rng.integers(-4, 1, (rows, 1)), jnp.float32)
+    scalars = jnp.array([1e-2, wd, 1 - 0.9 ** 3, 1 - 0.999 ** 3, 1e-8,
+                         0.9, 0.999], jnp.float32)
+    run = lambda *ops: q8_adam_update(
+        *ops, scalars, jnp.zeros((1,), jnp.int32), use_sr=False,
+        has_wd=bool(wd), interpret=True)
+    flat = lambda x: x.reshape(nb, -1)
+    got = run(*state, base, grad)
+    want = run(*map(flat, state), flat(base), flat(grad))
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(flat(a)), np.asarray(b))
+
+
+def _assert_q8_outputs_close(got, want):
+    """Kernel against chunked loop: the kernel forms 1 - beta in fp32 from
+    an fp32 beta where the loop rounds Python's double, and XLA's CPU
+    codegen contracts FMAs per fusion, so a code in ten thousand sits one
+    step over a rounding boundary (as `test_q8_pallas_kernel_matches_
+    chunked_path` has allowed since the kernel came)."""
+    for i in (0, 2):
+        d = np.abs(got[i].astype(np.int32) - want[i].astype(np.int32))
+        assert d.max() <= 1 and (d != 0).mean() < 1e-3
+    for i in (1, 3):
+        np.testing.assert_allclose(got[i], want[i], rtol=1e-5)
+    d = np.abs(got[4] - want[4])
+    assert d.max() < 1e-3 and (d > 1e-6).mean() < 1e-3
+
+
+def _assert_q8_route_matches_chunked_loop(shape, wd, routed, metrics,
+                                          monkeypatch):
+    """Three optimizer steps routed as a lone chip routes them (the caller
+    holds `q8_kernel_on_cpu`) against the chunked loop over the same
+    storage; `routed` is what the two gauges must read on the chip's
+    route."""
+    from paddle_tpu.optimizer import _Q8_STATE, _q8_shapes
+    opt, p = _q8_optimizer(shape, wd)
+    _q8_steps(opt, p, 3)
+    assert [opt._accumulators[name][id(p)]._data.shape
+            for name in _Q8_STATE] == list(_q8_shapes(shape)) * 2
+    assert _q8_routed(metrics) == routed
+    got = _q8_outputs(opt, p)
+
+    monkeypatch.undo()                          # the chunked loop, as on CPU
+    ref_opt, ref_p = _q8_optimizer(shape, wd)
+    _q8_steps(ref_opt, ref_p, 3)
+    _assert_q8_outputs_close(got, _q8_outputs(ref_opt, ref_p))
+    assert _q8_routed(metrics) == (0, int(np.prod(shape)))
+
+
+@pytest.mark.parametrize("wd", [0.0, 0.01])
+@pytest.mark.parametrize("shape", _IN_LAYOUT_SHAPES)
+def test_q8_in_layout_update_matches_chunked_path(
+        shape, wd, q8_kernel_on_cpu, metrics, monkeypatch):
+    """State kept as (R, C) / (R, C // 2048), the kernel in layout, nothing
+    relaid."""
+    from paddle_tpu.optimizer import _q8_shapes
+    rows, cols = int(np.prod(shape[:-1])), shape[-1]
+    assert _q8_shapes(shape) == ((rows, cols), (rows, cols // 2048))
+    _assert_q8_route_matches_chunked_loop(shape, wd, (1, 0), metrics,
+                                          monkeypatch)
+
+
+def test_q8_flat_rows_path_is_kept_for_narrow_last_dimension(
+        q8_kernel_on_cpu, metrics, monkeypatch):
+    """[4, 64, 1024]: whole blocks, but a row is half of one — the state
+    stays (nb, 2048) / (nb,), the kernel takes flat rows as before and the
+    gauge counts the parameter as relaid."""
+    from paddle_tpu.optimizer import _q8_shapes
+    shape = (4, 64, 1024)
+    assert _q8_shapes(shape) == ((128, 2048), (128,))
+    _assert_q8_route_matches_chunked_loop(
+        shape, 0.01, (0, 4 * 64 * 1024), metrics, monkeypatch)
+
+
+def test_q8_routed_gauges_over_a_model(q8_kernel_on_cpu, metrics):
+    """A model's worth of shapes in one step: stacked matrices, the
+    embedding and a stacked norm weight ([2, 2048]: rows of whole blocks)
+    in layout; the narrow stacked projection relaid; the ragged bias on
+    the chunked loop, which flattens everything."""
+    from paddle_tpu.static import create_parameter
+    paddle.seed(2)
+    params = [create_parameter(s, "float32") for s in (
+        (2, 8, 4096), (16, 2048), (2, 2048), (2, 8, 1024), (100,))]
+    opt = paddle.optimizer.AdamW(1e-2, parameters=params,
+                                 moment_dtype="int8",
+                                 stochastic_rounding=False)
+    sum((p * p).sum() for p in params).backward()
+    opt.step()
+    assert _q8_routed(metrics) == (3, 2 * 8 * 1024 + 100)
+
+
+def test_q8_checkpoint_in_the_flat_layout_loads_and_continues():
+    """A state saved before PR 32 — every moment (nb, 2048), every scale
+    (nb,) — loads into the view this optimizer keeps and continues on the
+    chunked path bit-equal to a run that was never interrupted; a state
+    saved now loads the same way."""
+    from paddle_tpu.optimizer import _Q8_STATE
+    shape = (2, 32, 4096)
+    opt, p = _q8_optimizer(shape, 0.01)
+    _q8_steps(opt, p, 2)
+    w_at_save = np.asarray(p._data)
+    old = {"step": 2}
+    for name, arr in zip(_Q8_STATE, _q8_outputs(opt, p)[:4]):
+        old[f"{p.name}_{name}"] = paddle.to_tensor(arr)
+    new = {k: (paddle.to_tensor(np.asarray(v._data))
+               if isinstance(v, paddle.Tensor) else v)
+           for k, v in opt.state_dict().items()}
+    assert new[f"{p.name}_moment1"].shape == [64, 4096]
+    _q8_steps(opt, p, 2, first=2)
+    want = _q8_outputs(opt, p)
+
+    for saved in (old, new):
+        opt2, p2 = _q8_optimizer(shape, 0.01, seed=99)
+        p2._set_data(jnp.asarray(w_at_save))
+        opt2.set_state_dict({k.replace(p.name, p2.name): v
+                             for k, v in saved.items()})
+        m = opt2._accumulators["moment1"][id(p2)]._data
+        s = opt2._accumulators["moment1_scale"][id(p2)]._data
+        assert m.shape == (64, 4096) and s.shape == (64, 2)
+        _q8_steps(opt2, p2, 2, first=2)
+        for a, b in zip(_q8_outputs(opt2, p2), want):
+            np.testing.assert_array_equal(a, b)

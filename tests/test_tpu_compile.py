@@ -251,3 +251,52 @@ def test_linear_state_kernel_compiles_for_the_chip_and_writes_in_place(
     assert "linear_state_decode" in text and "tpu_custom_call" in text
     # the donated pool is updated where it lies: no copy of its shape
     assert not pool_copies(text, (rows, layers, h, d, d))
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 32: the int8 Adam update of a stacked matrix, at `train-4k`'s widths
+# with few rows (the tiles cover the last two dimensions; 256 rows a layer
+# are whole tiles as 4096 are). The chip keeps `[L, rows, C]` in tiles of the
+# last two dimensions, so the view `[L * rows, C]` is a bitcast and the flat
+# `[nb, 2048]` form the kernel took before was three copies of the parameter.
+# ---------------------------------------------------------------------------
+
+def _ops_with_results_of(text, n):
+    """The operations of a compiled program whose result holds ``n``
+    elements, by name."""
+    import re
+    ops = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?\S+ = \w+\[([\d,]+)\]\S* ([\w\-]+)\(",
+                     line)
+        if m and int(np.prod([int(d) for d in m.group(1).split(",")])) == n:
+            ops.append(m.group(2))
+    return ops
+
+
+@pytest.mark.parametrize("shape", [(2, 256, 14336), (2, 256, 4096),
+                                   (2, 256, 1024)],
+                         ids=["mlp", "attn_qo", "attn_kv_flat_rows"])
+def test_q8_update_compiles_with_no_copy_of_the_parameter(
+        shape, q8_update_fn, one_chip):
+    update, arrays, opt = q8_update_fn(shape)
+    compiled = jax.jit(update, donate_argnums=(0, 2, 3, 4, 5)).lower(
+        *[_spec(one_chip, a.shape, a.dtype) for a in arrays]).compile()
+    text = compiled.as_text()
+    assert "q8_adam_update" in text and "tpu_custom_call" in text
+    n = int(np.prod(shape))
+    ops = _ops_with_results_of(text, n)
+    moved = [op for op in ops if op not in (
+        "parameter", "bitcast", "custom-call", "get-tuple-element")]
+    if shape[-1] % 2048:
+        # a row is half a block: flat (nb, 2048) rows as before PR 32, and
+        # the three relayouts that form costs (what the stacked k / v
+        # projections still pay, 29 M elements each in `train-4k`)
+        assert opt._q8_routed == {"in_layout_params": 0,
+                                  "relaid_elements": n}
+        assert moved.count("reshape") == 3, ops
+        return
+    assert opt._q8_routed == {"in_layout_params": 1, "relaid_elements": 0}
+    assert "bitcast" in ops and not moved, ops
+    # nothing of the parameter's size lives beside arguments and results
+    assert compiled.memory_analysis().temp_size_in_bytes < n
