@@ -7,9 +7,10 @@ python/paddle/incubate/distributed/models/moe/ + paddle/fluid/operators moe
 ops).
 
 :class:`DroplessMoE` (ISSUE 27) is the serving-side expert layer: sigmoid
-scores, top-k renormalised, no capacity and no dropped token — (token,
-expert) pairs sorted by expert and one grouped matmul
-(``jax.lax.ragged_dot``) over the experts this chip holds.
+or softmax scores (ISSUE 33), top-k renormalised, no capacity and no dropped
+token — (token, expert) pairs sorted by expert and one grouped matmul
+(``jax.lax.ragged_dot``) over the experts this chip holds — plus shared
+experts, averaged or added under a sigmoid gate.
 
 TPU-native design (SURVEY.md §2.5 item 10): token dispatch is the dense
 GShard einsum formulation — (tokens, experts, capacity) one-hot dispatch and
@@ -188,7 +189,13 @@ class MoELayer(Layer):
 _CHUNK_TOKENS = 1024
 
 
-def _dropless_chunk(h, valid, wr, wg, wu, wd, *, top_k: int, first: int):
+_SCORES = {"sigmoid": jax.nn.sigmoid,
+           "softmax": functools.partial(jax.nn.softmax, axis=-1)}
+_SHARED = ("average", "gated sum")
+
+
+def _dropless_chunk(h, valid, wr, wg, wu, wd, *, top_k: int, first: int,
+                    score: str = "sigmoid"):
     """One chunk of tokens through the routed experts held here.
 
     ``h`` (T, M); ``valid`` (T,) bool — a padding row routes nowhere;
@@ -207,7 +214,7 @@ def _dropless_chunk(h, valid, wr, wg, wu, wd, *, top_k: int, first: int):
     t, n = h.shape[0], wg.shape[0]
     f32 = jnp.float32
     with jax.named_scope("moe_route"):
-        scores = jax.nn.sigmoid(jnp.dot(
+        scores = _SCORES[score](jnp.dot(
             h.astype(f32), wr.astype(f32),
             precision=jax.lax.Precision.HIGHEST))
         top_v, top_i = jax.lax.top_k(scores, top_k)
@@ -234,16 +241,20 @@ def _dropless_chunk(h, valid, wr, wg, wu, wd, *, top_k: int, first: int):
 
 
 def dropless_moe(h, valid, wr, wg, wu, wd, *shared, top_k: int, first: int,
-                 chunk: int):
+                 chunk: int, score: str = "sigmoid",
+                 shared_mode: str = "average"):
     """The whole layer on arrays: routed experts held here (chunked over
     tokens: the sorted pairs of a 12k-token prompt would not fit beside
-    the weights) plus the ``shared`` experts, if any — one ``[M, s*F]`` /
-    ``[M, s*F]`` / ``[s*F, M]`` triple scaled by ``1/s``, the same sum as
-    s experts averaged. Returns ``(out (T, M) in h's dtype, rows per held
-    expert (n,) int32)``."""
+    the weights), their scores ``score`` (``sigmoid`` | ``softmax``) of the
+    router's output over ALL experts, plus the ``shared`` experts, if any —
+    one ``[M, s*F]`` / ``[M, s*F]`` / ``[s*F, M]`` triple. ``shared_mode``
+    ``average`` scales it by ``1/s``, the same sum as s experts averaged;
+    ``gated sum`` takes a fourth array ``[M, 1]`` and adds the triple's
+    output scaled by ``sigmoid(h . w)``, float32 a token. Returns ``(out (T,
+    M) in h's dtype, rows per held expert (n,) int32)``."""
     t = h.shape[0]
     part = functools.partial(_dropless_chunk, wr=wr, wg=wg, wu=wu, wd=wd,
-                             top_k=top_k, first=first)
+                             top_k=top_k, first=first, score=score)
     if t <= chunk:
         routed, sizes = part(h, valid)
     else:
@@ -254,20 +265,25 @@ def dropless_moe(h, valid, wr, wg, wu, wd, *shared, top_k: int, first: int,
         routed = routed.reshape(-1, h.shape[1])[:t]
         sizes = sizes.sum(axis=0)
     if shared:
-        sg, su, sd = shared
-        num_shared = sg.shape[1] // wg.shape[2]
+        sg, su, sd = shared[:3]
         with jax.named_scope("moe_shared"):
             f32 = jnp.float32
             mid = jax.nn.silu(jnp.dot(h, sg, preferred_element_type=f32)) \
                 * jnp.dot(h, su, preferred_element_type=f32)
-            routed = routed + jnp.dot(
-                mid.astype(h.dtype), sd,
-                preferred_element_type=f32) * (1.0 / num_shared)
+            out = jnp.dot(mid.astype(h.dtype), sd, preferred_element_type=f32)
+            if shared_mode == "average":
+                scale = 1.0 / (sg.shape[1] // wg.shape[2])
+            else:
+                scale = jax.nn.sigmoid(jnp.dot(
+                    h.astype(f32), shared[3].astype(f32),
+                    precision=jax.lax.Precision.HIGHEST))
+            routed = routed + out * scale
     return routed.astype(h.dtype), sizes
 
 
 class DroplessMoE(Layer):
-    """Sigmoid-routed top-k mixture of SwiGLU experts without capacity:
+    """Top-k mixture of SwiGLU experts without capacity, routed by the
+    ``score`` (``sigmoid`` | ``softmax``, float32) of the router's output:
     every (token, expert) pair is computed, none dropped.
 
     The layer is told which experts it holds (``experts_held = (first,
@@ -275,7 +291,9 @@ class DroplessMoE(Layer):
     each token's weights over all ``top_k`` chosen, and adds only what its
     own experts give — one chip's part of an expert-parallel layer, which
     on one chip runs without its exchange. ``num_shared`` shared experts
-    run for every token and are averaged.
+    of width ``d_ff_shared`` (default ``d_ff``) run for every token:
+    ``shared="average"`` averages them, ``"gated sum"`` adds them scaled by
+    ``sigmoid(x . shared_score)``.
 
     ``forward(x (T, M), valid=None) -> (out (T, M), rows (count,) int32)``:
     ``rows`` counts the pairs each held expert computed (the serving
@@ -284,15 +302,21 @@ class DroplessMoE(Layer):
 
     def __init__(self, d_model: int, d_ff: int, num_experts: int,
                  top_k: int, experts_held=None, num_shared: int = 0,
-                 dtype=None, chunk_tokens: int = _CHUNK_TOKENS):
+                 dtype=None, chunk_tokens: int = _CHUNK_TOKENS,
+                 score: str = "sigmoid", shared: str = "average",
+                 d_ff_shared: Optional[int] = None):
         super().__init__(dtype=dtype)
         from ..nn.initializer import Normal
         first, count = experts_held or (0, num_experts)
         if first < 0 or count < 1 or first + count > num_experts:
             raise ValueError(f"experts_held {(first, count)} lies outside "
                              f"the {num_experts} experts")
+        if score not in _SCORES or shared not in _SHARED:
+            raise ValueError(f"score must be one of {sorted(_SCORES)} and "
+                             f"shared of {_SHARED}, got {score!r}, {shared!r}")
         self.top_k, self.first, self.num_shared = top_k, first, num_shared
         self.chunk_tokens = chunk_tokens
+        self.score, self.shared = score, shared
 
         def w(*shape):
             return self.create_parameter(shape, dtype=dtype,
@@ -302,18 +326,24 @@ class DroplessMoE(Layer):
         self.w_gate, self.w_up = w(count, d_model, d_ff), w(count, d_model, d_ff)
         self.w_down = w(count, d_ff, d_model)
         self.shared_gate = self.shared_up = self.shared_down = None
+        self.shared_score = None
         if num_shared:
-            self.shared_gate = w(d_model, num_shared * d_ff)
-            self.shared_up = w(d_model, num_shared * d_ff)
-            self.shared_down = w(num_shared * d_ff, d_model)
+            wide = num_shared * (d_ff_shared or d_ff)
+            self.shared_gate = w(d_model, wide)
+            self.shared_up = w(d_model, wide)
+            self.shared_down = w(wide, d_model)
+            if shared == "gated sum":
+                self.shared_score = w(d_model, 1)
 
     def forward(self, x: Tensor, valid: Optional[Tensor] = None):
         fn = functools.partial(dropless_moe, top_k=self.top_k,
-                               first=self.first, chunk=self.chunk_tokens)
+                               first=self.first, chunk=self.chunk_tokens,
+                               score=self.score, shared_mode=self.shared)
         if valid is None:
             valid = Tensor(jnp.ones((x.shape[0],), bool))
-        shared = (self.shared_gate, self.shared_up, self.shared_down) \
-            if self.num_shared else ()
+        shared = tuple(w for w in (self.shared_gate, self.shared_up,
+                                   self.shared_down, self.shared_score)
+                       if w is not None)
         return apply("dropless_moe", fn, x, valid, self.router, self.w_gate,
                      self.w_up, self.w_down, *shared, differentiable=False,
                      amp=False)

@@ -13,3 +13,4 @@ from . import ernie  # noqa: F401
 from . import ppyoloe  # noqa: F401
 from . import cohere2_moe  # noqa: F401
 from . import minicpm_sala  # noqa: F401
+from . import qwen3_next  # noqa: F401

@@ -492,7 +492,7 @@ class MiniCPMSalaForCausalLM(nn.Layer):
         * ``prefill_fn(ids (1, Lp), cache: HybridPrefill, start=0)`` runs
           positions ``[start, start + Lp)``: ``start`` is a multiple of
           ``block`` (a state snapshot's boundary), ``cache.kv`` holds the
-          prefix below it and ``cache.state`` the state at it. Returns the
+          prefix below it and ``cache.states`` the state at it. Returns the
           first token and the cache filled as :class:`HybridPrefill` says.
         * ``step_fn(tok (B, 1), cache: HybridDecodeCache, t (B,))`` decodes
           one token a row: a sparse layer scores, chooses and streams its
@@ -541,10 +541,10 @@ class MiniCPMSalaForCausalLM(nn.Layer):
                 return nxt.reshape(1, 1), kv2[:, :, None], ents, state2, \
                     snaps, jax.lax.bitcast_convert_type(lg, jnp.int32)
             nxt, kv2, ents, state2, snaps, lg = apply(
-                "minicpm_sala_prefill", f, ids, cache.kv, cache.state,
+                "minicpm_sala_prefill", f, ids, cache.kv, cache.states[0],
                 *self._flat(), differentiable=False, amp=False)
-            out = HybridPrefill(kv=kv2, state=state2, entries=ents,
-                                snapshots=snaps)
+            out = HybridPrefill(kv=kv2, states=(state2,), entries=ents,
+                                snapshots=(snaps,))
             return (nxt, out, lg) if with_logits else (nxt, out)
 
         def pre(layer, heads, kv_heads, d, rope):
@@ -600,9 +600,9 @@ class MiniCPMSalaForCausalLM(nn.Layer):
                             1.0 / math.sqrt(c.lightning_head_dim),
                             impl=impl, interpret=interpret)
                     o, state = apply(
-                        "linear_state_decode", upd, q, k, v, cache.state,
+                        "linear_state_decode", upd, q, k, v, cache.states[0],
                         cache.state_rows, differentiable=False, amp=False)
-                    cache = replace(cache, state=state)
+                    cache = replace(cache, states=(state,))
                 x = apply("minicpm_sala_out", post(layer), x, h, o, *ws,
                           differentiable=False, amp=False)
             def head(a, n_, hd):

@@ -85,7 +85,10 @@ span, mode ``on`` only)::
                               ``rows`` = (token, expert) pairs the experts
                               held here computed, all layers;
                               ``experts_touched`` = held experts with a row,
-                              summed over layers; ``batch`` = live rows
+                              summed over layers; ``experts_held`` = experts
+                              held x expert layers (ISSUE 33: what
+                              ``experts_touched`` is a share of); ``batch`` =
+                              live rows
     serving.moe.prefill     * (instant) the same after a prefill's read-back
     serving.sparse.decode   * (instant) after a decode step's read-back, for
                               a model that chooses the pages it attends
@@ -96,15 +99,19 @@ span, mode ``on`` only)::
                               read), both summed over rows, KV heads and
                               sparse layers ON THE DEVICE by the decode
                               program and read back behind its tokens
-    serving.linear.decode   * (instant) the same step's state updates:
-                              ``rows`` live rows x ``layers`` lightning
-                              layers, one state read and written each
+    serving.linear.decode   * (instant) a decode step's state updates, for
+                              a model that keeps a state per slot (lightning
+                              or Gated DeltaNet layers): ``rows`` live rows x
+                              ``layers`` such layers, one state (every part
+                              of it) read and written each
     serving.state.snapshot    (span) an admission files the states its
                               prefill kept at snapshot boundaries
-                              (``states`` of them)
+                              (``states`` of them, ``bytes`` in all: every
+                              part of a state goes under the one digest)
     serving.state.restore     (span) an admission that mapped ``pages``
                               prefix pages fetches the state kept at that
-                              boundary, to start its tail prefill from
+                              boundary (``bytes``: all its parts), to start
+                              its tail prefill from
     jit.call                * StaticFunction: one whole compiled call
       jit.dispatch          * the jitted function alone; ``jit.call``'s self
                               time is hooks + registry walk + key + rebind
@@ -121,8 +128,10 @@ registry): counters ``serving.state.snapshot_hits_total`` /
 ``serving.state.snapshot_misses_total`` (admissions whose prompt's first
 pages were resident and that did / did not find a state snapshot to start
 from; a first ask counts as neither) and
-``serving.state.snapshot_evictions_total``; gauge
-``serving.state.snapshot_bytes``.
+``serving.state.snapshot_evictions_total``; gauges
+``serving.state.snapshot_bytes`` and ``serving.state.row_bytes`` (ISSUE 33:
+what one slot's state holds over all its parts and layers, set when the
+engine is built).
 
 Beside them the expert layer and the pages by layer kind count (ISSUE 27;
 ``observability`` registry, not events): counters ``serving.moe.rows_total``,

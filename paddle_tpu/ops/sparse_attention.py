@@ -250,8 +250,8 @@ class HybridDecodeCache(PagedDecodeCache):
       pages' dtype, held in float32 so that a page's rows are one whole
       (8, 128) tile and a gather moves no padding), indexed by the same
       tables
-    * ``state`` — ``(rows, L_lin, H, D, D)`` float32, row 0 scratch
-    * ``state_rows`` — ``(B,)`` int32: each batch row's row of ``state``
+    * ``states`` — one part: ``((rows, L_lin, H, D, D) float32,)``, row 0 scratch
+    * ``state_rows`` — ``(B,)`` int32: each batch row's row of the state
     * ``pending_index`` — per sparse layer decoded so far, the compressed
       key its token completes ``(B, H_kv, D)`` (written by
       :func:`commit_index` for the rows whose token ends a kernel)
@@ -263,7 +263,7 @@ class HybridDecodeCache(PagedDecodeCache):
     """
 
     index_pool: object = None
-    state: object = None
+    states: tuple = ()
     state_rows: object = None
     sparse: Optional[SparseConfig] = None
     pending_index: tuple = ()
@@ -275,17 +275,17 @@ class HybridPrefill:
     """What a prefill of such a model reads and leaves, in place of the dense
     stacked cache (Tensors): ``kv`` ``(L_sparse, 2, 1, H_kv, max_len, D)``
     — positions below ``start`` hold the shared prefix, the prefill writes
-    ``[start, start + Lp)``; ``state`` ``(L_lin, H, D, D)`` float32, the
+    ``[start, start + Lp)``; ``states`` ``((L_lin, H, D, D) float32,)``, the
     state before ``start`` going in and after the last token coming out.
     Coming out only: ``entries`` ``(L_sparse, H_kv, max_len / stride, D)``,
     the compressed keys of every position up to the prompt's end, and
-    ``snapshots`` ``(n, L_lin, H, D, D)``, the state after each whole
-    ``block`` of the run (``n = Lp // block``)."""
+    ``snapshots`` ``((n, L_lin, H, D, D),)``, the state after each whole
+    ``block`` of the run (``n = Lp // block``). One part, in tuples."""
 
     kv: object
-    state: object
+    states: tuple
     entries: Optional[object] = None
-    snapshots: Optional[object] = None
+    snapshots: Optional[tuple] = None
 
 
 def _sparse_decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, kn_ref,

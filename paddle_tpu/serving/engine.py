@@ -99,26 +99,31 @@ it always had. A model may return a third value from ``step_fn`` /
 layer's rows per held expert), which rides behind the tokens in the step's
 one read-back (``serving.moe.*``).
 
-A fixed state per slot (ISSUE 31): a model whose layers mix sparse
-attention over pages with linear attention (``layer_kinds`` ``"sparse"`` /
-``"linear"``, ``ServingConfig.state_shape``) gets, beside the one page pool
-of its sparse layers, a ``kv_cache.StatePool`` — one float32 row of
-``(linear layers, *state_shape)`` a slot, claimed at admission, released
-with the slot — and a ``kv_cache.IndexPool`` of the compressed keys stored
-with each page; both are donated to and returned by every program like a
-pool. **A prefix is shared only up to a boundary whose state was kept**: a
+A fixed state per slot (ISSUE 31, 33): a model whose layers mix attention
+over pages with linear attention (``layer_kinds`` ``"linear"`` beside
+``"sparse"`` or ``"full"``, ``ServingConfig.state_shape``) gets, beside the
+one page pool of its attention layers, a ``kv_cache.StatePool`` — one row a
+slot of ``(linear layers, *shape)`` in each of the state's parts (one
+float32 array, or several of different shapes: a delta-rule state and a
+convolution's tail), claimed at admission, released with the
+slot — and, only if its pages are ``"sparse"``, a ``kv_cache.IndexPool`` of
+the compressed keys stored with each page; all are donated to and returned
+by every program like a pool. **A prefix is shared only up to a boundary
+whose state was kept**: a
 prefill leaves the state after every ``state_snapshot_tokens`` tokens in a
 ``kv_cache.SnapshotStore`` (a byte budget, least recently used out first)
 under the prefix chain digest of the boundary's last page; a later prompt
 maps the pages up to the deepest such boundary and prefills the rest from
-the state kept there. Pages past it are not shared — a wrong state is not a
-slower answer. With decode-ahead nothing of a state crosses to the host;
-the third value such a model's ``step_fn`` returns is two counts — the
-pages its rows held and the pages its selections attended, summed on the
-device — which ride behind the tokens and become the
-``serving.sparse.decode`` instant (the engine knows nothing of the rule
-that chose them). The state pool has a row a slot and a slot takes exactly
-one, so admission never waits for a row.
+the state kept there (every part of it, under the one digest). Pages past
+it are not shared — a wrong state is not a slower answer. With decode-ahead
+nothing of a state crosses to the host; the third value a model with sparse
+pages returns from ``step_fn`` is two counts — the pages its rows held and
+the pages its selections attended, summed on the device — which ride behind
+the tokens and become the ``serving.sparse.decode`` instant (the engine
+knows nothing of the rule that chose them, nor of the rule inside a state's
+layer); any other model's third value is an expert layer's rows, as above.
+The state pool has a row a slot and a slot takes exactly one, so admission
+never waits for a row.
 
 Failure semantics (``resilience`` seams):
 
@@ -171,7 +176,8 @@ tier ran — ISSUE 13), ``serving.prefills_total``,
 ``serving.kv.window_pages_released_total``,
 ``serving.state.snapshot_hits_total`` / ``_misses_total`` (admissions that
 found resident prefix pages and did / did not find a state to start from) /
-``_evictions_total``, ``serving.state.snapshot_bytes``, and
+``_evictions_total``, ``serving.state.snapshot_bytes``,
+``serving.state.row_bytes`` (what one slot's state holds, all parts), and
 ``serving.ttft_seconds`` /
 ``serving.tpot_seconds`` / ``serving.queue_wait_seconds`` histograms
 (SLO-shaped buckets — see ``TTFT_BUCKETS``/``TPOT_BUCKETS`` below).
@@ -332,21 +338,26 @@ class ServingConfig:
     layer_kinds: Tuple[str, ...] = ()
     window: Optional[int] = None
     num_pages_window: Optional[int] = None
-    # a fixed state per slot (ISSUE 31): ``layer_kinds`` may also name
+    # a fixed state per slot (ISSUE 31, 33): ``layer_kinds`` may also name
     # "sparse" (full-attention pages whose blocks the model chooses from
     # compressed keys stored with them, ``index_per_page`` to a page) and
-    # "linear" (no pages: one float32 state of ``state_shape`` a slot).
+    # "linear" (no pages: a float32 state a slot — ``state_shape`` is its
+    # shape or, for a state in parts, a tuple of shapes; kept as the
+    # latter). One mechanism does not imply the other.
     # ``state_snapshot_tokens``: a prefill keeps the state after every so
     # many tokens (whole pages), and a prefix is shared up to such a
     # boundary only; ``state_snapshot_bytes`` bounds what is kept.
-    state_shape: Tuple[int, ...] = ()
+    state_shape: Tuple = ()
     index_per_page: int = 0
     state_snapshot_tokens: int = 4096
     state_snapshot_bytes: int = 1 << 30
 
     def __post_init__(self):
         self.layer_kinds = tuple(self.layer_kinds)
-        self.state_shape = tuple(self.state_shape)
+        shapes = tuple(self.state_shape)
+        if shapes and not isinstance(shapes[0], (tuple, list)):
+            shapes = (shapes,)              # one part
+        self.state_shape = tuple(tuple(int(n) for n in s) for s in shapes)
         if self.layer_kinds:
             if len(self.layer_kinds) != self.num_layers or \
                     set(self.layer_kinds) - {"full", "window", "sparse",
@@ -357,14 +368,21 @@ class ServingConfig:
                     f"of the {self.num_layers} layers, got {self.layer_kinds}")
             if "window" in self.layer_kinds and not self.window:
                 raise ValueError("layer_kinds has window layers: set window")
+        # a check per mechanism: sparse pages, a state per slot
+        if "linear" in self.layer_kinds and not self.state_shape:
+            raise ValueError("linear layers need state_shape")
+        if "sparse" in self.layer_kinds and not self.index_per_page:
+            raise ValueError("sparse layers need index_per_page")
         if "linear" in self.layer_kinds:
-            if not self.state_shape or set(self.layer_kinds) != {
-                    "sparse", "linear"} or not self.index_per_page:
+            if len(set(self.layer_kinds) - {"linear"}) != 1:
                 raise ValueError(
-                    "linear layers need state_shape, and today they come "
-                    "with sparse layers (index_per_page) and no other kind")
+                    "a model with a state per slot keeps one kind of pages "
+                    f"beside it today, got {self.layer_kinds}")
             if self.state_snapshot_tokens % self.page_size:
                 raise ValueError("state_snapshot_tokens must be whole pages")
+            if self.kv_dtype == "int8":
+                raise ValueError("a model with a state per slot keeps its "
+                                 "pages unquantized today")
         self.buckets = tuple(sorted(set(int(b) for b in self.buckets)))
         if not self.buckets or self.buckets[-1] < self.max_batch:
             raise ValueError(
@@ -415,9 +433,10 @@ class ServingConfig:
         return cfg
 
     def kv_configs(self) -> List[_kv.KVCacheConfig]:
-        """One pool's config per layer kind, "full" first: the one pool
-        every engine had, unless the model has window layers."""
-        if not {"window", "sparse"} & set(self.layer_kinds):
+        """One pool's config per layer kind that keeps pages, "full" first:
+        the one pool of every layer that every engine had, unless the model
+        names another kind."""
+        if not set(self.layer_kinds) - {"full"}:
             return [self.kv_config()]
         return [self.kv_config(kind, self.layer_kinds.count(kind))
                 for kind in ("full", "window", "sparse")
@@ -498,14 +517,19 @@ class Engine:
             k = names.index(kind)
             self._layer_pool.append((k, count[k]))
             count[k] += 1
-        # ISSUE 31: the state pool, the compressed keys beside the pages,
-        # and the states kept at prefix boundaries (None: a model of pages)
+        # ISSUE 31, 33, each by its own mechanism: the state pool and the
+        # states kept at prefix boundaries for a model with "linear" layers,
+        # the compressed keys beside the pages for one with "sparse" pages
+        # (None: neither)
         self.state = self.index = self.snapshots = None
         if n_linear:
             self.state = _kv.StatePool(config.max_batch, n_linear,
                                        config.state_shape)
-            self.index = _kv.IndexPool(self.kv.config, config.index_per_page)
             self.snapshots = _kv.SnapshotStore(config.state_snapshot_bytes)
+            _obs.set_gauge("serving.state.row_bytes",
+                           float(self.state.row_bytes))
+        if "sparse" in config.layer_kinds:
+            self.index = _kv.IndexPool(self.kv.config, config.index_per_page)
         # pages a window pool's admitted slots may come to hold at once:
         # admission keeps it within the pool, so a decode step's page
         # claim never fails (guarded by _slot_lock)
@@ -522,7 +546,7 @@ class Engine:
         # the compiled programs and how they are called; the tier they run
         self.programs = Programs(
             prefill_fn, step_fn, config, self.kvs, self._layer_pool,
-            extras=[self.index, self.state] if n_linear else ())
+            index=self.index, state=self.state)
         self._paged_path = self.programs.path
         # ISSUE 17: prefix-cache page sharing — on only when the prefill
         # callable can start from a page-aligned offset (3-arg form)
@@ -624,8 +648,9 @@ class Engine:
         _obs.inc("serving.pool_resets_total")
         for kv in self.kvs:
             kv.reset_pool()
-        if self.state is not None:          # the states went with the pools
+        if self.index is not None:
             self.index.reset()
+        if self.state is not None:          # the states went with the pools
             self.state.reset()
             self.snapshots.reset()
             for slot in self._slots:        # reset() freed every row
@@ -1314,30 +1339,36 @@ class Engine:
         (``None``: from zero)."""
         if not shared:
             return None
-        with _trace.span("serving.state.restore", pages=shared):
+        with _trace.span("serving.state.restore", pages=shared,
+                         bytes=self.state.row_bytes):
             digest = _kv.prefix_chain_digests(
                 prompt, self.config.page_size, limit=shared)[-1]
-            kept = self.snapshots.get(digest)
+            kept = self.snapshots.get_parts(digest)
         if kept is None:                    # evicted since the page claim:
             raise RuntimeError(             # the one step thread rules it out
                 "state snapshot vanished between the page claim and the "
                 "prefill")
-        return _T(kept)
+        return tuple(_T(a) for a in kept)
 
     def _keep_snapshots(self, prompt: np.ndarray, start: int, kept) -> None:
-        """File the states a prefill from ``start`` kept, one every
-        ``state_snapshot_tokens``, under the chain digests of the ORIGINAL
-        prompt's pages at those boundaries (a replay's appended tokens are
-        generated content, not a shareable prompt)."""
-        if kept is None or not int(kept.shape[0]):
+        """File the states a prefill from ``start`` kept (``kept``: per part
+        of the state ``(n, ...)``), one every ``state_snapshot_tokens``,
+        under the chain digests of the ORIGINAL prompt's pages at those
+        boundaries (a replay's appended tokens are generated content, not a
+        shareable prompt). The parts of one boundary go under one digest."""
+        n = int(kept[0].shape[0]) if kept else 0
+        if not n:
             return
         ps, every = self.config.page_size, self.config.state_snapshot_tokens
         digests = _kv.prefix_chain_digests(prompt, ps)
-        with _trace.span("serving.state.snapshot", states=int(kept.shape[0])):
-            for i in range(int(kept.shape[0])):
+        with _trace.span("serving.state.snapshot", states=n,
+                         bytes=n * self.state.row_bytes):
+            for i in range(n):
                 pages = (start + (i + 1) * every) // ps
                 if pages <= len(digests):
-                    self.snapshots.put(digests[pages - 1], kept._data[i])
+                    self.snapshots.put_parts(
+                        digests[pages - 1],
+                        tuple(part._data[i] for part in kept))
 
     def _free_pages(self, pages: List[List[int]]) -> None:
         """Release one claim on every page of a per-pool list of ids (0 is
@@ -1409,7 +1440,8 @@ class Engine:
         if _trace.mode() == "on":           # the instant exists there only
             _trace.phase_instant(
                 event, parent=self._engine_trace, rows=int(counts.sum()),
-                experts_touched=int(np.count_nonzero(counts)), batch=batch)
+                experts_touched=int(np.count_nonzero(counts)),
+                experts_held=int(counts.size), batch=batch)
 
     def _publish_expert_rows(self, now: float, every_s: float) -> None:
         """Feed ``serving.moe.rows_total``, ``.experts_touched_total`` and
@@ -1596,20 +1628,21 @@ class Engine:
             # model counted on the device (an expert layer's rows)
             next_np, counts = flight.step.read()
         now = time.monotonic()
-        if self.state is None:
+        # what the model counted, by mechanism: a model with sparse pages
+        # counts pages held and pages attended (per KV head and sparse
+        # layer), any other an expert layer's rows
+        if self.index is None:
             self._note_expert_rows(counts, "serving.moe.decode",
                                    len(flight.included))
-        elif _trace.mode() == "on":
-            # what the step's selections counted on the device: pages held
-            # and pages attended, per KV head and sparse layer
-            if counts.size:
-                _trace.phase_instant(
-                    "serving.sparse.decode", parent=self._engine_trace,
-                    rows=len(flight.included),
-                    pages_resident=int(counts[0]), pages_read=int(counts[1]))
+        elif counts.size and _trace.mode() == "on":
+            _trace.phase_instant(
+                "serving.sparse.decode", parent=self._engine_trace,
+                rows=len(flight.included),
+                pages_resident=int(counts[0]), pages_read=int(counts[1]))
+        if self.state is not None and _trace.mode() == "on":
             _trace.phase_instant(
                 "serving.linear.decode", parent=self._engine_trace,
-                rows=len(flight.included), layers=self.state.shape[1])
+                rows=len(flight.included), layers=self.state.layers)
         _obs.inc("serving.steps_total")
         if flight.ahead:
             _obs.inc("serving.decode_ahead_steps_total")

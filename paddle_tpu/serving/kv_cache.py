@@ -76,7 +76,7 @@ from ..ops.paged_attention import PagedDecodeCache  # noqa: F401  (re-export:
 __all__ = ["KVCacheConfig", "PagedKVCache", "PagedDecodeCache",
            "gather_pages", "scatter_token_page", "scatter_prefill_pages",
            "quantize_pages", "prefix_chain_digests", "StatePool",
-           "IndexPool", "SnapshotStore"]
+           "IndexPool", "SnapshotStore", "StatePart"]
 
 _Q8_MAX = 127.0  # symmetric absmax grid, same rule as the q8 optimizer state
 
@@ -609,27 +609,52 @@ class PagedKVCache:
 
 
 # ---------------------------------------------------------------------------
-# the third kind of cache (ISSUE 31): a fixed state per slot, the compressed
-# keys stored with the pages, and snapshots of the state at prefix boundaries
+# the third kind of cache (ISSUE 31, 33): a fixed state per slot in one or
+# more parts, the compressed keys a sparse model stores with its pages, and
+# snapshots of the state at prefix boundaries
 # ---------------------------------------------------------------------------
 
-class StatePool:
-    """One float32 state ``(layers, *shape)`` per slot for the layers that
-    keep a fixed state and no pages (linear attention): ``array`` is
-    ``(rows + 1, layers, *shape)``, row 0 the scratch row padded batch rows
-    name. Donated to every program and adopted back like a page pool; the
-    host side is a free list of rows. Thread-safe."""
+class StatePart:
+    """One donated array of a :class:`StatePool`: ``(rows + 1, layers,
+    *shape)`` float32, row 0 the scratch row."""
 
-    def __init__(self, rows: int, layers: int, shape: Sequence[int]):
-        self.shape = (rows + 1, layers) + tuple(int(n) for n in shape)
-        self.rows = rows
+    def __init__(self, shape: Sequence[int]):
+        self.shape = tuple(int(n) for n in shape)
+        self.array = None                   # the pool's ``reset`` makes it
+
+    def reset(self) -> None:
+        self.array = jnp.zeros(self.shape, jnp.float32)
+
+    @property
+    def row_bytes(self) -> int:
+        return 4 * int(np.prod(self.shape[1:]))
+
+
+class StatePool:
+    """One float32 state per slot for the layers that keep a fixed state
+    and no pages (linear attention), in one or more PARTS of different
+    shapes (ISSUE 33: a delta-rule state and a convolution's tail): part
+    ``i`` is ``(rows + 1, layers, *shapes[i])``, row 0 the scratch row padded
+    batch rows name (float32: the kernels that update a row in place take
+    nothing else). Every part is donated to every program and adopted back
+    like a page pool; a slot's row is the same in all of them, claimed and
+    released together. The host side is a free list of rows. ``shape`` is
+    the first part's. Thread-safe."""
+
+    def __init__(self, rows: int, layers: int,
+                 shapes: Sequence[Sequence[int]]):
+        self.parts = [StatePart((rows + 1, layers) + tuple(s))
+                      for s in shapes]
+        self.shape = self.parts[0].shape
+        self.rows, self.layers = rows, layers
         self._lock = threading.Lock()
         self.reset()
 
     def reset(self) -> None:
         """Fresh zeroed states; every row free (the engine replays every
         running slot after a call consumed the pools and raised)."""
-        self.array = jnp.zeros(self.shape, jnp.float32)
+        for part in self.parts:
+            part.reset()
         with self._lock:
             self._free: List[int] = list(range(self.rows, 0, -1))
 
@@ -640,7 +665,7 @@ class StatePool:
 
     @property
     def row_bytes(self) -> int:
-        return 4 * int(np.prod(self.shape[1:]))
+        return sum(part.row_bytes for part in self.parts)
 
     def alloc(self) -> int:
         """A free row. There is one per slot and a slot takes exactly one,
@@ -685,15 +710,17 @@ class SnapshotStore:
     """States kept at page-aligned prefix boundaries, keyed by the prefix
     chain digest of the boundary's last page (``prefix_chain_digests``): a
     later prompt that shares the pages up to a boundary starts its prefill
-    from the state kept there. A byte budget, least recently used first
-    out. ``serving.state.snapshot_evictions_total`` and the gauge
+    from the state kept there. A state is a tuple of arrays, one a part:
+    kept, counted and evicted together under the one digest. A byte budget,
+    least recently used first out.
+    ``serving.state.snapshot_evictions_total`` and the gauge
     ``serving.state.snapshot_bytes`` are fed here; hits and misses by the
     engine, which knows what a lookup was for. Thread-safe."""
 
     def __init__(self, budget_bytes: int):
         self.budget = int(budget_bytes)
         self._lock = threading.Lock()
-        self._kept: "OrderedDict[bytes, jnp.ndarray]" = OrderedDict()
+        self._kept: "OrderedDict[bytes, tuple]" = OrderedDict()
         self._bytes = 0
 
     def __len__(self) -> int:
@@ -714,15 +741,25 @@ class SnapshotStore:
                     return n
         return 0
 
-    def get(self, digest: bytes):
+    def get_parts(self, digest: bytes) -> Optional[tuple]:
+        """What is kept under ``digest``, one array a part (``None``:
+        nothing), now the most recently used."""
         with self._lock:
-            state = self._kept.get(digest)
-            if state is not None:
+            parts = self._kept.get(digest)
+            if parts is not None:
                 self._kept.move_to_end(digest)
-            return state
+            return parts
 
-    def put(self, digest: bytes, state) -> None:
-        size = int(state.size) * state.dtype.itemsize
+    def get(self, digest: bytes):
+        """The first part of what is kept under ``digest``: a one-part
+        state whole."""
+        parts = self.get_parts(digest)
+        return None if parts is None else parts[0]
+
+    def put_parts(self, digest: bytes, parts: Sequence) -> None:
+        """File a state, one array a part, under one digest."""
+        parts = tuple(parts)
+        size = self._size(parts)
         evicted = 0
         with self._lock:
             if digest in self._kept:
@@ -732,15 +769,19 @@ class SnapshotStore:
                 return
             while self._bytes + size > self.budget:
                 _, old = self._kept.popitem(last=False)
-                self._bytes -= int(old.size) * old.dtype.itemsize
+                self._bytes -= self._size(old)
                 evicted += 1
-            self._kept[digest] = state
+            self._kept[digest] = parts
             self._bytes += size
             total = self._bytes
         if evicted:
             _obs.inc("serving.state.snapshot_evictions_total",
                      float(evicted))
         _obs.set_gauge("serving.state.snapshot_bytes", float(total))
+
+    @staticmethod
+    def _size(parts: tuple) -> int:
+        return sum(int(a.size) * a.dtype.itemsize for a in parts)
 
     def reset(self) -> None:
         with self._lock:

@@ -24,16 +24,19 @@ tokens in ``carry``'s shape. Nobody else indexes either. ``carry`` is the
 tokens the step before left ON THE DEVICE (one shape for every bucket) and
 ``sel`` says, per row, which of them the row continues (-1: ``head``'s).
 
-**A model with a fixed state per slot** (ISSUE 31: ``config.state_shape``)
-adds two donated arrays behind the pools — the compressed keys stored with
-the pages (``kv_cache.IndexPool``) and the state pool
-(``kv_cache.StatePool``) — and their own bodies: the decode step hands the
-model a ``HybridDecodeCache`` (the page-pool view with those two on it and
-each row's state row), a prefill a ``HybridPrefill`` (the gathered prefix,
-the state at ``start``), scatters what it filled, puts the final state in
-the slot's row and returns the states it kept at snapshot boundaries
-(:attr:`Step.extra`). They ride in ``tail``: donated first, then a call's
-own small arguments.
+**A model with a fixed state per slot** (ISSUE 31, 33:
+``config.state_shape``) adds donated arrays behind the pools — every part of
+the state pool (``kv_cache.StatePool.parts``) and, before them, the
+compressed keys a model with sparse pages stores with them
+(``kv_cache.IndexPool``) — and their own bodies: the decode step hands the
+model the page-pool view with those on it and each row's state row (a
+``StateDecodeCache``; a ``HybridDecodeCache`` with sparse pages), a prefill
+the gathered prefix and the state at ``start`` (``StatePrefill`` /
+``HybridPrefill``), scatters what it filled, puts the final state in the
+slot's row of every part and returns what it kept of each at snapshot
+boundaries (:attr:`Step.extra`, one array a part). They ride in ``tail``:
+donated first, then a call's own small arguments. What a state IS — the
+rule inside a layer — only the model knows.
 
 **Adoption.** Every program takes the pools (and their scales) donated,
 writes them in place and gives them back; the call deletes the arrays it
@@ -96,7 +99,7 @@ class Step:
     tokens: _T
     rows: int
     carry: Optional[_T] = None
-    extra: Optional[_T] = None      # a hybrid prefill's state snapshots
+    extra: tuple = ()               # a prefill's state snapshots, per part
 
     def read(self) -> Tuple[np.ndarray, np.ndarray]:
         """The call's ONE host sync: ``(tokens (rows,), counts (flat))``."""
@@ -112,11 +115,15 @@ class Programs:
     def __init__(self, prefill_fn: Callable, step_fn: Callable, config,
                  kvs: Sequence[_kv.PagedKVCache],
                  layer_pool: Sequence[Tuple[int, int]],
-                 extras: Sequence = ()):
+                 index=None, state=None):
         self.kvs = list(kvs)
-        # donated arrays behind the pools, each held as ``.array`` (ISSUE
-        # 31: the compressed-key pool, then the state pool); () otherwise
-        self.extras = list(extras)
+        # donated arrays behind the pools, each held as ``.array``: the
+        # compressed-key pool of a model with sparse pages, then every part
+        # of the state pool; () for a model of pages
+        self.index = index
+        self.state_parts = list(state.parts) if state is not None else []
+        self.extras = ([index] if index is not None else []) \
+            + self.state_parts
         cfg = self.kvs[0].config
         self._quantized = cfg.quantized
         self.tail_capable = _prefill_accepts_start(prefill_fn)
@@ -215,7 +222,8 @@ class Programs:
         :attr:`Step.carry` (or :attr:`no_carry`); ``state_rows`` ``(B,)``
         each row's row of the state pool, for a model that keeps one.
         Returns unread."""
-        tail = (carry, sel) if not self.extras else (carry, sel, state_rows)
+        tail = (carry, sel) if not self.state_parts \
+            else (carry, sel, state_rows)
         first, (carried,) = self._call(self.decode_program, tok, tables, t,
                                        tail)
         return Step(first, int(tok.shape[0]), carried)
@@ -225,18 +233,18 @@ class Programs:
         """Prefill one slot whose table row in each pool is ``rows``: the
         full program, or for ``start > 0`` the tail program of that offset
         (``ids`` then holds positions ``start`` onwards only). A model that
-        keeps a state leaves it in ``state_row`` of the state pool and
-        starts from ``start_state`` (the snapshot at ``start``; zeros for a
-        full prefill); what it kept at the boundaries it passed is the
-        step's ``extra``."""
+        keeps a state leaves it in ``state_row`` of every part of the state
+        pool and starts from ``start_state`` (the snapshot at ``start``, one
+        Tensor a part; zeros for a full prefill); what it kept at the
+        boundaries it passed is the step's ``extra``."""
         prog = self._tail_program(start) if start else self.prefill_program
-        if not self.extras:
+        if not self.state_parts:
             return Step(self._call(prog, ids, rows, true_len)[0], 1)
         if start_state is None:
-            start_state = self.zero_state
-        first, (snaps,) = self._call(prog, ids, rows, true_len,
-                                     (state_row, start_state))
-        return Step(first, 1, extra=snaps)
+            start_state = self.zero_states
+        first, snaps = self._call(prog, ids, rows, true_len,
+                                  (state_row, *start_state))
+        return Step(first, 1, extra=tuple(snaps))
 
     def pools_lost(self) -> bool:
         """Whether a call that raised had already consumed the pools: the
@@ -271,7 +279,7 @@ class Programs:
         def zeros(*shape):
             return _T(jnp.zeros(shape, jnp.int32))
 
-        hybrid = bool(self.extras)
+        hybrid = bool(self.state_parts)
         for b in buckets:
             self.decode(zeros(b, 1), [zeros(b, self.table_width(kv, True))
                                       for kv in self.kvs], zeros(b),
@@ -427,8 +435,8 @@ class Programs:
                     for k, (row, pl_, sc) in enumerate(kinds)])
             return body
 
-        if self.extras:
-            decode_body, prefill_body, tail_body = self._hybrid_bodies(
+        if self.state_parts:
+            decode_body, prefill_body, tail_body = self._state_bodies(
                 prefill_fn, step_fn, carry_of, pick_tok, first_out)
         else:
             decode_body = decode_kernel if self.path == "kernel" \
@@ -439,47 +447,69 @@ class Programs:
         self.prefill_program = self._program(
             "serving_prefill", prefill_body, "serving.prefill", "prefill")
 
-    def _hybrid_bodies(self, prefill_fn, step_fn, carry_of, pick_tok,
-                       first_out):
-        """The decode, prefill and tail bodies of a model with sparse
-        pages, compressed keys beside them and a state per slot (ISSUE 31):
-        one page pool, ``tail = (index pool, state pool, ...)``."""
-        from ..ops import sparse_attention as _sa
+    def _state_bodies(self, prefill_fn, step_fn, carry_of, pick_tok,
+                      first_out):
+        """The decode, prefill and tail bodies of a model with one page pool
+        (not quantized) and a state per slot (ISSUE 31, 33): ``tail =
+        (held..., a call's own)``, ``held`` every part of the state pool
+        and, before them, the compressed keys of a model whose pages are
+        sparse. One set of bodies: the model's caches carry the state as
+        ``states``, one Tensor a part, with or without an index pool."""
         kv = self.kvs[0]
         cfg = kv.config
         ps = cfg.page_size
         compute_dtype = jnp.dtype(cfg.compute_dtype)
-        index, state = self.extras
-        per = index.per_page
-        stride = ps // per
-        self.zero_state = _T(jnp.zeros(state.shape[1:], jnp.float32))
+        sparse = self.index is not None
+        n_parts = len(self.state_parts)
+        n_held = n_parts + int(sparse)
+        if sparse:
+            # only a model with sparse pages needs (and imports) this
+            from ..ops import sparse_attention as _sa
+            decode_cache, prefill_cache = _sa.HybridDecodeCache, \
+                _sa.HybridPrefill
+            per = self.index.per_page
+            stride = ps // per
+        else:
+            from ..ops.linear_attention import (
+                StateDecodeCache as decode_cache,
+                StatePrefill as prefill_cache)
+        self.zero_states = tuple(_T(jnp.zeros(p.shape[1:], jnp.float32))
+                                 for p in self.state_parts)
         unflatten, returns = self._unflatten, self._returns
         impl = "kernel" if self.path == "kernel" else "dense"
 
+        def tensors(arrays):
+            return tuple(_T(a) for a in arrays)
+
         def decode_body(*args):
-            tok_a, kinds, t_a, (index_a, state_a, carry_a, sel_a, rows_a) = \
-                unflatten(args, 5)
+            tok_a, kinds, t_a, tail = unflatten(args, n_held + 3)
+            held, (carry_a, sel_a, rows_a) = tail[:n_held], tail[n_held:]
             tb, pl_, _ = kinds[0]
-            view = _sa.HybridDecodeCache(
+            view = decode_cache(
                 pool=_T(pl_), tables=_T(tb), t=_T(t_a), page_size=ps,
                 impl=impl, interpret=self._interpret,
-                index_pool=_T(index_a), state=_T(state_a),
-                state_rows=_T(rows_a))
+                states=tensors(held[int(sparse):]), state_rows=_T(rows_a),
+                **({"index_pool": _T(held[0])} if sparse else {}))
             with no_grad():
                 nxt, view2 = first_out(step_fn(
                     _T(pick_tok(tok_a, carry_a, sel_a)), view, _T(t_a)))
                 # the step's writes, after its last layer: the token's K/V
-                # and the compressed keys it completed (the state rows were
-                # written by their layers, in place)
-                view2 = _sa.commit_index(_pa.commit_pending(view2))
+                # (and the compressed keys it completed); the state rows
+                # were written by their layers, in place
+                view2 = _pa.commit_pending(view2)
+                held2 = tuple(a._data for a in view2.states)
+                if sparse:
+                    view2 = _sa.commit_index(view2)
+                    held2 = (view2.index_pool._data,) + held2
             return returns(nxt, [(view2.pool._data, None)],
-                           (view2.index_pool._data, view2.state._data)
-                           + carry_of(nxt, tok_a.shape[0]))
+                           held2 + carry_of(nxt, tok_a.shape[0]))
 
         def prefill_at(start: int):
             def body(*args):
-                ids_a, kinds, len_a, (index_a, state_a, row_a, from_a) = \
-                    unflatten(args, 4)
+                ids_a, kinds, len_a, tail = unflatten(
+                    args, n_held + 1 + n_parts)
+                held, row_a, from_as = tail[:n_held], tail[n_held], \
+                    tail[n_held + 1:]
                 row, pl_, _ = kinds[0]
                 if start:
                     dense = _kv.gather_pages(pl_, None, row[None, :],
@@ -490,24 +520,28 @@ class Programs:
                                       compute_dtype)
                 with no_grad():
                     nxt, out = first_out(prefill_fn(
-                        _T(ids_a), _sa.HybridPrefill(kv=_T(dense),
-                                                     state=_T(from_a)),
+                        _T(ids_a), prefill_cache(kv=_T(dense),
+                                                 states=tensors(from_as)),
                         start))
                 pages = row[start // ps:]
                 pool2, _ = _kv.scatter_prefill_pages(
                     out.kv._data.astype(compute_dtype), pl_, None, pages,
                     len_a, ps, start=start)
-                # (L, H, M / stride, D) -> the tail pages' entries
-                ent = out.entries._data[:, :, start // stride:]
-                l_, h_, _, d_ = ent.shape
-                ent = ent.reshape(l_, h_, -1, per, d_).transpose(
-                    2, 0, 3, 1, 4).reshape(-1, l_, per * h_, d_)
-                index2 = index_a.at[pages].set(ent.astype(index_a.dtype))
-                state2 = jax.lax.dynamic_update_slice(
-                    state_a, out.state._data.astype(state_a.dtype)[None],
-                    (row_a.reshape(()),) + (0,) * (state_a.ndim - 1))
+                held2 = []
+                if sparse:
+                    # (L, H, M / stride, D) -> the tail pages' entries
+                    ent = out.entries._data[:, :, start // stride:]
+                    l_, h_, _, d_ = ent.shape
+                    ent = ent.reshape(l_, h_, -1, per, d_).transpose(
+                        2, 0, 3, 1, 4).reshape(-1, l_, per * h_, d_)
+                    held2.append(held[0].at[pages].set(
+                        ent.astype(held[0].dtype)))
+                for part, new in zip(held[int(sparse):], out.states):
+                    held2.append(jax.lax.dynamic_update_slice(
+                        part, new._data.astype(part.dtype)[None],
+                        (row_a.reshape(()),) + (0,) * (part.ndim - 1)))
                 return returns(nxt, [(pool2, None)],
-                               (index2, state2, out.snapshots._data))
+                               (*held2, *(x._data for x in out.snapshots)))
             return body
 
         return decode_body, prefill_at(0), prefill_at

@@ -250,11 +250,11 @@ def test_logits_through_pages_state_and_chosen_tables(model, tier):
         step = p.prefill(
             _i32(prompt[None, start:]), [_i32(kv.table_row(ids))],
             _i32(len(prompt)), start, _i32(row),
-            T(snaps[start // BLOCK - 1]) if start else None)
+            (T(snaps[start // BLOCK - 1]),) if start else None)
         (tok,), lg = step.read()
         got[n].append((len(prompt), lg.view(np.float32), None))
         if n == 0:
-            snaps = step.extra._data
+            (snaps,) = (part._data for part in step.extra)
             assert snaps.shape[0] == len(prompt) // BLOCK
         seen.append(np.append(prompt, tok))
         rows.append(row)
@@ -367,7 +367,7 @@ def test_a_slot_takes_one_state_row_and_a_failed_admission_returns_it(model):
     asks = [np.concatenate([doc, _ids(42 + i, 6)]) for i in range(2)]
     assert len(_serve(eng, asks[:1], 3)[0]) == 3
     assert eng.state.free_rows == eng.config.max_batch
-    eng.snapshots.get = lambda digest: None          # evicted meanwhile
+    eng.snapshots.get_parts = lambda digest: None    # evicted meanwhile
     fut = eng.submit(serving.GenerationRequest(prompt=asks[1],
                                                max_new_tokens=3))
     eng.run()
@@ -389,10 +389,10 @@ def test_snapshot_store_keeps_to_its_budget_least_recently_used_first_out():
     store = kvc.SnapshotStore(budget_bytes=64)
     before = dict(obs.snapshot()).get(
         "serving.state.snapshot_evictions_total", 0)
-    store.put(b"a", state)
-    store.put(b"b", state)
+    store.put_parts(b"a", (state,))
+    store.put_parts(b"b", (state,))
     assert store.get(b"a") is state                  # a is now the newest
-    store.put(b"c", state)                           # b goes
+    store.put_parts(b"c", (state,))                  # b goes
     assert store.get(b"b") is None and store.get(b"a") is state
     assert store.nbytes == 64 and len(store) == 2
     assert store.deepest([b"x", b"a", b"b", b"c"], 4) == 4

@@ -275,7 +275,7 @@ def _hybrid_decode_args(eng, bucket):
 def test_layout_donates_the_state_pool_and_the_compressed_keys(hybrid):
     eng = hybrid()
     p = eng.programs
-    assert [type(x).__name__ for x in p.extras] == ["IndexPool", "StatePool"]
+    assert [type(x).__name__ for x in p.extras] == ["IndexPool", "StatePart"]
     parts = [("tables", "pool", None)]
     flat = p._flatten("head", parts, "mid",
                       ("index", "state", "carry", "sel", "rows"))
@@ -289,25 +289,26 @@ def test_layout_donates_the_state_pool_and_the_compressed_keys(hybrid):
 
 def test_a_decode_step_adopts_the_state_pool_it_was_given_donated(hybrid):
     eng = hybrid()
-    pool0, index0, state0 = eng.kv.pool, eng.index.array, eng.state.array
+    part = eng.state.parts[0]
+    pool0, index0, state0 = eng.kv.pool, eng.index.array, part.array
     step = eng.programs.decode(*_hybrid_decode_args(eng, 3))
     assert step.read()[0].shape == (3,)
     assert pool0.is_deleted() and index0.is_deleted() \
         and state0.is_deleted()                       # consumed by the call
-    assert not eng.state.array.is_deleted() \
-        and eng.state.array.shape == state0.shape     # what it returned
+    assert not part.array.is_deleted() \
+        and part.array.shape == state0.shape          # what it returned
     assert not eng.index.array.is_deleted()
-    assert not eng.programs.zero_state._data.is_deleted()   # never donated
+    assert not eng.programs.zero_states[0]._data.is_deleted()   # never donated
     assert not eng.programs.pools_lost()
     # a prefill writes the slot's row of the state pool and keeps snapshots
     row = eng.state.alloc()
-    before = np.asarray(eng.state.array)
+    before = np.asarray(part.array)
     step = eng.programs.prefill(
         T(jnp.ones((1, 20), jnp.int32)),
         [T(jnp.asarray(eng.kv.table_row(eng.kv.alloc(6))))],
         T(jnp.asarray(20, jnp.int32)), 0, T(jnp.asarray(row, jnp.int32)))
-    assert step.extra.shape[0] == 20 // eng.config.state_snapshot_tokens
-    after = np.asarray(eng.state.array)
+    assert step.extra[0].shape[0] == 20 // eng.config.state_snapshot_tokens
+    after = np.asarray(part.array)
     assert np.abs(after[row]).max() > 0
     assert (np.delete(after, row, 0) == np.delete(before, row, 0)).all()
 
@@ -321,11 +322,12 @@ def test_a_consuming_call_that_raised_loses_the_state_too(hybrid):
     _consume_and_raise_on(eng, "decode_program", nth=1)
     with pytest.raises(RuntimeError, match="consumed"):
         eng.programs.decode(*_hybrid_decode_args(eng, 1))
-    assert eng.state.array.is_deleted() and eng.index.array.is_deleted()
+    part = eng.state.parts[0]
+    assert part.array.is_deleted() and eng.index.array.is_deleted()
     assert eng.programs.pools_lost()
     assert eng._restore_lost_pool(RuntimeError("x"))
     assert not eng.programs.pools_lost()
-    assert not np.asarray(eng.state.array).any()      # fresh states
+    assert not np.asarray(part.array).any()           # fresh states
     assert len(eng.snapshots) == 0                    # nothing to start from
     assert eng.state.free_rows == eng.config.max_batch
     # and the engine serves on: the document is prefilled in full again

@@ -253,6 +253,74 @@ def test_linear_state_kernel_compiles_for_the_chip_and_writes_in_place(
     assert not pool_copies(text, (rows, layers, h, d, d))
 
 
+def test_gated_delta_kernels_compile_for_the_chip_and_write_in_place(
+        one_chip):
+    """ISSUE 33: the delta-rule update and the convolution's tail at
+    ``longform-decode``'s shapes — 64 rows, 32 value heads of 128 x 128, 8192
+    channels laid out 64 x 128, six state layers."""
+    from paddle_tpu.ops import linear_attention as la
+    b, h, d, rows, layers, taps = 64, 32, 128, 9, 6, 4
+    f32 = jnp.float32
+    text = jax.jit(
+        lambda q, k, v, g, beta, pool, r: la.gated_delta_decode(
+            q, k, v, g, beta, pool, r, 3),
+        donate_argnums=(5,)).lower(
+        *[_spec(one_chip, (b, h, d), f32)] * 3,
+        *[_spec(one_chip, (b, h), f32)] * 2,
+        _spec(one_chip, (rows, layers, h, d, d), f32),
+        _spec(one_chip, (b,), jnp.int32)).compile().as_text()
+    assert "gated_delta_decode" in text and "tpu_custom_call" in text
+    assert not pool_copies(text, (rows, layers, h, d, d))
+    tail = (rows, layers, taps - 1, 64, 128)
+    text = jax.jit(
+        lambda x, w, pool, r: la.conv_tail_decode(x, w, pool, r, 3),
+        donate_argnums=(2,)).lower(
+        _spec(one_chip, (b, 64, 128), f32),
+        _spec(one_chip, (taps, 64, 128), f32), _spec(one_chip, tail, f32),
+        _spec(one_chip, (b,), jnp.int32)).compile().as_text()
+    assert "gated_delta_decode_conv" in text and "tpu_custom_call" in text
+    assert not pool_copies(text, tail)
+
+
+def test_qwen3_next_decode_program_compiles_with_no_copy_of_any_pool(
+        one_chip, monkeypatch):
+    """ISSUE 33: the decode program of a Gated DeltaNet and a gated
+    attention layer at the published widths (head_dim 256 on 2 KV heads,
+    32 value heads of 128 x 128, a router of 512) with 8 experts held:
+    both delta kernels, the paged kernel and the grouped matmuls are in it,
+    and neither the pages nor either part of the state is copied."""
+    from paddle_tpu.models.qwen3_next import (Qwen3NextConfig,
+                                              Qwen3NextForCausalLM)
+    monkeypatch.setattr(pa, "kernel_interpret", lambda: False)
+    paddle.seed(4)
+    cfg = Qwen3NextConfig(layers_run=(2, 3), experts_held=(0, 8),
+                          vocab_held=(0, 512), dtype="bfloat16")
+    model = Qwen3NextForCausalLM(cfg)
+    model.eval()
+    eng = serving.Engine(*model.serving_callables(512, block=128),
+                         serving.ServingConfig(
+        num_layers=2, num_heads=cfg.num_key_value_heads,
+        head_dim=cfg.head_dim, max_len=512, max_batch=8, buckets=(8,),
+        page_size=64, compute_dtype="bfloat16", kv_dtype="bf16",
+        layer_kinds=cfg.layer_kinds, state_shape=cfg.state_shapes,
+        state_snapshot_tokens=128, paged_attention="on"))
+    assert eng._paged_path == "kernel" and eng.index is None
+    paddle.set_flags({"FLAGS_to_static_capture_lowered": True})
+    try:
+        with pytest.raises(Exception, match="interpret mode"):
+            eng.programs.warm(buckets=[8])
+        text = _compiled_for_chip(eng.programs.decode_program,
+                                  one_chip).as_text()
+    finally:
+        paddle.set_flags({"FLAGS_to_static_capture_lowered": False})
+    for name in ("gated_delta_decode", "gated_delta_decode_conv",
+                 "paged_attention_decode", "ragged-dot"):
+        assert name in text, name
+    assert "sparse_attention_decode" not in text
+    for shape in [eng.kv.pool.shape] + [p.shape for p in eng.state.parts]:
+        assert pool_copies(text, shape) == 0, shape
+
+
 # ---------------------------------------------------------------------------
 # ISSUE 32: the int8 Adam update of a stacked matrix, at `train-4k`'s widths
 # with few rows (the tiles cover the last two dimensions; 256 rows a layer
