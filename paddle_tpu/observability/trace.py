@@ -123,6 +123,15 @@ share of steps launched ahead, without a trace;
 ``serving.decode_discarded_rows_total`` the rows computed for a request that
 had already ended (known only after the read, so not a span attribute).
 
+Beside them the decode program says which paged-attention kernel its layers
+took (ISSUE 35; ``observability`` registry): gauge
+``serving.paged_attention_row_walk_layers``, set as a decode program is
+traced (``ops/paged_attention.py::commit_pending``) — the attention layers
+of that program whose call took the grouped kernel that walks live rows and
+live pages only (8 query heads to a KV head and more: 4 for one period of
+Command A+, 1 per period of Qwen3-Next, 0 for Mistral at 4 to 1 and for
+MiniCPM-SALA, whose sparse layers run their own kernel).
+
 Beside them the state snapshots count (ISSUE 31; ``observability``
 registry): counters ``serving.state.snapshot_hits_total`` /
 ``serving.state.snapshot_misses_total`` (admissions whose prompt's first

@@ -8,16 +8,22 @@ dense ``(L, 2, B, H, max_len, D)`` cache inside the trace every step
 scaled with ``max_len``, not with the live context. This module makes the
 decode step's KV traffic O(live pages) reads + O(1) page writes:
 
-* **Streaming kernel** (:func:`paged_attention`): one program per
-  (batch row, q head); the grid's innermost dimension walks the slot's
+* **Streaming kernels** (:func:`paged_attention`). The per-head kernel
+  (:func:`_decode_kernel`; fewer than 8 query heads to a KV head) runs one
+  program per batch row; the grid's innermost dimension walks the slot's
   page-table row, and the ``PrefetchScalarGridSpec`` index maps resolve
-  each K/V block to ``pool[tables[b, s], layer, k/v, h // rep]`` — Pallas
+  each K/V block to ``pool[tables[b, s], layer, k/v]`` — Pallas
   double-buffers the page DMAs, and a repeated block index (the trailing
-  scratch-page entries of a short slot) skips the re-fetch, so HBM
-  traffic follows the LIVE page count. Online softmax (the
+  scratch-page entries of a short slot) skips the re-fetch, so its HBM
+  bytes follow the live page count while its grid steps follow ``rows x
+  table width``. The grouped kernel (:func:`_decode_kernel_grouped`; 8
+  heads to one and more) has no page axis and no page BlockSpec: the pool
+  stays where it lies, the grid is the bucket's rows, and each row loops
+  over its OWN live page groups with copies it starts itself — bytes AND
+  steps follow the live rows' live pages (ISSUE 35). Online softmax (the
   ``ops/flash_attention.py`` pattern) runs in fp32 VMEM scratch carried
-  across the page dimension; pages whose first position is ``>= t`` skip
-  compute entirely (``@pl.when``).
+  across the pages; pages whose first position is ``>= t`` skip compute
+  (``@pl.when``) or are never visited (grouped).
 * **In-kernel dequant**: the int8 leg multiplies each streamed page by
   its per-(page, layer, K/V, head) absmax scale — the exact grid
   ``serving/kv_cache.py::quantize_pages`` wrote — so the quantized pool
@@ -47,17 +53,29 @@ decode step's KV traffic O(live pages) reads + O(1) page writes:
   first page are masked. ``window=None`` is the kernel as it was.
 * **Many query heads to a KV head** (``rep >= 8``, ISSUE 27: 16 to 1)
   take :func:`_decode_kernel_grouped`: the heads of one KV head are the
-  rows of one MXU matmul against eight streamed pages a grid step, where
-  the per-head kernel makes ``rep`` VPU passes over each page (on a v5e at
-  32 rows x 8000 tokens: 2.3 ms a layer against 22.8). The threshold is
-  not a crossover: at 4 heads to 1 (16 rows, 7 layers in the page, a v5e)
-  the grouped kernel is faster too — 0.37 against 0.75 ms at 300-600
-  tokens, 0.38 against 1.90 at 1500-3000, int8 0.24 / 0.33 against 0.74 /
-  2.18 — at 4-30 times the rounding error (2e-3 bf16, 8e-3 int8, still
-  under the 2e-2 held to). It stays at 8 so that a model with fewer heads
-  to a KV head runs the program it ran before ISSUE 27; taking the
-  per-head kernel out is a change to those models' numbers, to be made
-  and measured on its own.
+  rows of one MXU matmul against eight pages at a time, where the per-head
+  kernel makes ``rep`` VPU passes over each page (on a v5e at 32 rows x
+  8000 tokens: 2.3 ms a layer against 22.8). **It walks live rows and live
+  pages only** (ISSUE 35): one grid step a batch row; inside it a loop over
+  the row's page groups — ``ceil((t - first * ps) / (8 * ps))`` trips,
+  ``first`` the window's first page — each trip waiting for the eight
+  page copies (``make_async_copy`` out of the pool in HBM, a page's K and V
+  of every KV head in one copy) that the trip before started into the other
+  half of a two-slot VMEM buffer; a row's last trip starts the next row's
+  first group. A padding row (``t = 0``, how the engine fills a bucket)
+  copies and multiplies nothing. Before, the grid was ``rows x table width
+  / 8`` and every step visited 16 page operands whether the row was live
+  and whether the pages lay below ``t``: with 25 of 64 rows live at ~4.5k
+  of 10,752 positions five steps in six streamed nothing, 0.70 us each
+  (PERF.md section 6, PR 35). The threshold is not a crossover: at 4 heads
+  to 1 (16 rows, 7 layers in the page, a v5e) the grouped kernel was
+  faster too — 0.37 against 0.75 ms at 300-600 tokens, 0.38 against 1.90
+  at 1500-3000, int8 0.24 / 0.33 against 0.74 / 2.18 — at 4-30 times the
+  rounding error (2e-3 bf16, 8e-3 int8, still under the 2e-2 held to). It
+  stays at 8 so that a model with fewer heads to a KV head runs the
+  program it ran before ISSUE 27; taking the per-head kernel out is a
+  change to those models' numbers, to be made and measured on its own
+  (ROADMAP A9).
 
 Tiering (the flash-SDPA / step-capture contract): the kernel is the TPU
 tier; off-TPU it runs under the Pallas interpreter when forced (tests)
@@ -108,25 +126,35 @@ def kernel_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-# K and V page blocks, double-buffered by the pipeline, must fit the
-# default 16 MiB scoped-VMEM window beside the kernel's fp32 temporaries
+# K and V page blocks, double-buffered, must fit the default 16 MiB
+# scoped-VMEM window beside the kernel's fp32 temporaries
 _VMEM_BLOCK_BUDGET = 12 * 2 ** 20
+
+# with this many query heads to a KV head (a whole fp32 sublane tile of
+# them) the heads of one KV head go through the MXU together, over this
+# many pages at a time
+_GROUPED_MIN_REP = 8
+_GROUP_PAGES = 8
 
 
 def kernel_eligible(page_size: int, head_dim: int, storage_dtype,
-                    num_kv_heads: int = 1) -> bool:
+                    num_kv_heads: int = 1, rep: int = 1) -> bool:
     """What the compiled (non-interpret) kernel needs. Mosaic on a TPU v5e
     (libtpu 0.0.34) took every shape tried — page sizes 8, 16, 24, 32, 64
     and 128, head dims 64, 128 and 256, fp32, bf16 and int8 pages, 8 and 32
     KV heads, with and without GQA — so the stated bounds are the edge of
     what was tried: ``page_size`` in whole 8-row sublane groups and
-    ``head_dim`` in whole 64-lane halves. The one hard limit is VMEM: a
-    page's K and V blocks — every KV head, double-buffered — must fit the
-    scoped-VMEM budget. Anything else stays on the per-layer dense tier —
-    correctness is never gated on tiling. ``chip_smoke.py`` re-checks the
-    serve leg's shapes on every run."""
+    ``head_dim`` in whole 64-lane halves. The one hard limit is VMEM: what
+    a kernel holds of the pool — a page's K and V of every KV head, in two
+    buffers; ``_GROUP_PAGES`` such pages at ``rep`` query heads to a KV head
+    from ``_GROUPED_MIN_REP`` up, where the kernel copies a group of pages
+    at a time — must fit the scoped-VMEM budget. Anything else stays on the
+    per-layer dense tier — correctness is never gated on tiling.
+    ``chip_smoke.py`` re-checks the serve leg's shapes on every run."""
     block_bytes = (4 * num_kv_heads * page_size * head_dim
                    * jnp.dtype(storage_dtype).itemsize)
+    if rep >= _GROUPED_MIN_REP:
+        block_bytes *= _GROUP_PAGES
     return (page_size % 8 == 0 and head_dim % 64 == 0
             and block_bytes <= _VMEM_BLOCK_BUDGET)
 
@@ -195,6 +223,9 @@ class PagedDecodeCache:
       own) and ``layer_kinds[i] = (kind, layer within the kind's pool)``;
       :meth:`at_layer` then puts layer ``i``'s kind into ``pool`` /
       ``tables`` / ``scales`` / ``window``
+    * ``row_walk_layers`` — of the layers decoded so far, those whose call
+      took the row-walking grouped kernel (:func:`commit_pending` files the
+      count as the gauge ``serving.paged_attention_row_walk_layers``)
     """
 
     pool: object
@@ -209,6 +240,7 @@ class PagedDecodeCache:
     window: Optional[int] = None
     kinds: tuple = ()
     layer_kinds: tuple = ()
+    row_walk_layers: int = 0
 
     def at_layer(self, layer) -> "PagedDecodeCache":
         if not self.kinds:
@@ -343,27 +375,30 @@ def _decode_kernel(tables_ref, t_ref, layer_ref, q_ref, kn_ref, vn_ref,
         jax.lax.fori_loop(0, num_kv_heads, head, 0)
 
 
-# with this many query heads to a KV head (a whole fp32 sublane tile of
-# them) the heads of one KV head go through the MXU together, over this
-# many pages a grid step
-_GROUPED_MIN_REP = 8
-_GROUP_PAGES = 8
-
-
 def _decode_kernel_grouped(tables_ref, t_ref, layer_ref, q_ref, kn_ref,
-                           vn_ref, *rest, page_size: int, num_steps: int,
-                           num_kv_heads: int, rep: int, quantized: bool,
-                           group: int, sm_scale: float, exact: bool,
-                           window: Optional[int] = None):
+                           vn_ref, pool_ref, *rest, page_size: int,
+                           num_groups: int, num_kv_heads: int,
+                           quantized: bool, group: int, sm_scale: float,
+                           exact: bool, window: Optional[int] = None):
     """:func:`_decode_kernel` for many query heads to a KV head (ISSUE 27:
     16 to 1): the ``rep`` query heads that share KV head ``h`` are the rows
-    of ONE ``(rep, D) x (D, group * ps)`` matmul against ``group`` streamed
-    pages, and of one ``(rep, group * ps) x (group * ps, D)`` for the
-    weighted V, in place of ``rep`` VPU multiply-and-reduce passes over
-    each page. A grid step streams ``group`` consecutive table entries
-    (each its own block: pages are not contiguous in the pool), so the
-    grid is ``group`` times shorter. Same online softmax, same fold of
-    position ``t``.
+    of ONE ``(rep, D) x (D, group * ps)`` matmul against ``group`` pages,
+    and of one ``(rep, group * ps) x (group * ps, D)`` for the weighted V,
+    in place of ``rep`` VPU multiply-and-reduce passes over each page. Same
+    online softmax, same fold of position ``t``.
+
+    The walk (ISSUE 35): one grid step a batch row, the pool left in HBM,
+    and a loop INSIDE the kernel over the row's own page groups —
+    ``ceil((t - first * ps) / (group * ps))`` of them, ``first`` the
+    window's first page (0 for a full layer) — so a padding row (``t = 0``)
+    copies and multiplies nothing and a live row stops at its own ``t``,
+    not at the table's width. A trip waits for its group's page copies
+    (one a page: a page's K and V of every KV head lie side by side in the
+    pool) in one half of a two-slot VMEM buffer, having started the next
+    group's into the other half; a row's last trip — or a row without
+    trips — starts the NEXT row's first group, so of a whole call only the
+    first copy is waited for with nothing to do. Every row, a padding row
+    too, then folds in position ``t`` and writes its output.
 
     Precision: a bf16 or int8 pool's values, and a bf16 model's queries,
     are exact in bf16, so ``q . k`` takes ONE bf16 pass of the MXU and is
@@ -373,55 +408,107 @@ def _decode_kernel_grouped(tables_ref, t_ref, layer_ref, q_ref, kn_ref,
     precision instead.
 
     Refs: q/out ``(1, H_kv, rep, D)`` fp32 (q NOT pre-scaled); kn/vn
-    ``(1, H_kv, 1, D)``; then ``group`` K blocks, ``group`` V blocks
-    ``(1, 1, 1, H_kv, ps, D)`` and (int8) ``group`` scale blocks. Scratch:
-    m/l ``(H_kv, rep, 1)``, acc ``(H_kv, rep, D)``."""
+    ``(1, H_kv, 1, D)``; the pool ``(P, L, 2, H_kv, ps, D)`` whole, where
+    it lies; (int8) the scales of the row's table, ``(1, S, 2, H_kv)``.
+    Scratch: the page buffer ``(2, group, 2, H_kv, ps, D)`` with a DMA
+    semaphore a slot, the slot this row's first group is in (SMEM: it
+    outlives the grid step), m/l ``(H_kv, rep, 1)``, acc
+    ``(H_kv, rep, D)``."""
     rest = list(rest)
-    k_refs = [rest.pop(0) for _ in range(group)]
-    v_refs = [rest.pop(0) for _ in range(group)]
-    sc_refs = [rest.pop(0) for _ in range(group)] if quantized else None
-    o_ref, m_ref, l_ref, acc_ref = rest
+    sc_ref = rest.pop(0) if quantized else None
+    o_ref, buf, sem, slot_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
-    s = pl.program_id(1)
     ps = page_size
     f32 = jnp.float32
     precision = jax.lax.Precision.HIGHEST if exact \
         else jax.lax.Precision.DEFAULT
+    layer = layer_ref[0]
 
-    @pl.when(s == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def walk(row):
+        """Row ``row``'s position, first page and count of page groups."""
+        t = t_ref[row]
+        first = 0 if window is None else window_first_page(t, window, ps)
+        return t, first, jnp.minimum(pl.cdiv(t - first * ps, group * ps),
+                                     num_groups)
 
-    t = t_ref[b]
-    first = 0 if window is None else window_first_page(t, window, ps)
-    start = (first + s * group) * ps         # of this step's first page
+    def copies(row, g, slot):
+        """Group ``g`` of row ``row`` into ``slot``: a copy a page."""
+        done = sem.at[slot]
+        return [pltpu.make_async_copy(
+            pool_ref.at[tables_ref[row, g * group + j], layer],
+            buf.at[slot, j], done) for j in range(group)]
 
-    @pl.when(start < t)                      # a live page: stream the group
-    def _stream():
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, group * ps), 1)
+    def start(row, g, slot):
+        for c in copies(row, g, slot):
+            c.start()
+
+    t, first, n = walk(b)
+
+    @pl.when(b == 0)                         # nobody started row 0's
+    def _first_row():
+        slot_ref[0] = 0
+
+        @pl.when(n > 0)
+        def _():
+            start(0, 0, 0)
+
+    slot0 = slot_ref[0]                      # where this row's group 0 is
+    slot_ref[0] = (slot0 + n) % 2            # ... and the next row's
+
+    def start_next_row():
+        @pl.when(b + 1 < pl.num_programs(0))
+        def _():
+            @pl.when(walk(b + 1)[2] > 0)
+            def _():
+                start(b + 1, 0, (slot0 + n) % 2)
+
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(n == 0)
+    def _no_trip():
+        start_next_row()
+
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, num_kv_heads), 1)
+
+    def trip(g, carry):
+        slot = (slot0 + g) % 2
+
+        @pl.when(g + 1 < n)
+        def _():
+            start(b, g + 1, 1 - slot)
+
+        @pl.when(g + 1 == n)
+        def _():
+            start_next_row()
+
+        for c in copies(b, g, slot):
+            c.wait()
+        begin = (first + g * group) * ps     # of this group's first page
+        pos = begin + jax.lax.broadcasted_iota(jnp.int32, (1, group * ps), 1)
         live = pos < t
         if window is not None:
             live = jnp.logical_and(live, pos > t - window)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, num_kv_heads), 1)
 
-        def tile(refs, h):
-            """Head h's rows of the group's pages, (group * ps, D) fp32."""
-            parts = [ref[0, 0, 0, h].astype(f32) for ref in refs]
-            return jnp.concatenate(parts, axis=0) if group > 1 else parts[0]
+        def tile(kv, h):
+            """Head h's rows of the group's pages, (group * ps, D) fp32:
+            one load across the pages (every access to a ref costs the
+            trace as much as an operation: PERF.md section 6, PR 35)."""
+            return buf[slot, :, kv, h].astype(f32).reshape(group * ps, -1)
 
         def scale_row(h, row):
             """Head h's absmax scales of the group's pages, one a page,
             along the logits' columns: (1, group * ps)."""
             parts = [jnp.broadcast_to(jnp.sum(
-                jnp.where(lane == h, ref[0, 0, row:row + 1, :], 0.0),
-                axis=1, keepdims=True), (1, ps)) for ref in sc_refs]
-            return jnp.concatenate(parts, axis=1) if group > 1 else parts[0]
+                jnp.where(lane == h,
+                          sc_ref[0, g * group + j, row:row + 1, :], 0.0),
+                axis=1, keepdims=True), (1, ps)) for j in range(group)]
+            return jnp.concatenate(parts, axis=1)
 
         for h in range(num_kv_heads):        # unrolled: the heads overlap
             logits = jax.lax.dot_general(
-                q_ref[0, h], tile(k_refs, h), (((1,), (1,)), ((), ())),
+                q_ref[0, h], tile(0, h), (((1,), (1,)), ((), ())),
                 precision=precision,
                 preferred_element_type=f32) * sm_scale        # (rep, G*ps)
             if quantized:
@@ -436,24 +523,94 @@ def _decode_kernel_grouped(tables_ref, t_ref, layer_ref, q_ref, kn_ref,
             if quantized:
                 p = p * scale_row(h, 1)
             acc_ref[h] = alpha * acc_ref[h] + jnp.dot(
-                p, tile(v_refs, h), precision=precision,
+                p, tile(1, h), precision=precision,
                 preferred_element_type=f32)                   # (rep, D)
             m_ref[h] = m_new
+        return carry
 
-    @pl.when(s == num_steps - 1)             # fold in position t, emit
-    def _finish():
-        for h in range(num_kv_heads):
-            logit_t = jnp.sum(q_ref[0, h] * kn_ref[0, h], axis=1,
-                              keepdims=True) * sm_scale       # (rep, 1)
-            m_prev = m_ref[h]
-            m_new = jnp.maximum(m_prev, logit_t)
-            p_t = jnp.exp(logit_t - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_fin = alpha * l_ref[h] + p_t
-            acc = alpha * acc_ref[h] + p_t * vn_ref[0, h]
-            o_ref[0, h] = acc / jnp.maximum(l_fin, 1e-30)
+    jax.lax.fori_loop(0, n, trip, 0)
+
+    # fold in position t and emit, every KV head at once
+    logit_t = jnp.sum(q_ref[0] * kn_ref[0], axis=2,
+                      keepdims=True) * sm_scale               # (H_kv, rep, 1)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, logit_t)
+    p_t = jnp.exp(logit_t - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_fin = alpha * l_ref[...] + p_t
+    acc = alpha * acc_ref[...] + p_t * vn_ref[0]
+    o_ref[0] = acc / jnp.maximum(l_fin, 1e-30)
 
 
+def _grouped_call(q, k_new, v_new, pool, scales, tables, t, layer,
+                  page_size: int, interpret: bool, window: Optional[int]):
+    """:func:`_kernel_call` at ``rep >= _GROUPED_MIN_REP``: one grid step a
+    batch row, the pool handed over where it lies."""
+    b, h, d = q.shape
+    h_kv = pool.shape[3]
+    ps, group = page_size, _GROUP_PAGES
+    tables = tables.astype(jnp.int32)
+    if tables.shape[1] % group:              # whole groups: pad with the
+        tables = jnp.pad(                    # scratch page
+            tables, ((0, 0), (0, -tables.shape[1] % group)))
+    s = tables.shape[1]
+    quantized = scales is not None
+    qo = (h_kv, h // h_kv, d)                # a KV head's query heads: rows
+    f32 = jnp.float32
+    layer = jnp.asarray(layer, jnp.int32)
+    kern = functools.partial(
+        _decode_kernel_grouped, page_size=ps, num_groups=s // group,
+        num_kv_heads=h_kv, quantized=quantized, group=group,
+        sm_scale=1.0 / float(d) ** 0.5, exact=pool.dtype == jnp.float32,
+        window=window)
+
+    def row_map(bi, tabs, tt, lr):
+        return (bi, 0, 0, 0)
+
+    in_specs = [pl.BlockSpec((1,) + qo, row_map),
+                pl.BlockSpec((1, h_kv, 1, d), row_map),
+                pl.BlockSpec((1, h_kv, 1, d), row_map),
+                pl.BlockSpec(memory_space=pl.ANY)]
+    inputs = [q.astype(f32).reshape((b,) + qo),
+              k_new.astype(f32).reshape(b, h_kv, 1, d),
+              v_new.astype(f32).reshape(b, h_kv, 1, d), pool]
+    if quantized:
+        # the scales of a row's table, gathered here: Mosaic takes no copy
+        # of one page's (2, H_kv) out of the scales where they lie (a slice
+        # of the minor dimension, under its 128-lane tile)
+        n_p, n_l = scales.shape[:2]
+        in_specs.append(pl.BlockSpec((1, s, 2, h_kv), row_map))
+        inputs.append(jnp.take(scales.reshape(n_p * n_l, 2, h_kv),
+                               tables * n_l + layer, axis=0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(b,), in_specs=in_specs,
+        out_specs=pl.BlockSpec((1,) + qo, row_map),
+        scratch_shapes=[
+            pltpu.VMEM((2, group, 2, h_kv, ps, d), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),     # the slot of the row's group 0
+            pltpu.VMEM(qo[:2] + (1,), f32),  # running max
+            pltpu.VMEM(qo[:2] + (1,), f32),  # running denominator
+            pltpu.VMEM(qo, f32),             # weighted-V accumulator
+        ])
+    out = pl.pallas_call(
+        kern, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b,) + qo, f32),
+        # a row starts the next row's first copy: the rows run in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_attention_decode",
+    )(tables, t.astype(jnp.int32), layer.reshape(1), *inputs)
+    return out.reshape(b, h, d).astype(q.dtype)
+
+
+# jitted so that a decode program traces a kernel's body once for all its
+# layers of one shape and window, not once a layer: on a TPU host a kernel's
+# trace is seconds of every run's set-up, cached or not (PERF.md section 6,
+# PR 35), and the layer is an operand, not part of the trace
+@functools.partial(jax.jit,
+                   static_argnames=("page_size", "interpret", "window"))
 def _kernel_call(q, k_new, v_new, pool, scales, tables, t, layer,
                  page_size: int, interpret: bool,
                  window: Optional[int] = None):
@@ -464,77 +621,60 @@ def _kernel_call(q, k_new, v_new, pool, scales, tables, t, layer,
     b, h, d = q.shape
     h_kv = pool.shape[3]
     rep = h // h_kv
+    if rep >= _GROUPED_MIN_REP:
+        return _grouped_call(q, k_new, v_new, pool, scales, tables, t, layer,
+                             page_size, interpret, window)
     s = tables.shape[1]
     ps = page_size
     quantized = scales is not None
-    # q and out: one block per batch row, the per-head access on a MAJOR
-    # dimension — (H, 1, D) a head, or (H_kv, rep, D) a KV head's group
-    grouped = rep >= _GROUPED_MIN_REP
-    group = _GROUP_PAGES if grouped else 1
-    if s % group:                            # whole groups: pad with the
-        tables = jnp.pad(tables, ((0, 0), (0, -s % group)))  # scratch page
-        s = tables.shape[1]
-    steps = s // group
-    qo = (h_kv, rep, d) if grouped else (h, 1, d)
-    if grouped:
-        kern = functools.partial(
-            _decode_kernel_grouped, page_size=ps, num_steps=steps,
-            num_kv_heads=h_kv, rep=rep, quantized=quantized, group=group,
-            sm_scale=1.0 / float(d) ** 0.5,
-            exact=pool.dtype == jnp.float32, window=window)
-    else:
-        kern = functools.partial(
-            _decode_kernel, page_size=ps, num_pages=s, num_kv_heads=h_kv,
-            rep=rep, quantized=quantized, window=window)
+    kern = functools.partial(
+        _decode_kernel, page_size=ps, num_pages=s, num_kv_heads=h_kv,
+        rep=rep, quantized=quantized, window=window)
 
     def row_map(bi, si, tabs, tt, lr):
         return (bi, 0, 0, 0)
 
-    def col(si, j):                          # the table column of block j
-        return si if group == 1 else si * group + j
-
-    def page_map(kv, j=0):
+    def page_map(kv):
         def f(bi, si, tabs, tt, lr):
-            return (tabs[bi, col(si, j)], lr[0], kv, 0, 0, 0)
+            return (tabs[bi, si], lr[0], kv, 0, 0, 0)
         return f
 
-    def scale_map(j=0):
-        def f(bi, si, tabs, tt, lr):
-            return (tabs[bi, col(si, j)], lr[0], 0, 0)
-        return f
+    def scale_map(bi, si, tabs, tt, lr):
+        return (tabs[bi, si], lr[0], 0, 0)
 
-    # every block's trailing two dims are the array's own, which is what
-    # the Pallas TPU lowering accepts below the (8, 128) tile
+    # q and out: one block per batch row, the per-head access on a MAJOR
+    # dimension — (H, 1, D) a head. Every block's trailing two dims are the
+    # array's own, which is what the Pallas TPU lowering accepts below the
+    # (8, 128) tile
     in_specs = [
-        pl.BlockSpec((1,) + qo, row_map),
+        pl.BlockSpec((1, h, 1, d), row_map),
         pl.BlockSpec((1, h_kv, 1, d), row_map),
         pl.BlockSpec((1, h_kv, 1, d), row_map),
-    ] + [pl.BlockSpec((1, 1, 1, h_kv, ps, d), page_map(kv, j))
-         for kv in (0, 1) for j in range(group)]
+        pl.BlockSpec((1, 1, 1, h_kv, ps, d), page_map(0)),
+        pl.BlockSpec((1, 1, 1, h_kv, ps, d), page_map(1)),
+    ]
     f32 = jnp.float32
-    q32 = q.astype(f32) if grouped else q.astype(f32) * (1.0 / float(d) ** 0.5)
-    inputs = [q32.reshape((b,) + qo),
+    inputs = [(q.astype(f32) * (1.0 / float(d) ** 0.5)).reshape(b, h, 1, d),
               k_new.astype(f32).reshape(b, h_kv, 1, d),
-              v_new.astype(f32).reshape(b, h_kv, 1, d)] + [pool] * (2 * group)
+              v_new.astype(f32).reshape(b, h_kv, 1, d), pool, pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1, 2, h_kv), scale_map(j))
-                     for j in range(group)]
-        inputs += [scales] * group
+        in_specs.append(pl.BlockSpec((1, 1, 2, h_kv), scale_map))
+        inputs.append(scales)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, steps),
+        grid=(b, s),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1,) + qo, row_map),
+        out_specs=pl.BlockSpec((1, h, 1, d), row_map),
         scratch_shapes=[
-            pltpu.VMEM(qo[:2] + (1,), f32),    # running max
-            pltpu.VMEM(qo[:2] + (1,), f32),    # running denominator
-            pltpu.VMEM(qo, f32),               # weighted-V accumulator
+            pltpu.VMEM((h, 1, 1), f32),      # running max
+            pltpu.VMEM((h, 1, 1), f32),      # running denominator
+            pltpu.VMEM((h, 1, d), f32),      # weighted-V accumulator
         ],
     )
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     out = pl.pallas_call(
         kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b,) + qo, f32),
+        out_shape=jax.ShapeDtypeStruct((b, h, 1, d), f32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
@@ -595,13 +735,24 @@ def paged_attention(q, k_new, v_new, pool, scales, tables, t, layer, *,
     streaming kernel or the per-layer dense tier; the compiled TPU kernel
     additionally requires :func:`kernel_eligible` tiling (interpret mode
     has no tiling constraints)."""
-    if impl == "kernel" and (interpret or kernel_eligible(
-            page_size, int(pool.shape[-1]), pool.dtype,
-            int(pool.shape[3]))):
+    if _kernel_for(q.shape[1], pool, page_size, impl, interpret):
         return _kernel_call(q, k_new, v_new, pool, scales, tables, t,
                             layer, page_size, interpret, window)
     return paged_attention_dense(q, k_new, v_new, pool, scales, tables, t,
                                  layer, page_size, window)
+
+
+def _kernel_for(heads: int, pool, page_size: int, impl: str,
+                interpret: bool) -> Optional[str]:
+    """Which kernel a layer's call with ``heads`` query heads takes:
+    ``"rows"`` (the grouped kernel's walk of live rows and live pages),
+    ``"heads"`` (:func:`_decode_kernel`) or None (the dense tier)."""
+    h_kv, d = int(pool.shape[3]), int(pool.shape[-1])
+    rep = heads // h_kv
+    if impl != "kernel" or not (interpret or kernel_eligible(
+            page_size, d, pool.dtype, h_kv, rep)):
+        return None
+    return "rows" if rep >= _GROUPED_MIN_REP else "heads"
 
 
 # ---------------------------------------------------------------------------
@@ -704,7 +855,10 @@ def paged_decode_attention(q, k_new, v_new, cache: PagedDecodeCache):
             layer_t] + ([cache.scales] if quantized else [])
     out = apply("paged_attention_decode", f, *args, differentiable=False,
                 amp=False)
-    return out, replace(cache, pending=cache.pending + ((k_new, v_new),))
+    walked = _kernel_for(int(q.shape[1]), cache.pool, ps, impl,
+                         interpret) == "rows"
+    return out, replace(cache, pending=cache.pending + ((k_new, v_new),),
+                        row_walk_layers=cache.row_walk_layers + walked)
 
 
 def commit_pending(cache: PagedDecodeCache) -> PagedDecodeCache:
@@ -714,7 +868,11 @@ def commit_pending(cache: PagedDecodeCache) -> PagedDecodeCache:
     pool, so a handle with pages by layer kind makes one per kind. Called
     where the handle's owner takes the pool back (the serving engine,
     after ``step_fn``), so no model has to remember it."""
+    from .. import observability as _obs
     from ..core.tensor import apply
+    # set as the decode program is traced: which kernel its layers took
+    _obs.set_gauge("serving.paged_attention_row_walk_layers",
+                   cache.row_walk_layers)
     n = cache.pending_layers
     total = sum(int(k.pool.shape[1]) for k in cache.kinds) \
         if cache.kinds else int(cache.pool.shape[1])

@@ -385,9 +385,11 @@ def test_paged_decode_attention_window_reads_the_compact_table(impl):
 @pytest.mark.parametrize("window", [None, 16])
 def test_grouped_decode_kernel_agrees_with_the_dense_tier(kv, window):
     """16 query heads to a KV head take the grouped kernel (the heads of a
-    KV head as rows of one matmul, 8 pages a grid step); 7 or 4 table
-    columns are not a whole group, so the table is padded with the scratch
-    page. Against the per-layer dense tier on the same pool."""
+    KV head as rows of one matmul, 8 pages a trip of the row's own loop);
+    7 or 4 table columns are not a whole group, so the table is padded
+    with the scratch page. Against the per-layer dense tier on the same
+    pool (``tests/test_paged_row_walk.py``: the walk's edges at the serving
+    cells' shapes)."""
     assert 16 >= pa._GROUPED_MIN_REP
     rng = np.random.default_rng(5)
     scales = None
@@ -408,57 +410,3 @@ def test_grouped_decode_kernel_agrees_with_the_dense_tier(kv, window):
     got = np.asarray(pa.paged_attention(*args, impl="kernel", **kw))
     want = np.asarray(pa.paged_attention(*args, impl="dense", **kw))
     assert np.abs(got - want).max() < 1e-5
-
-
-# -- the chip's compiler: no pool-shaped copy, per kind -----------------------
-
-@pytest.fixture(scope="module")
-def one_chip():
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
-
-
-def test_decode_program_holds_no_copy_of_either_pool(one_chip, monkeypatch):
-    """``tests/test_tpu_compile.py``'s check, with pages by layer kind at
-    the published attention geometry (128 query heads on 8 KV heads of 128,
-    pages of 64, window 4096): the compiled decode program writes each
-    kind's pool in place."""
-    from chip_smoke import pool_copies
-    from test_tpu_compile import _compiled_for_chip
-    paddle.seed(12)
-    cfg = Cohere2MoeConfig(
-        vocab_size=256, hidden_size=128, intermediate_size=128,
-        num_hidden_layers=4, num_experts=8, num_experts_per_tok=2,
-        num_shared_experts=1, experts_held=(0, 2), dtype="bfloat16",
-        max_position_embeddings=8192)
-    m = Cohere2MoeForCausalLM(cfg)
-    m.eval()
-    monkeypatch.setattr(pa, "kernel_interpret", lambda: False)
-    pf, sf = m.serving_callables(8192)
-    eng = serving.Engine(pf, sf, serving.ServingConfig(
-        num_layers=4, num_heads=8, head_dim=128, max_len=8192, max_batch=4,
-        buckets=(4,), page_size=64, compute_dtype="bfloat16",
-        kv_dtype="bf16", layer_kinds=cfg.layer_kinds, window=4096,
-        paged_attention="on"))
-    assert eng._paged_path == "kernel"
-    paddle.set_flags({"FLAGS_to_static_capture_lowered": True})
-    try:
-        with pytest.raises(Exception, match="interpret mode"):
-            eng.programs.warm(buckets=[4])
-        compiled = _compiled_for_chip(eng.programs.decode_program, one_chip)
-    finally:
-        paddle.set_flags({"FLAGS_to_static_capture_lowered": False})
-    text = compiled.as_text()
-    assert text.count("paged_attention_decode") >= 4
-    for kv in eng.kvs:
-        assert pool_copies(text, kv.pool.shape) == 0, kv.config.kind
-    pools = sum(int(np.prod(kv.pool.shape)) * 2 for kv in eng.kvs)
-    assert compiled.memory_analysis().temp_size_in_bytes < pools // 4
-    # the window pool's table is the compact one: 66 columns, not 128
-    assert eng.programs.table_width(eng.kvs[1], True) == 66

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 import paddle_tpu as paddle
+from paddle_tpu import observability as obs
 from paddle_tpu import serving
 from paddle_tpu.core.tensor import Tensor as T
 from paddle_tpu.ops import paged_attention as pa
@@ -305,20 +306,154 @@ def test_qwen3_next_decode_program_compiles_with_no_copy_of_any_pool(
         layer_kinds=cfg.layer_kinds, state_shape=cfg.state_shapes,
         state_snapshot_tokens=128, paged_attention="on"))
     assert eng._paged_path == "kernel" and eng.index is None
+    obs.enable()
     paddle.set_flags({"FLAGS_to_static_capture_lowered": True})
     try:
         with pytest.raises(Exception, match="interpret mode"):
             eng.programs.warm(buckets=[8])
+        # ISSUE 35: 8 query heads to a KV head take the row-walking kernel
+        assert obs.snapshot()["serving.paged_attention_row_walk_layers"] == 1
         text = _compiled_for_chip(eng.programs.decode_program,
                                   one_chip).as_text()
     finally:
         paddle.set_flags({"FLAGS_to_static_capture_lowered": False})
+        obs.disable()
     for name in ("gated_delta_decode", "gated_delta_decode_conv",
                  "paged_attention_decode", "ragged-dot"):
         assert name in text, name
     assert "sparse_attention_decode" not in text
     for shape in [eng.kv.pool.shape] + [p.shape for p in eng.state.parts]:
         assert pool_copies(text, shape) == 0, shape
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 35: the grouped paged-decode kernel walks a row's own page groups with
+# copies of its own out of the pool where it lies. Its three calls in the
+# serving cells, at their real shapes: Command A+'s full layer and window
+# layer (32 rows, 128 query heads on 8 KV heads of 128), Qwen3-Next's (64
+# rows, 16 on 2 of 256); a bf16 pool as the cells hold, int8 and float32 as
+# the tests do. The page buffer (2 x 8 pages of K and V) is 4 / 2 / 8 MiB.
+# ---------------------------------------------------------------------------
+
+ROW_WALK_CALLS = {
+    # rows, KV heads, query heads to one, head_dim, layers a page, pages,
+    # table columns, window
+    "command-a-full": (32, 8, 16, 128, 1, 6401, 200, None),
+    "command-a-window": (32, 8, 16, 128, 3, 2113, 66, 4096),
+    "qwen3-next": (64, 2, 8, 256, 2, 10753, 168, None),
+}
+
+
+def _row_walk_call(spec, call, kv):
+    """The kernel call of ``ROW_WALK_CALLS[call]`` over a ``kv`` pool and
+    its arguments' shapes, each made by ``spec(shape, dtype)``."""
+    b, h_kv, rep, d, layers, pages, cols, window = ROW_WALK_CALLS[call]
+    pool = (pages, layers, 2, h_kv, PAGE, d)
+    bf = jnp.bfloat16
+    args = [spec((b, h_kv * rep, d), bf), spec((b, h_kv, d), bf),
+            spec((b, h_kv, d), bf), spec(pool, jnp.dtype(kv)),
+            spec((b, cols), jnp.int32), spec((b,), jnp.int32),
+            spec((), jnp.int32)]
+    if kv == "int8":
+        args.append(spec(pool[:4], jnp.float32))
+
+    def call_(q, kn, vn, pool, tables, t, layer, scales=None):
+        return pa._kernel_call(q, kn, vn, pool, scales, tables, t, layer,
+                               PAGE, False, window)
+
+    return call_, args, pool
+
+
+@pytest.mark.parametrize("kv", ["bfloat16", "int8", "float32"])
+@pytest.mark.parametrize("call", sorted(ROW_WALK_CALLS))
+def test_row_walk_kernel_compiles_at_the_cells_shapes(one_chip, call, kv):
+    fn, args, pool = _row_walk_call(
+        lambda shape, dt: _spec(one_chip, shape, dt), call, kv)
+    rep = ROW_WALK_CALLS[call][2]
+    assert pa.kernel_eligible(PAGE, pool[-1], jnp.dtype(kv), pool[3], rep)
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert "paged_attention_decode" in text and "tpu_custom_call" in text
+    # the kernel takes the pool where it lies: no copy of it, nothing of its
+    # size beside it
+    assert not pool_copies(text, pool)
+    pool_bytes = int(np.prod(pool)) * jnp.dtype(kv).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+
+
+def test_row_walk_kernel_lowers_to_the_same_text_in_every_process():
+    """What the compile cache's key is made from does not change from run
+    to run: the Command A+ full-layer call, lowered for the TPU in two
+    fresh processes under different hash seeds, is the same text (the
+    Mosaic kernel's serialized body is inside it). Lowering for a platform
+    needs no device and no TPU library, so the children load none."""
+    import subprocess
+    child = (
+        "import hashlib, sys; import jax, jax.numpy as jnp\n"
+        "sys.path[:0] = [%r, %r]\n"
+        "from test_tpu_compile import _row_walk_call\n"
+        "fn, args, _ = _row_walk_call(jax.ShapeDtypeStruct, "
+        "'command-a-full', 'bfloat16')\n"
+        "text = jax.jit(fn).trace(*args).lower("
+        "lowering_platforms=('tpu',)).as_text()\n"
+        "assert 'tpu_custom_call' in text\n"
+        "print(hashlib.sha256(text.encode()).hexdigest(), len(text))\n"
+    ) % (os.path.dirname(os.path.abspath(__file__)),
+         os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    said = []
+    for hash_seed in ("1", "2"):
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONHASHSEED=hash_seed)
+        run = subprocess.run([sys.executable, "-c", child], env=env,
+                             capture_output=True, text=True, timeout=300)
+        assert run.returncode == 0, run.stderr[-2000:]
+        said.append(run.stdout.strip().splitlines()[-1])
+    assert said[0] == said[1], said
+
+
+def test_command_a_plus_decode_program_walks_rows_with_no_copy_of_a_pool(
+        one_chip, monkeypatch):
+    """ISSUE 35: the decode program of one period of Command A+ at the
+    published attention geometry (128 query heads on 8 KV heads of 128,
+    pages of 64, window 4096) with pages by layer kind: all four layers take
+    the row-walking kernel — three on the window kind's compact table, one
+    on the full kind's — and neither kind's pool is copied."""
+    from paddle_tpu.models.cohere2_moe import (Cohere2MoeConfig,
+                                               Cohere2MoeForCausalLM)
+    monkeypatch.setattr(pa, "kernel_interpret", lambda: False)
+    paddle.seed(12)
+    cfg = Cohere2MoeConfig(
+        vocab_size=256, hidden_size=128, intermediate_size=128,
+        num_hidden_layers=4, num_experts=8, num_experts_per_tok=2,
+        num_shared_experts=1, experts_held=(0, 2), dtype="bfloat16",
+        max_position_embeddings=8192)
+    model = Cohere2MoeForCausalLM(cfg)
+    model.eval()
+    eng = serving.Engine(*model.serving_callables(8192),
+                         serving.ServingConfig(
+        num_layers=4, num_heads=8, head_dim=128, max_len=8192, max_batch=8,
+        buckets=(8,), page_size=PAGE, compute_dtype="bfloat16",
+        kv_dtype="bf16", layer_kinds=cfg.layer_kinds, window=4096,
+        paged_attention="on"))
+    assert eng._paged_path == "kernel" and len(eng.kvs) == 2
+    obs.enable()
+    paddle.set_flags({"FLAGS_to_static_capture_lowered": True})
+    try:
+        with pytest.raises(Exception, match="interpret mode"):
+            eng.programs.warm(buckets=[8])
+        walked = obs.snapshot()["serving.paged_attention_row_walk_layers"]
+        compiled = _compiled_for_chip(eng.programs.decode_program, one_chip)
+    finally:
+        paddle.set_flags({"FLAGS_to_static_capture_lowered": False})
+        obs.disable()
+    assert walked == 4
+    text = compiled.as_text()
+    assert text.count("paged_attention_decode") >= 4
+    for kv in eng.kvs:
+        assert pool_copies(text, kv.pool.shape) == 0, kv.config.kind
+    pools = sum(int(np.prod(kv.pool.shape)) * 2 for kv in eng.kvs)
+    assert compiled.memory_analysis().temp_size_in_bytes < pools // 4
+    # the window pool's table is the compact one: 66 columns, not 128
+    assert eng.programs.table_width(eng.kvs[1], True) == 66
 
 
 # ---------------------------------------------------------------------------
