@@ -131,6 +131,7 @@ class StaticFunction:
                  iters_per_call: int = 1, donate_argnums=()):
         functools.update_wrapper(self, fn)
         self._fn = fn
+        self._name = getattr(fn, "__name__", "program")
         self._donate = donate_states
         # positional arguments the CALLER gives up beside the state (the
         # serving engine's page pool): every array under them is donated,
@@ -410,7 +411,12 @@ class StaticFunction:
                 _own_buffers(state_arrays, range(len(state_arrays)), seen)
         holder["traced"] = False
         try:
-            with _trace.phase("jit.dispatch"):
+            # ``program`` names the jit.trace / jit.lower / jit.compile
+            # written under this span (observability/compile_events.py). On
+            # every call, not only a fresh build: one entry's jax.jit
+            # compiles anew for each input shape
+            with _trace.phase("jit.dispatch",
+                              program=self.cost_label or self._name):
                 out_arrays, new_state, mut_vals = jitted(state_arrays,
                                                          arg_arrays)
         except Exception as e:

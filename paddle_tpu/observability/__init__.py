@@ -111,6 +111,16 @@ _CACHE_BYPASS = _REGISTRY.counter(
     labelnames=("reason",))
 
 
+# what to_static counts, said where a scrape shows it: one per cache entry.
+# An entry's jax.jit compiles again for every new input shape (the serving
+# engine's batch buckets and prompt lengths); those are counted from jax's
+# own events (compile_events: jit.compile_seconds_total, the jit.* spans)
+_REGISTRY.counter(
+    "jit.compiles_total",
+    "fresh StaticFunction builds whose first call succeeded: one per cache "
+    "entry, NOT one per XLA compile")
+
+
 def _dispatch_hook(op_name: str, t0: float, t1: float) -> None:
     """Installed into ``core.tensor._op_metrics_hook`` while enabled."""
     _DISPATCH_OPS.inc()
@@ -141,6 +151,10 @@ def enable() -> None:
         from ..core import dispatch_cache as _dcache_mod
         _tensor_mod._op_metrics_hook = _dispatch_hook
         _dcache_mod._obs_hook = _cache_hook
+        # jax's trace / lower / compile events as spans and counters: one
+        # listener for the life of the process, gated by enabled()
+        from . import compile_events
+        compile_events.install()
     # compile-time cost capture rides the same switch (its own is-None
     # hooks in to_static/dispatch_cache; no-op under PADDLE_TPU_COST=off)
     from . import cost

@@ -36,6 +36,9 @@ span events additionally carry ``trace``/``span``/``parent`` ids):
   ring (as the per-op events are not), so the per-step phases of the
   serving loop and the compiled call cannot churn the post-mortem's tail
   and mode ``flight`` keeps what it kept before them.
+  :func:`phase_done` is the same tier for work that is only known once it
+  is over (ISSUE 36): it writes the begin and the end of a span that ended
+  now and began ``seconds`` ago, in one call, with no profiler mirror.
 * **The flight recorder** — an ALWAYS-ON lock-free ring of the last N
   events (``PADDLE_TPU_FLIGHT_EVENTS``, default 512): lifecycle instants,
   injected/real fault events, watchdog trips, NaN skips, restores. On an
@@ -112,10 +115,45 @@ span, mode ``on`` only)::
                               prefix pages fetches the state kept at that
                               boundary (``bytes``: all its parts), to start
                               its tail prefill from
+    serving.warmup            Engine.warmup's body (``replica``,
+                              ``programs`` = buckets + lengths + tails);
+                              holds one jit.call a program it compiles
     jit.call                * StaticFunction: one whole compiled call
-      jit.dispatch          * the jitted function alone; ``jit.call``'s self
-                              time is hooks + registry walk + key + rebind
+      jit.dispatch          * the jitted function alone (``program`` = the
+                              function's ``cost_label``, else its name);
+                              ``jit.call``'s self time is hooks + registry
+                              walk + key + rebind
+        jit.trace           * (ISSUE 36; these three are written when they
+        jit.lower           *  END, by ``phase_done``, from jax's own
+        jit.compile         *  monitoring events: ``compile_events.py``) the
+                              trace to a jaxpr, the lowering to an MLIR
+                              module, and the backend compile — an XLA
+                              compile or a load from the persistent cache,
+                              whichever the call paid. Whose program: the
+                              ``program`` of the ``jit.dispatch`` begin that
+                              ``parent`` names; ``fun`` is jax's name for
+                              what was traced; on ``jit.compile`` also
+                              ``cache_hit`` (1 loaded, 0 compiled and
+                              written, absent: the cache was not asked) and
+                              ``load_s``. Only events of a millisecond and
+                              more. A jitted function traced inside a trace
+                              (a kernel body, a ``jax.numpy`` helper) is a
+                              child by TIME, a sibling by ``parent``: sum a
+                              phase as the union of its intervals. They
+                              appear wherever jax compiles: under
+                              ``serving.warmup``, under a ``serving.prefill``
+                              that met a new tail in mid-traffic, under
+                              ``train.captured_step``
     train.step / train.captured_step   the supervisor's / CapturedStep's
+
+Beside them set-up counts, tracing on or off (ISSUE 36; ``observability``
+registry, fed by the same listener once ``observability.enable()`` has run):
+``jit.compile_seconds_total{phase="trace"|"lower"|"compile"}`` (nested events
+counted once, so the three sum to wall time),
+``jit.persistent_cache_hits_total`` and ``jit.persistent_cache_misses_total``
+(both on the scrape at 0 from ``enable()``). ``jit.compiles_total`` stays
+what it was: fresh ``StaticFunction`` builds, one per cache entry, not one
+per XLA compile.
 
 Beside them the decode pipe counts (ISSUE 28; ``observability`` registry):
 ``serving.decode_ahead_steps_total`` against ``serving.steps_total`` is the
@@ -181,8 +219,8 @@ from typing import Any, Dict, List, NamedTuple, Optional
 
 __all__ = [
     "SpanContext", "FlightRecorder",
-    "span", "phase", "instant", "phase_instant", "record", "new_trace",
-    "current",
+    "span", "phase", "phase_done", "instant", "phase_instant", "record",
+    "new_trace", "current",
     "mode", "enabled", "set_mode", "tracing",
     "events", "clear", "dropped", "make_event", "span_problems",
     "export_chrome", "trace_dir",
@@ -450,6 +488,32 @@ def phase(name: str, parent: Optional[SpanContext] = None, **attrs):
     if _MODE != "on":
         return _NOOP
     return _Span(name, parent, attrs, ring=False)
+
+
+def phase_done(name: str, seconds: float, **attrs) -> None:
+    """A span of the detail tier that has ALREADY run: it ended now and
+    began ``seconds`` ago. For work whose only record is a duration handed
+    over at its end — jax reports a trace, a lowering and a compile that
+    way. Mode ``on`` only, buffer only (the phase tier's rule), a child of
+    the innermost open span of this thread (track 0 when none is open).
+    Begin and end are written in this one call, so pairing stays
+    structural; the begin's ``ts`` lies before events already in the
+    buffer (its children by time containment). No profiler mirror: an
+    annotation cannot be backdated."""
+    if _MODE != "on":
+        return
+    end = time.perf_counter()
+    buf = _STATE.buffer
+    if len(buf) + 2 > _BUFFER_CAP:      # both events or neither
+        _STATE.dropped += 2
+        return
+    tr, par = current() or (0, 0)
+    sid = next(_IDS)
+    buf.append({"ts": end - seconds, "kind": "B", "name": name,
+                "attrs": attrs, "trace": tr, "span": sid, "parent": par,
+                "thread": threading.get_ident()})
+    buf.append({"ts": end, "kind": "E", "name": name, "attrs": {},
+                "trace": tr, "span": sid})
 
 
 def new_trace(label: str, **attrs) -> Optional[SpanContext]:

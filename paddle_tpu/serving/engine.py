@@ -617,7 +617,10 @@ class Engine:
         admissions) up front, against the scratch page only — admission
         then never recompiles mid-flight. Idempotent; call before serving
         traffic."""
-        self.programs.warm(self.config.buckets, prompt_lens, tails)
+        with _trace.span("serving.warmup", replica=self.config.name
+                         or "engine", programs=len(self.config.buckets)
+                         + len(prompt_lens) + len(tails)):
+            self.programs.warm(self.config.buckets, prompt_lens, tails)
         return self
 
     # ROADMAP C11's debt: perfbench's ``_warm_tails`` reaches in with the

@@ -254,6 +254,126 @@ class TestJitCounters:
         assert snap["jit.cache_hits_total"] >= 1
 
 
+_TRACE_EV = "/jax/core/compile/jaxpr_trace_duration"
+_COMPILE_EV = "/jax/core/compile/backend_compile_duration"
+_CACHE_EV = "/jax/compilation_cache/"
+
+
+def _compile_seconds():
+    return obs.snapshot().get("jit.compile_seconds_total", {})
+
+
+class TestCompileEvents:
+    """ISSUE 36: jax's own trace / lower / compile events as counters (and,
+    in trace mode ``on``, as spans) — one listener, behind obs.enable()."""
+
+    def test_phases_grow_when_jax_compiles_and_only_then(self):
+        obs.enable()
+
+        @paddle.jit.to_static
+        def f(a):
+            return a * 2.0 + 1.0
+
+        x = paddle.to_tensor(np.ones((4,), np.float32))
+        f(x)
+        first = _compile_seconds()
+        assert set(first) == {"phase=trace", "phase=lower", "phase=compile"}
+        assert all(v > 0 for v in first.values())
+        f(x)
+        f(x)
+        assert _compile_seconds() == first
+        # a new shape under the same entry: jax compiles, jit.compiles_total
+        # (fresh StaticFunction builds) does not move
+        f(paddle.to_tensor(np.ones((8,), np.float32)))
+        grown = _compile_seconds()
+        assert all(grown[k] > first[k] for k in first)
+        snap = obs.snapshot()
+        assert snap["jit.compiles_total"] == 1
+        assert "NOT one per XLA compile" in \
+            obs.default_registry().get("jit.compiles_total").help
+        assert 'jit_compile_seconds_total{phase="lower"}' in \
+            obs.prometheus_text()
+
+    def test_nested_events_are_counted_once(self):
+        import jax.monitoring
+        from paddle_tpu.observability import compile_events
+        obs.enable()
+        # what this thread's earlier compiles covered is not this test's
+        compile_events._TLS.__dict__.clear()
+        # a kernel body traced inside a program's trace ends first
+        jax.monitoring.record_event_duration_secs(_TRACE_EV, 0.2)
+        jax.monitoring.record_event_duration_secs(_TRACE_EV, 0.1)
+        jax.monitoring.record_event_duration_secs(_TRACE_EV, 0.5)
+        assert _compile_seconds()["phase=trace"] == pytest.approx(0.5,
+                                                                  abs=5e-3)
+        # the next program's trace overlaps nothing
+        import time
+        time.sleep(0.02)
+        jax.monitoring.record_event_duration_secs(_TRACE_EV, 0.01)
+        assert _compile_seconds()["phase=trace"] == pytest.approx(0.51,
+                                                                  abs=5e-3)
+
+    def test_cache_counters_read_zero_before_any_compile(self):
+        obs.enable()
+        snap = obs.snapshot()
+        assert snap["jit.persistent_cache_hits_total"] == 0
+        assert snap["jit.persistent_cache_misses_total"] == 0
+
+    @pytest.mark.parametrize("event,hit", [("cache_hits", 1),
+                                           ("cache_misses", 0),
+                                           (None, None)])
+    def test_cache_event_inside_a_compile(self, tracing, event, hit):
+        """Driven with jax's own calls, as ``compiler.compile_or_get_cached``
+        makes them: no cache directory, nothing the CPU backend must admit."""
+        import jax.monitoring
+        obs.enable()
+        with tracing.span("jit.dispatch", program="r0.decode") as asked:
+            if event:
+                jax.monitoring.record_event(_CACHE_EV + event)
+            if hit:
+                jax.monitoring.record_event_duration_secs(
+                    _CACHE_EV + "compile_time_saved_sec", 9.0)
+                jax.monitoring.record_event_duration_secs(
+                    _CACHE_EV + "cache_retrieval_time_sec", 0.125)
+            jax.monitoring.record_event_duration_secs(
+                _COMPILE_EV, 0.5, fun_name="jit(pure_fn)")
+            # the next compile on this thread met no cache
+            jax.monitoring.record_event_duration_secs(
+                _COMPILE_EV, 0.25, fun_name="jit(other)")
+        assert tracing.span_problems() == []
+        first, second = [e for e in tracing.events()
+                         if e["kind"] == "B" and e["name"] == "jit.compile"]
+        # whose compile: the dispatch that ``parent`` names
+        assert first["parent"] == second["parent"] == asked.ctx.span
+        want = {"fun": "jit(pure_fn)"}
+        if hit is not None:
+            want["cache_hit"] = hit
+        if hit:
+            want["load_s"] = 0.125
+        assert first["attrs"] == want
+        assert second["attrs"] == {"fun": "jit(other)"}
+        snap = obs.snapshot()
+        assert snap["jit.persistent_cache_hits_total"] == (1 if hit else 0)
+        assert snap["jit.persistent_cache_misses_total"] == (
+            1 if hit == 0 else 0)
+
+    def test_disabled_listener_counts_nothing(self):
+        import jax.monitoring
+        obs.enable()                  # registers; stays registered
+        obs.disable()
+        obs.reset()
+        jax.monitoring.record_event(_CACHE_EV + "cache_misses")
+        jax.monitoring.record_event_duration_secs(_COMPILE_EV, 0.5)
+        snap = obs.snapshot()
+        assert "jit.compile_seconds_total" not in snap
+        assert "jit.persistent_cache_misses_total" not in snap
+        # and enabling twice registered one listener, not two
+        obs.enable()
+        obs.enable()
+        jax.monitoring.record_event(_CACHE_EV + "cache_misses")
+        assert obs.snapshot()["jit.persistent_cache_misses_total"] == 1
+
+
 # ---------------------------------------------------------------------------
 # exporters
 # ---------------------------------------------------------------------------

@@ -913,3 +913,221 @@ class TestEnvelopeUnification:
         assert trace.span_problems(evs) == []
         names = {e["name"] for e in evs if e["kind"] == "B"}
         assert {"hapi.fit", "hapi.train_batch"} <= names
+
+
+# ---------------------------------------------------------------------------
+# set-up names itself (ISSUE 36): completed phases, jax's trace / lower /
+# compile as spans of the program, the warm-up as a span around them
+# ---------------------------------------------------------------------------
+
+class TestCompletedPhase:
+    def test_pair_is_balanced_and_parented_to_the_open_span(self, tracing):
+        with trace.span("serving.warmup") as warm:
+            trace.phase_done("jit.trace", 0.25, program="r0.decode")
+        evs = trace.events()
+        assert trace.span_problems(evs) == []
+        b, e = [x for x in evs if x["name"] == "jit.trace"]
+        assert (b["kind"], e["kind"]) == ("B", "E")
+        assert (b["trace"], b["parent"]) == tuple(warm.ctx)
+        assert (e["trace"], e["span"]) == (b["trace"], b["span"])
+        assert e["ts"] - b["ts"] == pytest.approx(0.25)
+        assert b["attrs"] == {"program": "r0.decode"}
+        # _Span's envelope, key for key
+        outer = next(x for x in evs if x["name"] == "serving.warmup")
+        assert set(b) == set(outer)
+        # it ended inside the span that was open, and never saw the ring
+        assert e["ts"] <= evs[-1]["ts"] and evs[-1]["kind"] == "E"
+        assert {x["name"] for x in trace.flight_recorder().snapshot()} == {
+            "serving.warmup"}
+        # a reader of the Chrome export sees one complete event
+        doc = trace.export_chrome(evs=evs)
+        done, = [x for x in doc["traceEvents"] if x["name"] == "jit.trace"]
+        assert done["ph"] == "X" and done["dur"] == pytest.approx(250e3)
+
+    def test_with_no_span_open_it_rides_track_zero(self, tracing):
+        trace.phase_done("jit.compile", 0.5)
+        b, e = trace.events()
+        assert (b["trace"], b["parent"]) == (0, 0)
+        assert trace.span_problems() == []
+
+    def test_at_the_buffer_cap_both_events_are_dropped(self, tracing,
+                                                       monkeypatch):
+        with trace.span("serving.warmup"):
+            monkeypatch.setattr(trace, "_BUFFER_CAP", len(trace.events()) + 1)
+            trace.phase_done("jit.trace", 0.25)
+            assert trace.dropped() == 2
+            monkeypatch.setattr(trace, "_BUFFER_CAP", 500_000)
+        assert trace.span_problems() == []
+        assert "jit.trace" not in {e["name"] for e in trace.events()}
+
+    @pytest.mark.parametrize("m", ["off", "flight"])
+    def test_off_and_flight_write_nothing(self, m):
+        trace.clear()
+        trace.flight_recorder().clear()
+        with trace.tracing(m):
+            with trace.span("serving.warmup"):
+                trace.phase_done("jit.trace", 0.25, program="r0.decode")
+        assert trace.events() == []
+        assert "jit.trace" not in {
+            x["name"] for x in trace.flight_recorder().snapshot()}
+        trace.flight_recorder().clear()
+
+    def test_parent_is_this_threads_innermost_open_span(self, tracing):
+        """Whose work a finished phase is reads off ``parent``: the span
+        open on the thread that reports it, never another thread's."""
+        with trace.span("jit.dispatch", program="outer") as outer:
+            with trace.phase("jit.dispatch", program="inner") as inner:
+                trace.phase_done("jit.trace", 0.01, fun="f")
+            trace.phase_done("jit.lower", 0.01, fun="f")
+            th = threading.Thread(
+                target=lambda: trace.phase_done("jit.compile", 0.01, fun="f"))
+            th.start()
+            th.join(timeout=10)
+        parent = {e["name"]: (e["trace"], e["parent"]) for e in trace.events()
+                  if e["kind"] == "B" and e["name"] != "jit.dispatch"}
+        assert parent == {"jit.trace": tuple(inner.ctx),
+                          "jit.lower": tuple(outer.ctx),
+                          "jit.compile": (0, 0)}
+        assert trace.span_problems() == []
+
+
+_JIT_PHASES = ("jit.trace", "jit.lower", "jit.compile")
+
+
+@pytest.fixture()
+def every_event_a_span(monkeypatch):
+    """The CPU traces a toy function in well under the millisecond below
+    which an event feeds the counters only."""
+    from paddle_tpu.observability import compile_events
+    monkeypatch.setattr(compile_events, "MIN_SPAN_S", 0.0)
+
+
+def _jit_phases(evs, since=0):
+    """name -> (begin event, program) of the jit.* spans written after
+    buffer index ``since``; the program is the one its parent names where
+    that is a jit.dispatch, None for what jax compiled for eager code."""
+    begins = {e["span"]: e for e in evs if e["kind"] == "B"}
+    out = {}
+    for e in evs[since:]:
+        if e["kind"] == "B" and e["name"] in _JIT_PHASES:
+            asked = begins.get(e["parent"], {"name": None})
+            out.setdefault(e["name"], []).append(
+                (e, asked["attrs"]["program"]
+                 if asked["name"] == "jit.dispatch" else None))
+    return out
+
+
+class TestSetupSpans:
+    @pytest.mark.parametrize("label", ["r0.decode", None])
+    def test_first_call_and_new_shape_name_their_three_phases(
+            self, tracing, metrics, every_event_a_span, label):
+        @paddle.jit.to_static
+        def triple(x):
+            return x * 3.0
+
+        triple.cost_label = label
+        program = label or "triple"
+
+        def call(n):
+            at = len(trace.events())
+            out = triple(paddle.to_tensor(np.ones((n,), np.float32)))
+            assert out.numpy().tolist() == [3.0] * n
+            return _jit_phases(trace.events(), at)
+
+        first = call(4)
+        assert set(first) == set(_JIT_PHASES)
+        # the program's own lowering and compile, once each; its trace may
+        # hold traces of jitted helpers, children by time containment
+        assert len(first["jit.lower"]) == len(first["jit.compile"]) == 1
+        for evs in first.values():
+            assert {prog for _, prog in evs} == {program}
+        own = [e for e, _ in first["jit.trace"]
+               if e["attrs"]["fun"] == "pure_fn"]
+        assert len(own) == 1
+        assert "cache_hit" not in first["jit.compile"][0][0]["attrs"]
+        # same shape: jax's own cache, no event; a new shape under the SAME
+        # StaticFunction entry: all three again (how buckets arrive)
+        assert call(4) == {}
+        assert len(triple.program_cache) == 1
+        again = call(8)
+        assert set(again) == set(_JIT_PHASES)
+        assert len(triple.program_cache) == 1
+        assert {prog for _, prog in again["jit.compile"]} == {program}
+        assert trace.span_problems() == []
+
+    def test_events_under_a_millisecond_feed_the_counters_only(
+            self, tracing, metrics, monkeypatch):
+        from paddle_tpu.observability import compile_events
+        monkeypatch.setattr(compile_events, "MIN_SPAN_S", 3600.0)
+
+        @paddle.jit.to_static
+        def triple(x):
+            return x * 3.0
+
+        triple(paddle.to_tensor(np.ones((4,), np.float32)))
+        assert _jit_phases(trace.events()) == {}
+        assert set(metrics.snapshot()["jit.compile_seconds_total"]) == {
+            "phase=trace", "phase=lower", "phase=compile"}
+
+    def test_listener_is_silent_without_obs_enable(self, tracing,
+                                                   every_event_a_span):
+        from paddle_tpu import observability as obs
+        assert not obs.enabled()
+
+        @paddle.jit.to_static
+        def triple(x):
+            return x * 3.0
+
+        triple(paddle.to_tensor(np.ones((4,), np.float32)))
+        assert _jit_phases(trace.events()) == {}
+
+    @pytest.mark.parametrize("lens", [(), (8, 5)])
+    def test_warmup_holds_one_compiled_call_per_bucket_and_length(
+            self, tracing, metrics, lens):
+        eng = make_engine(max_batch=4)          # buckets (1, 4)
+        eng.warmup(prompt_lens=lens)
+        evs = trace.events()
+        assert trace.span_problems(evs) == []
+        begins, ends, kids = _span_table(evs)
+        warm, = [b for b in begins.values() if b["name"] == "serving.warmup"]
+        assert warm["attrs"]["programs"] == 2 + len(lens)
+        calls = [k for k in kids[warm["span"]] if k["name"] == "jit.call"]
+        asked = []
+        for call in calls:
+            disp, = kids[call["span"]]
+            asked.append(disp["attrs"]["program"])
+            # what jax did for this program lies inside its dispatch
+            phases = kids.get(disp["span"], [])
+            assert {k["name"] for k in phases} == set(_JIT_PHASES)
+            for k in phases:
+                assert disp["ts"] <= k["ts"] <= ends[k["span"]]["ts"] \
+                    <= ends[disp["span"]]["ts"]
+        assert asked == ["engine.decode"] * 2 + ["engine.prefill"] * len(lens)
+        # the lifecycle span reaches the flight ring, the compiled call's
+        # phases and jax's events never do
+        ring = {e["name"] for e in trace.flight_recorder().snapshot()}
+        assert "serving.warmup" in ring
+        assert not {n for n in ring if n.startswith("jit.")}
+
+    def test_warmup_names_a_tail_program(self, tracing, metrics,
+                                         tier_engine):
+        eng = tier_engine("dense", buckets=(2,), max_batch=2)
+        eng.warmup(prompt_lens=(8,), tails=((16, 8),))
+        evs = trace.events()
+        assert trace.span_problems(evs) == []
+        begins, ends, _ = _span_table(evs)
+        warm, = [b for b in begins.values() if b["name"] == "serving.warmup"]
+        assert warm["attrs"]["programs"] == 3
+        programs = [b["attrs"]["program"] for b in begins.values()
+                    if b["name"] == "jit.dispatch"]
+        assert programs == ["engine.decode", "engine.prefill",
+                            "engine.prefill_tail16"]
+        # each compiled under its own name, inside the warm-up (a tiny
+        # Llama's compile is well over a millisecond)
+        compiles = _jit_phases(evs)["jit.compile"]
+        assert {prog for _, prog in compiles} >= set(programs)
+        for e, prog in compiles:
+            if prog is None:                # the engine's build, eagerly
+                continue
+            assert warm["ts"] <= e["ts"] <= ends[e["span"]]["ts"] \
+                <= ends[warm["span"]]["ts"]
