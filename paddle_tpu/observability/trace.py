@@ -168,7 +168,11 @@ traced (``ops/paged_attention.py::commit_pending``) — the attention layers
 of that program whose call took the grouped kernel that walks live rows and
 live pages only (8 query heads to a KV head and more: 4 for one period of
 Command A+, 1 per period of Qwen3-Next, 0 for Mistral at 4 to 1 and for
-MiniCPM-SALA, whose sparse layers run their own kernel).
+MiniCPM-SALA, whose sparse layers run their own kernel). Beside it, set at
+the same point (ISSUE 37): gauge ``serving.sparse_attention_row_walk_layers``
+— the sparse layers of that program whose call took the kernel that walks a
+live row's chosen pages only and not the dense tier (2 for the MiniCPM-SALA
+stage the benchmark runs, 0 for a model without sparse pages).
 
 Beside them the state snapshots count (ISSUE 31; ``observability``
 registry): counters ``serving.state.snapshot_hits_total`` /

@@ -873,6 +873,9 @@ def commit_pending(cache: PagedDecodeCache) -> PagedDecodeCache:
     # set as the decode program is traced: which kernel its layers took
     _obs.set_gauge("serving.paged_attention_row_walk_layers",
                    cache.row_walk_layers)
+    # ... and its sparse layers (a sparse_attention.HybridDecodeCache's count)
+    _obs.set_gauge("serving.sparse_attention_row_walk_layers",
+                   getattr(cache, "sparse_walk_layers", 0))
     n = cache.pending_layers
     total = sum(int(k.pool.shape[1]) for k in cache.kinds) \
         if cache.kinds else int(cache.pool.shape[1])

@@ -27,14 +27,22 @@ the mask does the choosing. There is no kernel of this repo's in it.
 
 Decode (:func:`sparse_decode_attention`) scores the row's compressed keys,
 chooses, and hands a ``(B, H_kv, topk)`` table of PHYSICAL pages to a Pallas
-kernel that streams those pages only — ``sparse_attention_decode`` in a
-device trace: ``paged_attention._decode_kernel_grouped`` with a table per
-(row, KV head) where that one has a table per row. Rows at or below
-``dense_len`` go through ``paged_attention`` over their first ``dense_len /
-page`` pages instead, under a ``lax.cond`` that costs nothing when no row
-is that short. The compressed key a decoded token completes is computed
-from the pool's last pages and written, with the token's K and V, after the
-last layer (:func:`commit_index`).
+kernel that copies those pages only — ``sparse_attention_decode`` in a
+device trace. Its work follows what is live (ISSUE 37): one grid step a row
+of the bucket, the pool handed over once where it lies in HBM, and inside a
+loop over each KV head's own list of chosen pages, a group of pages a trip
+through a two-slot VMEM buffer — the walk of
+``paged_attention._decode_kernel_grouped``, whose list is a range of the
+row's table where this one's is what the head chose; a padding row copies
+nothing. The gather of the compressed keys before it still visits every
+column of every row's table, but a column its row does not use reads an
+entry of its own, not the scratch page's that every such column names
+(:func:`_unused_entry`). Rows at or below ``dense_len`` go through
+``paged_attention`` over their first ``dense_len / page`` pages instead,
+under a ``lax.cond`` that costs nothing when no row is that short. The
+compressed key a decoded token completes is computed from the pool's last
+pages and written, with the token's K and V, after the last layer
+(:func:`commit_index`).
 """
 
 from __future__ import annotations
@@ -58,9 +66,11 @@ __all__ = ["SparseConfig", "HybridDecodeCache", "HybridPrefill", "compress_keys"
            "sparse_paged_attention_dense"]
 
 _BIG = 1e30
-# pages a grid step of the decode kernel streams for one (row, KV head): a
-# step costs about a microsecond whatever it moves, and a row past
-# dense_len has topk = 64 pages a head
+# pages a trip of the decode kernel's walk copies for one (row, KV head): a
+# row past dense_len has topk = 64 pages a head, two trips. Alone on a v5e at
+# repo-agent-64k's shapes (7 live rows of 32) a layer's call took 0.131 /
+# 0.085 / 0.066 / 0.059 ms at 4 / 8 / 16 / 32 pages a trip (PERF.md section
+# 6, PR 37): a trip's fixed cost, not the first group's wait, is what counts
 _GROUP_PAGES = 32
 
 
@@ -260,6 +270,10 @@ class HybridDecodeCache(PagedDecodeCache):
       row held and the pages it attended, each counted per KV head, and
       the logical blocks chosen (-1: none); :func:`pages_counted` sums the
       first over a step
+    * ``sparse_walk_layers`` — of the sparse layers decoded so far, those
+      whose call took the kernel that walks live rows' chosen pages and not
+      the dense tier (``paged_attention.commit_pending`` files the count as
+      the gauge ``serving.sparse_attention_row_walk_layers``)
     """
 
     index_pool: object = None
@@ -268,6 +282,7 @@ class HybridDecodeCache(PagedDecodeCache):
     sparse: Optional[SparseConfig] = None
     pending_index: tuple = ()
     chose: tuple = ()
+    sparse_walk_layers: int = 0
 
 
 @dataclass
@@ -288,126 +303,216 @@ class HybridPrefill:
     snapshots: Optional[tuple] = None
 
 
-def _sparse_decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, kn_ref,
-                          vn_ref, *rest, page_size: int, num_steps: int,
+def _sparse_decode_kernel(tables_ref, lens_ref, counts_ref, layer_ref, q_ref,
+                          kn_ref, vn_ref, pool_ref, o_ref, buf, sem, slot_ref,
+                          m_ref, l_ref, acc_ref, *, page_size: int,
                           num_kv_heads: int, group: int, sm_scale: float,
                           exact: bool):
-    """One (batch row, KV head); grid dim 2 streams that head's table row,
-    ``group`` pages a step. ``lens[b * H_kv + h, c]`` is how many leading
-    positions of table column ``c``'s page the row attends: the page size
-    for a whole page, less for the page being written, 0 for a column that
-    names no page. Refs: q/out ``(1, 1, rep, D)`` float32 (q NOT scaled),
-    kn/vn ``(1, 1, 1, D)``, then ``group`` K blocks and ``group`` V blocks
-    ``(1, 1, 1, 1, ps, D)``. Scratch: m/l ``(rep, 1)``, acc ``(rep, D)``.
-    Precision as ``_decode_kernel_grouped``."""
-    rest = list(rest)
-    k_refs = [rest.pop(0) for _ in range(group)]
-    v_refs = [rest.pop(0) for _ in range(group)]
-    o_ref, m_ref, l_ref, acc_ref = rest
-    row = pl.program_id(0) * num_kv_heads + pl.program_id(1)
-    s = pl.program_id(2)
-    ps = page_size
+    """One batch row a grid step; inside, a loop over the row's KV heads'
+    own lists of chosen pages, ``group`` pages a trip, copied out of the
+    pool where it lies in HBM (ISSUE 37: the walk of
+    ``paged_attention._decode_kernel_grouped``, whose list here is a head's
+    chosen pages and not a range of the row's table).
+
+    ``lens[b * H_kv + h, c]`` is how many leading positions of table column
+    ``c``'s page the row attends: the page size for a whole page, less for
+    the page being written, 0 for a column that names no page — at the end
+    of a head's list, or the page being written when ``t`` is its first
+    position, among the forced ones at the list's front. ``counts[b * H_kv
+    + h]`` is the head's last column with a position to read, plus one: the
+    head makes ``ceil(count / group)`` trips and masks by ``lens`` inside a
+    group, so a padding row (every count 0) copies and multiplies nothing.
+
+    A row's trips run head after head as one sequence. A trip waits for its
+    group's copies (one a page: a head's K and V of a page are two strided
+    halves of one copy) in one half of a two-slot VMEM buffer, having
+    started the next trip's into the other half; a row's last trip — or a
+    row without trips — starts the NEXT row's first, so of a whole call only
+    the first copy is waited for with nothing to do. Every row, a padding
+    row too, then folds in position ``t`` and writes its output.
+
+    Refs: q/out ``(1, H_kv, rep, D)`` float32 (q NOT scaled), kn/vn ``(1,
+    H_kv, 1, D)``, the pool ``(P, L, 2, H_kv, ps, D)`` whole. Scratch: the
+    page buffer ``(2, group, 2, ps, D)`` with a DMA semaphore a slot, the
+    slot this row's first trip is in (SMEM: it outlives the grid step), m/l
+    ``(H_kv, rep, 1)``, acc ``(H_kv, rep, D)``. Precision as
+    ``_decode_kernel_grouped``."""
+    b = pl.program_id(0)
+    ps, hkv = page_size, num_kv_heads
     f32 = jnp.float32
     precision = jax.lax.Precision.HIGHEST if exact \
         else jax.lax.Precision.DEFAULT
+    layer = layer_ref[0]
 
-    @pl.when(s == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def walk(row):
+        """Where each KV head's trips begin in row ``row``'s sequence, and
+        how many trips the row makes."""
+        begins, total = [], 0
+        for h in range(hkv):
+            begins.append(total)
+            total = total + pl.cdiv(counts_ref[row * hkv + h], group)
+        return begins, total
 
-    lens = [lens_ref[row, s * group + j] for j in range(group)]
-    total = functools.reduce(lambda a, b: a + b, lens)
+    def locate(begins, i):
+        """Trip ``i`` of a row: ``(KV head, group of its list)``."""
+        h, g = 0, i
+        for j in range(1, hkv):
+            past = i >= begins[j]
+            h, g = jnp.where(past, j, h), jnp.where(past, i - begins[j], g)
+        return h, g
 
-    @pl.when(total > 0)
-    def _stream():
+    def copies(row, h, g, slot):
+        """Group ``g`` of head ``h``'s list into ``slot``: a copy a page."""
+        line, done = row * hkv + h, sem.at[slot]
+        return [pltpu.make_async_copy(
+            pool_ref.at[tables_ref[line, g * group + j], layer, :, h],
+            buf.at[slot, j], done) for j in range(group)]
+
+    def start(row, h, g, slot):
+        for c in copies(row, h, g, slot):
+            c.start()
+
+    begins, n = walk(b)
+
+    @pl.when(b == 0)                         # nobody started row 0's
+    def _first_row():
+        slot_ref[0] = 0
+
+        @pl.when(n > 0)
+        def _():
+            start(0, *locate(begins, 0), 0)
+
+    slot0 = slot_ref[0]                      # where this row's trip 0 is
+    slot_ref[0] = (slot0 + n) % 2            # ... and the next row's
+
+    def start_next_row():
+        @pl.when(b + 1 < pl.num_programs(0))
+        def _():
+            begins1, n1 = walk(b + 1)
+
+            @pl.when(n1 > 0)
+            def _():
+                start(b + 1, *locate(begins1, 0), (slot0 + n) % 2)
+
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(n == 0)
+    def _no_trip():
+        start_next_row()
+
+    def trip(i, carry):
+        slot = (slot0 + i) % 2
+        h, g = locate(begins, i)
+
+        @pl.when(i + 1 < n)
+        def _():
+            start(b, *locate(begins, i + 1), 1 - slot)
+
+        @pl.when(i + 1 == n)
+        def _():
+            start_next_row()
+
+        for c in copies(b, h, g, slot):
+            c.wait()
+        line = b * hkv + h
         col = jax.lax.broadcasted_iota(jnp.int32, (1, group * ps), 1)
+        of_page = col // ps
         limit = jnp.zeros((1, group * ps), jnp.int32)
-        for j, n in enumerate(lens):         # column's page j attends n
-            limit = jnp.where(col // ps == j, n + j * ps, limit)
+        for j in range(group):               # column's page j attends lens
+            limit = jnp.where(of_page == j,
+                              lens_ref[line, g * group + j] + j * ps, limit)
         live = col < limit
-
-        def tile(refs):
-            parts = [ref[0, 0, 0, 0].astype(f32) for ref in refs]
-            return jnp.concatenate(parts, axis=0) if group > 1 else parts[0]
-
+        # one load across the group's pages (every access to a ref costs
+        # the trace as much as an operation: PERF.md section 6, PR 35)
+        kv = buf[slot].astype(f32)                      # (group, 2, ps, D)
         logits = jax.lax.dot_general(
-            q_ref[0, 0], tile(k_refs), (((1,), (1,)), ((), ())),
-            precision=precision, preferred_element_type=f32) * sm_scale
+            q_ref[0, h], kv[:, 0].reshape(group * ps, -1),
+            (((1,), (1,)), ((), ())), precision=precision,
+            preferred_element_type=f32) * sm_scale      # (rep, group * ps)
         logits = jnp.where(live, logits, _NEG_INF)
-        m_prev = m_ref[...]
+        m_prev = m_ref[h]                               # (rep, 1)
         m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1, keepdims=True))
         p = jnp.where(live, jnp.exp(logits - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
-            p, tile(v_refs), precision=precision, preferred_element_type=f32)
-        m_ref[...] = m_new
+        l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[h] = alpha * acc_ref[h] + jnp.dot(
+            p, kv[:, 1].reshape(group * ps, -1), precision=precision,
+            preferred_element_type=f32)                 # (rep, D)
+        m_ref[h] = m_new
+        return carry
 
-    @pl.when(s == num_steps - 1)
-    def _finish():
-        logit_t = jnp.sum(q_ref[0, 0] * kn_ref[0, 0], axis=1,
-                          keepdims=True) * sm_scale               # (rep, 1)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, logit_t)
-        p_t = jnp.exp(logit_t - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_fin = alpha * l_ref[...] + p_t
-        acc = alpha * acc_ref[...] + p_t * vn_ref[0, 0]
-        o_ref[0, 0] = acc / jnp.maximum(l_fin, 1e-30)
+    jax.lax.fori_loop(0, n, trip, 0)
+
+    # fold in position t and emit, every KV head at once
+    logit_t = jnp.sum(q_ref[0] * kn_ref[0], axis=2,
+                      keepdims=True) * sm_scale         # (H_kv, rep, 1)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, logit_t)
+    p_t = jnp.exp(logit_t - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_fin = alpha * l_ref[...] + p_t
+    acc = alpha * acc_ref[...] + p_t * vn_ref[0]
+    o_ref[0] = acc / jnp.maximum(l_fin, 1e-30)
 
 
+# jitted so that a decode program's sparse layers share ONE trace of the
+# kernel's body: the layer is an operand, not part of the trace (on a TPU
+# host a kernel's trace is seconds of every run's set-up, cached or not:
+# PERF.md section 6, PR 35)
+@functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
 def _sparse_kernel_call(q, k_new, v_new, pool, tables, lens, layer,
                         page_size: int, interpret: bool):
     b, h, d = q.shape
     hkv = pool.shape[3]
-    rep = h // hkv
-    ps = page_size
-    group = _GROUP_PAGES
+    ps, group = page_size, _GROUP_PAGES
     cols = tables.shape[-1]
-    if cols % group:
-        extra = ((0, 0), (0, 0), (0, -cols % group))
+    tables = tables.reshape(b * hkv, cols).astype(jnp.int32)
+    lens = lens.reshape(b * hkv, cols).astype(jnp.int32)
+    if cols % group:             # whole groups: columns that name no page
+        extra = ((0, 0), (0, -cols % group))
         tables, lens = jnp.pad(tables, extra), jnp.pad(lens, extra)
-        cols = tables.shape[-1]
-    steps = cols // group
+    # a head's last column with a position to read, plus one
+    counts = jnp.max(jnp.where(lens > 0, jnp.arange(1, lens.shape[1] + 1), 0),
+                     axis=1).astype(jnp.int32)
+    qo = (hkv, h // hkv, d)                  # a KV head's query heads: rows
     f32 = jnp.float32
 
-    def row_map(bi, hi, si, tabs, ln, lr):
-        return (bi, hi, 0, 0)
+    def row_map(bi, tabs, ln, cn, lr):
+        return (bi, 0, 0, 0)
 
-    def page_map(kv, j):
-        def f(bi, hi, si, tabs, ln, lr):
-            return (tabs[bi * hkv + hi, si * group + j], lr[0], kv, hi, 0, 0)
-        return f
-
-    in_specs = [pl.BlockSpec((1, 1, rep, d), row_map),
-                pl.BlockSpec((1, 1, 1, d), row_map),
-                pl.BlockSpec((1, 1, 1, d), row_map)] + [
-        pl.BlockSpec((1, 1, 1, 1, ps, d), page_map(kv, j))
-        for kv in (0, 1) for j in range(group)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3, grid=(b, hkv, steps), in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rep, d), row_map),
-        scratch_shapes=[pltpu.VMEM((rep, 1), f32), pltpu.VMEM((rep, 1), f32),
-                        pltpu.VMEM((rep, d), f32)])
+        num_scalar_prefetch=4, grid=(b,),
+        in_specs=[pl.BlockSpec((1,) + qo, row_map),
+                  pl.BlockSpec((1, hkv, 1, d), row_map),
+                  pl.BlockSpec((1, hkv, 1, d), row_map),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1,) + qo, row_map),
+        scratch_shapes=[
+            pltpu.VMEM((2, group, 2, ps, d), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),     # the slot of the row's trip 0
+            pltpu.VMEM(qo[:2] + (1,), f32),  # running max
+            pltpu.VMEM(qo[:2] + (1,), f32),  # running denominator
+            pltpu.VMEM(qo, f32),             # weighted-V accumulator
+        ])
     out = pl.pallas_call(
         functools.partial(
-            _sparse_decode_kernel, page_size=ps, num_steps=steps,
-            num_kv_heads=hkv, group=group, sm_scale=1.0 / float(d) ** 0.5,
+            _sparse_decode_kernel, page_size=ps, num_kv_heads=hkv,
+            group=group, sm_scale=1.0 / float(d) ** 0.5,
             exact=pool.dtype == jnp.float32),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), f32),
+        out_shape=jax.ShapeDtypeStruct((b,) + qo, f32),
+        # a row starts the next row's first copies: the rows run in order
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="sparse_attention_decode",
-    )(tables.reshape(b * hkv, cols).astype(jnp.int32),
-      lens.reshape(b * hkv, cols).astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1),
-      q.astype(f32).reshape(b, hkv, rep, d),
+    )(tables, lens, counts, jnp.asarray(layer, jnp.int32).reshape(1),
+      q.astype(f32).reshape((b,) + qo),
       k_new.astype(f32).reshape(b, hkv, 1, d),
-      v_new.astype(f32).reshape(b, hkv, 1, d), *([pool] * (2 * group)))
+      v_new.astype(f32).reshape(b, hkv, 1, d), pool)
     return out.reshape(b, h, d).astype(q.dtype)
 
 
@@ -449,12 +554,28 @@ def sparse_paged_attention(q, k_new, v_new, pool, tables, lens, layer, *,
     of each are attended (0: the column names no page), then the current
     token's ``k_new`` / ``v_new`` (B, H_kv, D) unquantized. ``q`` (B, H,
     D); ``pool`` (P, L, 2, H_kv, page, D). Returns (B, H, D)."""
-    if impl == "kernel" and (interpret or kernel_eligible(
-            page_size, int(pool.shape[-1]), pool.dtype, 1)):
+    if _takes_kernel(pool, page_size, impl, interpret):
         return _sparse_kernel_call(q, k_new, v_new, pool, tables, lens,
                                    layer, page_size, interpret)
     return sparse_paged_attention_dense(q, k_new, v_new, pool, tables, lens,
                                         layer, page_size)
+
+
+def _takes_kernel(pool, page_size: int, impl: str, interpret: bool) -> bool:
+    """Whether a layer's call takes the kernel (else the dense tier). What
+    the kernel holds of the pool, two groups of one KV head's pages, is what
+    a page of ``_GROUP_PAGES`` KV heads would be to ``paged_attention``."""
+    return impl == "kernel" and (interpret or kernel_eligible(
+        page_size, int(pool.shape[-1]), pool.dtype, _GROUP_PAGES))
+
+
+def _unused_entry(rows: int, width: int, num_pages: int):
+    """``(rows, width)`` int32: the page whose entry the gather reads for a
+    table column its row does not use — inside the pool, and no two columns
+    of a row the same page while a row's table is no wider than the pool."""
+    at = jnp.arange(rows, dtype=jnp.int32)[:, None] * width \
+        + jnp.arange(width, dtype=jnp.int32)[None, :]
+    return at % num_pages
 
 
 def _decode_layer(q, k_new, v_new, pool, index_pool, tables, t, layer,
@@ -490,8 +611,15 @@ def _decode_layer(q, k_new, v_new, pool, index_pool, tables, t, layer,
     f_new = t32 // st
     ends = ((t32 + 1) % st == 0) & cfg.visible(f_new, t32)
     # every entry of the row's pages: rows (e, h) of page w are entries
-    # w * per + e, so the gathered rows ARE (B, F, Hkv, D)
-    eidx = tables * index_pool.shape[1] + layer
+    # w * per + e, so the gathered rows ARE (B, F, Hkv, D). A column past
+    # the row's own page names the scratch page in every row's table; the
+    # gather reads an entry of the column's own there instead (ISSUE 37:
+    # ~30,000 reads of one 4 KB entry a step took longer than as many reads
+    # of distinct ones) — select_blocks discards what such a column scores
+    col = jnp.arange(width, dtype=jnp.int32)[None, :]
+    used = (col <= page[:, None]) & (t32 > 0)[:, None]
+    own = _unused_entry(b, width, index_pool.shape[0])
+    eidx = jnp.where(used, tables, own) * index_pool.shape[1] + layer
     ent = jnp.take(index_pool.reshape((-1,) + index_pool.shape[2:]), eidx,
                    axis=0).reshape(b, width * per, hkv, d)
     qg = q.reshape(b, hkv, rep, d)
@@ -553,9 +681,11 @@ def sparse_decode_attention(q, k_new, v_new, cache: HybridDecodeCache):
         "sparse_attention_decode", f, q, k_new, v_new, cache.pool,
         cache.index_pool, cache.tables, cache.t, layer_t,
         differentiable=False, amp=False)
+    walked = _takes_kernel(cache.pool, ps, impl, interpret)
     return out, replace(cache, pending=cache.pending + ((k_new, v_new),),
                         pending_index=cache.pending_index + ((fresh, ends),),
-                        chose=cache.chose + ((pages, blocks),))
+                        chose=cache.chose + ((pages, blocks),),
+                        sparse_walk_layers=cache.sparse_walk_layers + walked)
 
 
 def pages_counted(cache: HybridDecodeCache):

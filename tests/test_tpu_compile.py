@@ -233,6 +233,14 @@ def test_sparse_decode_kernel_compiles_for_the_chip(one_chip):
         _spec(one_chip, (b, hkv, 64), i32)).compile().as_text()
     assert "sparse_attention_decode" in text and "tpu_custom_call" in text
     assert not pool_copies(text, (pages, 2, 2, hkv, ps, d))
+    # ISSUE 37: the pool is handed over once, where it lies, and the kernel
+    # copies a live row's chosen pages out of it (before: 64 operands, a
+    # block of one page each)
+    (call,) = [line for line in text.splitlines()
+               if "custom-call(" in line and "tpu_custom_call" in line]
+    operands = call.split("operand_layout_constraints={")[1].split("}}")[0]
+    assert operands.count(f"bf16[{pages},2,2,{hkv},{ps},{d}]") == 1
+    assert operands.count("[") == 8, operands
 
 
 def test_linear_state_kernel_compiles_for_the_chip_and_writes_in_place(
