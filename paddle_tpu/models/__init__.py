@@ -14,3 +14,4 @@ from . import ppyoloe  # noqa: F401
 from . import cohere2_moe  # noqa: F401
 from . import minicpm_sala  # noqa: F401
 from . import qwen3_next  # noqa: F401
+from . import mellum  # noqa: F401

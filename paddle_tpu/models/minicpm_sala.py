@@ -51,6 +51,7 @@ from .. import nn
 from ..core.tensor import Tensor, apply
 from ..core.tracing import no_grad
 from ..nn.initializer import Constant, Normal
+from ..ops import rotary
 from ..ops.linear_attention import (chunked_linear_attention,
                                     lightning_slopes, linear_state_decode)
 from ..ops.sparse_attention import (HybridDecodeCache, HybridPrefill,
@@ -159,17 +160,6 @@ def _rms(x, w, eps):
     x32 = x.astype(jnp.float32)
     return (x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True)
                                 + eps) * w.astype(jnp.float32)).astype(x.dtype)
-
-
-def _rope(x, pos, theta: float):
-    """``x`` (..., H, D) at positions ``pos`` (...): the halves rotated."""
-    d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = pos.astype(jnp.float32)[..., None, None] * inv
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
 
 
 def _by_blocks(fn, block: int, *arrays):
@@ -284,7 +274,10 @@ class MiniCPMSalaForCausalLM(nn.Layer):
             q = _rms(q, w["q_norm"], c.rms_norm_eps)
             k = _rms(k, w["k_norm"], c.rms_norm_eps)
         if pos is not None:
-            q, k = _rope(q, pos, c.rope_theta), _rope(k, pos, c.rope_theta)
+            inv, _ = rotary.frequencies({"rope_theta": c.rope_theta},
+                                        q.shape[-1])
+            q, k = (rotary.rotate_halves(q, pos, inv),
+                    rotary.rotate_halves(k, pos, inv))
         return q, k, v
 
     def _mixer_out(self, x, h, o, w):

@@ -54,6 +54,7 @@ from ..core.tensor import Tensor, apply
 from ..core.tracing import no_grad
 from ..incubate.moe import DroplessMoE, dropless_moe
 from ..nn.initializer import Constant, Normal, Uniform
+from ..ops import rotary
 from ..ops.linear_attention import (StateDecodeCache, StatePrefill,
                                     chunked_gated_delta_rule,
                                     conv_tail_decode, gated_delta_decode)
@@ -157,20 +158,6 @@ def _norm(x, w, eps):
     return (x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True)
                                 + eps)
             * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
-
-
-def _rope(x, pos, theta: float, rotary: int):
-    """``x`` (..., H, D) at positions ``pos`` (...): the first ``rotary``
-    dimensions rotated by halves, the rest passed through."""
-    inv = 1.0 / (theta ** (jnp.arange(0, rotary, 2, dtype=jnp.float32)
-                           / rotary))
-    ang = pos.astype(jnp.float32)[..., None, None] * inv
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x32 = x.astype(jnp.float32)
-    x1, x2, rest = x32[..., :rotary // 2], x32[..., rotary // 2:rotary], \
-        x32[..., rotary:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest],
-                           axis=-1).astype(x.dtype)
 
 
 def _l2(x):
@@ -316,15 +303,16 @@ class Qwen3NextForCausalLM(nn.Layer):
         (N, H * D), k, v (N, H_kv, D)."""
         c = self.config
         n, d = h.shape[0], c.head_dim
-        rotary = int(d * c.partial_rotary_factor)
+        inv, _ = rotary.frequencies({"rope_theta": c.rope_theta},
+                                    int(d * c.partial_rotary_factor))
         qg = jnp.dot(h, w["q_proj"]).reshape(n, c.num_attention_heads, 2 * d)
         q, gate = qg[..., :d], qg[..., d:].reshape(n, -1)
         k = jnp.dot(h, w["k_proj"]).reshape(n, c.num_key_value_heads, d)
         v = jnp.dot(h, w["v_proj"]).reshape(n, c.num_key_value_heads, d)
-        q = _rope(_norm(q, w["q_norm"], c.rms_norm_eps), pos, c.rope_theta,
-                  rotary)
-        k = _rope(_norm(k, w["k_norm"], c.rms_norm_eps), pos, c.rope_theta,
-                  rotary)
+        q = rotary.rotate_halves(_norm(q, w["q_norm"], c.rms_norm_eps), pos,
+                                 inv)
+        k = rotary.rotate_halves(_norm(k, w["k_norm"], c.rms_norm_eps), pos,
+                                 inv)
         return q, gate, k, v
 
     def _attn_out(self, x, attn, gate, w):

@@ -115,6 +115,10 @@ span, mode ``on`` only)::
                               prefix pages fetches the state kept at that
                               boundary (``bytes``: all its parts), to start
                               its tail prefill from
+    serving.kv.window_keep    (span) an admission hands the window pages its
+                              prefill wrote at prefix boundaries to the
+                              engine's store of them (``pages``: those it
+                              claimed for that alone), one claim each
     serving.warmup            Engine.warmup's body (``replica``,
                               ``programs`` = buckets + lengths + tails);
                               holds one jit.call a program it compiles
@@ -193,7 +197,16 @@ most four times a second and when the last slot goes, not every step) and ``serv
 ``serving.kv.pages_in_use_by_kind{kind}`` and
 ``serving.kv.window_pages_per_slot_high_water`` (the most window-kind pages
 one slot has held at once: window / page + 2 when pages leave with the
-window). The model's ``jax.named_scope``s ``attn_window``, ``attn_full``,
+window). The window pages kept at prefix boundaries count there too:
+counters ``serving.kv.window_prefix_hits_total`` /
+``serving.kv.window_prefix_misses_total`` (admissions whose prompt's first
+pages the full-attention pool held, and whose window pools did / did not
+hold the window before the shared prefix's end — the first asker's own
+pages, or those kept at a boundary) and
+``serving.kv.window_boundary_evictions_total``; gauges
+``serving.kv.window_boundary_pages`` (what the store keeps) and
+``serving.kv.window_boundary_pages_high_water`` (the most window pages held
+at once only for later sharers: kept, and read by no live slot). The model's ``jax.named_scope``s ``attn_window``, ``attn_full``,
 ``moe_route``, ``moe_experts`` and ``moe_shared`` name its ops in the HLO's
 metadata and in the profiler's own viewer; an ``.xplane.pb`` read through
 ``jax.profiler.ProfileData`` carries an op's instruction name only.

@@ -93,6 +93,16 @@ the page the step writes and releases the pages below ``t - window``
 once, and the decode kernel is handed the compact table of just those.
 Admission counts both kinds; a prefix is shared when the full pool holds
 its pages AND the window pool still holds the last window before the tail.
+The first asker holds only the window a sharer of its WHOLE prompt reads;
+for a sharer whose own tail is longer than that, a window layer's state at
+a page-aligned boundary is the window of K/V before it, and it is kept like
+a state (``ServingConfig.window_boundary_tokens``): a prefill writes those
+window pages every so many tokens and hands them, by reference — one claim
+each — to a ``kv_cache.SnapshotStore`` under the prefix chain digest of the
+boundary's last page, a page budget, least recently used out first; a later
+prompt maps the prefix up to the deepest boundary so kept when the window
+before its full match is gone (``serving.kv.window_prefix_hits_total`` /
+``_misses_total``).
 A model whose layers are all of one kind gets the one pool and the programs
 it always had. A model may return a third value from ``step_fn`` /
 ``prefill_fn``: an int32 array of what it counted on the device (an expert
@@ -174,6 +184,9 @@ tier ran — ISSUE 13), ``serving.prefills_total``,
 ``serving.kv.pages_in_use_by_kind{kind}``,
 ``serving.kv.window_pages_per_slot_high_water``,
 ``serving.kv.window_pages_released_total``,
+``serving.kv.window_prefix_hits_total`` / ``_misses_total``,
+``serving.kv.window_boundary_evictions_total``,
+``serving.kv.window_boundary_pages`` and ``_pages_high_water``,
 ``serving.state.snapshot_hits_total`` / ``_misses_total`` (admissions that
 found resident prefix pages and did / did not find a state to start from) /
 ``_evictions_total``, ``serving.state.snapshot_bytes``,
@@ -351,6 +364,15 @@ class ServingConfig:
     index_per_page: int = 0
     state_snapshot_tokens: int = 4096
     state_snapshot_bytes: int = 1 << 30
+    # a window layer's state at a page-aligned boundary is the window of
+    # K/V before it: ``window_boundary_tokens`` (whole pages; 0: off) has a
+    # prefill keep those window-pool pages every so many tokens, for a
+    # later prompt that shares the prefix up to the boundary and whose tail
+    # is longer than what the first asker's own pages cover;
+    # ``window_boundary_pages`` bounds what is kept, in window-pool pages,
+    # and the window pool is that much larger by default
+    window_boundary_tokens: int = 0
+    window_boundary_pages: int = 0
 
     def __post_init__(self):
         self.layer_kinds = tuple(self.layer_kinds)
@@ -412,6 +434,13 @@ class ServingConfig:
         if self.min_shared_pages < 1:
             raise ValueError(f"min_shared_pages must be >= 1, got "
                              f"{self.min_shared_pages}")
+        if self.window_boundary_tokens and (
+                "window" not in self.layer_kinds
+                or self.window_boundary_tokens % self.page_size
+                or self.window_boundary_pages < 1):
+            raise ValueError(
+                "window_boundary_tokens needs window layers, whole pages "
+                "and a window_boundary_pages budget")
 
     def kv_config(self, kind: str = "", num_layers: Optional[int] = None
                   ) -> _kv.KVCacheConfig:
@@ -430,6 +459,8 @@ class ServingConfig:
             # deeper queue when num_pages is set below this default
             cfg.num_pages = self.max_batch * (
                 cfg.window_pages or cfg.pages_per_slot) + 1
+            if window and self.window_boundary_tokens:
+                cfg.num_pages += self.window_boundary_pages
         return cfg
 
     def kv_configs(self) -> List[_kv.KVCacheConfig]:
@@ -535,6 +566,18 @@ class Engine:
         # claim never fails (guarded by _slot_lock)
         self._window_committed = [0] * len(self.kvs)
         self._window_high_water = 0
+        # the window pools' pages kept at prefix boundaries: per entry one
+        # list of page ids a window pool, each held by a claim of the
+        # store's own (None: not kept)
+        self.window_boundaries = None
+        self._boundary_high_water = 0
+        if config.window_boundary_tokens:
+            self.window_boundaries = _kv.SnapshotStore(
+                config.window_boundary_pages,
+                size=lambda parts: sum(len(ids) for ids in parts),
+                on_evict=self._give_back_boundary,
+                evictions="serving.kv.window_boundary_evictions_total",
+                gauge="serving.kv.window_boundary_pages")
         # an expert layer's row counts since they were last published
         self._expert_rows: Optional[np.ndarray] = None
         self._expert_touches = 0
@@ -651,6 +694,8 @@ class Engine:
         _obs.inc("serving.pool_resets_total")
         for kv in self.kvs:
             kv.reset_pool()
+        if self.window_boundaries is not None:
+            self.window_boundaries.reset()
         if self.index is not None:
             self.index.reset()
         if self.state is not None:          # the states went with the pools
@@ -982,6 +1027,9 @@ class Engine:
                         break
                 jitter_sleep(0.002)
             self._resolve_stragglers(on_timeout)
+            if self.window_boundaries is not None:
+                # a drained engine holds no page: the kept boundaries too
+                self.window_boundaries.reset()
         # a cleanly stopped engine is not a liveness failure; and with
         # PADDLE_TPU_TRACE=on + a TRACE_DIR, leave the operator a
         # Perfetto-loadable trace of the run
@@ -1063,6 +1111,8 @@ class Engine:
         # letting a small request slip past a requeued large one —
         # breaking the scheduler's strict-FIFO contract
         claimed = [0] * len(self.kvs)
+        kept_budget = self.config.window_boundary_pages \
+            if self.window_boundaries is not None else 0
 
         def can_fit(req: GenerationRequest) -> bool:
             # both kinds of page (ISSUE 27). A full-attention pool hands a
@@ -1072,8 +1122,10 @@ class Engine:
             # kept within the pool, a step's claim cannot fail
             need = [self._pages_needed(req, kv) for kv in self.kvs]
             for k, kv in enumerate(self.kvs):
+                # (the kept boundaries' budget is theirs, not the slots')
                 room = kv.free_pages if not kv.config.window else \
-                    kv.config.num_pages - 1 - self._window_committed[k]
+                    kv.config.num_pages - 1 - self._window_committed[k] \
+                    - kept_budget
                 if claimed[k] + need[k] > room:
                     return False
             for k, n in enumerate(need):
@@ -1154,8 +1206,12 @@ class Engine:
             or self._claim_pages(req, prompt, False)
         if claim is None:
             return "noroom"
-        pages, first_page, shared = claim
+        pages, first_page, shared, boundaries = claim
         start = shared * self.config.page_size
+        rows = [kv.table_row(ids, first=lo)
+                for kv, ids, lo in zip(self.kvs, pages, first_page)]
+        for k, got in (boundaries[1] if boundaries else {}).items():
+            rows[k][list(got)] = list(got.values())
         state_row, start_state = 0, None
         try:
             if self.state is not None:
@@ -1183,8 +1239,7 @@ class Engine:
                 # either consumes the pools and adopts what comes back
                 first = self.programs.prefill(
                     _T(jnp.asarray(prompt[None, start:], jnp.int32)),
-                    [_T(jnp.asarray(kv.table_row(ids, first=lo)))
-                     for kv, ids, lo in zip(self.kvs, pages, first_page)],
+                    [_T(jnp.asarray(row)) for row in rows],
                     _T(jnp.asarray(prompt.size, jnp.int32)), start,
                     *(() if self.state is None else (
                         _T(jnp.asarray(state_row, jnp.int32)), start_state)))
@@ -1210,12 +1265,25 @@ class Engine:
                 # every published page). Over the ORIGINAL prompt only —
                 # a replay's appended tokens are generated content, not a
                 # shareable prompt.
-                for kv, ids, lo in zip(self.kvs, pages, first_page):
-                    kv.publish(req.prompt, ids, first=lo)
+                for kv, row in zip(self.kvs, rows):
+                    kv.publish(req.prompt, row.tolist())
                 self._keep_snapshots(req.prompt, start, first.extra)
+                if boundaries:
+                    self._keep_window_boundaries(req.prompt, boundaries)
+                    boundaries = None
+            # what only the tail prefill read: a window pool's pages below
+            # the first decode step's window go now, so a slot holds at most
+            # the window's pages + 2 however long its tail was
+            for k, kv in enumerate(self.kvs):
+                if kv.config.window:
+                    pages[k], first_page[k] = self._release_below_window(
+                        kv, pages[k], first_page[k], int(prompt.size))
         except Exception as exc:
             if state_row:
                 self.state.free(state_row)
+            if boundaries:
+                for k, got in boundaries[1].items():
+                    self.kvs[k].free(list(got.values()))
             self._free_pages(pages)             # refcount-aware: shared
             # pages are decremented, private ones actually released
             _obs.inc("serving.requests_total", status="failed")
@@ -1273,20 +1341,23 @@ class Engine:
     def _claim_pages(self, req: GenerationRequest, prompt: np.ndarray,
                      share: bool):
         """Claim the request's pages in every pool: ``(pages, first_page,
-        shared)`` — per pool the page ids and the logical page the first
-        of them is, and how many leading pages of the prompt were mapped
-        read-only from the prefix index — or ``None`` when a pool cannot
-        cover it (nothing stays claimed).
+        shared, boundaries)`` — per pool the page ids and the logical page
+        the first of them is, how many leading pages of the prompt were
+        mapped read-only from the prefix index, and the window pools' pages
+        this prefill writes only to keep at prefix boundaries
+        (:meth:`_boundary_pages`) — or ``None`` when a pool cannot cover it
+        (nothing stays claimed).
 
         The first pool decides how far the prefix is shared. A
         full-attention pool maps those pages and claims the rest of the
         request's lifetime. A window pool maps only what the tail prefill
         reads — the pages of the ``window`` positions before the tail —
-        all of them or the prefix is not shared at all, and claims the
-        pages a later sharer of this whole prompt would read in turn and
-        up to the prompt's last; its decode steps claim and release as
-        they go (:meth:`_advance_window`). A logical page in between that
-        nobody will read has no page: its id is 0, the scratch page."""
+        all of them or the prefix is not shared that far
+        (:meth:`_map_window_prefix`), and claims the pages a later sharer
+        of this whole prompt would read in turn and up to the prompt's
+        last; its decode steps claim and release as they go
+        (:meth:`_advance_window`). A logical page in between that nobody
+        will read has no page: its id is 0, the scratch page."""
         ps = self.config.page_size
         size = int(prompt.size)
         mapped = [[] for _ in self.kvs]
@@ -1302,16 +1373,13 @@ class Engine:
                          "serving.state.snapshot_misses_total")
         elif share:
             mapped[0] = self.kv.acquire_prefix(prompt)
-            n = len(mapped[0])
-            for k, kv in enumerate(self.kvs[1:], 1):
-                lo = kv.config.window_first_page(n * ps)
-                if n and lo < n:
-                    mapped[k] = kv.acquire_prefix(prompt, first=lo, count=n)
-                    if not mapped[k]:
-                        self._free_pages(mapped)
-                        return None
+            n = self._map_window_prefix(prompt, mapped)
+            if not n:                  # a full prefill: nothing stays mapped
+                self._free_pages(mapped)
+                mapped = [[] for _ in self.kvs]
         pages: List[List[int]] = []
         first_page: List[int] = []
+        boundaries = None
         try:
             for k, kv in enumerate(self.kvs):
                 if not kv.config.window:
@@ -1327,14 +1395,157 @@ class Engine:
                     raise MemoryError
                 pages.append(mapped[k] + [0] * gap + new)
                 first_page.append(lo)
+            boundaries = self._boundary_pages(req.prompt, n, pages,
+                                              first_page)
         except BaseException as exc:
             # a pool REFUSING is the None return; a pool (or the sizing
             # arithmetic) RAISING must not strand what was claimed so far
-            self._free_pages(mapped[len(pages):] + pages)
+            self._free_pages(pages + mapped[len(pages):])
             if isinstance(exc, MemoryError):
                 return None
             raise
-        return pages, first_page, n
+        return pages, first_page, n, boundaries
+
+    def _map_window_prefix(self, prompt: np.ndarray,
+                           mapped: List[List[int]]) -> int:
+        """After the full pool mapped ``mapped[0]``: map every window
+        pool's pages of the ``window`` positions before the shared prefix's
+        end — at the full pool's match, or else at the deepest boundary
+        below it whose pages are kept (:attr:`window_boundaries`), handing
+        back the full pool's pages past it. Returns the pages shared, 0 if
+        no window pool had them (``serving.kv.window_prefix_hits_total`` /
+        ``_misses_total``, counted when the full pool matched)."""
+        n = len(mapped[0])
+        if not n or len(self.kvs) == 1:
+            return n
+        ps = self.config.page_size
+        tries = [n]
+        digests = []
+        if self.window_boundaries is not None:
+            digests = _kv.prefix_chain_digests(prompt, ps, limit=n)
+            kept = self.window_boundaries.deepest(digests, n)
+            if 0 < kept < n:
+                tries.append(kept)
+        for m in tries:
+            got = []
+            for kv in self.kvs[1:]:
+                lo = kv.config.window_first_page(m * ps)
+                ids = kv.acquire_prefix(prompt, first=lo, count=m)
+                if not ids:
+                    break
+                got.append(ids)
+            if len(got) == len(self.kvs) - 1:
+                self.kv.free(mapped[0][m:])
+                mapped[0] = mapped[0][:m]
+                mapped[1:] = got
+                if digests:                     # a use: the newest now
+                    self.window_boundaries.get_parts(digests[m - 1])
+                _obs.inc("serving.kv.window_prefix_hits_total")
+                return m
+            for kv, ids in zip(self.kvs[1:], got):
+                kv.free(ids)
+        _obs.inc("serving.kv.window_prefix_misses_total")
+        return 0
+
+    def _boundary_pages(self, prompt: np.ndarray, n: int,
+                        pages: List[List[int]], first_page: List[int]):
+        """The boundaries a prefill from page ``n`` of ``prompt`` keeps for
+        later sharers, every ``window_boundary_tokens`` up to the last page
+        a sharer could map, none kept already, the deepest ones that the
+        budget holds — and per window pool the pages it claims for them
+        beyond the request's own (``{logical page: id}``): the prefill
+        writes them, :meth:`_keep_window_boundaries` hands them over.
+        ``None``: nothing to keep."""
+        store = self.window_boundaries
+        if store is None or not self._share_prefix:
+            return None
+        ps = self.config.page_size
+        every = self.config.window_boundary_tokens // ps
+        last = (int(prompt.size) - 1) // ps
+        digests = _kv.prefix_chain_digests(prompt, ps, limit=last)
+        wins = [k for k, kv in enumerate(self.kvs) if kv.config.window]
+        span = {b: [self.kvs[k].config.window_first_page(b * ps)
+                    for k in wins]
+                for b in range((n // every + 1) * every, last + 1, every)
+                if digests[b - 1] not in store}
+        bounds, size = [], 0
+        for b in sorted(span, reverse=True):    # the deepest first
+            cost = sum(b - lo for lo in span[b])
+            if size + cost > store.budget:
+                break
+            bounds.insert(0, b)
+            size += cost
+        if not bounds:
+            return None
+        store.make_room(size)
+        extra = {}
+        for j, k in enumerate(wins):
+            kv, have = self.kvs[k], first_page[k]
+            own = {have + i for i, p in enumerate(pages[k]) if p}
+            want = sorted({lp for b in bounds
+                           for lp in range(span[b][j], b)} - own)
+            ids = kv.alloc(len(want))
+            if ids is None:                      # kept within the pool by
+                for kk, got in extra.items():    # the budget: defensive
+                    self.kvs[kk].free(list(got.values()))
+                return None
+            extra[k] = dict(zip(want, ids))
+        return bounds, extra
+
+    def _keep_window_boundaries(self, prompt: np.ndarray, boundaries) -> None:
+        """After the prefill wrote them and its pages were published: file
+        each boundary's window pages under the chain digest of its last
+        page, one claim each of the store's own, and give back the
+        request's claims on the pages it took only for them."""
+        bounds, extra = boundaries
+        ps = self.config.page_size
+        digests = _kv.prefix_chain_digests(prompt, ps, limit=bounds[-1])
+        wins = [kv for kv in self.kvs if kv.config.window]
+        with _trace.span("serving.kv.window_keep",
+                         pages=sum(len(e) for e in extra.values())):
+            for b in bounds:
+                got = []
+                try:
+                    for kv in wins:
+                        got.append(kv.acquire_prefix(
+                            prompt, first=kv.config.window_first_page(b * ps),
+                            count=b, quiet=True))
+                    filed = all(got) and self.window_boundaries.put_parts(
+                        digests[b - 1], tuple(got))
+                except BaseException:
+                    for kv, ids in zip(wins, got):
+                        kv.free(ids)
+                    raise
+                if not filed:
+                    for kv, ids in zip(wins, got):
+                        kv.free(ids)
+            for k, got in extra.items():
+                self.kvs[k].free(list(got.values()))
+        self._note_boundary_pages()
+
+    def _give_back_boundary(self, parts) -> None:
+        """Release the store's claims on one boundary's window pages."""
+        for kv, ids in zip([kv for kv in self.kvs if kv.config.window],
+                           parts):
+            kv.free(ids)
+
+    def _note_boundary_pages(self) -> None:
+        """Feed ``serving.kv.window_boundary_pages_high_water``: the window
+        pages held only for later sharers — kept at a boundary, read by no
+        live slot (one claim: the store's)."""
+        store = self.window_boundaries
+        if store is None:
+            return
+        wins = [kv for kv in self.kvs if kv.config.window]
+        held = sum(kv.sole_claims(ids) for parts in store.values()
+                   for kv, ids in zip(wins, parts))
+        with self._slot_lock:               # stop() may step from its thread
+            risen = held > self._boundary_high_water
+            if risen:
+                self._boundary_high_water = held
+        if risen:
+            _obs.set_gauge("serving.kv.window_boundary_pages_high_water",
+                           float(held))
 
     def _start_state(self, prompt: np.ndarray, shared: int):
         """The state an admission that mapped ``shared`` prefix pages
@@ -1412,18 +1623,27 @@ class Engine:
                     _obs.set_gauge(
                         "serving.kv.window_pages_per_slot_high_water",
                         float(held))
-            drop = kv.config.window_first_page(t) - slot.first_page[k]
-            if drop > 0:
-                gone = [p for p in ids[:drop] if p]
-                kv.free(gone)
-                _obs.inc("serving.kv.window_pages_released_total",
-                         float(len(gone)))
-                slot.pages[k] = ids = ids[drop:]
-                slot.first_page[k] += drop
+            ids, first = self._release_below_window(
+                kv, ids, slot.first_page[k], t)
+            if first != slot.first_page[k]:
+                slot.pages[k], slot.first_page[k] = ids, first
                 changed = True
             if changed:
                 slot.rows[k] = self.programs.decode_row(
                     kv, ids, slot.first_page[k])
+
+    @staticmethod
+    def _release_below_window(kv, ids: List[int], first: int, t: int):
+        """Release what of a window pool's ``ids`` (logical page ``first``
+        on) no window layer reads from position ``t`` on -> ``(ids left,
+        their first logical page)``."""
+        drop = kv.config.window_first_page(t) - first
+        if drop <= 0:
+            return ids, first
+        gone = [p for p in ids[:drop] if p]
+        kv.free(gone)
+        _obs.inc("serving.kv.window_pages_released_total", float(len(gone)))
+        return ids[drop:], first + drop
 
     def _note_expert_rows(self, counts: np.ndarray, event: str,
                           batch: int) -> None:
@@ -1737,6 +1957,7 @@ class Engine:
         self._free_pages(slot.pages)
         if slot.state_row:
             self.state.free(slot.state_row)
+        self._note_boundary_pages()
         return True
 
     def _finish(self, slot: _Slot, reason: str) -> None:

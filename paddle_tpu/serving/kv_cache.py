@@ -453,7 +453,8 @@ class PagedKVCache:
 
     # -- prefix sharing ------------------------------------------------------
     def acquire_prefix(self, tokens, first: int = 0,
-                       count: Optional[int] = None) -> List[int]:
+                       count: Optional[int] = None,
+                       quiet: bool = False) -> List[int]:
         """Map the longest resident prefix chain of ``tokens`` read-only:
         walk the page-aligned chain digests through the index, bump each
         hit page's refcount, and return the page ids in chain order (empty
@@ -467,7 +468,9 @@ class PagedKVCache:
         ISSUE 27) the answer is all or nothing: logical pages ``[first,
         count)`` of the chain, every one resident, or ``[]`` — a window
         layer's tail prefill reads no page below ``first``, so none is
-        asked for."""
+        asked for. ``quiet``: a claim that is no sharer's admission (the
+        engine keeping a boundary's pages), left out of the sharing
+        stats."""
         ps = self.config.page_size
         toks = np.asarray(tokens).reshape(-1)
         cap = max(0, (toks.size - 1) // ps)
@@ -475,7 +478,7 @@ class PagedKVCache:
             cap = min(cap, count)
         digests = prefix_chain_digests(toks, ps, limit=cap)
         with self._lock:
-            self._prefix_queries += 1
+            self._prefix_queries += not quiet
             got: List[int] = []
             for h in digests[first:]:
                 pid = self._index.get(h)
@@ -491,11 +494,18 @@ class PagedKVCache:
                 if pid in self._idle:
                     del self._idle[pid]         # revive from the idle LRU
                 self._ref[pid] = self._ref.get(pid, 0) + 1
-            self._prefix_query_hits += 1
-            self._prefix_pages_shared_total += len(got)
-            _obs.inc("serving.kv.prefix_pages_shared_total", float(len(got)))
+            if not quiet:
+                self._prefix_query_hits += 1
+                self._prefix_pages_shared_total += len(got)
+                _obs.inc("serving.kv.prefix_pages_shared_total",
+                         float(len(got)))
             self._note_usage_locked()
         return got
+
+    def sole_claims(self, ids: Sequence[int]) -> int:
+        """How many of ``ids`` carry exactly one claim."""
+        with self._lock:
+            return sum(1 for pid in ids if self._ref.get(pid) == 1)
 
     def peek_prefix_pages(self, tokens) -> int:
         """Length of the resident prefix chain for ``tokens`` WITHOUT
@@ -707,18 +717,28 @@ class IndexPool:
 
 
 class SnapshotStore:
-    """States kept at page-aligned prefix boundaries, keyed by the prefix
+    """What is kept at page-aligned prefix boundaries, keyed by the prefix
     chain digest of the boundary's last page (``prefix_chain_digests``): a
     later prompt that shares the pages up to a boundary starts its prefill
-    from the state kept there. A state is a tuple of arrays, one a part:
-    kept, counted and evicted together under the one digest. A byte budget,
-    least recently used first out.
-    ``serving.state.snapshot_evictions_total`` and the gauge
-    ``serving.state.snapshot_bytes`` are fed here; hits and misses by the
-    engine, which knows what a lookup was for. Thread-safe."""
+    from what is kept there. An entry is a tuple of parts: kept, counted and
+    evicted together under the one digest. A budget, least recently used
+    first out.
 
-    def __init__(self, budget_bytes: int):
+    Two things are kept this way. A state per slot (the default): a part is
+    an array, the budget is bytes, ``serving.state.snapshot_evictions_total``
+    and the gauge ``serving.state.snapshot_bytes`` are fed here. A window
+    pool's pages at a boundary (``serving.kv.window_boundary_*``): a part is
+    one pool's page ids, held by a claim of the store's own, ``size`` counts
+    pages and ``on_evict`` gives the claims back. Hits and misses are the
+    engine's, which knows what a lookup was for. Thread-safe."""
+
+    def __init__(self, budget_bytes: int, size=None, on_evict=None,
+                 evictions: str = "serving.state.snapshot_evictions_total",
+                 gauge: str = "serving.state.snapshot_bytes"):
         self.budget = int(budget_bytes)
+        self._size = size or self._nbytes
+        self._on_evict = on_evict
+        self._evictions, self._gauge = evictions, gauge
         self._lock = threading.Lock()
         self._kept: "OrderedDict[bytes, tuple]" = OrderedDict()
         self._bytes = 0
@@ -729,12 +749,21 @@ class SnapshotStore:
 
     @property
     def nbytes(self) -> int:
+        """What is kept, in the budget's unit."""
         with self._lock:
             return self._bytes
 
+    def values(self) -> List[tuple]:
+        with self._lock:
+            return list(self._kept.values())
+
+    def __contains__(self, digest: bytes) -> bool:
+        with self._lock:
+            return digest in self._kept
+
     def deepest(self, digests: Sequence[bytes], limit: int) -> int:
-        """The largest ``n <= limit`` with a snapshot under
-        ``digests[n - 1]`` (0: none)."""
+        """The largest ``n <= limit`` with an entry under ``digests[n - 1]``
+        (0: none)."""
         with self._lock:
             for n in range(min(limit, len(digests)), 0, -1):
                 if digests[n - 1] in self._kept:
@@ -742,7 +771,7 @@ class SnapshotStore:
         return 0
 
     def get_parts(self, digest: bytes) -> Optional[tuple]:
-        """What is kept under ``digest``, one array a part (``None``:
+        """What is kept under ``digest``, one part a tuple entry (``None``:
         nothing), now the most recently used."""
         with self._lock:
             parts = self._kept.get(digest)
@@ -756,35 +785,61 @@ class SnapshotStore:
         parts = self.get_parts(digest)
         return None if parts is None else parts[0]
 
-    def put_parts(self, digest: bytes, parts: Sequence) -> None:
-        """File a state, one array a part, under one digest."""
+    def make_room(self, size: int) -> None:
+        """Evict, least recently used first, until ``size`` more fits."""
+        with self._lock:
+            gone = self._evict_locked(size)
+        self._evicted(gone)
+
+    def put_parts(self, digest: bytes, parts: Sequence) -> bool:
+        """File an entry, one part a tuple entry, under one digest. Returns
+        whether it is kept: ``False`` when the digest is kept already (that
+        entry is now the most recently used) or the entry alone outgrows
+        the budget — what was handed over is then the caller's."""
         parts = tuple(parts)
         size = self._size(parts)
-        evicted = 0
         with self._lock:
             if digest in self._kept:
                 self._kept.move_to_end(digest)
-                return
+                return False
             if size > self.budget:
-                return
-            while self._bytes + size > self.budget:
-                _, old = self._kept.popitem(last=False)
-                self._bytes -= self._size(old)
-                evicted += 1
+                return False
+            gone = self._evict_locked(size)
             self._kept[digest] = parts
             self._bytes += size
             total = self._bytes
-        if evicted:
-            _obs.inc("serving.state.snapshot_evictions_total",
-                     float(evicted))
-        _obs.set_gauge("serving.state.snapshot_bytes", float(total))
+        self._evicted(gone)
+        _obs.set_gauge(self._gauge, float(total))
+        return True
+
+    def _evict_locked(self, size: int) -> List[tuple]:
+        gone = []
+        while self._kept and self._bytes + size > self.budget:
+            _, old = self._kept.popitem(last=False)
+            self._bytes -= self._size(old)
+            gone.append(old)
+        return gone
+
+    def _evicted(self, gone: List[tuple]) -> None:
+        if not gone:
+            return
+        _obs.inc(self._evictions, float(len(gone)))
+        _obs.set_gauge(self._gauge, float(self.nbytes))
+        if self._on_evict is not None:
+            for parts in gone:
+                self._on_evict(parts)
 
     @staticmethod
-    def _size(parts: tuple) -> int:
+    def _nbytes(parts: tuple) -> int:
         return sum(int(a.size) * a.dtype.itemsize for a in parts)
 
     def reset(self) -> None:
+        """Drop everything (``on_evict`` sees each entry go)."""
         with self._lock:
+            gone = list(self._kept.values())
             self._kept.clear()
             self._bytes = 0
-        _obs.set_gauge("serving.state.snapshot_bytes", 0.0)
+        _obs.set_gauge(self._gauge, 0.0)
+        if self._on_evict is not None:
+            for parts in gone:
+                self._on_evict(parts)

@@ -336,11 +336,14 @@ def test_qwen3_next_decode_program_compiles_with_no_copy_of_any_pool(
 
 # ---------------------------------------------------------------------------
 # ISSUE 35: the grouped paged-decode kernel walks a row's own page groups with
-# copies of its own out of the pool where it lies. Its three calls in the
-# serving cells, at their real shapes: Command A+'s full layer and window
-# layer (32 rows, 128 query heads on 8 KV heads of 128), Qwen3-Next's (64
-# rows, 16 on 2 of 256); a bf16 pool as the cells hold, int8 and float32 as
-# the tests do. The page buffer (2 x 8 pages of K and V) is 4 / 2 / 8 MiB.
+# copies of its own out of the pool where it lies. Its calls in the serving
+# cells, at their real shapes: Command A+'s full layer and window layer (32
+# rows, 128 query heads on 8 KV heads of 128), Qwen3-Next's (64 rows, 16 on 2
+# of 256), Mellum 2's full layer at 1576 table columns (a 100,864-token
+# context; the table is scalar-prefetched whole) and its window layer over a
+# pool with kept boundary pages (24 rows, 32 on 4 of 128); a bf16 pool as the
+# cells hold, int8 and float32 as the tests do. The page buffer (2 x 8 pages
+# of K and V) is 4 / 2 / 8 / 2 / 2 MiB.
 # ---------------------------------------------------------------------------
 
 ROW_WALK_CALLS = {
@@ -349,6 +352,8 @@ ROW_WALK_CALLS = {
     "command-a-full": (32, 8, 16, 128, 1, 6401, 200, None),
     "command-a-window": (32, 8, 16, 128, 3, 2113, 66, 4096),
     "qwen3-next": (64, 2, 8, 256, 2, 10753, 168, None),
+    "mellum-full": (24, 4, 8, 128, 1, 24 * 1576 + 1, 1576, None),
+    "mellum-window": (24, 4, 8, 128, 3, 24 * 18 + 1 + 1024, 18, 1024),
 }
 
 
@@ -462,6 +467,51 @@ def test_command_a_plus_decode_program_walks_rows_with_no_copy_of_a_pool(
     assert compiled.memory_analysis().temp_size_in_bytes < pools // 4
     # the window pool's table is the compact one: 66 columns, not 128
     assert eng.programs.table_width(eng.kvs[1], True) == 66
+
+
+def test_mellum_decode_program_walks_rows_with_no_copy_of_a_pool(
+        one_chip, monkeypatch):
+    """Mellum 2's decode program at the published attention geometry (32
+    query heads on 4 KV heads of 128, pages of 64, window 1024, YaRN on the
+    full layer) over pages by layer kind with window pages kept at prefix
+    boundaries: all four layers take the row-walking kernel, the experts
+    the grouped matmuls, and neither kind's pool is copied."""
+    from paddle_tpu.models.mellum import MellumConfig, MellumForCausalLM
+    monkeypatch.setattr(pa, "kernel_interpret", lambda: False)
+    paddle.seed(13)
+    cfg = MellumConfig(vocab_size=256, hidden_size=128,
+                       moe_intermediate_size=128, num_hidden_layers=4,
+                       num_experts=8, num_experts_per_tok=2,
+                       dtype="bfloat16", max_position_embeddings=8192)
+    model = MellumForCausalLM(cfg)
+    model.eval()
+    eng = serving.Engine(*model.serving_callables(8192),
+                         serving.ServingConfig(
+        num_layers=4, num_heads=4, head_dim=128, max_len=8192, max_batch=8,
+        buckets=(8,), page_size=PAGE, compute_dtype="bfloat16",
+        kv_dtype="bf16", layer_kinds=cfg.layer_kinds, window=1024,
+        window_boundary_tokens=2048, window_boundary_pages=64,
+        paged_attention="on"))
+    assert eng._paged_path == "kernel" and len(eng.kvs) == 2
+    obs.enable()
+    paddle.set_flags({"FLAGS_to_static_capture_lowered": True})
+    try:
+        with pytest.raises(Exception, match="interpret mode"):
+            eng.programs.warm(buckets=[8])
+        walked = obs.snapshot()["serving.paged_attention_row_walk_layers"]
+        compiled = _compiled_for_chip(eng.programs.decode_program, one_chip)
+    finally:
+        paddle.set_flags({"FLAGS_to_static_capture_lowered": False})
+        obs.disable()
+    assert walked == 4
+    text = compiled.as_text()
+    assert text.count("paged_attention_decode") >= 4 and "ragged-dot" in text
+    for kv in eng.kvs:
+        assert pool_copies(text, kv.pool.shape) == 0, kv.config.kind
+    # the window pool's decode table is the compact one: 18 columns; the
+    # pool itself has every slot's 18 pages, the budget and the scratch page
+    assert eng.programs.table_width(eng.kvs[1], True) == 18
+    assert eng.kvs[1].pool.shape[0] == 8 * 18 + 64 + 1
 
 
 # ---------------------------------------------------------------------------
